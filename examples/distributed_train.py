@@ -18,8 +18,9 @@ import os
 import sys
 
 if __name__ == "__main__" and "--launch" not in sys.argv:
-    # worker processes: simulate 4 chips per host on CPU
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # worker processes: simulate 4 chips per host on CPU (this demo never
+    # runs on an accelerator, whatever the machine has)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=4")
 
@@ -29,8 +30,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def worker() -> None:
     import jax
     import numpy as np
-
-    jax.config.update("jax_platforms", "cpu")
 
     from sparkdl_tpu.core.mesh import MeshConfig, make_mesh
     from sparkdl_tpu.engine.dataframe import DataFrame
@@ -46,7 +45,8 @@ def worker() -> None:
     # below runs host-local, so none is needed here
     _ = make_mesh(MeshConfig(data=jax.device_count()))
     print(f"[host {pid}] joined: {n} processes, "
-          f"{jax.device_count()} global devices")
+          f"{jax.device_count()} global {jax.default_backend()} devices "
+          "(simulated hosts; an example, not a measurement)")
 
     # identical frame on every host (real jobs read shared storage)
     rng = np.random.default_rng(0)

@@ -5,7 +5,9 @@ DataFrame, featurize with a pre-trained named CNN, train a logistic
 regression on the features — as ONE Pipeline — then serve the model as
 a SQL UDF over a temp view.
 
-Run (CPU works; a TPU chip makes featurize fast):
+Run (an example, not a measurement: it runs wherever JAX puts it, prints
+which device that was, and times nothing — `chip_smoke.py` and `bench.py`
+are the paths that refuse the CPU):
     python examples/flagship_pipeline.py
 """
 
@@ -43,6 +45,11 @@ def make_dataset(directory: str, n: int = 32):
 
 
 def main() -> None:
+    import jax
+
+    device = jax.devices()[0]
+    print(f"running on {device.platform} ({device.device_kind}) — an "
+          "example, not a measurement")
     with tempfile.TemporaryDirectory() as d:
         labels = make_dataset(d)
 
@@ -85,7 +92,13 @@ def main() -> None:
 
         # 5. cluster inference plane (docs/DISTRIBUTED.md "Cluster
         #    inference"): the same transform fanned across 2 worker
-        #    processes — bit-identical output, one merged report
+        #    processes — bit-identical output, one merged report. A TPU
+        #    chip belongs to one process and this one holds it, so the
+        #    plane does not run on a TPU backend yet.
+        if device.platform == "tpu":
+            print("cluster: skipped — the cluster plane does not run on "
+                  "a TPU backend yet (one process per chip)")
+            return
         from sparkdl_tpu.cluster import router as cluster_router
         from sparkdl_tpu.engine import EngineConfig
 

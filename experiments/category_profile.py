@@ -2,7 +2,7 @@
 
 Companion to fusion_profile.py (which prints the top-20 individual
 fusions): sums duration / FLOPs / bytes over ALL fusions per category,
-giving the one-line roofline attribution per model the BASELINE.md zoo
+giving the one-line roofline attribution per model the earlier zoo
 footnote needs (VERDICT r4 #2).
 
 Run: python experiments/category_profile.py <trace_dir> [batches=8]

@@ -13,6 +13,7 @@ lazily on attribute access so that ``import sparkdl_tpu`` stays cheap.
 """
 
 import logging as _logging
+import os as _os
 
 from sparkdl_tpu.version import __version__
 
@@ -29,35 +30,62 @@ _logging.getLogger(__name__).addHandler(_logging.NullHandler())
 # JAX persistent compilation cache (docs/PERF.md "Cross-partition
 # coalescing": the bucket ladder can compile a handful of programs per
 # model; a warm on-disk cache makes every process after the first
-# compile-free). Opt-in via SPARKDL_COMPILE_CACHE_DIR so the default
-# `import sparkdl_tpu` stays jax-import free and cheap.
-COMPILE_CACHE_DIR_ENV = "SPARKDL_COMPILE_CACHE_DIR"
+# compile-free). The directory is placed from OUTSIDE with JAX's own
+# variable; unset, it is one fixed git-ignored path in the checkout
+# (the path is part of the cache key, so it must never move).
+COMPILE_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+_IN_CHECKOUT_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+    ".jax_cache")
+# zeroed thresholds: even the small bucket-ladder programs are cached
+_CACHE_THRESHOLDS = {
+    "jax_persistent_cache_min_compile_time_secs": 0.0,
+    "jax_persistent_cache_min_entry_size_bytes": -1,
+}
 
 
-def _configure_compile_cache(cache_dir=None):
-    """Point JAX's persistent compilation cache at ``cache_dir`` (default:
-    ``$SPARKDL_COMPILE_CACHE_DIR``). Returns True when configured. The
-    thresholds are zeroed so even the small bucket-ladder programs are
-    cached; first-launch compiles are visible as ``sparkdl.compile``
-    spans in the telemetry run report either way."""
-    import os as _os
+def _compile_cache_dir():
+    """Where JAX's persistent compilation cache lives:
+    ``$JAX_COMPILATION_CACHE_DIR`` when set, else the fixed in-checkout
+    path."""
+    return _os.environ.get(COMPILE_CACHE_DIR_ENV) or _IN_CHECKOUT_CACHE_DIR
 
-    cache_dir = (cache_dir if cache_dir is not None
-                 else _os.environ.get(COMPILE_CACHE_DIR_ENV))
-    if not cache_dir:
-        return False
-    try:
+
+def _sidecar_store_dir():
+    """Directory of the kernel-verdict and bucket-ladder stores: the
+    compile cache's, but ONLY when ``$JAX_COMPILATION_CACHE_DIR`` names
+    it — under the in-checkout default they stay in-process (None)."""
+    named = _os.environ.get(COMPILE_CACHE_DIR_ENV)
+    if not named or _os.path.abspath(named) == _IN_CHECKOUT_CACHE_DIR:
+        return None
+    return named
+
+
+def _configure_compile_cache():
+    """Place JAX's persistent compilation cache at
+    :func:`_compile_cache_dir` without importing JAX: the settings go
+    into the environment, which JAX reads when it is first imported
+    (and which spawned workers inherit); only when JAX is ALREADY
+    imported are the ones the environment did not carry applied with
+    ``jax.config.update``. A directory named from outside is never
+    overridden in code. First-launch compiles are visible as
+    ``sparkdl.compile`` spans in the telemetry run report either
+    way."""
+    import sys as _sys
+
+    late = {}
+    if not _os.environ.get(COMPILE_CACHE_DIR_ENV):
+        _os.environ[COMPILE_CACHE_DIR_ENV] = _IN_CHECKOUT_CACHE_DIR
+        late["jax_compilation_cache_dir"] = _IN_CHECKOUT_CACHE_DIR
+    for flag, value in _CACHE_THRESHOLDS.items():
+        if flag.upper() not in _os.environ:
+            _os.environ[flag.upper()] = str(value)
+            late[flag] = value
+    if late and "jax" in _sys.modules:
         import jax as _jax
 
-        _jax.config.update("jax_compilation_cache_dir", cache_dir)
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        _jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception as e:  # pragma: no cover - jax version drift
-        _logging.getLogger(__name__).warning(
-            "could not enable the persistent compilation cache at %r: %s",
-            cache_dir, e)
-        return False
-    return True
+        for flag, value in late.items():
+            _jax.config.update(flag, value)
 
 
 _configure_compile_cache()

@@ -84,6 +84,10 @@ _MP_CTX = mp.get_context("spawn")
 # latency) and worker join budget at close.
 _WAIT_POLL_S = 0.05
 _JOIN_TIMEOUT_S = 10.0
+# How long the constructor waits for every initial worker's boot outcome
+# (interpreter start + imports + backend bring-up) before it gives up,
+# reaps them and raises.
+_BOOT_WAIT_S = 120.0
 # Autoscaler thread tick, and the grace a draining worker gets to finish
 # its in-flight tasks before it is torn down hard (DrainTimeout: its
 # tasks then take the ordinary lost-worker re-dispatch path).
@@ -117,6 +121,38 @@ def _rebuild_error(type_name: str, msg: str, kind: str) -> BaseException:
     err = RuntimeError(f"{type_name}: {msg} (from cluster worker)")
     err.failure_kind = kind  # type: ignore[attr-defined]
     return err
+
+
+def _boot_failure(message: str) -> RuntimeError:
+    """The error a cluster that cannot boot raises from the call that
+    armed it. FATAL: the same configuration fails the same way, so
+    neither the supervisor nor a gang restart may replay it."""
+    err = RuntimeError(message)
+    err.failure_kind = resilience.FATAL  # type: ignore[attr-defined]
+    return err
+
+
+def _configured_platform() -> Tuple[Optional[str], bool]:
+    """``(platform, backend_up)``: the platform workers are pinned to and
+    whether THIS process has already initialised its JAX backend — read
+    WITHOUT initialising it (``jax.default_backend()`` on a cold process
+    would take the TPU away from every worker; the same reason
+    ``train/runner.py`` reads the configuration instead). ``None``: not
+    chosen yet — a worker then resolves the same default this process
+    would."""
+    import jax
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        return jax.default_backend(), True
+    return (jax.config.jax_platforms or None), False
+
+
+_ONE_PROCESS_PER_CHIP = (
+    "A TPU chip belongs to one process at a time, and the cluster plane "
+    "does not yet give each worker a chip of its own (ROADMAP.md: "
+    "'cluster plane, one process per chip'), so it does not run on a TPU "
+    "backend: use cluster_workers=0 there.")
 
 
 class _Task:
@@ -156,7 +192,7 @@ class _Worker:
     __slots__ = ("wid", "proc", "queue", "conn", "clock", "assigned",
                  "tokens", "outstanding_rows", "finished", "lost",
                  "draining", "drain_started", "drain_reason", "pilled",
-                 "serving_assigned")
+                 "serving_assigned", "boot_error", "platform")
 
     def __init__(self, wid: int, proc: Any, queue: Any, conn: Any,
                  clock: Any) -> None:
@@ -175,6 +211,10 @@ class _Worker:
         self.outstanding_rows = 0
         self.finished = False  # final snapshot received
         self.lost = False      # died without a final snapshot
+        # boot outcome (the worker's first message): the platform its
+        # backend landed on, or the "Type: message" that stopped it
+        self.platform: Optional[str] = None
+        self.boot_error: Optional[str] = None
         # WorkerDraining state: no new dispatches; in-flight tasks run
         # to completion, then the router pills the worker, which ships
         # its final snapshot and exits cleanly (never a worker-lost
@@ -184,6 +224,10 @@ class _Worker:
         self.drain_started = 0.0
         self.drain_reason = ""
         self.pilled = False    # poison pill already sent
+
+    @property
+    def booted(self) -> bool:
+        return self.platform is not None
 
 
 class ClusterRouter:
@@ -224,12 +268,16 @@ class ClusterRouter:
         self.run_id = run_id or (
             tel.run_id if tel is not None
             else f"cluster-{os.getpid():x}-{next(_run_ids):04x}")
-        # workers must land on the coordinator's RESOLVED backend and
-        # config — a spawned interpreter re-derives both from scratch
-        # otherwise (env vars, sitecustomize), and "cluster on" must
-        # not change what runs
-        import jax
-
+        # workers must land on the coordinator's platform and config — a
+        # spawned interpreter re-derives both from scratch otherwise, and
+        # "cluster on" must not change what runs
+        self._platform, backend_up = _configured_platform()
+        if backend_up and self._platform == "tpu":
+            raise _boot_failure(
+                f"cluster_workers={self.workers} cannot start: this "
+                "(coordinator) process has already initialised JAX and "
+                "holds the TPU, so no cluster worker could bring up a "
+                f"backend of its own. {_ONE_PROCESS_PER_CHIP}")
         from sparkdl_tpu.engine.dataframe import EngineConfig
 
         config = EngineConfig.snapshot()
@@ -247,7 +295,7 @@ class ClusterRouter:
         # under it instead of dangling off the worker's private root —
         # None (tracing off) keeps the worker's trace fully local
         self._boot_blob = cloudpickle.dumps(
-            {"config": config, "platform": jax.default_backend(),
+            {"config": config, "platform": self._platform,
              "root_ctx": tel.root_context if tel is not None else None,
              # exemplar reservoirs are per-registry opt-in: workers arm
              # the SAME k as the coordinator, or federated breach events
@@ -255,6 +303,9 @@ class ClusterRouter:
              "exemplar_k": (tel.metrics.exemplar_k
                             if tel is not None else 0)})
         self._lock = threading.Lock()
+        # boot outcomes are worker state under the router lock; the
+        # constructor's bounded boot wait sleeps on this condition
+        self._boot_cond = threading.Condition(self._lock)
         # the attached cluster serving handler (serving/cluster.py), or
         # None while the serving plane is off — srv_* replies, precise
         # worker-loss request sets, and post-spawn replica top-ups route
@@ -342,12 +393,69 @@ class ClusterRouter:
             target=self._collect, name="sparkdl-cluster-collector",
             daemon=True)
         self._collector.start()
+        self._await_boot()
         self._gauge_workers_locked_free()
         if self._autoscale:
             self._autoscale_thread = threading.Thread(
                 target=self._autoscale_loop,
                 name="sparkdl-cluster-autoscaler", daemon=True)
             self._autoscale_thread.start()
+
+    def _await_boot(self) -> None:
+        """Bounded wait for every initial worker's boot outcome. All
+        booted on ONE platform: return. Any worker that reported
+        ``boot_err``, died before reporting, stayed silent past
+        ``_BOOT_WAIT_S``, or landed somewhere else than its siblings:
+        reap EVERY worker (no child is left behind, nothing is
+        respawned) and raise one FATAL error from the constructor —
+        i.e. from the call that armed the cluster."""
+        deadline = time.monotonic() + _BOOT_WAIT_S
+        with self._boot_cond:
+            while True:
+                failed = [w for w in self._workers
+                          if w.boot_error is not None
+                          or (w.lost and not w.booted)]
+                if failed or all(w.booted for w in self._workers):
+                    break
+                remaining = deadline - time.monotonic()
+                if remaining <= 0:
+                    break
+                self._boot_cond.wait(remaining)
+            timed_out = deadline - time.monotonic() <= 0
+            pending = [w for w in self._workers
+                       if not w.booted and not w.lost
+                       and w.boot_error is None]
+            landed = {w.proc.name: w.platform for w in self._workers
+                      if w.booted}
+        # with no platform configured a JAX that cannot have the TPU
+        # falls back to the CPU in silence: a split landing is a failure
+        split = len(set(landed.values())) > 1
+        if not failed and not pending and not split:
+            return
+        for worker in pending:
+            # still in (or stuck in) backend bring-up: it may never read
+            # a pill
+            worker.proc.kill()
+        self.close()
+        causes = [
+            f"{w.proc.name} could not bring up its JAX backend "
+            f"({w.boot_error})" if w.boot_error is not None else
+            f"{w.proc.name} died during boot (exit code {w.proc.exitcode})"
+            for w in failed]
+        causes += [f"{w.proc.name} reported no backend within "
+                   f"{_BOOT_WAIT_S:.0f}s" if timed_out else
+                   f"{w.proc.name} was still booting (killed)"
+                   for w in pending]
+        if split:
+            causes.append(
+                f"the workers landed on different platforms {landed} — one "
+                "that could not have the accelerator fell back")
+        story = (f"cluster_workers={self.workers} failed to boot on platform "
+                 f"{self._platform or 'default'!r}: " + "; ".join(causes)
+                 + ".")
+        if "tpu" in story.lower():
+            story += f" {_ONE_PROCESS_PER_CHIP}"
+        raise _boot_failure(story)
 
     def _spawn(self, index: int) -> _Worker:
         queue = _MP_CTX.Queue()
@@ -705,6 +813,21 @@ class ClusterRouter:
             if handler is not None:
                 handler.on_message(worker.wid, msg)
             return
+        if kind in ("booted", "boot_err"):
+            with self._lock:
+                if kind == "booted":
+                    worker.platform = msg[2]
+                else:
+                    worker.boot_error = f"{msg[2]}: {msg[3]}"
+                self._boot_cond.notify_all()
+            if kind == "boot_err":
+                # the initial set raises this from the constructor; for a
+                # later spawn (autoscale, preemption replacement) this
+                # line is where it surfaces — its EOF then retires the
+                # worker as lost, and nothing respawns it
+                logger.error("cluster worker %s failed to boot: %s",
+                             worker.proc.name, worker.boot_error)
+            return
         if kind == "frame":
             # windowed metrics delta frame (the federation cadence):
             # fold it, then judge the merged fold
@@ -875,6 +998,7 @@ class ClusterRouter:
             if not worker.finished and not self._closed:
                 lost = True
                 worker.lost = True
+                self._boot_cond.notify_all()  # died before its boot outcome?
                 # the precise serving loss set: exactly the request ids
                 # awaiting an answer from this worker — handed to the
                 # serving handler (outside the lock) for deadline-bounded
@@ -1180,7 +1304,10 @@ class ClusterRouter:
             self._note_autoscale_event("drain_timeout",
                                        worker=w.proc.name,
                                        error="DrainTimeout")
-            w.proc.terminate()  # EOF reap marks it lost + re-dispatches
+            # SIGKILL, not SIGTERM: a booted worker handles SIGTERM as a
+            # preemption NOTICE and keeps running — the opposite of a
+            # hard teardown. EOF reap marks it lost + re-dispatches.
+            w.proc.kill()
         if now - self._last_scale_ts < EngineConfig.autoscale_cooldown_s:
             return None
         rows_per = (outstanding / n_live) if n_live else float("inf")
@@ -1254,7 +1381,7 @@ class ClusterRouter:
         for worker in workers:
             worker.proc.join(timeout=_JOIN_TIMEOUT_S)
             if worker.proc.is_alive():  # pragma: no cover - wedged worker
-                worker.proc.terminate()
+                worker.proc.kill()  # SIGTERM is only a notice to a worker
                 worker.proc.join(timeout=_JOIN_TIMEOUT_S)
             # a dead worker never consumed its pill; don't let the
             # queue's feeder thread block interpreter exit on it

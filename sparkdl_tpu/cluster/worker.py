@@ -21,9 +21,16 @@ canonicalization (``durability.ops_token``), then partitions reference
 the token — model weights cross the pipe once, not per partition.
 
 Boot order matters: the jax platform is pinned from the coordinator's
-resolved backend BEFORE any backend initialization (a spawned
-interpreter re-runs ``sitecustomize``/env resolution from scratch —
-the coordinator's choice must win), then the coordinator's
+configured platform BEFORE any backend initialization (a spawned
+interpreter re-runs env resolution from scratch — the coordinator's
+choice must win), the backend is then brought up AT BOOT, and the
+outcome is the worker's first message: ``("booted", worker_id,
+platform)``, or
+``("boot_err", ...)`` typed and FATAL-classified, after which the worker
+exits. The router waits a bounded time for it, so a worker that cannot
+get a device (a TPU chip belongs to ONE process; this plane gives no
+worker a chip of its own yet) fails the call that armed the cluster
+instead of hanging its first task. Then the coordinator's
 ``EngineConfig`` snapshot is restored with the cluster/durability/
 decode-pool knobs forced off (a worker must never recurse into
 another cluster, journal coordinator-owned state, or nest decode
@@ -52,6 +59,9 @@ Protocol (parent -> worker queue):
       a merged partial trace
   ``None``                                      poison pill
 (worker -> parent pipe):
+  ``("booted", worker_id, platform)`` /
+  ``("boot_err", worker_id, type, msg, kind)``
+      the boot outcome, always the first message (see above)
   ``("ok", task_id, ipc, meta)`` / ``("err", task_id, type, msg, kind)``
   ``("draining", worker_id)``                   SIGTERM-with-warning
       received (spot-VM preemption): the router stops dispatching here
@@ -139,18 +149,36 @@ def _worker_main(worker_id: int, tasks: Any, conn: Any, owner_pid: int,
     import cloudpickle
 
     boot = cloudpickle.loads(boot_blob)
-    # pin the platform BEFORE anything can initialize the backend: the
-    # spawned interpreter re-resolves platform selection from scratch
-    # and must land where the coordinator landed
-    import jax
+    from sparkdl_tpu.core import resilience
 
-    jax.config.update("jax_platforms", boot["platform"])
-    from sparkdl_tpu.cluster import aggregate
-    from sparkdl_tpu.core import (executor, health, profiling, resilience,
-                                  telemetry)
-    from sparkdl_tpu.engine.dataframe import EngineConfig
+    try:
+        # pin the platform BEFORE anything can initialize the backend:
+        # the spawned interpreter re-resolves platform selection from
+        # scratch and must land where the coordinator landed (None: the
+        # coordinator had not chosen — same environment, same default)
+        import jax
 
-    EngineConfig.restore(boot["config"])
+        if boot["platform"]:
+            jax.config.update("jax_platforms", boot["platform"])
+        # bring the backend up NOW, inside the router's bounded boot
+        # wait — not lazily under the first task, where a device this
+        # process cannot have would surface as a hang or a retry loop
+        jax.devices()
+        from sparkdl_tpu.cluster import aggregate
+        from sparkdl_tpu.core import executor, health, profiling, telemetry
+        from sparkdl_tpu.engine.dataframe import EngineConfig
+
+        EngineConfig.restore(boot["config"])
+    # sparkdl: allow(broad-retry): not a retry — whatever stops the boot ships typed to the coordinator, which raises it from the call that armed the cluster
+    except Exception as e:  # noqa: BLE001 - re-raised parent-side
+        conn.send(("boot_err", worker_id, type(e).__name__, str(e),
+                   resilience.FATAL))
+        conn.close()
+        return
+    # the platform it LANDED on rides along: with none configured, a JAX
+    # that cannot get the TPU falls back to the CPU without a word, and
+    # the router refuses a worker set that did not all land in one place
+    conn.send(("booted", worker_id, jax.default_backend()))
     name = f"sparkdl-cluster-{worker_id}"
     # SIGTERM-with-warning (spot-VM preemption): the handler ONLY sets a
     # flag — touching the result pipe from a signal frame could tear a
@@ -190,7 +218,11 @@ def _worker_main(worker_id: int, tasks: Any, conn: Any, owner_pid: int,
     # byte-identical to the pre-federation protocol
     fed_s = EngineConfig.cluster_federation_s
     frame_seq = 0
-    next_frame = (time.monotonic() + fed_s) if fed_s else None
+    # armed by the first message: a worker idling through the router's
+    # boot wait has nothing to federate, and frames that started before
+    # its first (slow: the op chain unpickles) message would only make it
+    # look stale
+    next_frame = None
     with monitor, telemetry.Telemetry(
             name=name, out_dir="", run_id=run_id,
             process_scope=f"w{worker_id}",
@@ -241,6 +273,8 @@ def _worker_main(worker_id: int, tasks: Any, conn: Any, owner_pid: int,
                 continue
             if msg is None:
                 break
+            if fed_s and next_frame is None:
+                next_frame = time.monotonic() + fed_s
             if msg[0] == "ops":
                 _, token, blob = msg
                 ops_cache[token] = cloudpickle.loads(blob)

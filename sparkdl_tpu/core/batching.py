@@ -20,18 +20,13 @@ from sparkdl_tpu.core import health, resilience, telemetry
 
 logger = logging.getLogger(__name__)
 
-# Transfer economics of the staging path (r3, measured with true barriers —
-# scalar fetched through a jitted reduction; block_until_ready is NOT a
-# reliable barrier here, see core/profiling.py):
-#   host→device ~47 MB/s regardless of chunking; device→host ~100 ms fixed
-#   latency + ~92 MB/s. A chunked device_put + on-device reassembly was
-#   tried and measured NO faster (the apparent 1.5 GB/s for small puts was
-#   async dispatch, not completed DMA). The levers that DO work: transfer
-#   uint8 not float32 (4x), resize to the model input size on the host
-#   BEFORE transfer when that shrinks bytes (native batch resizer), and
-#   fetch each partition's outputs as ONE device-concatenated array
-#   instead of one fetch per bucket (saves the ~100 ms fixed latency per
-#   batch).
+# Levers of the staging path (transfer rates and fetch latency are not
+# measured on the current machine; the behaviour below is kept until they
+# are): transfer uint8 not float32 (4x fewer bytes), resize to the model
+# input size on the host BEFORE transfer when that shrinks bytes (native
+# batch resizer), and fetch each partition's outputs as ONE
+# device-concatenated array instead of one fetch per bucket (one fixed
+# fetch latency per partition, not per batch).
 
 
 def pad_batch(arr: np.ndarray, batch_size: int) -> Tuple[np.ndarray, int]:
@@ -298,18 +293,18 @@ _LADDER_STORE_BASENAME = "sparkdl_bucket_ladders.json"
 
 def ladder_store_path() -> Optional[str]:
     """Learned-ladder persistence file, beside the persistent compilation
-    cache (``$SPARKDL_COMPILE_CACHE_DIR``): a warm process reloads the
-    tuned ladder together with the compiled programs it selected, so the
-    retune (and its compiles) are paid once per cluster, not per process.
-    None when the cache dir is not configured (no persistence)."""
+    cache when ``$JAX_COMPILATION_CACHE_DIR`` names it: a warm process
+    reloads the tuned ladder together with the compiled programs it
+    selected, so the retune (and its compiles) are paid once per cluster,
+    not per process. None otherwise (no persistence)."""
     import os
 
-    from sparkdl_tpu import COMPILE_CACHE_DIR_ENV
+    from sparkdl_tpu import _sidecar_store_dir
 
-    cache_dir = os.environ.get(COMPILE_CACHE_DIR_ENV)
-    if not cache_dir:
+    store_dir = _sidecar_store_dir()
+    if store_dir is None:
         return None
-    return os.path.join(cache_dir, _LADDER_STORE_BASENAME)
+    return os.path.join(store_dir, _LADDER_STORE_BASENAME)
 
 
 def _pow2_ladder(batch_size: int, multiple: int, min_bucket: int
@@ -628,10 +623,10 @@ def run_batched(fn: Callable, tree, batch_size: int,
     chunk k with the staging of chunk k+1: all chunks are dispatched
     before blocking on any result, and the per-bucket outputs are
     concatenated ON DEVICE so the host pays ONE device→host fetch per
-    leaf per call instead of one ~100 ms round-trip per bucket. Pad rows
+    leaf per call instead of one fetch per bucket. Pad rows
     of a single-bucket call are sliced off ON DEVICE before that fetch —
     a small tail-bucket partition transfers its valid rows only, not up
-    to 2× of them at the ~92 MB/s D2H link. ``multiple``: bucket-size
+    to 2× of them. ``multiple``: bucket-size
     divisibility constraint (mesh data axis).
 
     Per-chunk failures are classified (core.resilience): transient errors
@@ -677,8 +672,8 @@ def run_batched(fn: Callable, tree, batch_size: int,
         leaf_per_batch = [f[0][j] for f in flat_outs]
         if len(leaf_per_batch) == 1:
             # slice pad rows off ON DEVICE before the fetch: a tail-bucket
-            # partition transfers only its valid rows over the ~92 MB/s
-            # D2H link instead of the full padded bucket (ISSUE 3)
+            # partition transfers only its valid rows instead of the full
+            # padded bucket (ISSUE 3)
             leaf = leaf_per_batch[0]
             if valids[0] < leaf.shape[0]:
                 leaf = leaf[:valids[0]]

@@ -26,7 +26,7 @@ production port plus the machinery that makes shipping them SAFE:
   :func:`ensure_autotuned` — hooked into ``ModelFunction``'s
   first-launch-of-a-shape path, so shootouts run at the deployment's
   actual bucket rungs, before the shape's first trace — and persist
-  beside the compile cache (``$SPARKDL_COMPILE_CACHE_DIR/
+  beside the compile cache (``$JAX_COMPILATION_CACHE_DIR/
   sparkdl_kernel_verdicts.json``, atomic replace, versioned): a losing
   kernel is never re-auditioned every boot, but because the batch
   dimension is part of the key, a bucket-ladder retune (new rungs →
@@ -42,7 +42,9 @@ never imported; subprocess-pinned), ``"autotune"`` (default),
 with :data:`INTERPRET` to exercise kernel numerics on CPU).
 
 Telemetry: ``sparkdl.kernel.autotune_s`` histogram per shootout,
-``sparkdl.kernel.adopted``/``rejected`` counters. docs/PERF.md "Fused
+``sparkdl.kernel.adopted``/``rejected`` counters, and
+``sparkdl.kernel.audition_error`` for an audition that raised where
+Mosaic lowers (never a clean rejection, never persisted). docs/PERF.md "Fused
 kernels & AOT warmup" is the operator story; the ``kernel-gate``
 analyzer rule keeps raw ``pallas_call``/kernel entry points from
 bypassing this registry anywhere else in the tree.
@@ -95,7 +97,7 @@ _BLOCK_LIMIT_BYTES = 1536 * 1024
 _WEIGHT_LIMIT_BYTES = 4 * 1024 * 1024
 
 _VERDICT_STORE_BASENAME = "sparkdl_kernel_verdicts.json"
-VERDICT_STORE_VERSION = 1
+VERDICT_STORE_VERSION = 2
 
 
 # ---------------------------------------------------------------------------
@@ -131,16 +133,16 @@ def _site_key(site: Site) -> str:
 
 def verdict_store_path() -> Optional[str]:
     """Verdict persistence file, beside the persistent compilation cache
-    (``$SPARKDL_COMPILE_CACHE_DIR``) — the same placement as the learned
-    bucket ladders: a warm process reloads the shootout outcomes
-    together with the compiled programs they selected. None when the
-    cache dir is not configured (verdicts stay in-process)."""
-    from sparkdl_tpu import COMPILE_CACHE_DIR_ENV
+    when ``$JAX_COMPILATION_CACHE_DIR`` names it — the same placement as
+    the learned bucket ladders: a warm process reloads the shootout
+    outcomes together with the compiled programs they selected. None
+    otherwise (verdicts stay in-process)."""
+    from sparkdl_tpu import _sidecar_store_dir
 
-    cache_dir = os.environ.get(COMPILE_CACHE_DIR_ENV)
-    if not cache_dir:
+    store_dir = _sidecar_store_dir()
+    if store_dir is None:
         return None
-    return os.path.join(cache_dir, _VERDICT_STORE_BASENAME)
+    return os.path.join(store_dir, _VERDICT_STORE_BASENAME)
 
 
 _verdicts: Dict[str, Dict[str, Any]] = {}
@@ -306,8 +308,13 @@ def ensure_autotuned(fn, x, model: str = "model") -> None:
         jax.eval_shape(fn, x)
     except Exception as e:  # sparkdl: allow(broad-retry): collection is
         # best-effort discovery — a model that cannot abstractly
-        # evaluate simply gets no kernels, never a broken launch
-        logger.debug("kernel site collection failed for %s: %s", model, e)
+        # evaluate simply gets no kernels, never a broken launch. On the
+        # TPU that silence would hide every kernel of the model, so there
+        # it is a WARNING.
+        logger.log(
+            logging.WARNING if jax.default_backend() == "tpu"
+            else logging.DEBUG,
+            "kernel site collection failed for %s: %s", model, e)
     finally:
         _collect.sites = prev
     for site in sorted(sites):
@@ -577,7 +584,12 @@ def _resize_matrix(src: int, dst: int) -> np.ndarray:
 
 
 def _preproc_kernel(x_ref, wh_ref, wwt_ref, o_ref):
-    x = x_ref[0].astype(jnp.float32)  # (H, W) — uint8 casts in VMEM
+    x = x_ref[0]  # (H, W)
+    if jnp.issubdtype(x.dtype, jnp.integer):
+        # Mosaic has no uint8 -> float32 cast; widening through int32
+        # first is exact and lowers (the v5e compile tests pin this)
+        x = x.astype(jnp.int32)
+    x = x.astype(jnp.float32)
     t = jnp.dot(wh_ref[:], x, preferred_element_type=jnp.float32)
     y = jnp.dot(t, wwt_ref[:], preferred_element_type=jnp.float32)
     o_ref[0] = y.astype(o_ref.dtype)
@@ -757,9 +769,9 @@ def _build_shootout(site: Site):
     if site.kernel == "preproc":
         b, h, w, c, th, tw = site.shape
         in_dt, out_dt = site.dtype.split("->")
-        x = rng.integers(0, 256, size=(b, h, w, c)).astype(in_dt) \
+        x = rng.integers(0, 256, size=(b, h, w, c), dtype=np.uint8) \
             if np.dtype(in_dt) == np.uint8 \
-            else rng.normal(size=(b, h, w, c)).astype(np.float32) \
+            else rng.standard_normal(size=(b, h, w, c), dtype=np.float32) \
             .astype(in_dt)
         return (lambda a: preproc_resize(a, (th, tw), out_dt),
                 lambda a: xla_preproc(a, (th, tw), out_dt),
@@ -767,8 +779,8 @@ def _build_shootout(site: Site):
     b, h, w, cin, cout = site.shape
     dt = jnp.dtype(site.dtype.replace("pw1x1_relu", "")
                    if "->" not in site.dtype else "float32")
-    x = jnp.asarray(rng.normal(size=(b, h, w, cin)).astype(np.float32),
-                    dt)
+    x = jnp.asarray(
+        rng.standard_normal(size=(b, h, w, cin), dtype=np.float32), dt)
     gamma = jnp.asarray(
         (np.abs(rng.normal(size=cout)) + 0.5).astype(np.float32))
     beta = jnp.asarray((rng.normal(size=cout) * 0.1).astype(np.float32))
@@ -816,9 +828,15 @@ def _time_jitted(fn, x, repeats: int = 5, inner: int = 3) -> float:
 
 def _audition(site: Site) -> Dict[str, Any]:
     """One shootout: build both candidates at the site's shape, check
-    the numeric contract, time both, decide. Every exception path —
-    including "this backend has no Mosaic lowering" (the CPU test
-    suite) — lands as a clean rejected verdict, never a crash."""
+    the numeric contract, time both, decide. No exception path crashes
+    the caller (the XLA path always remains shippable), but only "this
+    backend has no Mosaic lowering" (the CPU test suite) is a clean
+    rejection. An audition that raises on a backend that DOES lower
+    Mosaic (TPU, interpret mode) — the chip's compiler refusing the
+    kernel, out of memory — carries ``error`` in its verdict, logs at
+    WARNING and counts under ``sparkdl.kernel.audition_error``:
+    :func:`ensure_verdict` never persists it, so a repaired kernel is
+    auditioned again by the next process."""
     t0 = time.perf_counter()
     verdict: Dict[str, Any] = {"adopted": False, "backend": _backend_tag()}
     try:
@@ -857,19 +875,29 @@ def _audition(site: Site) -> Dict[str, Any]:
             verdict["adopted"] = True
             verdict["reason"] = (f"{xla_s / max(pallas_s, 1e-12):.2f}x "
                                  "speedup, numerics in contract")
-    except Exception as e:  # sparkdl: allow(broad-retry): ANY audition
-        # failure (no Mosaic, lowering error, OOM) must become a clean
-        # rejected verdict — the XLA path always remains shippable
+    except _Unsupported as e:
         verdict["reason"] = f"{type(e).__name__}: {e}"
+    except Exception as e:  # sparkdl: allow(broad-retry): ANY audition
+        # failure (lowering error, OOM) must leave the XLA path shipping
+        # — but visibly: the backend claimed Mosaic support, so this is a
+        # broken kernel, not a lost shoot-out
+        verdict["reason"] = verdict["error"] = f"{type(e).__name__}: {e}"
     dt = time.perf_counter() - t0
     verdict["audition_s"] = dt
     if telemetry.active() is not None:
         telemetry.observe(telemetry.M_KERNEL_AUTOTUNE_S, dt)
-        telemetry.count(telemetry.M_KERNEL_ADOPTED if verdict["adopted"]
+        telemetry.count(telemetry.M_KERNEL_AUDITION_ERROR
+                        if "error" in verdict
+                        else telemetry.M_KERNEL_ADOPTED if verdict["adopted"]
                         else telemetry.M_KERNEL_REJECTED)
-    logger.info("kernel audition %s: %s — %s", _site_key(site),
-                "ADOPTED" if verdict["adopted"] else "rejected",
-                verdict["reason"])
+    if "error" in verdict:
+        logger.warning("kernel audition %s FAILED on a backend with Mosaic "
+                       "lowering (XLA path kept, verdict not persisted): %s",
+                       _site_key(site), verdict["error"])
+    else:
+        logger.info("kernel audition %s: %s — %s", _site_key(site),
+                    "ADOPTED" if verdict["adopted"] else "rejected",
+                    verdict["reason"])
     return verdict
 
 
@@ -898,7 +926,8 @@ def ensure_verdict(site: Site) -> Dict[str, Any]:
             verdict = _audition(site)
             with _verdict_lock:
                 _verdicts[key] = verdict
-            _persist_verdict(key, verdict)
+            if "error" not in verdict:
+                _persist_verdict(key, verdict)
             return verdict
         finally:
             with _verdict_lock:

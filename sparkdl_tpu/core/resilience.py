@@ -310,7 +310,9 @@ class RetryPolicy:
                    self.max_delay_s)
         if not self.jitter or base <= 0:
             return base
-        frac = random.Random((self.seed, attempt)).uniform(0.0, self.jitter)
+        # a str seed: Python 3.12's Random takes no tuple
+        frac = random.Random(f"{self.seed}:{attempt}").uniform(
+            0.0, self.jitter)
         return base * (1.0 + frac)
 
     def execute(self, fn: Callable[[], Any], *,

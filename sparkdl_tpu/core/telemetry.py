@@ -230,10 +230,14 @@ M_CLUSTER_DRAIN_S = "sparkdl.cluster.drain_s"          # histogram
 # Pallas kernel autotune (core/kernels.py, docs/PERF.md "Fused kernels &
 # AOT warmup"): one histogram observation per shootout (build + numeric
 # check + timing of both candidates) and one adopted/rejected counter
-# bump per settled verdict.
+# bump per settled verdict. An audition that RAISED on a backend with
+# Mosaic lowering (a kernel the chip's compiler refuses, OOM) is neither:
+# it counts under audition_error, so a broken kernel never hides among
+# the honest shoot-out losses.
 M_KERNEL_AUTOTUNE_S = "sparkdl.kernel.autotune_s"      # histogram
 M_KERNEL_ADOPTED = "sparkdl.kernel.adopted"            # counter
 M_KERNEL_REJECTED = "sparkdl.kernel.rejected"          # counter
+M_KERNEL_AUDITION_ERROR = "sparkdl.kernel.audition_error"  # counter
 # Per-tenant fair queueing (core/executor.py, docs/RESILIENCE.md): each
 # tenant's queue-wait histogram gets a per-tenant NAME (metrics carry no
 # labels), declared dynamically as "sparkdl.executor.queue_wait_s.<tenant>"
@@ -284,6 +288,7 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_KERNEL_AUTOTUNE_S: "histogram",
     M_KERNEL_ADOPTED: "counter",
     M_KERNEL_REJECTED: "counter",
+    M_KERNEL_AUDITION_ERROR: "counter",
 }
 
 CANONICAL_METRIC_NAMES = frozenset(CANONICAL_METRIC_KINDS)

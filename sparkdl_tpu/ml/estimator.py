@@ -7,7 +7,7 @@ The rebuild keeps the Estimator surface (``fit``, lazy ``fitMultiple``
 param-map search, ``CanLoadImage`` host decode) but trains with the
 Trainer's jitted step: forward/backward/update in one XLA program, data
 sharded over the mesh's ``data`` axis when a mesh is supplied (the
-MobileNetV2 fine-tune and ResNet50 DP configs in BASELINE.md).
+MobileNetV2 fine-tune and ResNet50 DP configs in BASELINE.json).
 """
 
 from __future__ import annotations

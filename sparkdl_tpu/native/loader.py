@@ -8,31 +8,53 @@ check :func:`available` and use the PIL path otherwise. Build with
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import threading
 from typing import Optional, Tuple
 
 import numpy as np
 
+logger = logging.getLogger(__name__)
+
 _LIB_NAME = "libsparkdl_image.so"
+# what the library is built from: one newer than the library makes it stale
+_SOURCES = ("image_loader.cc", "build.sh")
+_NATIVE_DIR = os.path.dirname(__file__)
 _lib = None
 _lib_lock = threading.Lock()
 _load_attempted = False
 
 
 def _library_path() -> str:
-    return os.path.join(os.path.dirname(__file__), _LIB_NAME)
+    return os.path.join(_NATIVE_DIR, _LIB_NAME)
+
+
+def _is_current(path: str) -> bool:
+    """True when the library exists and is no older than its sources. A
+    stale library (or one carried over from another machine's build of
+    older sources) counts as absent, so what loads is always built from
+    the files git tracks."""
+    try:
+        built = os.path.getmtime(path)
+        return all(os.path.getmtime(os.path.join(_NATIVE_DIR, src)) <= built
+                   for src in _SOURCES)
+    except OSError:
+        return False
 
 
 def _try_build() -> bool:
     """Best-effort one-shot build of the .so from the in-tree C++ source.
+    A failure is logged with the compiler's output (the caller then
+    decodes with PIL, which is slower — not something to learn from a
+    profile).
 
     Disable with SPARKDL_TPU_NO_NATIVE_BUILD=1 (tests of the PIL fallback,
     or environments without g++/libjpeg-dev).
     """
     if os.environ.get("SPARKDL_TPU_NO_NATIVE_BUILD"):
         return False
-    script = os.path.join(os.path.dirname(__file__), "build.sh")
+    script = os.path.join(_NATIVE_DIR, "build.sh")
     if not os.path.exists(script):
         return False
     import subprocess
@@ -40,8 +62,17 @@ def _try_build() -> bool:
     try:
         # sparkdl: allow(blocking-under-lock): one-shot native build on first load; _lib_lock exists to serialize exactly this
         subprocess.run(["bash", script], check=True, capture_output=True,
-                       timeout=120)
-    except Exception:
+                       text=True, timeout=120)
+    except subprocess.CalledProcessError as e:
+        logger.warning(
+            "native image loader build failed (exit %d); decoding falls "
+            "back to PIL. Compiler output:\n%s", e.returncode,
+            (e.stderr or "").strip())
+        return False
+    except (OSError, subprocess.TimeoutExpired) as e:
+        logger.warning(
+            "native image loader build could not run (%s: %s); decoding "
+            "falls back to PIL", type(e).__name__, e)
         return False
     return os.path.exists(_library_path())
 
@@ -53,12 +84,13 @@ def _load():
             return _lib
         _load_attempted = True
         path = _library_path()
-        if not os.path.exists(path):
-            if not _try_build():
-                return None
+        if not _is_current(path) and not _try_build():
+            return None
         try:
             lib = ctypes.CDLL(path)
-        except OSError:
+        except OSError as e:
+            logger.warning("native image loader at %s could not be loaded "
+                           "(%s); decoding falls back to PIL", path, e)
             return None
         # int sdl_decode(const uint8_t* data, size_t len, int target_h,
         #                int target_w, uint8_t* out, int* out_h, int* out_w,

@@ -41,23 +41,9 @@ def maybe_initialize_distributed() -> bool:
     coordinator = os.environ.get("SPARKDL_COORDINATOR")
     if not coordinator:
         return False
-    # read the configured platform WITHOUT touching jax.default_backend()
-    # — that would initialize the backend, which initialize() forbids
-    platforms = (jax.config.jax_platforms
-                 or os.environ.get("JAX_PLATFORMS", ""))
-    if platforms.split(",")[0].strip() == "cpu":
-        # CPU multi-process collectives need the gloo transport — the
-        # default XFER implementation raises INVALID_ARGUMENT
-        # ("Multiprocess computations aren't implemented on the CPU
-        # backend") the moment a psum crosses processes. Real TPU/GPU
-        # gangs never enter this branch.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        # sparkdl: allow(broad-retry): not a retry — config flag probe; jax versions without the flag fall through to the default transport
-        except Exception:  # noqa: BLE001
-            logger.warning("jax_cpu_collectives_implementation=gloo not "
-                           "available in this jax; CPU multi-process "
-                           "collectives may be unsupported")
+    # nothing here may touch jax.devices()/default_backend(): that would
+    # initialize the backend, which initialize() forbids (CPU gangs need
+    # no help — gloo is the installed JAX's default CPU collective)
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=int(os.environ["SPARKDL_NUM_PROCESSES"]),

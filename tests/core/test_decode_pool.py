@@ -390,3 +390,16 @@ def test_decode_without_scope_ships_no_spans():
             pass
     assert tel.tracer.spans(telemetry.SPAN_DECODE_CHUNK) == []
     assert tel.tracer.summary()["remote_adopted"] == 0
+
+
+def test_workers_never_load_jax():
+    """A TPU chip belongs to one process: a decode worker that imported
+    JAX would try to take it from the parent that holds it. After real
+    work, no worker has a jaxlib (or TPU) library mapped."""
+    with DecodePool(workers=2) as pool:
+        pool.decode(_blobs(8), target_size=(12, 12), channels=3)
+        for worker in pool._workers:
+            with open(f"/proc/{worker.proc.pid}/maps") as f:
+                mapped = f.read()
+            assert "numpy" in mapped  # the probe sees this process's libs
+            assert "jaxlib" not in mapped and "libtpu" not in mapped

@@ -151,6 +151,54 @@ def test_backend_tag_partitions_verdicts(tmp_path, monkeypatch):
     assert kernels.verdict_for(site) is None
 
 
+def test_failed_audition_is_visible_and_never_persisted(
+        tmp_path, monkeypatch, caplog):
+    """An audition that RAISES where Mosaic lowers (interpret mode stands
+    in for the TPU) is not a lost shoot-out: the verdict carries
+    ``error``, a WARNING is logged, its own counter moves, and the store
+    never sees it — so the next process auditions the repaired kernel."""
+    from sparkdl_tpu.core import telemetry
+
+    monkeypatch.setenv(COMPILE_CACHE_DIR_ENV, str(tmp_path))
+    kernels.INTERPRET = True
+
+    def broken(site):
+        raise NotImplementedError("Unsupported cast: uint8 -> float32")
+
+    monkeypatch.setattr(kernels, "_build_shootout", broken)
+    site = _site()
+    with telemetry.Telemetry(out_dir="") as tel, \
+            caplog.at_level("WARNING", logger=kernels.logger.name):
+        verdict = kernels.ensure_verdict(site)
+        counters = tel.metrics.snapshot()["counters"]
+    assert verdict["adopted"] is False
+    assert verdict["error"] == ("NotImplementedError: Unsupported cast: "
+                                "uint8 -> float32")
+    assert verdict["reason"] == verdict["error"]
+    assert counters.get(telemetry.M_KERNEL_AUDITION_ERROR) == 1
+    assert telemetry.M_KERNEL_REJECTED not in counters
+    assert any("FAILED" in r.getMessage() and r.levelname == "WARNING"
+               for r in caplog.records)
+    # settled for THIS process (no re-audition per launch) ...
+    assert kernels.verdict_for(site) == verdict
+    # ... but never written: a fresh process finds nothing and re-auditions
+    assert not (tmp_path / kernels._VERDICT_STORE_BASENAME).exists()
+    kernels.reset()
+    assert kernels.verdict_for(site) is None
+
+
+def test_unsupported_backend_rejection_is_clean_and_persisted(
+        tmp_path, monkeypatch):
+    """The CPU "no Mosaic lowering" case stays a quiet rejection: no
+    ``error``, and it persists like any other verdict."""
+    monkeypatch.setenv(COMPILE_CACHE_DIR_ENV, str(tmp_path))
+    verdict = kernels.ensure_verdict(_site())  # INTERPRET False, CPU
+    assert verdict["adopted"] is False and "error" not in verdict
+    assert "Mosaic" in verdict["reason"]
+    kernels.reset()
+    assert kernels.verdict_for(_site())["reason"] == verdict["reason"]
+
+
 # ---------------------------------------------------------------------------
 # Numeric contract: every fused kernel vs its XLA twin (interpreter mode)
 # ---------------------------------------------------------------------------
@@ -185,9 +233,12 @@ def test_fused_kernel_matches_xla_twin(site):
         assert err <= 1e-5, err
 
 
-@pytest.mark.parametrize("out_dtype,atol", [("float32", 1e-3),
-                                            ("bfloat16", 2.0)])
-def test_preproc_kernel_matches_resize(out_dtype, atol):
+@pytest.mark.parametrize("in_dtype,out_dtype,atol", [
+    ("uint8", "float32", 1e-3), ("uint8", "bfloat16", 2.0),
+    # a float source must NOT take the integer widening (it would
+    # truncate): the kernel widens through int32 only for integer inputs
+    ("float32", "float32", 1e-5)])
+def test_preproc_kernel_matches_resize(in_dtype, out_dtype, atol):
     """Fused cast+resize vs the jax.image.resize twin. Outputs live on
     the uint8 [0, 255] scale, so the bound is one bf16 ulp at 255 (2.0)
     rather than the O(1) BF16_TOLERANCE — the audition gate judges
@@ -195,7 +246,7 @@ def test_preproc_kernel_matches_resize(out_dtype, atol):
     is the conservative-by-design outcome."""
     kernels.INTERPRET = True
     site = kernels.Site("preproc", "matrix", (1, 8, 10, 3, 5, 6),
-                        f"uint8->{out_dtype}")
+                        f"{in_dtype}->{out_dtype}")
     pallas_fn, xla_fn, x = kernels._build_shootout(site)
     y_p = np.asarray(jnp.asarray(pallas_fn(x), jnp.float32))
     ref = np.asarray(kernels.xla_preproc(x, (5, 6), "float32"))

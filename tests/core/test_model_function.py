@@ -1,6 +1,11 @@
 """ModelFunction tests, incl. the ingestion format-matrix (SURVEY.md §4):
 one tiny model exported every way, identical results through each ctor."""
 
+import json
+import os
+import subprocess
+import sys
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -135,18 +140,74 @@ def test_first_launch_records_compile_span_once_per_shape():
     assert compiles[0]["attributes"]["model"] == "compile_span"
 
 
-def test_compile_cache_env_configures_jax(tmp_path, monkeypatch):
-    """ISSUE 5 satellite: SPARKDL_COMPILE_CACHE_DIR wires jax's persistent
-    compilation cache at package init."""
+def test_compile_cache_resolver_follows_jax_variable(tmp_path, monkeypatch):
+    """The cache is placed from outside with JAX's own variable; unset, it
+    is the one fixed path inside the checkout. The sidecar stores persist
+    only where the variable names the directory."""
     import sparkdl_tpu
 
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        monkeypatch.delenv(sparkdl_tpu.COMPILE_CACHE_DIR_ENV, raising=False)
-        assert sparkdl_tpu._configure_compile_cache() is False  # unset: no-op
-        target = str(tmp_path / "xla_cache")
-        monkeypatch.setenv(sparkdl_tpu.COMPILE_CACHE_DIR_ENV, target)
-        assert sparkdl_tpu._configure_compile_cache() is True
-        assert jax.config.jax_compilation_cache_dir == target
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+    assert sparkdl_tpu.COMPILE_CACHE_DIR_ENV == "JAX_COMPILATION_CACHE_DIR"
+    repo = os.path.dirname(os.path.dirname(
+        os.path.abspath(sparkdl_tpu.__file__)))
+    fixed = os.path.join(repo, ".jax_cache")
+    monkeypatch.delenv(sparkdl_tpu.COMPILE_CACHE_DIR_ENV, raising=False)
+    assert sparkdl_tpu._compile_cache_dir() == fixed
+    assert sparkdl_tpu._sidecar_store_dir() is None
+    target = str(tmp_path / "xla_cache")
+    monkeypatch.setenv(sparkdl_tpu.COMPILE_CACHE_DIR_ENV, target)
+    assert sparkdl_tpu._compile_cache_dir() == target
+    assert sparkdl_tpu._sidecar_store_dir() == target
+    # the in-checkout default inherited by a spawned worker is still
+    # "not placed from outside" for the sidecar stores
+    monkeypatch.setenv(sparkdl_tpu.COMPILE_CACHE_DIR_ENV, fixed)
+    assert sparkdl_tpu._sidecar_store_dir() is None
+
+
+_CACHE_PROBE = r"""
+import json, os, sys
+{pre}
+import sparkdl_tpu
+jax_free = "jax" not in sys.modules
+import jax
+print(json.dumps({{
+    "jax_free": jax_free,
+    "resolver": sparkdl_tpu._compile_cache_dir(),
+    "jax_dir": jax.config.jax_compilation_cache_dir,
+    "min_secs": jax.config.jax_persistent_cache_min_compile_time_secs,
+}}))
+"""
+
+
+@pytest.mark.parametrize("placed,jax_first", [
+    (False, False), (True, False), (False, True), (True, True)],
+    ids=["default", "from-outside", "default-jax-first",
+         "from-outside-jax-first"])
+def test_import_places_compile_cache_without_importing_jax(
+        tmp_path, placed, jax_first):
+    """A fresh interpreter: ``import sparkdl_tpu`` leaves jax out of
+    ``sys.modules`` yet JAX, once imported, has its cache where the
+    resolver says — the outside directory when the variable is set, the
+    fixed in-checkout path otherwise — whichever was imported first."""
+    import sparkdl_tpu
+
+    repo = os.path.dirname(os.path.dirname(
+        os.path.abspath(sparkdl_tpu.__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    for var in (sparkdl_tpu.COMPILE_CACHE_DIR_ENV,
+                "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"):
+        env.pop(var, None)
+    want = os.path.join(repo, ".jax_cache")
+    if placed:
+        want = env[sparkdl_tpu.COMPILE_CACHE_DIR_ENV] = str(tmp_path / "c")
+    script = _CACHE_PROBE.format(pre="import jax" if jax_first else "")
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=300,
+                          cwd=str(tmp_path))
+    assert proc.returncode == 0, proc.stderr
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert got["jax_free"] is (not jax_first)
+    assert got["resolver"] == want
+    assert got["jax_dir"] == want
+    assert got["min_secs"] == 0.0
+    assert not os.path.exists(str(tmp_path / "c"))  # placing writes nothing

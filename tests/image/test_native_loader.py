@@ -99,3 +99,64 @@ def test_grayscale_png_with_trns_probe_matches_decode(rng):
     Image.fromarray(arr, mode="L").save(buf, format="PNG", transparency=128)
     out = loader.decode(buf.getvalue())
     assert out is not None and out.shape == (16, 16, 2)
+
+
+# ---------------------------------------------------------------------------
+# Build-on-load: stale libraries are rebuilt, failed builds are loud
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def private_native_dir(tmp_path, monkeypatch):
+    """A private copy of the native sources with fresh loader state, so a
+    test can build (or fail to build) without touching the package's own
+    library that every other test in this process has loaded."""
+    import os
+    import shutil
+
+    for src in loader._SOURCES:
+        shutil.copy(os.path.join(loader._NATIVE_DIR, src), tmp_path / src)
+    monkeypatch.setattr(loader, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(loader, "_lib", None)
+    monkeypatch.setattr(loader, "_load_attempted", False)
+    monkeypatch.delenv("SPARKDL_TPU_NO_NATIVE_BUILD", raising=False)
+    return tmp_path
+
+
+def test_stale_library_is_rebuilt(private_native_dir, rng):
+    """A library older than image_loader.cc/build.sh counts as absent: the
+    (here unloadable) leftover is replaced by a build from the sources."""
+    import os
+
+    lib = private_native_dir / loader._LIB_NAME
+    lib.write_bytes(b"built somewhere else, from older sources")
+    src_mtime = os.path.getmtime(private_native_dir / "image_loader.cc")
+    os.utime(lib, (src_mtime - 60, src_mtime - 60))
+    assert not loader._is_current(str(lib))
+    assert loader.available()  # rebuilt (subprocess bounded at 120 s)
+    assert loader._is_current(str(lib))
+    arr = rng.integers(0, 255, (9, 7, 3), dtype=np.uint8)
+    np.testing.assert_array_equal(loader.decode(_png_bytes(arr)), arr)
+
+
+def test_current_library_is_not_rebuilt(private_native_dir):
+    import os
+
+    assert loader.available()
+    lib = private_native_dir / loader._LIB_NAME
+    built = os.path.getmtime(lib)
+    loader._lib, loader._load_attempted = None, False
+    (private_native_dir / "build.sh").write_text("exit 1\n")  # must not run
+    os.utime(private_native_dir / "build.sh", (built - 60, built - 60))
+    assert loader.available()
+    assert os.path.getmtime(lib) == built
+
+
+def test_failed_build_logs_compiler_output(private_native_dir, caplog):
+    (private_native_dir / "image_loader.cc").write_text(
+        "this is not C++ @@@\n")
+    with caplog.at_level("WARNING", logger=loader.logger.name):
+        assert not loader.available()
+    text = "\n".join(r.getMessage() for r in caplog.records)
+    assert "build failed" in text and "error" in text.lower()
+    assert "image_loader.cc" in text  # the compiler's own stderr, kept

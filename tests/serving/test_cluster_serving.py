@@ -385,8 +385,16 @@ def test_cutover_is_cluster_atomic_no_version_mix(rng):
                for _ in range(3)]
     for t in threads:
         t.start()
-    while len(log) < 6:  # let v1 traffic establish
+    # let v1 traffic establish — bounded, and only while a stream lives: a
+    # stream thread that died on its own assertion used to leave this loop
+    # spinning for ever (it is what cut the tier-1 run at the clock)
+    settle = time.monotonic() + 60
+    while (len(log) < 6 and time.monotonic() < settle
+           and any(t.is_alive() for t in threads)):
         time.sleep(0.01)
+    if len(log) < 6:
+        stop.set()
+    assert len(log) >= 6, (len(log), fail)
     with HealthMonitor("swap") as mon:
         prev = srv.cutover("clf", "v2")
     assert prev == "v1"
