@@ -16,7 +16,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
-from sparkdl_tpu.core import health, resilience, telemetry
+from sparkdl_tpu.core import health, profiling, resilience, telemetry
 
 logger = logging.getLogger(__name__)
 
@@ -138,6 +138,41 @@ def _valid_rows(chunk, n_valid: int):
     return jax.tree_util.tree_map(lambda leaf: leaf[:n_valid], chunk)
 
 
+def tree_nbytes(tree) -> int:
+    """Bytes of a pytree's array leaves (host or device)."""
+    import jax
+
+    return sum(int(getattr(leaf, "nbytes", 0))
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
+def launch(fn: Callable, chunk, rows: int):
+    """``fn(chunk)`` under ``sparkdl.launch``: the host's side of one
+    launch — the argument hand-over (the H2D enqueue of a numpy chunk) and
+    the async dispatch. Returns without waiting for the device."""
+    with profiling.annotate(telemetry.SPAN_LAUNCH, rows=rows,
+                            bytes=tree_nbytes(chunk)):
+        return fn(chunk)
+
+
+def fetch(tree, rows: int):
+    """Device outputs → host numpy, in two named steps: wait until the
+    device has produced them (``sparkdl.device_sync`` — this launch and
+    whatever other threads put in front of it), then copy them over
+    (``sparkdl.fetch``, counted in ``sparkdl.executor.fetched_bytes``).
+    The wait is what the copy would have blocked on anyway; an execution
+    error of the launch raises here, in whichever of the two comes first."""
+    import jax
+
+    with profiling.annotate(profiling.DEVICE_SYNC, rows=rows):
+        jax.block_until_ready(tree)
+    nbytes = tree_nbytes(tree)
+    with profiling.annotate(telemetry.SPAN_FETCH, rows=rows, bytes=nbytes):
+        host = jax.tree_util.tree_map(np.asarray, tree)
+    telemetry.count(telemetry.M_FETCHED_BYTES, nbytes)
+    return host
+
+
 def _dispatch_chunk(fn: Callable, chunk, n_valid: int,
                     multiple: int, policy: resilience.RetryPolicy
                     ) -> List[Tuple[object, int]]:
@@ -164,7 +199,8 @@ def _dispatch_chunk(fn: Callable, chunk, n_valid: int,
     def attempt():
         resilience.inject("device_oom", rows=rows, valid=n_valid)
         resilience.inject("transfer_stall", rows=rows)
-        return [(fn(chunk), n_valid)]  # dispatched async; no block here
+        # dispatched async; no block here
+        return [(launch(fn, chunk, rows), n_valid)]
 
     try:
         return policy.execute(
@@ -677,11 +713,12 @@ def run_batched(fn: Callable, tree, batch_size: int,
             leaf = leaf_per_batch[0]
             if valids[0] < leaf.shape[0]:
                 leaf = leaf[:valids[0]]
-            result_leaves.append(np.asarray(leaf))
+            result_leaves.append(fetch(leaf, valids[0]))
             continue
         import jax.numpy as jnp
 
-        fetched = np.asarray(jnp.concatenate(leaf_per_batch, axis=0))
+        fetched = fetch(jnp.concatenate(leaf_per_batch, axis=0),
+                        sum(valids))
         host = []
         off = 0
         for o, v in zip(leaf_per_batch, valids):

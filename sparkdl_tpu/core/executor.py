@@ -109,7 +109,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from sparkdl_tpu.core import batching, health, resilience, telemetry
+from sparkdl_tpu.core import (batching, health, profiling, resilience,
+                              telemetry)
 from sparkdl_tpu.core.resilience import (  # noqa: F401 - re-exported API
     ExecutorCircuitOpen,
     ExecutorOverloaded,
@@ -615,10 +616,15 @@ class DeviceExecutor:
         request down with it. Errors delivered via ``set_exception``
         already went through per-request isolation and propagate as-is.
         """
-        import jax
-
         try:
-            out = request.future.result()  # isolated failures raise here
+            # enqueue → a coalesced result or the hand-back sentinel: the
+            # per-request queue wait (an inline request records none; the
+            # M_QUEUE_WAIT_S histogram keeps its own meaning, fed by the
+            # coalescer at drain time)
+            with profiling.annotate(telemetry.SPAN_QUEUE_WAIT,
+                                    rows=request.rows,
+                                    priority=request.priority):
+                out = request.future.result()  # isolated failures raise here
         except BaseException as e:  # sparkdl: allow(broad-retry): breaker accounting only — re-raised below, never retried here
             # once per REQUEST, not per waiter: two hedged waiters share
             # one dedup'd future, and a launch-plumbing failure already
@@ -646,7 +652,7 @@ class DeviceExecutor:
                 with state.cond:
                     state.note_latency(time.monotonic() - t0)
         try:
-            host = jax.tree_util.tree_map(np.asarray, out)
+            host = batching.fetch(out, request.rows)
         except Exception as e:  # noqa: BLE001 - classified, then replayed
             kind = resilience.classify(e)
             if kind == resilience.OOM:
@@ -1352,7 +1358,8 @@ class DeviceExecutor:
                                   valid=total_rows)
                 resilience.inject("transfer_stall", rows=bucket,
                                   valid=total_rows)
-                out = fn(padded)  # dispatched async; no block here
+                # dispatched async; no block here
+                out = batching.launch(fn, padded, bucket)
             except Exception as e:  # noqa: BLE001 - classified below
                 kind = resilience.classify(e)
                 if kind == resilience.OOM:
@@ -1487,22 +1494,6 @@ def reset() -> DeviceExecutor:
     return _service
 
 
-def _tree_leaves(obj: Any) -> list:
-    """Flatten a staged payload (array / tuple / list / dict pytree)
-    without importing jax on the counting path."""
-    if isinstance(obj, dict):
-        out = []
-        for v in obj.values():
-            out.extend(_tree_leaves(v))
-        return out
-    if isinstance(obj, (tuple, list)):
-        out = []
-        for v in obj:
-            out.extend(_tree_leaves(v))
-        return out
-    return [obj]
-
-
 def execute(model: Any, array: Any, *, batch_size: int = 64,
             mesh: Any = None,
             retry_policy: Optional[resilience.RetryPolicy] = None,
@@ -1550,8 +1541,7 @@ def execute(model: Any, array: Any, *, batch_size: int = 64,
         # uint8 only" (docs/PERF.md "Columnar data plane"); a float32
         # staging regression shows up as a 4x jump per image.
         try:
-            payload = sum(int(getattr(leaf, "nbytes", 0))
-                          for leaf in _tree_leaves(array))
+            payload = batching.tree_nbytes(array)
         except Exception:  # exotic payloads never break the data plane
             payload = 0
         if payload:

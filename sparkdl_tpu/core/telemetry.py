@@ -125,6 +125,19 @@ SPAN_SERVING_PREDICT = "sparkdl.serving_predict"  # worker-side execution
 SPAN_SERVING_WARMUP = "sparkdl.serving.warmup_s"  # AOT bucket-ladder
                                               # warmup of one deployment
                                               # (serving/registry.py)
+# Where a featurize pass spends its host time (docs/OBSERVABILITY.md):
+# all opened through profiling.annotate, so each also feeds a phase timer.
+SPAN_ROW_ASSEMBLY = "sparkdl.row_assembly"    # Arrow table → Python rows /
+                                              # pandas (engine/dataframe.py)
+SPAN_QUEUE_WAIT = "sparkdl.queue_wait"        # a queued request waiting for
+                                              # the coalescer's answer
+                                              # (core/executor.py _await)
+SPAN_LAUNCH = "sparkdl.launch"                # host side of one launch:
+                                              # argument hand-over + async
+                                              # dispatch (core/batching.py,
+                                              # core/executor.py)
+SPAN_FETCH = "sparkdl.fetch"                  # device→host copy of ready
+                                              # outputs (core/batching.py)
 
 CANONICAL_SPAN_NAMES = frozenset({
     SPAN_RUN, SPAN_RUNNER_ATTEMPT, SPAN_FIT, SPAN_EPOCH,
@@ -134,6 +147,7 @@ CANONICAL_SPAN_NAMES = frozenset({
     SPAN_MODEL_LOAD, SPAN_CLUSTER_DISPATCH, SPAN_CLUSTER_TASK,
     SPAN_DECODE_CHUNK, SPAN_SERVING_SHADOW, SPAN_SERVING_PREDICT,
     SPAN_SERVING_WARMUP,
+    SPAN_ROW_ASSEMBLY, SPAN_QUEUE_WAIT, SPAN_LAUNCH, SPAN_FETCH,
     # phase names (core/profiling.py constants + literal call sites)
     "sparkdl.decode", "sparkdl.stage", "sparkdl.stage_batch",
     "sparkdl.host_stage", "sparkdl.host_resize", "sparkdl.host_wait",
@@ -180,6 +194,9 @@ M_EXECUTOR_SHED_RATE = "sparkdl.executor.shed_rate"    # gauge (shed fraction)
 # columnar path this is raw uint8 pixels — the counter is how bench and
 # tests assert "host ships uint8 only" (a f32 regression quadruples it).
 M_STAGED_BYTES = "sparkdl.executor.staged_bytes"       # counter
+# ...and the way back: bytes every sparkdl.fetch span copied device→host
+# (pad rows of a multi-bucket call included — they cross the link too).
+M_FETCHED_BYTES = "sparkdl.executor.fetched_bytes"     # counter
 # Parallel host decode pool (core/decode_pool.py, docs/PERF.md "Parallel
 # host ingest"):
 M_DECODE_POOL_DEPTH = "sparkdl.decode_pool.queue_depth"    # gauge (chunks)
@@ -271,6 +288,7 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_EXECUTOR_QUEUE_DEPTH: "gauge",
     M_EXECUTOR_SHED_RATE: "gauge",
     M_STAGED_BYTES: "counter",
+    M_FETCHED_BYTES: "counter",
     M_DECODE_POOL_DEPTH: "gauge",
     M_DECODE_POOL_BUSY: "gauge",
     M_DECODE_POOL_DECODE_S: "histogram",
@@ -441,6 +459,7 @@ class Tracer:
     HealthMonitor event log keeps the FIRST n — traces want the tail: the
     end of a run is where failures live) and counts evictions in
     :attr:`dropped`. Thread-safe; spans may finish on any thread.
+    Recorded times are nanoseconds since :attr:`epoch_ns`.
     """
 
     def __init__(self, trace_id: str, max_spans: int = 65536) -> None:
@@ -456,7 +475,11 @@ class Tracer:
         # each process must never collide (Linux pids fit in 22 bits;
         # 40 low bits leave ~10^12 spans per process)
         self._ids = itertools.count((os.getpid() << 40) | 1)
-        self._t0_ns = time.perf_counter_ns()
+        #: the tracer's epoch, a reading of ``time.perf_counter_ns``:
+        #: ``spans()`` times are relative to it, so ``start_ns + epoch_ns``
+        #: is the host clock — what lines a telemetry trace up with any
+        #: other record of the same run (``profiling.maybe_trace``)
+        self.epoch_ns = time.perf_counter_ns()
 
     # -- producing -----------------------------------------------------------
 
@@ -489,8 +512,8 @@ class Tracer:
             "parent_id": span.parent_id,
             "thread_id": thread.ident,
             "thread_name": thread.name,
-            "start_ns": start_ns - self._t0_ns,
-            "end_ns": end_ns - self._t0_ns,
+            "start_ns": start_ns - self.epoch_ns,
+            "end_ns": end_ns - self.epoch_ns,
         }
         if span.attributes:
             rec["attributes"] = span.attributes
@@ -526,6 +549,7 @@ class Tracer:
             agg["mean_s"] = round(agg["total_s"] / agg["count"], 6)
         return {
             "trace_id": self.trace_id,
+            "epoch_ns": self.epoch_ns,
             "spans_recorded": len(spans),
             "spans_dropped": self.dropped,
             "remote_adopted": self.remote_adopted,
@@ -560,8 +584,8 @@ class Tracer:
         out = []
         for s in spans:
             rec = dict(s)
-            rec["start_ns"] = s["start_ns"] + self._t0_ns + clock_offset_ns
-            rec["end_ns"] = s["end_ns"] + self._t0_ns + clock_offset_ns
+            rec["start_ns"] = s["start_ns"] + self.epoch_ns + clock_offset_ns
+            rec["end_ns"] = s["end_ns"] + self.epoch_ns + clock_offset_ns
             rec["pid"] = pid
             if process is not None:
                 rec["process"] = process
@@ -587,8 +611,8 @@ class Tracer:
                 rejected += 1
                 continue
             rec = dict(s)
-            rec["start_ns"] = s["start_ns"] - self._t0_ns
-            rec["end_ns"] = s["end_ns"] - self._t0_ns
+            rec["start_ns"] = s["start_ns"] - self.epoch_ns
+            rec["end_ns"] = s["end_ns"] - self.epoch_ns
             with self._lock:
                 if len(self._spans) == self.max_spans:
                     self.dropped += 1
@@ -620,8 +644,8 @@ class Tracer:
             "parent_id": parent.span_id if parent else None,
             "thread_id": 0,
             "thread_name": process or f"pid-{pid}",
-            "start_ns": start_abs_ns - self._t0_ns,
-            "end_ns": end_abs_ns - self._t0_ns,
+            "start_ns": start_abs_ns - self.epoch_ns,
+            "end_ns": end_abs_ns - self.epoch_ns,
             "pid": pid,
         }
         if process is not None:
@@ -690,7 +714,7 @@ class Tracer:
         rejection as a real adoption), so the live ring stays
         untouched."""
         scratch = Tracer(self.trace_id, max_spans=self.max_spans)
-        scratch._t0_ns = self._t0_ns
+        scratch.epoch_ns = self.epoch_ns
         with self._lock:
             scratch._spans.extend(dict(s) for s in self._spans)
         for ring in rings:

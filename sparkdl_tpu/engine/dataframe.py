@@ -34,7 +34,7 @@ import numpy as np
 import pandas as pd
 import pyarrow as pa
 
-from sparkdl_tpu.core import durability, resilience, telemetry
+from sparkdl_tpu.core import durability, profiling, resilience, telemetry
 from sparkdl_tpu.engine import supervisor as _sup
 from sparkdl_tpu.engine.supervisor import (  # noqa: F401 - re-exported API
     PartitionSupervisor,
@@ -599,6 +599,14 @@ def _cluster_dispatch() -> Callable[..., pa.RecordBatch]:
     return _run_partition if router is None else router.run_partition
 
 
+def _row_assembly(table: pa.Table, to: str):
+    """The ``sparkdl.row_assembly`` span (and phase timer) around turning a
+    whole Arrow table into Python rows or pandas — ``collect()``'s and
+    ``toPandas()``'s own cost, apart from materializing the table."""
+    return profiling.annotate(telemetry.SPAN_ROW_ASSEMBLY,
+                              rows=table.num_rows, bytes=table.nbytes, to=to)
+
+
 def _as_record_batches(table: pa.Table, num_partitions: int) -> List[pa.RecordBatch]:
     n = max(1, table.num_rows)
     num_partitions = max(1, min(num_partitions, n))
@@ -833,13 +841,17 @@ class DataFrame:
             return pa.Table.from_batches(casted, schema=unified)
 
     def toPandas(self) -> pd.DataFrame:
-        return self.toArrow().to_pandas()
+        table = self.toArrow()
+        with _row_assembly(table, "pandas"):
+            return table.to_pandas()
 
     def collect(self) -> List[Dict[str, Any]]:
-        # sparkdl: allow(columnar-hot-path): collect's CONTRACT is
-        # per-row Python dicts (Spark Row analog); batch callers use
-        # streamPartitions/toArrow
-        return self.toArrow().to_pylist()
+        table = self.toArrow()
+        with _row_assembly(table, "pylist"):
+            # sparkdl: allow(columnar-hot-path): collect's CONTRACT is
+            # per-row Python dicts (Spark Row analog); batch callers use
+            # streamPartitions/toArrow
+            return table.to_pylist()
 
     def count(self) -> int:
         return sum(b.num_rows for b in self._materialize())

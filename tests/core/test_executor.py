@@ -12,7 +12,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from sparkdl_tpu.core import executor, health, resilience, telemetry
+from sparkdl_tpu.core import (executor, health, profiling, resilience,
+                              telemetry)
 from sparkdl_tpu.core.executor import ExecutorShutdown, task_scope
 from sparkdl_tpu.core.health import HealthMonitor
 from sparkdl_tpu.core.model_function import ModelFunction, TensorSpec
@@ -582,3 +583,100 @@ def test_solo_drained_window_replays_on_the_requester_thread():
         outcome["queued"], orig_apply(x_queued, batch_size=32))
     assert set(apply_threads) == {"requester-busy", "requester-queued"}
     assert not any(n.startswith("sparkdl-exec") for n in apply_threads)
+
+
+# ---------------------------------------------------------------------------
+# Spans inside execute (PR 30): queue wait, launch, device wait and fetch,
+# each named, and the D2H byte counter
+# ---------------------------------------------------------------------------
+
+_INSIDE = (telemetry.SPAN_LAUNCH, profiling.DEVICE_SYNC,
+           telemetry.SPAN_FETCH)
+
+
+def test_lone_execute_records_launch_sync_fetch_in_order_on_the_caller():
+    mf = _model()
+    x = _rows(5)
+    with Telemetry() as tel:
+        with profiling.annotate("sparkdl.device_apply", rows=len(x)):
+            executor.execute(mf, x, batch_size=16)
+    (outer,) = tel.tracer.spans("sparkdl.device_apply")
+    inside = sorted((s for s in tel.tracer.spans() if s["name"] in _INSIDE),
+                    key=lambda s: s["start_ns"])
+    assert [s["name"] for s in inside] == list(_INSIDE)
+    me = threading.get_ident()
+    for earlier, later in zip(inside, inside[1:]):
+        assert earlier["end_ns"] <= later["start_ns"]
+    for s in inside:
+        assert s["thread_id"] == me
+        assert s["parent_id"] == outer["span_id"]
+        assert outer["start_ns"] <= s["start_ns"]
+        assert s["end_ns"] <= outer["end_ns"]
+    launch, sync, fetch = inside
+    assert launch["attributes"] == {"rows": 8, "bytes": 8 * 6 * 4}
+    assert sync["attributes"] == {"rows": 5}
+    assert fetch["attributes"] == {"rows": 5, "bytes": 5 * _FEATURES * 4}
+    # ran inline: it never queued, so there is no wait to name
+    assert tel.tracer.spans(telemetry.SPAN_QUEUE_WAIT) == []
+
+
+def test_queued_request_records_one_queue_wait_as_long_as_the_window():
+    """A request that arrives while a launch is in flight queues; the
+    coalescer holds it for the window, and that wait is one span on the
+    REQUESTER's thread — followed there by its own launch/sync/fetch."""
+    window_s = 0.15
+    mf = _model(sleep_s=0.3)
+    EngineConfig.coalesce_window_ms = window_s * 1e3
+    idents = {}
+
+    def run(name, x):
+        idents[name] = threading.get_ident()
+        executor.execute(mf, x, batch_size=32)
+
+    with Telemetry() as tel:
+        t_busy = threading.Thread(target=run, args=("busy", _rows(2)))
+        t_busy.start()
+        time.sleep(0.05)  # the inline launch is now in flight
+        t_q = threading.Thread(target=run, args=("queued", _rows(3)))
+        t_q.start()
+        t_busy.join(timeout=30)
+        t_q.join(timeout=30)
+        assert not t_busy.is_alive() and not t_q.is_alive()
+    (wait,) = tel.tracer.spans(telemetry.SPAN_QUEUE_WAIT)
+    assert wait["thread_id"] == idents["queued"]
+    assert wait["attributes"] == {"rows": 3,
+                                  "priority": executor.PRIORITY_BULK}
+    assert (wait["end_ns"] - wait["start_ns"]) / 1e9 >= 0.9 * window_s
+    after = [s["name"] for s in sorted(tel.tracer.spans(),
+                                       key=lambda s: s["start_ns"])
+             if s["thread_id"] == idents["queued"] and s["name"] in _INSIDE]
+    assert after == list(_INSIDE)
+    # the histogram keeps its own meaning: requests that queued
+    assert tel.metrics.snapshot()["histograms"][
+        telemetry.M_QUEUE_WAIT_S]["count"] == 1
+
+
+@pytest.mark.parametrize("rows,fetches", [(5, 1), (48, 1)],
+                         ids=["single-chunk", "multi-chunk"])
+def test_fetched_bytes_counts_what_execute_returned(rows, fetches):
+    mf = _model()
+    with Telemetry() as tel:
+        out = executor.execute(mf, _rows(rows), batch_size=16)
+    assert out.shape == (rows, _FEATURES)
+    counters = tel.metrics.snapshot()["counters"]
+    assert counters[telemetry.M_FETCHED_BYTES] == out.nbytes
+    fetched = tel.tracer.spans(telemetry.SPAN_FETCH)
+    assert len(fetched) == fetches
+    assert sum(s["attributes"]["bytes"] for s in fetched) == out.nbytes
+    assert len(tel.tracer.spans(telemetry.SPAN_LAUNCH)) == -(-rows // 16)
+
+
+@pytest.mark.parametrize("rows", [5, 48])
+def test_execute_bit_identical_with_and_without_a_scope(rows):
+    mf = _model()
+    x = _rows(rows, seed=7)
+    plain = executor.execute(mf, x, batch_size=16)
+    with Telemetry():
+        traced = executor.execute(mf, x, batch_size=16)
+    assert traced.dtype == plain.dtype
+    np.testing.assert_array_equal(traced, plain)

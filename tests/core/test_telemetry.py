@@ -905,3 +905,80 @@ def test_chrome_trace_process_groups_only_after_remote_merge():
               if e["ph"] == "M" and e["name"] == "process_name"}
     assert groups == {os.getpid(): "coordinator",
                       424242: "decode-424242"}
+
+
+# ---------------------------------------------------------------------------
+# PR 30: annotate without the profiler's host annotation, a public tracer
+# epoch, and a device-only maybe_trace with its clock file
+# ---------------------------------------------------------------------------
+
+
+def test_annotate_opens_no_trace_annotation(monkeypatch):
+    """The profiler's host tracer cannot be used on this path, so nothing
+    would ever read a TraceAnnotation: annotate must not open one."""
+    import jax.profiler
+
+    def boom(*a, **k):
+        raise AssertionError("annotate opened a jax.profiler.TraceAnnotation")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    profiling.reset_phase_stats()
+    with Telemetry() as tel:
+        with profiling.annotate("sparkdl.decode", rows=1):
+            pass
+    with profiling.annotate("sparkdl.decode"):
+        pass
+    assert profiling.phase_stats()["sparkdl.decode"]["count"] == 2
+    assert len(tel.tracer.spans("sparkdl.decode")) == 1
+
+
+def test_tracer_epoch_puts_spans_on_the_host_clock():
+    before = time.perf_counter_ns()
+    with Telemetry() as tel:
+        opened = time.perf_counter_ns()
+        with telemetry.span("sparkdl.decode"):
+            inside = time.perf_counter_ns()
+        closed = time.perf_counter_ns()
+    assert before <= tel.tracer.epoch_ns <= opened
+    (s,) = tel.tracer.spans("sparkdl.decode")
+    assert opened <= s["start_ns"] + tel.tracer.epoch_ns <= inside
+    assert inside <= s["end_ns"] + tel.tracer.epoch_ns <= closed
+
+
+def test_maybe_trace_writes_the_clock_file(tmp_path, monkeypatch):
+    """Device-only profiler options, two marker programs inside the trace,
+    and sparkdl_clock.json with what on_device_clock takes."""
+    import jax.profiler
+
+    calls = []
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda d, profiler_options=None: calls.append(
+            ("start", d, profiler_options.host_tracer_level,
+             profiler_options.python_tracer_level, time.perf_counter_ns())))
+    monkeypatch.setattr(
+        jax.profiler, "stop_trace",
+        lambda: calls.append(("stop", time.perf_counter_ns())))
+    monkeypatch.delenv(profiling.PROFILE_DIR_ENV, raising=False)
+    with profiling.maybe_trace() as on:
+        assert on is False
+    assert calls == []
+    target = str(tmp_path / "trace")
+    os.makedirs(target)
+    monkeypatch.setenv(profiling.PROFILE_DIR_ENV, target)
+    with Telemetry() as tel:
+        with profiling.maybe_trace() as on:
+            assert on is True
+            body_ns = time.perf_counter_ns()
+    (start, stop) = calls
+    assert start[:4] == ("start", target, 0, 0)
+    with open(os.path.join(target, profiling.PROFILE_CLOCK_FILE)) as f:
+        clock = json.load(f)
+    assert clock["epoch_ns"] == tel.tracer.epoch_ns
+    assert set(clock["ready_ns"]) == {profiling.PROFILE_START,
+                                      profiling.PROFILE_STOP}
+    # each marker ran inside the trace, on its side of the body
+    assert (start[4] <= clock["ready_ns"][profiling.PROFILE_START]
+            <= body_ns <= clock["ready_ns"][profiling.PROFILE_STOP]
+            <= stop[1])
+    assert os.listdir(target) == [profiling.PROFILE_CLOCK_FILE]

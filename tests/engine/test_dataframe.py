@@ -540,3 +540,64 @@ def test_eval_bool_short_circuits():
     null_or = sql_expr.parse_bool("a = 1 OR b = 2")
     assert sql_expr.eval_bool(null_or, {"a": None, "b": 2}) is True
     assert sql_expr.eval_bool(null_or, {"a": None, "b": 3}) is None
+
+
+# ---------------------------------------------------------------------------
+# sparkdl.row_assembly: the Arrow table → Python rows / pandas step of
+# collect() and toPandas(), named so a traced run can see it (PR 30)
+# ---------------------------------------------------------------------------
+
+
+def _mapped_df():
+    return make_df(12, 3).withColumn(
+        "sum", lambda x, y: float(x) + y, inputCols=["x", "y"],
+        outputType=pa.float64())
+
+
+@pytest.mark.parametrize("method,to", [("collect", "pylist"),
+                                       ("toPandas", "pandas")])
+def test_row_assembly_span_follows_materialize(method, to):
+    from sparkdl_tpu.core import telemetry
+
+    with telemetry.Telemetry() as tel:
+        getattr(_mapped_df(), method)()
+    assembly = tel.tracer.spans(telemetry.SPAN_ROW_ASSEMBLY)
+    materialize = tel.tracer.spans(telemetry.SPAN_MATERIALIZE)
+    assert len(assembly) == 1 and len(materialize) == 1
+    span = assembly[0]
+    assert span["attributes"]["rows"] == 12
+    assert span["attributes"]["to"] == to
+    assert span["attributes"]["bytes"] > 0
+    # the table is whole before assembly starts: materialize stays outside
+    assert span["start_ns"] >= materialize[0]["end_ns"]
+    assert span["parent_id"] != materialize[0]["span_id"]
+
+
+def test_row_assembly_without_scope_feeds_the_phase_timer_only(monkeypatch):
+    from sparkdl_tpu.core import profiling, telemetry
+
+    assert telemetry.active() is None
+    made = []
+    monkeypatch.setattr(telemetry.Tracer, "span",
+                        lambda *a, **k: made.append(a) or telemetry.NULL_SPAN)
+    before = profiling.phase_stats().get(telemetry.SPAN_ROW_ASSEMBLY,
+                                         {"count": 0})["count"]
+    df = _mapped_df()
+    df.collect()
+    df.toPandas()
+    after = profiling.phase_stats()[telemetry.SPAN_ROW_ASSEMBLY]
+    assert after["count"] == before + 2 and after["total_s"] > 0
+    assert made == []
+
+
+def test_collect_and_to_pandas_identical_with_and_without_a_scope():
+    from sparkdl_tpu.core import telemetry
+
+    plain_rows = _mapped_df().collect()
+    plain_frame = _mapped_df().toPandas()
+    with telemetry.Telemetry():
+        traced_rows = _mapped_df().collect()
+        traced_frame = _mapped_df().toPandas()
+    assert traced_rows == plain_rows
+    pd.testing.assert_frame_equal(traced_frame, plain_frame,
+                                  check_exact=True)
