@@ -69,15 +69,16 @@ HOST_ETL_PHASES = ("sparkdl.decode", "sparkdl.stage", STAGE_BATCH,
 
 
 @contextlib.contextmanager
-def annotate(name: str, **attributes: Any) -> Iterator[None]:
+def annotate(name: str, **attributes: Any) -> Iterator[Any]:
     """Named span: feeds the phase timer ``name`` and — when a
     ``core.telemetry`` scope is active — the telemetry tracer
     (ambient-parented, so existing phase names become correlated spans
     for free). ``attributes`` ride on the telemetry span only; the
-    phase timers stay name-keyed aggregates."""
+    phase timers stay name-keyed aggregates. Yields the span (the inert
+    ``NULL_SPAN`` without a scope) for attributes known only inside."""
     t0 = time.perf_counter()
-    with telemetry.span(name, **attributes):
-        yield
+    with telemetry.span(name, **attributes) as span:
+        yield span
     dt = time.perf_counter() - t0
     with _lock:
         _phase_totals[name] = _phase_totals.get(name, 0.0) + dt

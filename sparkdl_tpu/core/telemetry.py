@@ -177,6 +177,10 @@ M_BUCKET_LADDER_UPDATE = "sparkdl.batching.bucket_ladder_update"  # counter
 M_PLANNER_WASTE = "sparkdl.batching.planner_waste"     # gauge (pad fraction)
 M_ENGINE_ROWS_OUT = "sparkdl.engine.rows_out"          # counter
 M_ENGINE_BYTES_OUT = "sparkdl.engine.bytes_out"        # counter
+# Leaf values collect() turned into Python numbers through numpy instead of
+# an Arrow scalar each (engine/dataframe.py _table_rows): rows × width of a
+# featurizer's output column; 0 for a frame with no vector column.
+M_COLLECT_VECTORIZED_VALUES = "sparkdl.collect.vectorized_values"  # counter
 # Device execution service (core/executor.py, docs/PERF.md coalescing):
 M_COALESCE_REQUESTS = "sparkdl.executor.coalesce_requests"  # histogram
 M_COALESCE_ROWS = "sparkdl.executor.coalesce_rows"     # histogram
@@ -279,6 +283,7 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_PLANNER_WASTE: "gauge",
     M_ENGINE_ROWS_OUT: "counter",
     M_ENGINE_BYTES_OUT: "counter",
+    M_COLLECT_VECTORIZED_VALUES: "counter",
     M_COALESCE_REQUESTS: "histogram",
     M_COALESCE_ROWS: "histogram",
     M_COALESCE_DEDUP: "counter",

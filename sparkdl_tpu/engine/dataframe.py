@@ -607,6 +607,82 @@ def _row_assembly(table: pa.Table, to: str):
                               rows=table.num_rows, bytes=table.nbytes, to=to)
 
 
+def _is_vector_type(t: pa.DataType) -> bool:
+    """A list column whose cells ``ndarray.tolist()`` turns into the very
+    objects Arrow's ``as_py()`` gives: the integers and float32/float64
+    (tests/engine/test_dataframe.py holds each to it). float16, decimals,
+    temporal and bit-packed values stay with Arrow."""
+    if not (pa.types.is_list(t) or pa.types.is_large_list(t)
+            or pa.types.is_fixed_size_list(t)):
+        return False
+    v = t.value_type
+    return (pa.types.is_integer(v) or pa.types.is_float32(v)
+            or pa.types.is_float64(v))
+
+
+def _vector_cells(chunk: pa.Array) -> Optional[Tuple[List[Any], int]]:
+    """One chunk of a vector column as Python lists, through numpy: one
+    ``tolist()`` over the chunk's values instead of an Arrow scalar per
+    element. Returns the cells (``None`` for a null row) and the number
+    of values in them, or ``None`` when a value inside a valid row is
+    null (Arrow's ``to_pylist()`` keeps those)."""
+    # flatten(): the values of the valid rows alone, back to back — it
+    # minds the chunk's offset, a sliced chunk's first offset and whatever
+    # a null row's slot covers
+    flat = chunk.flatten()
+    if flat.null_count:
+        return None
+    values = flat.to_numpy(zero_copy_only=True)
+    valid = (chunk.is_valid().to_numpy(zero_copy_only=False)
+             if chunk.null_count else None)
+    n_valid = len(chunk) - chunk.null_count
+    if pa.types.is_fixed_size_list(chunk.type):
+        lengths = np.full(n_valid, chunk.type.list_size, dtype=np.int64)
+    else:
+        lengths = np.diff(chunk.offsets.to_numpy())
+        if valid is not None:
+            lengths = lengths[valid]
+    if n_valid and lengths[0] > 0 and (lengths == lengths[0]).all():
+        rows = values.reshape(n_valid, lengths[0]).tolist()
+    else:  # ragged, or zero-length lists: a slice a row
+        items = values.tolist()
+        ends = np.cumsum(lengths).tolist()
+        rows = [items[a:b] for a, b in zip([0] + ends, ends)]
+    if valid is None:
+        return rows, values.size
+    cells: List[Any] = [None] * len(chunk)
+    for i, row in zip(np.flatnonzero(valid).tolist(), rows):
+        cells[i] = row
+    return cells, values.size
+
+
+def _table_rows(table: pa.Table) -> Tuple[List[Dict[str, Any]], int, int]:
+    """``table.to_pylist()``, assembled column by column and chunk by
+    chunk: vector columns through numpy, every other column through
+    Arrow's own ``to_pylist()``. Returns the rows, the number of columns
+    that went through numpy whole, and the leaf values numpy converted."""
+    columns, vector_columns, values = [], 0, 0
+    for column in table.columns:
+        eligible = whole = _is_vector_type(column.type)
+        cells: List[Any] = []
+        for chunk in column.chunks:
+            got = _vector_cells(chunk) if eligible else None
+            if got is None:
+                whole = False
+                # sparkdl: allow(columnar-hot-path): collect's CONTRACT is
+                # per-row Python dicts (Spark Row analog); batch callers use
+                # streamPartitions/toArrow
+                cells.extend(chunk.to_pylist())
+            else:
+                cells.extend(got[0])
+                values += got[1]
+        vector_columns += whole
+        columns.append(cells)
+    names = table.column_names
+    return ([dict(zip(names, row)) for row in zip(*columns)],
+            vector_columns, values)
+
+
 def _as_record_batches(table: pa.Table, num_partitions: int) -> List[pa.RecordBatch]:
     n = max(1, table.num_rows)
     num_partitions = max(1, min(num_partitions, n))
@@ -846,12 +922,19 @@ class DataFrame:
             return table.to_pandas()
 
     def collect(self) -> List[Dict[str, Any]]:
+        """The frame's rows: a list of dicts keyed by column name in the
+        schema's order, equal to ``toArrow().to_pylist()`` cell types
+        included — a vector cell is a list of Python ``int``/``float``, a
+        ``binary`` cell ``bytes``, a struct a dict, a null cell ``None``
+        (tests/engine/test_dataframe.py holds ``collect()`` to it)."""
         table = self.toArrow()
-        with _row_assembly(table, "pylist"):
-            # sparkdl: allow(columnar-hot-path): collect's CONTRACT is
-            # per-row Python dicts (Spark Row analog); batch callers use
-            # streamPartitions/toArrow
-            return table.to_pylist()
+        with _row_assembly(table, "pylist") as span:
+            rows, vector_columns, values = _table_rows(table)
+            span.set_attribute("vector_columns", vector_columns)
+            span.set_attribute("fallback_columns",
+                               table.num_columns - vector_columns)
+            telemetry.count(telemetry.M_COLLECT_VECTORIZED_VALUES, values)
+            return rows
 
     def count(self) -> int:
         return sum(b.num_rows for b in self._materialize())

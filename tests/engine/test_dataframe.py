@@ -601,3 +601,189 @@ def test_collect_and_to_pandas_identical_with_and_without_a_scope():
     assert traced_rows == plain_rows
     pd.testing.assert_frame_equal(traced_frame, plain_frame,
                                   check_exact=True)
+
+
+# ---------------------------------------------------------------------------
+# collect() assembles its rows column by column (PR 31): vector columns
+# through numpy, the rest through Arrow — the rows are Table.to_pylist()'s,
+# cell types included
+# ---------------------------------------------------------------------------
+
+_INTS = [pa.int8(), pa.int16(), pa.int32(), pa.int64(),
+         pa.uint8(), pa.uint16(), pa.uint32(), pa.uint64()]
+
+
+def _frame(columns, parts=1):
+    return DataFrame.fromArrow(pa.table(columns), numPartitions=parts)
+
+
+def _int_extremes(t):
+    info = np.iinfo(t.to_pandas_dtype())
+    return _frame({"v": pa.array([[info.min, 0, info.max], [1], None],
+                                 pa.list_(t))})
+
+
+def _sliced_chunks():
+    # partitions that are slices of a longer batch: each chunk has a
+    # non-zero offset and its list offsets do not start at 0
+    batch = pa.record_batch({
+        "i": pa.array(range(10)),
+        "v": pa.array([[float(i)] * (i % 3) if i != 4 else None
+                       for i in range(10)], pa.list_(pa.float32())),
+        "f": pa.array([[i, i + 1] if i != 7 else None for i in range(10)],
+                      pa.list_(pa.int16(), 2)),
+        "w": pa.array([[i + 0.5] * 3 for i in range(10)],
+                      pa.large_list(pa.float64()))})
+    return DataFrame([batch.slice(2, 3), batch.slice(5, 5)], batch.schema)
+
+
+def _null_rows_with_values_under_them():
+    # a null row whose slot still covers values: flatten() must skip them
+    v = pa.ListArray.from_arrays(
+        pa.array([0, 2, 4, 6, 8], pa.int32()),
+        pa.array(np.arange(8, dtype=np.float32)),
+        mask=pa.array([False, True, False, False]))
+    return _frame({"v": v})
+
+
+def _image_struct_frame():
+    from sparkdl_tpu.image import imageIO
+
+    pixels = np.arange(3 * 4 * 5 * 3, dtype=np.uint8).reshape(3, 4, 5, 3)
+    return _frame({
+        "image": imageIO.imageArraysToStructColumn(
+            pixels, [f"mem://{i}" for i in range(3)]),
+        "features": pa.array([[0.5, 1.5], None, [2.5, 3.5]],
+                             pa.list_(pa.float32()))})
+
+
+def _duplicated_name():
+    batch = pa.RecordBatch.from_arrays(
+        [pa.array([[1.0], [2.0]], pa.list_(pa.float32())),
+         pa.array(["x", "y"]),
+         pa.array([[3.0], [4.0]], pa.list_(pa.float32()))],
+        names=["a", "b", "a"])
+    return DataFrame([batch], batch.schema)
+
+
+# case → (frame, columns that go through numpy whole)
+_COLLECT_CASES = {
+    "list_float32": lambda: (_frame({"v": pa.array(
+        [[0.1, 0.2, 0.3], [1e-45, 3.4e38, -1.5]], pa.list_(pa.float32()))}),
+        1),
+    "list_float64": lambda: (_frame({"v": pa.array(
+        [[0.1, 1e308], [5e-324, -2.5]], pa.list_(pa.float64()))}), 1),
+    "float_specials": lambda: (_frame({
+        "a": pa.array([[float("nan"), float("inf"), -float("inf"), -0.0,
+                        0.0]], pa.list_(pa.float32())),
+        "b": pa.array([[float("nan"), float("inf"), -float("inf"), -0.0,
+                        0.0]], pa.list_(pa.float64()))}), 2),
+    "large_list": lambda: (_frame({"v": pa.array(
+        [[1.0, 2.0], [3.0, 4.0]], pa.large_list(pa.float32()))}), 1),
+    "fixed_size_list": lambda: (_frame({"v": fixed_size_list_array(
+        np.arange(12, dtype=np.float32).reshape(4, 3))}), 1),
+    "fixed_size_list_null_rows": lambda: (_frame({"v": pa.array(
+        [[1, 2], None, [3, 4], None], pa.list_(pa.int32(), 2))}), 1),
+    **{f"list_{t}": (lambda t=t: (_int_extremes(t), 1)) for t in _INTS},
+    "null_rows": lambda: (_frame({"v": pa.array(
+        [None, [1.0, 2.0], None, [3.0, 4.0], [5.0, 6.0], None],
+        pa.list_(pa.float32()))}), 1),
+    "null_rows_with_values_under_them": lambda: (
+        _null_rows_with_values_under_them(), 1),
+    "all_null_column": lambda: (_frame({"v": pa.array(
+        [None, None, None], pa.list_(pa.float32()))}), 1),
+    "ragged": lambda: (_frame({"v": pa.array(
+        [[1.0], [2.0, 3.0, 4.0], None, [5.0, 6.0]],
+        pa.list_(pa.float64()))}), 1),
+    "zero_length_lists": lambda: (_frame({
+        "some": pa.array([[], [1], []], pa.list_(pa.int64())),
+        "all": pa.array([[], [], []], pa.list_(pa.float32()))}), 2),
+    "sliced_chunks": lambda: (_sliced_chunks(), 3),
+    "limit_and_filter": lambda: (_frame({
+        "i": pa.array(range(12)),
+        "v": pa.array([[float(i), -float(i)] for i in range(12)],
+                      pa.list_(pa.float32()))}, parts=2)
+        .filter(lambda i: i % 3 != 0, ["i"]).limit(5), 1),
+    "several_partitions": lambda: (_frame({
+        "i": pa.array(range(11)),
+        "v": pa.array([[i, i * i] for i in range(11)],
+                      pa.list_(pa.int32()))}, parts=4), 1),
+    "empty_frame": lambda: (_frame({
+        "i": pa.array([], pa.int64()),
+        "v": pa.array([], pa.list_(pa.float32()))}), 1),
+    "inner_nulls": lambda: (_frame({"v": pa.array(
+        [[1.0, None], [2.0, 3.0]], pa.list_(pa.float32()))}), 0),
+    "inner_nulls_in_one_partition": lambda: (_frame({"v": pa.array(
+        [[1.0, 2.0], [3.0, 4.0], [5.0, None], [6.0, 7.0]],
+        pa.list_(pa.float32()))}, parts=2), 0),
+    "list_float16": lambda: (_frame({"v": pa.array(
+        [np.array([1.5, 2.5], np.float16)], pa.list_(pa.float16()))}), 0),
+    "list_bool": lambda: (_frame({"v": pa.array(
+        [[True, False], [False]], pa.list_(pa.bool_()))}), 0),
+    "list_list_float": lambda: (_frame({"v": pa.array(
+        [[[1.0], [2.0, 3.0]], [[4.0]]],
+        pa.list_(pa.list_(pa.float32())))}), 0),
+    "list_string": lambda: (_frame({"v": pa.array(
+        [["a", "b"], None, []], pa.list_(pa.string()))}), 0),
+    "scalars_strings_timestamps": lambda: (_frame({
+        "i": pa.array([1, None, 3]),
+        "s": pa.array(["a", None, "c"]),
+        "b": pa.array([b"\x00\x01", b"", None], pa.binary()),
+        "t": pa.array([0, 1_000_000, None], pa.timestamp("us")),
+        "m": pa.array([[("k", 1)], [], None],
+                      pa.map_(pa.string(), pa.int32()))}), 0),
+    "struct_with_binary_child": lambda: (_image_struct_frame(), 1),
+    "duplicated_column_name": lambda: (_duplicated_name(), 2),
+}
+
+
+def _assert_same_cells(got, want, where="rows"):
+    assert type(got) is type(want), where
+    if isinstance(want, dict):
+        assert list(got) == list(want), where
+        for key in want:
+            _assert_same_cells(got[key], want[key], f"{where}[{key!r}]")
+    elif isinstance(want, (list, tuple)):
+        assert len(got) == len(want), where
+        for i, (g, w) in enumerate(zip(got, want)):
+            _assert_same_cells(g, w, f"{where}[{i}]")
+    elif isinstance(want, float):
+        assert repr(got) == repr(want), where   # NaN, ±inf, −0.0
+    else:
+        assert got == want, where
+
+
+@pytest.mark.parametrize("case", list(_COLLECT_CASES))
+def test_collect_is_to_pylist_cell_types_included(case):
+    from sparkdl_tpu.core import telemetry
+
+    df, vector_columns = _COLLECT_CASES[case]()
+    table = df.toArrow()
+    with telemetry.Telemetry() as tel:
+        rows = df.collect()
+    _assert_same_cells(rows, table.to_pylist())
+    (span,) = tel.tracer.spans(telemetry.SPAN_ROW_ASSEMBLY)
+    assert span["attributes"]["vector_columns"] == vector_columns
+    assert span["attributes"]["fallback_columns"] == \
+        table.num_columns - vector_columns
+
+
+def test_collect_counts_the_values_numpy_converted():
+    from sparkdl_tpu.core import telemetry
+
+    def counted(df):
+        with telemetry.Telemetry() as tel:
+            df.collect()
+        return tel.metrics.snapshot()["counters"].get(
+            telemetry.M_COLLECT_VECTORIZED_VALUES, 0)
+
+    vectors = _frame({
+        "i": pa.array(range(6)),
+        "v": pa.array([[1.0, 2.0, 3.0]] * 4 + [None, [4.0]],
+                      pa.list_(pa.float32()))}, parts=3)
+    assert counted(vectors) == 13
+    assert counted(make_df(10, 3)) == 0     # no vector column
+    # a chunk with a null value inside a row goes to Arrow whole; the
+    # column's other chunk still goes through numpy and is counted
+    mixed = _COLLECT_CASES["inner_nulls_in_one_partition"]()[0]
+    assert counted(mixed) == 4

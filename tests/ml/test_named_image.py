@@ -287,3 +287,37 @@ def test_r5_zoo_size_variants_registered():
     out = mf.apply_fn(mf.variables,
                       np.zeros((1, 224, 224, 3), np.float32))
     assert out.shape == (1, 960)
+
+
+def test_collect_assembles_the_features_column_through_numpy(rng):
+    """PR 31: under a scope, featurize → collect() says the features went
+    through numpy (counter = rows × width, one vector column, the image
+    struct falls back to Arrow); a row that does not decode stays None
+    between neighbours that are lists of float."""
+    from sparkdl_tpu.core import telemetry
+
+    structs = [imageIO.imageArrayToStruct(
+        rng.integers(0, 255, size=(40, 36, 3), dtype=np.uint8),
+        origin=f"i{i}") for i in range(5)]
+    structs[2] = dict(structs[2], data=structs[2]["data"][:10])
+    df = DataFrame.fromRows(
+        [{"image": s} for s in structs],
+        schema=pa.schema([pa.field("image", imageIO.imageSchema)]),
+        numPartitions=2)
+    out = DeepImageFeaturizer(inputCol="image", outputCol="features",
+                              modelName="TestNet", batchSize=4).transform(df)
+    with telemetry.Telemetry() as tel:
+        rows = out.collect()
+    width = registry.get_model_spec("TestNet").feature_dim
+    assert [r["features"] is None for r in rows] == \
+        [False, False, True, False, False]
+    for r in rows[:2] + rows[3:]:
+        assert type(r["features"]) is list and len(r["features"]) == width
+        assert {type(v) for v in r["features"]} == {float}
+    assert type(rows[0]["image"]["data"]) is bytes
+    counters = tel.metrics.snapshot()["counters"]
+    assert counters[telemetry.M_COLLECT_VECTORIZED_VALUES] == 4 * width
+    (span,) = tel.tracer.spans(telemetry.SPAN_ROW_ASSEMBLY)
+    assert span["attributes"]["vector_columns"] == 1
+    assert span["attributes"]["fallback_columns"] == 1
+    assert rows == out.toArrow().to_pylist()
