@@ -1,0 +1,82 @@
+#!/usr/bin/env python3
+"""Readings for the limits of ``correct`` of a ``windows`` cell, taken on the
+chip at the cell's own size (run by hand through the chip tool; ``control.py``
+reads the ``featurize`` and ``fit`` drivers' cells and knows no third):
+
+    python3 benchmarks/control_windows.py --workload <cell> --seeds 1,2,3
+
+For every seed, in one process, each of these goes through the harness's own
+comparison, ``check.decide`` with the cell's limits: the sound program
+against the plain reference (``sound``: the lower readings, with the
+routing's agreement by expert layer beside them); the control — the
+reference with float8 operands put in the program's place (the program's
+``inference_precision="int8"`` does not run this model); and a fault the
+cell can have — the sampled rows handed back in each other's place. One
+JSON line per seed.
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+CHECKOUT = os.path.dirname(HERE)
+
+
+def readings(driver, seconds, decide):
+    import harness
+    from references import plain
+
+    driver.setup()
+    driver.measure(seconds, harness.Tracer(False))
+    samples, got_counts = driver.samples, driver.expert_counts
+    driver.release()
+    pooled, logprobs, counts = driver.reference_outputs()
+    want = (pooled, logprobs)
+    out = {"sound": decide(driver.numbers(samples, want)),
+           "routing_agreement": driver.routing_agreement(got_counts, counts)}
+    control = driver.reference_outputs(quant=plain.fp8_operands)
+    out["control_fp8_reference"] = decide(driver.numbers([control[:2]], want))
+    out["control_routing_agreement"] = driver.routing_agreement(control[2],
+                                                                counts)
+    out["fault_rows_swapped"] = decide(driver.numbers(
+        [tuple(a[::-1] for a in samples[-1])], want))
+    return out
+
+
+def main(argv=None):
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--workload", required=True)
+    parser.add_argument("--seeds", required=True)
+    parser.add_argument("--seconds", type=float, default=5.0)
+    args = parser.parse_args(argv)
+
+    sys.path[:0] = [p for p in (HERE, CHECKOUT) if p not in sys.path]
+    import run as run_module
+
+    run_module.place_compile_cache()
+    import check
+    import harness
+    import peaks
+
+    cell = harness.Cell(args.workload)
+    device, chip_peaks = peaks.require_tpu(cell.chips)
+    module = harness.by_name("drivers", cell.config["entry"])
+    for seed in (int(s) for s in args.seeds.split(",")):
+        t0 = time.perf_counter()
+        driver = module.Driver(cell, seed, {"peaks": chip_peaks,
+                                            "device": device,
+                                            "root": harness.ROOT})
+        out = readings(driver, args.seconds, lambda numbers: dict(zip(
+            ("correct", "compared"),
+            check.decide(numbers, cell.workload["limits"]))))
+        print(json.dumps({"workload": args.workload, "seed": seed,
+                          "seconds": round(time.perf_counter() - t0, 1),
+                          **out}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -112,6 +112,7 @@ _LAZY_EXPORTS = {
     "TPUTransformer": ("sparkdl_tpu.ml", "TPUTransformer"),
     "DeepImageFeaturizer": ("sparkdl_tpu.ml", "DeepImageFeaturizer"),
     "DeepImagePredictor": ("sparkdl_tpu.ml", "DeepImagePredictor"),
+    "DeepSequenceScorer": ("sparkdl_tpu.ml", "DeepSequenceScorer"),
     "KerasImageFileTransformer": ("sparkdl_tpu.ml", "KerasImageFileTransformer"),
     "KerasImageFileEstimator": ("sparkdl_tpu.ml", "KerasImageFileEstimator"),
     "KerasTransformer": ("sparkdl_tpu.ml", "KerasTransformer"),

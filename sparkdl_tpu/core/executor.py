@@ -1560,11 +1560,13 @@ def execute(model: Any, array: Any, *, batch_size: int = 64,
         getattr(model, "name", "model"), eff_batch, multiple)
     if coalesce is None:
         coalesce = EngineConfig.coalesce
+    # counts a program returns among its outputs are recorded here, once
+    # they are on the host, and never reach the caller
     if not coalesce:
-        return model.apply_batch(array, batch_size=batch_size, mesh=mesh,
-                                 retry_policy=retry_policy,
-                                 prefetch=prefetch, donate=donate,
-                                 planner=planner)
+        return telemetry.take_program_counts(model.apply_batch(
+            array, batch_size=batch_size, mesh=mesh,
+            retry_policy=retry_policy, prefetch=prefetch, donate=donate,
+            planner=planner))
     import jax
 
     array = model.stage_inputs(array)
@@ -1575,10 +1577,10 @@ def execute(model: Any, array: Any, *, batch_size: int = 64,
     if rows == 0 or rows > cap:
         # nothing to coalesce (empty partitions hit the memoized empty
         # template) / already a full bucket or more: chunked path
-        return model.apply_batch(array, batch_size=batch_size, mesh=mesh,
-                                 retry_policy=retry_policy,
-                                 prefetch=prefetch, donate=donate,
-                                 planner=planner)
+        return telemetry.take_program_counts(model.apply_batch(
+            array, batch_size=batch_size, mesh=mesh,
+            retry_policy=retry_policy, prefetch=prefetch, donate=donate,
+            planner=planner))
     window_ms = (coalesce_window_ms if coalesce_window_ms is not None
                  else EngineConfig.coalesce_window_ms)
     window_s = None if window_ms is None else max(0.0, window_ms / 1e3)
@@ -1604,10 +1606,8 @@ def execute(model: Any, array: Any, *, batch_size: int = 64,
         tenant = current_tenant()
         if tenant is None:
             tenant = EngineConfig.executor_default_tenant
-    return _service.submit(model, array, rows, batch_size, mesh, multiple,
-                           policy, window_s, cap, prefetch,
-                           priority=priority, deadline=deadline,
-                           tenant=tenant,
-                           tenant_weights=EngineConfig.executor_tenant_weights,
-                           overload=overload, donate=donate,
-                           planner=planner)
+    return telemetry.take_program_counts(_service.submit(
+        model, array, rows, batch_size, mesh, multiple, policy, window_s,
+        cap, prefetch, priority=priority, deadline=deadline, tenant=tenant,
+        tenant_weights=EngineConfig.executor_tenant_weights,
+        overload=overload, donate=donate, planner=planner))

@@ -115,6 +115,25 @@ def _dequantize_tree(variables):
     return jax.tree_util.tree_map(deq, variables, is_leaf=_is_q8_leaf)
 
 
+def _cast_float_inputs(x, specs, dtype):
+    """A model's inputs cast to its compute ``dtype`` — except those whose
+    spec declares an integer dtype, which pass as they are. ``jnp.asarray``
+    first: an eager numpy input would otherwise flow numpy's promotion
+    rules through the graph (np-bf16 * python float -> f32, unlike JAX's
+    weak-type rules) and break dtype-strict convs mid-model. ``specs``
+    mirrors ``x``: one TensorSpec, or a dict of them for multi-input
+    models."""
+    def cast(a, spec):
+        a = jnp.asarray(a)
+        if jnp.issubdtype(jnp.dtype(spec.dtype), jnp.integer):
+            return a
+        return a.astype(dtype)
+
+    if isinstance(specs, dict):
+        return {name: cast(a, specs[name]) for name, a in x.items()}
+    return cast(x, specs)
+
+
 _DONATION_WARNING_MSG = "Some donated buffers were not usable"
 
 
@@ -396,28 +415,35 @@ class ModelFunction:
 
     def with_compute_dtype(self, dtype) -> "ModelFunction":
         """Run this model in ``dtype`` (e.g. bfloat16 for MXU inference):
-        float weights cast once here, input casts in-program, output casts
-        back to the original output dtype. Used by the registry's
-        ingestion-backed named models, whose keras-derived apply is
-        float32 by construction."""
+        float weights cast once here, float inputs cast in-program, float
+        outputs cast back to float32. An input whose spec is an integer
+        dtype (token ids) and integer outputs (counts) pass uncast: an id
+        above 256 does not survive bfloat16. Weights that arrive in
+        ``dtype`` already are taken as they are — no second copy is kept.
+        Used by the registry's ingestion-backed named models, whose
+        keras-derived apply is float32 by construction."""
         import jax.numpy as jnp
 
         dtype = jnp.dtype(dtype)
         apply_fn = self.apply_fn
-        variables = jax.tree.map(
-            lambda a: a.astype(dtype)
-            if hasattr(a, "dtype") and jnp.issubdtype(a.dtype, jnp.floating)
-            else a, self.variables)
+
+        def is_float(a):
+            return hasattr(a, "dtype") and jnp.issubdtype(a.dtype,
+                                                          jnp.floating)
+
+        if any(is_float(a) and a.dtype != dtype
+               for a in jax.tree.leaves(self.variables)):
+            variables = jax.tree.map(
+                lambda a: a.astype(dtype) if is_float(a) else a,
+                self.variables)
+        else:
+            variables = self.variables
+        specs = self.input_spec
 
         def fn(vs, x):
-            # jnp.asarray first: an eager numpy input would otherwise flow
-            # numpy's promotion rules through the graph (np-bf16 * python
-            # float -> f32, unlike JAX's weak-type rules) and break
-            # dtype-strict convs mid-model. tree.map, not a bare astype:
-            # multi-input models feed a dict of arrays.
-            x = jax.tree.map(lambda a: jnp.asarray(a).astype(dtype), x)
-            out = apply_fn(vs, x)
-            return jax.tree.map(lambda o: o.astype(jnp.float32), out)
+            out = apply_fn(vs, _cast_float_inputs(x, specs, dtype))
+            return jax.tree.map(
+                lambda o: o.astype(jnp.float32) if is_float(o) else o, out)
 
         out = ModelFunction(fn, variables, self.input_spec, name=self.name,
                             trainable_mask=self.trainable_mask)
@@ -425,7 +451,10 @@ class ModelFunction:
         # model's msgpack artifact would otherwise store truncated values
         # that switching back to f32 cannot recover). Chain through an
         # existing source so re-casting a cast model keeps the original.
-        out.float_source = getattr(self, "float_source", self)
+        # Where nothing was cast there is nothing to keep.
+        source = getattr(self, "float_source", None)
+        if source is not None or variables is not self.variables:
+            out.float_source = source or self
         return out
 
     def with_dtype(self, precision: str) -> "ModelFunction":
@@ -491,12 +520,11 @@ class ModelFunction:
                     _Q8_SCALE: jnp.asarray(scale)}
 
         variables = jax.tree.map(quant, self.variables)
+        specs = self.input_spec
 
         def fn(vs, x):
             deq = _dequantize_tree(vs)
-            x = jax.tree.map(
-                lambda a: jnp.asarray(a).astype(jnp.bfloat16), x)
-            out = apply_fn(deq, x)
+            out = apply_fn(deq, _cast_float_inputs(x, specs, jnp.bfloat16))
             return jax.tree.map(lambda o: o.astype(jnp.float32), out)
 
         # trainable_mask dropped deliberately: quantized weights are an

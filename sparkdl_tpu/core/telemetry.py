@@ -259,6 +259,31 @@ M_KERNEL_AUTOTUNE_S = "sparkdl.kernel.autotune_s"      # histogram
 M_KERNEL_ADOPTED = "sparkdl.kernel.adopted"            # counter
 M_KERNEL_REJECTED = "sparkdl.kernel.rejected"          # counter
 M_KERNEL_AUDITION_ERROR = "sparkdl.kernel.audition_error"  # counter
+# Counts that come OUT of a compiled program (models/latent_moe.py): the
+# program returns them as small row-aligned outputs under the reserved
+# output name PROGRAM_COUNTS = {metric name: array, dim 0 = rows}, and the
+# executor's choke point records them once the outputs are on the host
+# (take_program_counts) — an integer array adds its sum to the counter, a
+# float array feeds every value to the histogram. Pad rows are cut off
+# before, like every other output's.
+PROGRAM_COUNTS = "sparkdl.program_counts"
+M_SEQUENCE_TOKENS = "sparkdl.sequence.tokens"          # counter (tokens of
+                                                       # the rows scored)
+M_MOE_ROUTED_TOKENS = "sparkdl.moe.routed_tokens"      # counter (tokens
+                                                       # routed, once for each
+                                                       # expert layer)
+M_MOE_LOCAL_PAIRS = "sparkdl.moe.local_pairs"          # counter ((token,
+                                                       # expert) pairs routed
+                                                       # to experts held here)
+M_MOE_OVERFLOW_PAIRS = "sparkdl.moe.overflow_pairs"    # counter (pairs beyond
+                                                       # the grouped products'
+                                                       # buffer: computed in a
+                                                       # further round)
+M_MOE_LOAD_MAX_OVER_MEAN = "sparkdl.moe.load_max_over_mean"  # histogram (per
+                                                       # row and expert layer:
+                                                       # its launch's fullest
+                                                       # held expert's pairs
+                                                       # over the mean)
 # Per-tenant fair queueing (core/executor.py, docs/RESILIENCE.md): each
 # tenant's queue-wait histogram gets a per-tenant NAME (metrics carry no
 # labels), declared dynamically as "sparkdl.executor.queue_wait_s.<tenant>"
@@ -312,6 +337,11 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_KERNEL_ADOPTED: "counter",
     M_KERNEL_REJECTED: "counter",
     M_KERNEL_AUDITION_ERROR: "counter",
+    M_SEQUENCE_TOKENS: "counter",
+    M_MOE_ROUTED_TOKENS: "counter",
+    M_MOE_LOCAL_PAIRS: "counter",
+    M_MOE_OVERFLOW_PAIRS: "counter",
+    M_MOE_LOAD_MAX_OVER_MEAN: "histogram",
 }
 
 CANONICAL_METRIC_NAMES = frozenset(CANONICAL_METRIC_KINDS)
@@ -1868,6 +1898,28 @@ def observe(name: str, value: float,
     tel = _active
     if tel is not None:
         tel.metrics.histogram(name, bounds).observe(value, exemplar)
+
+
+#: Bounds of the ratio histograms a program reports (1 = even load).
+RATIO_BOUNDS = (1.0, 1.05, 1.1, 1.2, 1.35, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0)
+
+
+def take_program_counts(outputs: Any) -> Any:
+    """Strip the ``PROGRAM_COUNTS`` entry off a model's host outputs and
+    record it on the active scope (see the catalog above). Outputs without
+    the entry — every model but the ones that count — pass through."""
+    if not isinstance(outputs, dict) or PROGRAM_COUNTS not in outputs:
+        return outputs
+    outputs = dict(outputs)
+    counts = outputs.pop(PROGRAM_COUNTS)
+    if _active is not None:
+        for name, values in counts.items():
+            if CANONICAL_METRIC_KINDS.get(name) == "histogram":
+                for value in values.ravel().tolist():
+                    observe(name, value, bounds=RATIO_BOUNDS)
+            else:
+                count(name, int(values.sum()))
+    return outputs
 
 
 def remote_span(name: str, start_abs_ns: int, end_abs_ns: int, *,

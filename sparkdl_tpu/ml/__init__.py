@@ -56,6 +56,7 @@ from sparkdl_tpu.ml.image_transformer import TPUImageTransformer
 from sparkdl_tpu.ml.keras_image import KerasImageFileTransformer
 from sparkdl_tpu.ml.keras_tensor import KerasTransformer
 from sparkdl_tpu.ml.named_image import DeepImageFeaturizer, DeepImagePredictor
+from sparkdl_tpu.ml.named_sequence import DeepSequenceScorer
 from sparkdl_tpu.ml.persistence import load
 from sparkdl_tpu.ml.tensor_transformer import TPUTransformer
 
@@ -70,6 +71,7 @@ __all__ = [
     "CrossValidatorModel",
     "DeepImageFeaturizer",
     "DeepImagePredictor",
+    "DeepSequenceScorer",
     "Estimator",
     "MulticlassClassificationEvaluator",
     "ParamGridBuilder",

@@ -15,6 +15,7 @@ import numpy as np
 import pyarrow as pa
 
 from sparkdl_tpu.core import executor as device_executor
+from sparkdl_tpu.core import profiling
 from sparkdl_tpu.engine.dataframe import (
     _schema_with,
     _set_column,
@@ -64,7 +65,10 @@ class TPUTransformer(Transformer, HasInputCol, HasOutputCol,
     ``TFTransformer``'s tensor↔column maps, SURVEY.md §2.1): a model whose
     ``input_spec`` is a ``{input-name: TensorSpec}`` dict takes
     ``inputMapping={column: input-name}`` and emits one column per entry of
-    ``outputMapping={output-name: column}`` from its dict output.
+    ``outputMapping={output-name: column}`` from its dict output. A model
+    with ONE input and a dict output takes ``inputCol`` with
+    ``outputMapping``. Input blocks are staged in the spec's dtype, so a
+    ``list<int32>`` column of token ids reaches an ``int32`` spec as it is.
     """
 
     inputMapping = Param(
@@ -127,7 +131,8 @@ class TPUTransformer(Transformer, HasInputCol, HasOutputCol,
         # single-process, idempotent across chained transformers. Assembly
         # is opt-in via DataFrame.gatherProcesses (docs/DISTRIBUTED.md).
         dataset = dataset.processShard()
-        if isinstance(model.input_spec, dict) or self.getInputMapping():
+        if (isinstance(model.input_spec, dict) or self.getInputMapping()
+                or self.getOutputMapping()):
             return self._transform_multi(dataset, model)
         input_col = self.getInputCol()
         output_col = self.getOutputCol()
@@ -148,9 +153,11 @@ class TPUTransformer(Transformer, HasInputCol, HasOutputCol,
             block = block.astype(model.input_spec.dtype, copy=False)
             # device entry via the execution-service choke point
             # (core/executor.py): concurrent partition chunks coalesce
-            out = device_executor.execute(model, block,
-                                          batch_size=batch_size, mesh=mesh,
-                                          priority=priority)
+            with profiling.annotate("sparkdl.device_apply",
+                                    rows=batch.num_rows):
+                out = device_executor.execute(
+                    model, block, batch_size=batch_size, mesh=mesh,
+                    priority=priority)
             out = np.asarray(out, dtype=np.float32).reshape(batch.num_rows, -1)
             return fixed_size_list_array(out).cast(pa.list_(pa.float32()))
 
@@ -161,24 +168,32 @@ class TPUTransformer(Transformer, HasInputCol, HasOutputCol,
         """Column↔named-IO mapping path for dict-spec models."""
         in_map = self.getInputMapping()
         out_map = self.getOutputMapping()
-        if not isinstance(model.input_spec, dict):
+        single = not isinstance(model.input_spec, dict)
+        if single and in_map:
             raise ValueError(
                 "inputMapping requires a model with a dict input_spec")
-        if not in_map:
-            raise ValueError(
-                "multi-input model requires inputMapping={column: input}")
         if not out_map:
             raise ValueError(
                 "multi-input model requires outputMapping={output: column}")
-        missing = set(model.input_spec) - set(in_map.values())
-        if missing:
-            raise ValueError(f"inputMapping covers no column for model "
-                             f"inputs {sorted(missing)}")
-        unknown = set(in_map.values()) - set(model.input_spec)
-        if unknown:
-            raise ValueError(
-                f"inputMapping references unknown model inputs "
-                f"{sorted(unknown)}; model has {sorted(model.input_spec)}")
+        if single:
+            # one input column, several outputs: the spec stands under the
+            # column's own name below and the block goes in bare
+            in_map = {self.getInputCol(): None}
+            input_specs = {None: model.input_spec}
+        else:
+            input_specs = model.input_spec
+            if not in_map:
+                raise ValueError(
+                    "multi-input model requires inputMapping={column: input}")
+            missing = set(input_specs) - set(in_map.values())
+            if missing:
+                raise ValueError(f"inputMapping covers no column for model "
+                                 f"inputs {sorted(missing)}")
+            unknown = set(in_map.values()) - set(input_specs)
+            if unknown:
+                raise ValueError(
+                    f"inputMapping references unknown model inputs "
+                    f"{sorted(unknown)}; model has {sorted(input_specs)}")
         for col in in_map:
             if col not in dataset.columns:
                 raise KeyError(f"No such column: {col!r}")
@@ -199,12 +214,16 @@ class TPUTransformer(Transformer, HasInputCol, HasOutputCol,
                 return out
             blocks = {}
             for col, input_name in in_map.items():
-                spec = model.input_spec[input_name]
+                spec = input_specs[input_name]
                 arr = batch.column(batch.schema.get_field_index(col))
-                blocks[input_name] = column_to_block(arr, spec.element_shape)
-            outs = device_executor.execute(model, blocks,
-                                           batch_size=batch_size, mesh=mesh,
-                                           priority=priority)
+                blocks[input_name] = column_to_block(
+                    arr, spec.element_shape).astype(spec.dtype, copy=False)
+            if single:
+                blocks = blocks[None]
+            with profiling.annotate("sparkdl.device_apply", rows=n):
+                outs = device_executor.execute(
+                    model, blocks, batch_size=batch_size, mesh=mesh,
+                    priority=priority)
             if not isinstance(outs, dict):
                 raise ValueError(
                     "outputMapping requires the model to return a "

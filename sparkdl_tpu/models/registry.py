@@ -14,6 +14,7 @@ dimension, and how to obtain weights. Weight sources:
 
 from __future__ import annotations
 
+import dataclasses
 import os
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -23,7 +24,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from sparkdl_tpu.core.model_function import ModelFunction, TensorSpec
+from sparkdl_tpu.models import latent_moe
 from sparkdl_tpu.models.inception import InceptionV3
+from sparkdl_tpu.models.latent_moe import LatentMoEConfig
 from sparkdl_tpu.models.mobilenet import MobileNetV2
 from sparkdl_tpu.models.resnet import ResNet50, ResNet101, ResNet152
 from sparkdl_tpu.models.testnet import TestNet
@@ -211,6 +214,69 @@ _KERAS_BUILDERS = {
     "VGG19": ("vgg19", "VGG19"),
     "MobileNetV2": ("mobilenet_v2", "MobileNetV2"),
 }
+
+
+# Sequence models (token-id windows in, a pooled state and per-token
+# log-probabilities out; models/latent_moe.py): the published sizes, with
+# every expert and the whole vocabulary. What a chip holds of them —
+# how many layers, which experts, which slice of the vocabulary — comes
+# with the weights (build_sequence_scorer).
+SEQUENCE_MODELS: Dict[str, LatentMoEConfig] = {
+    # huggingface.co/FreedomIntelligence/openPangu-Ultra-MoE-718B config.json
+    "openPangu-Ultra-MoE-718B": LatentMoEConfig(
+        hidden=7680, heads=128, q_rank=1536, kv_rank=512, nope=128, rope=64,
+        v=128, dense_width=18432, expert_width=2048, experts=256,
+        experts_held=tuple(range(256)), top_k=8, vocab=153600, layers=61,
+        dense_layers=3, scaling=2.5, norm_topk=True, eps=1e-5,
+        theta=25600000.0),
+    # the same block at sizes a CPU test runs (TestNet's counterpart)
+    "TestLatentMoE": LatentMoEConfig(
+        hidden=64, heads=4, q_rank=32, kv_rank=16, nope=16, rope=8, v=16,
+        dense_width=128, expert_width=32, experts=16,
+        experts_held=tuple(range(16)), top_k=4, vocab=64, layers=3,
+        dense_layers=2, scaling=2.5, norm_topk=True, eps=1e-5,
+        theta=25600000.0, query_block=8),
+}
+
+
+def build_sequence_scorer(name, weights: Dict[str, Any], window: int,
+                          experts_held=None) -> ModelFunction:
+    """Named sequence model as a ModelFunction over ``(rows, window)`` int32
+    token ids, emitting ``pooled``, ``logprobs`` and ``expert_counts``.
+
+    ``name``: a key of :data:`SEQUENCE_MODELS`, or a ``LatentMoEConfig`` of
+    one's own. ``weights``: the variables dict the model is run with —
+    ``{"embed", "layers": [...], "final_norm", "head"}``, taken as given
+    (bfloat16 on the device for a model of this size; there is no
+    ``"random"``). The chip's share is read off them: as many layers as
+    the list has, dense where a layer has ``"mlp"``, the vocabulary slice of
+    ``embed``'s rows; ``experts_held`` names the expert ids the expert layers'
+    stacked weights stand for (default: all of them, where all are there).
+    """
+    config = SEQUENCE_MODELS.get(name) if isinstance(name, str) else name
+    if config is None:
+        raise ValueError(f"Unsupported sequence model {name!r}; supported: "
+                         f"{sorted(SEQUENCE_MODELS)}")
+    layers = weights["layers"]
+    dense = sum(1 for layer in layers if "mlp" in layer)
+    if any("mlp" in layer for layer in layers[dense:]):
+        raise ValueError("dense layers must lead the expert layers")
+    stacked = {layer["moe"]["experts"]["down"].shape[0]
+               for layer in layers[dense:]}
+    if experts_held is None:
+        experts_held = range(config.experts)
+    experts_held = tuple(int(e) for e in experts_held)
+    if stacked - {len(experts_held)}:
+        raise ValueError(
+            f"the expert layers hold {sorted(stacked)} experts' weights, "
+            f"experts_held names {len(experts_held)}")
+    config = dataclasses.replace(
+        config, layers=len(layers), dense_layers=dense,
+        experts_held=experts_held, vocab=int(weights["embed"].shape[0]))
+    label = name if isinstance(name, str) else "latent_moe"
+    return ModelFunction.fromFunction(
+        lambda vs, tokens: latent_moe.forward(vs, tokens, config), weights,
+        TensorSpec((None, int(window)), "int32"), name=f"{label}_score")
 
 
 def get_model_spec(name: str) -> ModelSpec:
