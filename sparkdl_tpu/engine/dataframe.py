@@ -25,6 +25,7 @@ for the TPU data path rather than translated from Spark:
 from __future__ import annotations
 
 import concurrent.futures as _futures
+import itertools
 import os
 import threading
 from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
@@ -599,12 +600,23 @@ def _cluster_dispatch() -> Callable[..., pa.RecordBatch]:
     return _run_partition if router is None else router.run_partition
 
 
-def _row_assembly(table: pa.Table, to: str):
-    """The ``sparkdl.row_assembly`` span (and phase timer) around turning a
-    whole Arrow table into Python rows or pandas — ``collect()``'s and
-    ``toPandas()``'s own cost, apart from materializing the table."""
+# Rows collect() assembled while another partition of the same collect() was
+# still unresolved: the assembly hidden under the later partitions' H2D and
+# device work; 0 where collect() took the whole-table path. Declared here and
+# not in core/telemetry.py's table: the engine alone writes it, and the
+# catalog module is imported by paths that never see a DataFrame.
+M_COLLECT_OVERLAPPED_ROWS = telemetry.declare_metric(
+    "sparkdl.collect.overlapped_rows", "counter")
+
+
+def _row_assembly(table: pa.Table, to: str, **attributes: Any):
+    """The ``sparkdl.row_assembly`` span (and phase timer) around turning an
+    Arrow table — the whole frame's, or one partition's in ``collect()`` —
+    into Python rows or pandas: ``collect()``'s and ``toPandas()``'s own
+    cost, apart from computing the partitions."""
     return profiling.annotate(telemetry.SPAN_ROW_ASSEMBLY,
-                              rows=table.num_rows, bytes=table.nbytes, to=to)
+                              rows=table.num_rows, bytes=table.nbytes, to=to,
+                              **attributes)
 
 
 def _is_vector_type(t: pa.DataType) -> bool:
@@ -681,6 +693,19 @@ def _table_rows(table: pa.Table) -> Tuple[List[Dict[str, Any]], int, int]:
     names = table.column_names
     return ([dict(zip(names, row)) for row in zip(*columns)],
             vector_columns, values)
+
+
+def _assemble_rows(table: pa.Table, **attributes: Any
+                   ) -> List[Dict[str, Any]]:
+    """``collect()``'s rows of ``table`` (:func:`_table_rows`) under one
+    ``sparkdl.row_assembly`` span; ``attributes`` ride on the span."""
+    with _row_assembly(table, "pylist", **attributes) as span:
+        rows, vector_columns, values = _table_rows(table)
+        span.set_attribute("vector_columns", vector_columns)
+        span.set_attribute("fallback_columns",
+                           table.num_columns - vector_columns)
+        telemetry.count(telemetry.M_COLLECT_VECTORIZED_VALUES, values)
+        return rows
 
 
 def _as_record_batches(table: pa.Table, num_partitions: int) -> List[pa.RecordBatch]:
@@ -782,7 +807,14 @@ class DataFrame:
             out = op(out)
         return out
 
-    def _materialize(self) -> List[pa.RecordBatch]:
+    def _materialize(self, on_result: Optional[
+            Callable[[int, pa.RecordBatch], None]] = None
+            ) -> List[pa.RecordBatch]:
+        """The computed partitions, in order; computed once and kept.
+        ``on_result`` is :meth:`PartitionSupervisor.run_all`'s, and is
+        used only where the partitions run as supervised tasks of this
+        call: a frame already materialized or without ops, a nested call
+        run inline and the durable path hand nothing over."""
         with self._lock:
             if self._materialized is not None:
                 return self._materialized
@@ -827,7 +859,7 @@ class DataFrame:
                 self._materialized = sup.run_all(
                     [(i, lambda cancel, i=i, b=b: dispatch(i, b, ops,
                                                            cancel))
-                     for i, b in enumerate(self._partitions)])
+                     for i, b in enumerate(self._partitions)], on_result)
             return self._materialized
 
     def _durable_supervisor(self, journal) -> PartitionSupervisor:
@@ -926,15 +958,55 @@ class DataFrame:
         schema's order, equal to ``toArrow().to_pylist()`` cell types
         included — a vector cell is a list of Python ``int``/``float``, a
         ``binary`` cell ``bytes``, a struct a dict, a null cell ``None``
-        (tests/engine/test_dataframe.py holds ``collect()`` to it)."""
-        table = self.toArrow()
-        with _row_assembly(table, "pylist") as span:
-            rows, vector_columns, values = _table_rows(table)
-            span.set_attribute("vector_columns", vector_columns)
-            span.set_attribute("fallback_columns",
-                               table.num_columns - vector_columns)
-            telemetry.count(telemetry.M_COLLECT_VECTORIZED_VALUES, values)
-            return rows
+        (tests/engine/test_dataframe.py holds ``collect()`` to it).
+
+        Where the partitions are computed by this call, as supervised
+        tasks, a partition's rows are assembled as soon as its task has
+        resolved, on the calling thread, while the partitions behind it
+        are still in H2D or on the device (``run_all``'s ``on_result``;
+        what that costs the supervision is said there), and the lists are
+        put together in partition order at the end, whatever order the
+        partitions finished in. Each assembled partition has its own
+        ``sparkdl.row_assembly`` span (attribute ``partition``);
+        ``sparkdl.collect.overlapped_rows`` counts the rows assembled
+        with another partition still unresolved. That is done only while
+        every batch has the frame's declared schema, since then the
+        table's rows are the partitions' rows, one after the other. On
+        the first batch that has another one (a ``withColumn`` without
+        ``outputType``) what was assembled is dropped and the rows are
+        those of ``toArrow()``'s unified, cast table, assembled whole
+        under one span — as they are for a frame already materialized or
+        without ops, one partition, a nested ``collect()`` from a
+        partition's thread and the durable path, where nothing runs
+        beside the assembly. A failing partition raises what
+        ``toArrow()`` raises, and the rows assembled until then go with
+        it."""
+        assembled: Dict[int, List[Dict[str, Any]]] = {}
+        declared = True     # every batch so far has the declared schema
+
+        def assemble(index: int, batch: pa.RecordBatch) -> None:
+            nonlocal declared
+            if not declared:
+                return
+            try:    # Table.from_batches' own test in toArrow()
+                table = pa.Table.from_batches([batch], schema=self._schema)
+            except (pa.ArrowInvalid, pa.ArrowTypeError):
+                declared = False
+                return
+            assembled[index] = _assemble_rows(table, partition=index)
+
+        batches = self._materialize(assemble)
+        # what run_all handed over, it handed over beside unresolved tasks
+        overlapped = sum(map(len, assembled.values()))
+        if assembled:
+            for index, batch in enumerate(batches):
+                if index not in assembled:
+                    assemble(index, batch)
+        if not (assembled and declared):
+            return _assemble_rows(self.toArrow())
+        telemetry.count(M_COLLECT_OVERLAPPED_ROWS, overlapped)
+        return list(itertools.chain.from_iterable(
+            assembled[index] for index in range(len(batches))))
 
     def count(self) -> int:
         return sum(b.num_rows for b in self._materialize())

@@ -397,20 +397,46 @@ class PartitionSupervisor:
     # -- barrier mode (materialize) ------------------------------------------
 
     def run_all(self, indexed_runners:
-                Sequence[Tuple[int, Callable[[threading.Event], Any]]]
+                Sequence[Tuple[int, Callable[[threading.Event], Any]]],
+                on_result: Optional[Callable[[int, Any], None]] = None
                 ) -> List[Any]:
         """Run every task; results in input order. First failure raises
         (after the barrier drain), unless quarantine absorbs it. Each
-        runner receives the task's cancellation event."""
+        runner receives the task's cancellation event.
+
+        ``on_result(index, result)`` lets the caller work on a finished
+        task's result while the run still waits for other tasks
+        (``DataFrame.collect`` assembles a partition's rows there). It is
+        called on THIS thread, the supervising one, between two ticks and
+        at most once a tick, with the winning result of a task that has
+        resolved — never a hedge loser's, a failed task's or a quarantine
+        stand-in — and only while at least one other task of the run is
+        still unresolved. A result not handed over by the time the last
+        task resolves is not handed over at all: the caller takes it from
+        the returned list. While the callback runs the tick does not, so
+        a deadline check or a hedge for the tasks still running comes
+        late by at most ONE call's duration on top of
+        ``poll_interval_s``, which keeps its meaning (with a result
+        waiting to be handed over the tick polls without blocking). An
+        exception out of the callback unwinds like any other: barrier
+        drain first, then it propagates."""
         tasks: List[_Task] = []
         outstanding: Dict[_futures.Future, _Task] = {}
         for index, runner in indexed_runners:
             task = _Task(index, runner, self._pool.submit)
             outstanding[task.launch()] = task
             tasks.append(task)
+        ready: "deque[_Task]" = deque()  # resolved, not yet handed over
         try:
             while not all(t.done for t in tasks):
-                self._tick(outstanding, tasks, len(tasks))
+                resolved = self._tick(outstanding, tasks, len(tasks),
+                                      block=not ready)
+                if on_result is None:
+                    continue
+                ready.extend(resolved)
+                if ready and not all(t.done for t in tasks):
+                    task = ready.popleft()
+                    on_result(task.index, task.result)
         except BaseException:
             self._drain(outstanding, include_lingering=True)
             raise
@@ -473,17 +499,23 @@ class PartitionSupervisor:
     # -- the supervision tick ------------------------------------------------
 
     def _tick(self, outstanding: Dict[_futures.Future, _Task],
-              tasks: List[_Task], total: int) -> None:
+              tasks: List[_Task], total: int, block: bool = True
+              ) -> List[_Task]:
+        """One round of supervision; returns the tasks that resolved with
+        a result in it. ``block=False`` looks without waiting."""
         live = [f for f in outstanding]
         if live:
-            _futures.wait(live, timeout=self._cfg.poll_interval_s,
+            _futures.wait(live,
+                          timeout=self._cfg.poll_interval_s if block else 0,
                           return_when=_futures.FIRST_COMPLETED)
-        self._resolve_ready(outstanding)
+        resolved = self._resolve_ready(outstanding)
         self._check_deadlines(tasks, outstanding)
         self._maybe_hedge(tasks, outstanding, total)
+        return resolved
 
     def _resolve_ready(self, outstanding: Dict[_futures.Future, _Task]
-                       ) -> None:
+                       ) -> List[_Task]:
+        resolved: List[_Task] = []
         for fut in [f for f in outstanding if f.done()]:
             task = outstanding.pop(fut, None)
             if task is None or task.done or fut.cancelled():
@@ -512,6 +544,7 @@ class PartitionSupervisor:
                                   partition=task.index)
             else:
                 task.result = fut.result()
+                resolved.append(task)
                 self._durations.append(task.duration)
                 if telemetry.active() is not None:
                     # rows/bytes of the WINNING attempt only (a hedge
@@ -540,6 +573,7 @@ class PartitionSupervisor:
                     outstanding.pop(other, None)
                     if not other.cancel():
                         self._lingering.append(other)
+        return resolved
 
     def _check_deadlines(self, tasks: List[_Task],
                          outstanding: Dict[_futures.Future, _Task]) -> None:

@@ -1,5 +1,8 @@
 """Engine DataFrame tests — partitioned execution, retry, columnar UDFs."""
 
+import os
+import threading
+
 import numpy as np
 import pandas as pd
 import pyarrow as pa
@@ -562,15 +565,24 @@ def test_row_assembly_span_follows_materialize(method, to):
     with telemetry.Telemetry() as tel:
         getattr(_mapped_df(), method)()
     assembly = tel.tracer.spans(telemetry.SPAN_ROW_ASSEMBLY)
-    materialize = tel.tracer.spans(telemetry.SPAN_MATERIALIZE)
-    assert len(assembly) == 1 and len(materialize) == 1
-    span = assembly[0]
-    assert span["attributes"]["rows"] == 12
-    assert span["attributes"]["to"] == to
-    assert span["attributes"]["bytes"] > 0
-    # the table is whole before assembly starts: materialize stays outside
-    assert span["start_ns"] >= materialize[0]["end_ns"]
-    assert span["parent_id"] != materialize[0]["span_id"]
+    (materialize,) = tel.tracer.spans(telemetry.SPAN_MATERIALIZE)
+    assert sum(s["attributes"]["rows"] for s in assembly) == 12
+    assert all(s["attributes"]["to"] == to and s["attributes"]["bytes"] > 0
+               for s in assembly)
+    # toPandas(): the table is whole before the one assembly starts.
+    # collect(): a partition assembled while others still ran lies inside
+    # materialize, the rest (the last to resolve among them) after it
+    early = [s for s in assembly
+             if s["parent_id"] == materialize["span_id"]]
+    late = [s for s in assembly if s not in early]
+    assert late and all(s["start_ns"] >= materialize["end_ns"]
+                        for s in late)
+    assert all(s["end_ns"] <= materialize["end_ns"] for s in early)
+    if method == "toPandas":
+        assert len(assembly) == 1
+    else:   # one span a partition, or one over the whole table
+        assert sorted(s["attributes"].get("partition", 0)
+                      for s in assembly) in ([0], [0, 1, 2])
 
 
 def test_row_assembly_without_scope_feeds_the_phase_timer_only(monkeypatch):
@@ -583,10 +595,12 @@ def test_row_assembly_without_scope_feeds_the_phase_timer_only(monkeypatch):
     before = profiling.phase_stats().get(telemetry.SPAN_ROW_ASSEMBLY,
                                          {"count": 0})["count"]
     df = _mapped_df()
-    df.collect()
+    df.collect()    # one timing a partition, or one for the whole table
+    mid = profiling.phase_stats()[telemetry.SPAN_ROW_ASSEMBLY]["count"]
     df.toPandas()
     after = profiling.phase_stats()[telemetry.SPAN_ROW_ASSEMBLY]
-    assert after["count"] == before + 2 and after["total_s"] > 0
+    assert mid - before in (1, 3)
+    assert after["count"] == mid + 1 and after["total_s"] > 0
     assert made == []
 
 
@@ -787,3 +801,313 @@ def test_collect_counts_the_values_numpy_converted():
     # column's other chunk still goes through numpy and is counted
     mixed = _COLLECT_CASES["inner_nulls_in_one_partition"]()[0]
     assert counted(mixed) == 4
+
+
+# ---------------------------------------------------------------------------
+# collect() assembles a partition's rows as soon as the partition has
+# resolved, under the later partitions' work (PR 34). The tests make the
+# partitions resolve in a chosen order, each only once collect() has been
+# handed the one before it, so what is assembled early is decided and not
+# left to the threads
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def in_order(monkeypatch):
+    """``in_order(frame, order)``: ``frame`` with a pass-through op that
+    holds partition ``order[k + 1]`` back until ``run_all`` has handed
+    partition ``order[k]`` to its ``on_result`` — so all but ``order[-1]``
+    are handed over while something is unresolved, and ``order[-1]``, which
+    resolves last, never is."""
+    from sparkdl_tpu.engine.supervisor import PartitionSupervisor
+
+    monkeypatch.setattr(EngineConfig, "max_workers", 8)
+    gates = {}      # id(first batch of the frame) → (gate by partition, next)
+    real = PartitionSupervisor.run_all
+
+    def run_all(sup, runners, on_result=None):
+        def handed(index, result):
+            on_result(index, result)
+            for events, after in gates.values():
+                if index in after:
+                    events[after[index]].set()
+        return real(sup, runners, handed if on_result else None)
+
+    monkeypatch.setattr(PartitionSupervisor, "run_all", run_all)
+
+    def make(frame, order, op=lambda index, batch: batch):
+        parts = frame._partitions
+        order = list(order)
+        assert not frame._ops and sorted(order) == list(range(len(parts)))
+        events = {i: threading.Event() for i in order}
+        if order:
+            events[order[0]].set()
+        gates[id(events)] = (events, dict(zip(order, order[1:])))
+
+        def held(batch):
+            index = next((i for i, p in enumerate(parts) if p is batch),
+                         None)      # None: a quarantine probe's empty slice
+            if index is not None:
+                assert events[index].wait(20), "the gate never opened"
+            return op(index, batch)
+
+        return frame.mapPartitions(held)
+
+    yield make
+    for events, _ in gates.values():
+        for event in events.values():
+            event.set()
+
+
+def _collect_traced(df):
+    """``df.collect()`` under a scope: rows, the row-assembly spans in the
+    order they were opened, the overlapped-rows counter (None: not bumped)."""
+    from sparkdl_tpu.core import telemetry
+    from sparkdl_tpu.engine import dataframe
+
+    with telemetry.Telemetry() as tel:
+        rows = df.collect()
+    spans = sorted(tel.tracer.spans(telemetry.SPAN_ROW_ASSEMBLY),
+                   key=lambda s: s["start_ns"])
+    counter = tel.metrics.snapshot()["counters"].get(
+        dataframe.M_COLLECT_OVERLAPPED_ROWS)
+    return rows, spans, counter
+
+
+_OWN_PARTITIONS = ["sliced_chunks", "several_partitions",
+                   "inner_nulls_in_one_partition", "duplicated_column_name"]
+
+
+@pytest.mark.parametrize(
+    "case,parts",
+    [(case, parts) for case in _COLLECT_CASES for parts in (1, 2, 4)]
+    + [(case, "own") for case in _OWN_PARTITIONS])
+def test_collect_with_ops_is_to_pylist_whatever_the_partitions(
+        case, parts, in_order):
+    table = _COLLECT_CASES[case]()[0].toArrow()
+    if parts == "own":  # the case's own batches: slices, offsets and all
+        base = DataFrame(table.to_batches(), table.schema)
+    else:
+        base = DataFrame.fromArrow(table, numPartitions=parts)
+    n = base.numPartitions
+    sizes = [b.num_rows for b in base._partitions]
+    # backwards: the first partition resolves last
+    df = in_order(base, reversed(range(n)))
+    rows, spans, counter = _collect_traced(df)
+    _assert_same_cells(rows, table.to_pylist())
+    _assert_same_cells(rows, df.toArrow().to_pylist())
+    if n == 1:      # nothing to overlap with: the whole-table path
+        (span,) = spans
+        assert "partition" not in span["attributes"] and counter is None
+    else:
+        assert [s["attributes"]["partition"] for s in spans] == \
+            list(reversed(range(n)))
+        assert [s["attributes"]["rows"] for s in spans] == sizes[::-1]
+        assert counter == sum(sizes) - sizes[0]
+    # a column goes through numpy whole in the table where it does in
+    # every partition
+    assert min(s["attributes"]["vector_columns"] for s in spans) == \
+        _COLLECT_CASES[case]()[1]
+
+
+def _uneven_frame():
+    """Four partitions of 1, 2, 3 and 4 rows with a vector column."""
+    batches = []
+    start = 0
+    for size in (1, 2, 3, 4):
+        ids = list(range(start, start + size))
+        batches.append(pa.record_batch({
+            "i": pa.array(ids, pa.int64()),
+            "v": pa.array([[float(i), i + 0.5] for i in ids],
+                          pa.list_(pa.float32()))}))
+        start += size
+    return DataFrame(batches, batches[0].schema)
+
+
+@pytest.mark.parametrize("order", [(0, 1, 2, 3), (3, 2, 1, 0), (2, 0, 3, 1),
+                                   (1, 3, 0, 2)])
+def test_collect_rows_come_back_in_partition_order(order, in_order):
+    df = in_order(_uneven_frame(), order)
+    rows, spans, counter = _collect_traced(df)
+    assert [r["i"] for r in rows] == list(range(10))
+    _assert_same_cells(rows, df.toArrow().to_pylist())
+    # assembled in the order they resolved; the last one after the run
+    assert [s["attributes"]["partition"] for s in spans] == list(order)
+    for span in spans:
+        assert span["attributes"]["rows"] == \
+            span["attributes"]["partition"] + 1
+        assert span["attributes"]["to"] == "pylist"
+        assert span["attributes"]["vector_columns"] == 1
+        assert span["attributes"]["fallback_columns"] == 1
+    # the counter: every row but those of the last partition to resolve
+    assert counter == 10 - (order[-1] + 1)
+
+
+@pytest.mark.parametrize("odd", [0, 1, 2, 3])
+def test_collect_falls_back_to_the_table_on_another_schema(odd, in_order):
+    # partition `odd` comes back with v as float64 where int64 is declared:
+    # toArrow() unifies and casts, so the other partitions' cells change
+    # type too, and nothing assembled from a batch alone may be kept.
+    # Resolving order 2, 0, 3, 1: the odd one is met early (first, second,
+    # third) or after the run (1, the last to resolve)
+    def op(index, batch):
+        if index != odd:
+            return batch
+        return batch.set_column(
+            1, "v", batch.column("v").cast(pa.list_(pa.float64())))
+
+    base = DataFrame.fromArrow(pa.table({
+        "i": pa.array(range(8)),
+        "v": pa.array([[i, -i] for i in range(8)], pa.list_(pa.int64()))}),
+        numPartitions=4)
+    df = in_order(base, (2, 0, 3, 1), op)
+    rows, spans, counter = _collect_traced(df)
+    want = df.toArrow().to_pylist()
+    _assert_same_cells(rows, want)
+    assert {type(v) for r in rows for v in r["v"]} == {float}
+    whole = spans[-1]["attributes"]
+    assert whole["rows"] == 8 and "partition" not in whole
+    # what was assembled before the odd batch showed is in the trace, and
+    # is not counted as rows collect() gave back
+    assert [s["attributes"]["partition"] for s in spans[:-1]] == \
+        list((2, 0, 3, 1)[:(2, 0, 3, 1).index(odd)])
+    assert counter is None
+
+
+def test_collect_failing_partition_raises_and_keeps_nothing(in_order):
+    seen = []
+
+    def op(index, batch):
+        seen.append(index)
+        if index == 3:
+            raise ValueError("deliberate")
+        return batch
+
+    df = in_order(_uneven_frame(), (0, 1, 2, 3), op)
+    from sparkdl_tpu.core import telemetry
+    from sparkdl_tpu.engine import dataframe
+
+    with telemetry.Telemetry() as tel:
+        with pytest.raises(TaskFailure, match="deliberate") as failure:
+            df.collect()
+    assert failure.value.index == 3
+    assert df._materialized is None
+    # the other partitions were assembled before 3 failed; their rows went
+    # with the failure and are not counted
+    assert sorted(s["attributes"]["partition"] for s in
+                  tel.tracer.spans(telemetry.SPAN_ROW_ASSEMBLY)) == [0, 1, 2]
+    assert dataframe.M_COLLECT_OVERLAPPED_ROWS not in \
+        tel.metrics.snapshot()["counters"]
+    assert sorted(seen) == [0, 1, 2, 3]
+
+
+def test_collect_then_to_arrow_runs_each_partition_once(in_order):
+    seen = []
+    df = in_order(_uneven_frame(), (1, 0, 3, 2),
+                  lambda index, batch: seen.append(index) or batch)
+    rows = df.collect()
+    table = df.toArrow()
+    assert df.collect() == rows == table.to_pylist()
+    assert df.count() == 10 and sorted(seen) == [0, 1, 2, 3]
+
+
+def test_collect_quarantined_partition_is_assembled_after_the_run(
+        in_order, monkeypatch):
+    monkeypatch.setattr(EngineConfig, "quarantine", True)
+
+    def op(index, batch):
+        if index == 1:
+            raise ValueError("poison")
+        return batch
+
+    df = in_order(_uneven_frame(), (0, 2, 3, 1), op)
+    rows, spans, counter = _collect_traced(df)
+    assert [r["i"] for r in rows] == [0, 3, 4, 5, 6, 7, 8, 9]
+    _assert_same_cells(rows, df.toArrow().to_pylist())
+    # the others early; the stand-in is no task's result: after the run
+    assert [(s["attributes"]["partition"], s["attributes"]["rows"])
+            for s in spans] == [(0, 1), (2, 3), (3, 4), (1, 0)]
+    assert counter == 8
+
+
+@pytest.mark.parametrize("how", ["no_ops", "materialized", "one_partition",
+                                 "durable"])
+def test_collect_takes_the_table_path_where_nothing_runs_beside_it(
+        how, tmp_path, monkeypatch):
+    df = _uneven_frame()
+    if how == "one_partition":
+        df = df.repartition(1)
+    if how != "no_ops":
+        df = df.mapPartitions(lambda batch: batch)
+    if how == "materialized":
+        df.toArrow()
+    if how == "durable":
+        monkeypatch.setattr(EngineConfig, "durable_dir", str(tmp_path))
+    rows, spans, counter = _collect_traced(df)
+    _assert_same_cells(rows, df.toArrow().to_pylist())
+    (span,) = spans
+    assert span["attributes"]["rows"] == 10
+    assert "partition" not in span["attributes"] and counter is None
+    if how == "durable":
+        assert os.listdir(tmp_path)     # the journal was written
+
+
+def test_nested_collect_from_a_partition_thread_runs_inline(in_order):
+    from sparkdl_tpu.core import telemetry
+
+    inner_rows = {}
+
+    def op(index, batch):
+        inner = _uneven_frame().mapPartitions(lambda b: b)
+        assert threading.current_thread().name.startswith("sparkdl-part")
+        inner_rows[index] = inner.collect()
+        return batch
+
+    df = in_order(_uneven_frame(), (3, 2, 1, 0), op)
+    with telemetry.Telemetry() as tel:
+        rows = df.collect()
+    assert [r["i"] for r in rows] == list(range(10))
+    assert all(inner_rows[i] == rows for i in range(4))
+    spans = tel.tracer.spans(telemetry.SPAN_ROW_ASSEMBLY)
+    # four inner collects over the whole table, four outer partitions
+    assert sorted(s["attributes"].get("partition", -1) for s in spans) == \
+        [-1, -1, -1, -1, 0, 1, 2, 3]
+
+
+_FIT_CELL_IMPORTS = """
+import sparkdl_tpu
+from sparkdl_tpu.models import registry
+from sparkdl_tpu.train import Trainer
+from sparkdl_tpu.train.metrics import MetricsLogger
+from sparkdl_tpu.core import profiling, telemetry
+"""
+
+
+@pytest.mark.parametrize("imports,engine_loaded", [
+    ("import sparkdl_tpu.engine\n"
+     "from sparkdl_tpu.engine import DataFrame\n"
+     "import pyarrow as pa\n"
+     "df = DataFrame.fromArrow(pa.table({'i': [1, 2, 3]}), 2)"
+     ".mapPartitions(lambda b: b)\n", True),
+    (_FIT_CELL_IMPORTS, False)])
+def test_engine_import_starts_no_thread_and_fit_never_imports_it(
+        imports, engine_loaded):
+    # the early assembly lives inside collect()'s call: importing the
+    # engine or building a frame starts no thread and makes no pool, and
+    # what benchmarks/drivers/fit.py imports does not reach the engine
+    import subprocess
+    import sys
+
+    script = (
+        "import sys, threading\n" + imports +
+        "assert [t.name for t in threading.enumerate()] == ['MainThread'], "
+        "threading.enumerate()\n"
+        "engine = [m for m in sys.modules "
+        "if m.startswith('sparkdl_tpu.engine')]\n"
+        "assert bool(engine) is " + str(engine_loaded) + ", engine\n"
+        "if engine:\n"
+        "    from sparkdl_tpu.engine import dataframe\n"
+        "    assert dataframe._pool is None\n")
+    done = subprocess.run([sys.executable, "-c", script],
+                          capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]

@@ -543,3 +543,210 @@ def test_retry_loop_attempt_restarts_executor_call_sequence():
     # attempt's sequence restarted at 0 instead of continuing at 1
     assert seen == [("task", 7, 0, 0), ("task", 7, 0, 0),
                     ("task", 7, 0, 1)]
+
+
+# -- run_all's on_result: a finished task's result, while others run (PR 34) --
+
+def _supervisor(probe=None, **config):
+    import concurrent.futures as futures
+
+    from sparkdl_tpu.engine.supervisor import (
+        PartitionSupervisor,
+        SupervisorConfig,
+    )
+
+    pool = futures.ThreadPoolExecutor(8, thread_name_prefix="sparkdl-part")
+    return PartitionSupervisor(pool, SupervisorConfig(**config),
+                               quarantine_probe=probe), pool
+
+
+def _gated(n):
+    """``n`` runners that return ``10 * index`` once their gate opens."""
+    gates = [threading.Event() for _ in range(n)]
+
+    def runner(i):
+        def run(cancel):
+            assert gates[i].wait(20), "the gate never opened"
+            return 10 * i
+        return run
+
+    return gates, [(i, runner(i)) for i in range(n)]
+
+
+@pytest.mark.parametrize("order", [(0, 1, 2, 3), (3, 2, 1, 0), (1, 3, 0, 2)])
+def test_on_result_gets_each_result_as_it_resolves_but_the_last(order):
+    sup, pool = _supervisor()
+    gates, runners = _gated(4)
+    handed = []
+
+    def on_result(index, result):
+        handed.append((index, result, threading.current_thread()))
+        gates[order[len(handed)]].set()     # the next may finish now
+
+    gates[order[0]].set()
+    try:
+        out = sup.run_all(runners, on_result)
+    finally:
+        pool.shutdown()
+    assert out == [0, 10, 20, 30]           # input order, whatever resolved
+    # on the calling thread, in resolving order; the last task to resolve
+    # has nothing left to wait for and is the returned list's alone
+    assert [(i, r) for i, r, _ in handed] == [(i, 10 * i) for i in order[:3]]
+    assert {t for _, _, t in handed} == {threading.current_thread()}
+
+
+def test_on_result_is_called_at_most_once_a_tick(monkeypatch):
+    from sparkdl_tpu.engine.supervisor import PartitionSupervisor
+
+    sup, pool = _supervisor()
+    gates, runners = _gated(4)
+    log = []
+    checks = PartitionSupervisor._check_deadlines
+
+    def check(self, tasks, outstanding):
+        log.append("tick")
+        return checks(self, tasks, outstanding)
+
+    monkeypatch.setattr(PartitionSupervisor, "_check_deadlines", check)
+
+    def on_result(index, result):
+        log.append(index)
+        if index == 2:
+            gates[3].set()
+
+    for gate in gates[:3]:      # three resolve before the first tick ends
+        gate.set()
+    time.sleep(0.2)
+    try:
+        assert sup.run_all(runners, on_result) == [0, 10, 20, 30]
+    finally:
+        pool.shutdown()
+    calls = [i for i, entry in enumerate(log) if entry != "tick"]
+    assert [log[i] for i in calls] == [0, 1, 2]
+    # the watchdog and the hedger looked between any two calls
+    assert all("tick" in log[a:b] for a, b in zip(calls, calls[1:]))
+
+
+def _attempt(kind):
+    from sparkdl_tpu.engine.supervisor import TaskAttempt
+
+    return TaskAttempt(kind, "ValueError('poison')", 0.0)
+
+
+def test_on_result_never_sees_a_failed_task_or_a_quarantine_stand_in():
+    handed = []
+    gates, runners = _gated(3)
+
+    def poisoned(cancel):
+        raise TaskFailure("poison", index=1, kind=resilience.FATAL, attempts=[
+            _attempt(resilience.FATAL)])
+
+    runners[1] = (1, poisoned)
+    sup, pool = _supervisor(probe=lambda index: "stand-in", quarantine=True)
+    gates[0].set()
+    try:
+        out = sup.run_all(
+            runners, lambda i, r: (handed.append((i, r)), gates[2].set()))
+    finally:
+        pool.shutdown()
+    assert out == [0, "stand-in", 20]
+    assert handed == [(0, 0)]
+
+    # without quarantine the failure raises as it did, after the barrier
+    gates, runners = _gated(3)
+    runners[1] = (1, poisoned)
+    sup, pool = _supervisor()
+    handed.clear()
+    gates[0].set()
+    try:
+        with pytest.raises(TaskFailure, match="poison"):
+            sup.run_all(runners,
+                        lambda i, r: (handed.append((i, r)), gates[2].set()))
+    finally:
+        pool.shutdown()
+    assert handed == [(0, 0)]
+
+
+def test_on_result_gets_a_hedged_task_once_the_winner_only():
+    sup, pool = _supervisor(speculation=True, speculation_quantile=0.5,
+                            speculation_min_runtime_s=0.05)
+    attempts = []
+    lock = threading.Lock()
+    release = threading.Event()
+
+    def straggler(cancel):
+        with lock:
+            attempts.append(len(attempts))
+            mine = attempts[-1]
+        if mine == 0:           # the primary straggles, the hedge is fast
+            release.wait(20)
+            return "loser"
+        return "winner"
+
+    def quick(value):
+        return lambda cancel: value
+
+    handed = []
+    runners = [(0, quick("a")), (1, quick("b")), (2, quick("c")),
+               (3, straggler), (4, lambda cancel: release.wait(20) and "e")]
+
+    def on_result(index, result):
+        handed.append((index, result))
+        if index == 3:
+            release.set()
+
+    try:
+        out = sup.run_all(runners, on_result)
+    finally:
+        release.set()
+        pool.shutdown()
+    assert out == ["a", "b", "c", "winner", "e"]
+    assert (3, "winner") in handed and (3, "loser") not in handed
+    assert [i for i, _ in handed].count(3) == 1 and len(attempts) >= 2
+
+
+def test_watchdog_still_fires_while_on_result_is_slow():
+    sup, pool = _supervisor(task_timeout_s=0.3)
+    hung = threading.Event()
+    handed = []
+
+    def on_result(index, result):
+        handed.append(index)
+        time.sleep(0.2)         # one call's duration: the most a check waits
+
+    runners = [(0, lambda cancel: 0), (1, lambda cancel: 1),
+               (2, lambda cancel: hung.wait(20))]
+    t0 = time.monotonic()
+    try:
+        with HealthMonitor() as mon:
+            with pytest.raises(TaskFailure, match="deadline") as failure:
+                sup.run_all(runners, on_result)
+    finally:
+        hung.set()
+        pool.shutdown()
+    assert failure.value.index == 2 and failure.value.deadline_exceeded
+    assert time.monotonic() - t0 < 2.0
+    assert mon.count(health.TASK_DEADLINE_EXCEEDED) == 1
+    assert handed == [0, 1]
+
+
+def test_an_error_out_of_on_result_drains_then_propagates():
+    sup, pool = _supervisor()
+    started, finished = threading.Event(), []
+
+    def slow(cancel):
+        started.set()
+        time.sleep(0.3)
+        finished.append(1)
+        return 1
+
+    def on_result(index, result):
+        assert started.wait(20)     # an attempt not yet started is dropped
+        raise KeyError("assembly broke")
+
+    try:
+        with pytest.raises(KeyError, match="assembly broke"):
+            sup.run_all([(0, lambda cancel: 0), (1, slow)], on_result)
+        assert finished == [1]      # the barrier: no user op still running
+    finally:
+        pool.shutdown()
