@@ -205,12 +205,11 @@ def measured_flops_per_image(apply_fn, variables, x_np, fallback):
     returns a LIST of per-computation dicts on some backends, handled
     here), falling back to the registry's analytic 2*MACs constant
     (``ModelSpec.flops_per_image``) when the backend reports none — OR
-    reports less than it: a program containing Pallas kernels counts
-    only what each kernel's ``cost_estimate`` declares (possibly
-    nothing), so an under-reported analysis would silently DEFLATE the
-    work estimate and with it MFU's denominator... and the adopted
-    kernel would look like an MFU regression (or, flipped, a partial
-    analysis could inflate images/sec-normalized MFU). Preferring
+    reports less than it: a custom call counts only what its
+    ``cost_estimate`` declares (possibly nothing), so an under-reported
+    analysis would silently DEFLATE the work estimate and with it MFU's
+    denominator (or, flipped, a partial analysis could inflate
+    images/sec-normalized MFU). Preferring
     whichever is LARGER keeps the denominator the full analytic work
     regardless of how much of the program the compiler can see.
     Returns ``(flops_per_image, source)``."""
@@ -275,96 +274,6 @@ def bench_device_featurize(name, size, flops_per_img):
     return (ips, max(spread, cross), mfu, [round(v, 1) for v in values],
             {"flops_per_image": round(flops / 1e9, 3),
              "flops_source": flops_src})
-
-
-def bench_kernel_autotune(name="InceptionV3", size=(299, 299)):
-    """ISSUE 20 tentpole leg: the flagship featurize with the fused
-    Pallas kernel plane OFF vs under the accept-if-faster autotune,
-    ONE record.
-
-    The autotune mode settles every per-rung verdict BEFORE the
-    measured runs (the same eval-shape collection + shootout path the
-    first-launch wrapper and the serving warmup use), so the measured
-    throughput is pure steady state — no shootout cost leaks into the
-    slope. The record carries both modes' images/sec/chip + MFU, the
-    per-rung verdict table (adopted/rejected, reason, the measured
-    xla/pallas timing pair, numeric delta), and the shootout wall
-    time. On a host backend every candidate records a clean rejection
-    (no Mosaic lowering) and the two modes run byte-identical
-    programs — the record then documents an all-rejected autotune,
-    not a win.
-
-    Both modes build with ``fast=False``: the fused-kernel registry
-    routes through the structural Flax units (ConvBN/SeparableConvBN),
-    while InceptionV3's default fast path is an orthogonal
-    hand-specialization that bypasses them — holding it off on BOTH
-    sides isolates exactly the kernel plane."""
-    import jax.numpy as jnp
-
-    from sparkdl_tpu.core import batching
-    from sparkdl_tpu.engine.dataframe import EngineConfig
-    from sparkdl_tpu.models import registry
-
-    spec = registry.get_model_spec(name)
-    rng = np.random.default_rng(0)
-    x = rng.integers(0, 255, size=(HEADLINE_BATCH,) + size + (3,)
-                     ).astype(np.float32)
-
-    saved = EngineConfig.snapshot()
-    modes, verdicts, autotune_s = {}, {}, 0.0
-    try:
-        for mode in ("off", "autotune"):
-            EngineConfig.pallas_kernels = mode
-            # a FRESH ModelFunction per mode: routing happens at trace
-            # time, so a shared jit cache would let mode A's compiled
-            # program answer for mode B
-            mf = registry.build_featurizer(name, weights="random",
-                                           dtype=jnp.bfloat16, fast=False)
-            if mode == "autotune":
-                from sparkdl_tpu.core import kernels
-                kernels.reset()
-                eff, mult = mf.bucket_params(HEADLINE_BATCH)
-                planner = batching.default_planner(name, eff, mult)
-                rungs = (planner.ladder() if planner is not None
-                         else batching._pow2_ladder(eff, mult, 8))
-                t0 = time.perf_counter()
-                for rung in rungs:
-                    xr = np.zeros((int(rung),) + size + (3,), np.float32)
-                    kernels.ensure_autotuned(
-                        lambda a: mf.apply_fn(mf.variables, a), xr,
-                        model=name)
-                autotune_s = time.perf_counter() - t0
-                verdicts = {
-                    k: {f: v[f] for f in ("adopted", "reason", "xla_s",
-                                          "pallas_s", "max_abs_err")
-                        if v.get(f) is not None}
-                    for k, v in kernels.verdicts_snapshot().items()}
-            flops, flops_src = measured_flops_per_image(
-                mf.apply_fn, mf.variables, x,
-                spec.flops_per_image or FLOPS_PER_IMG_INCEPTION)
-            measure = make_slope_measurer(mf.apply_fn, mf.variables, x)
-            measure()  # discarded warmup: compile residue + clock ramp
-            runs = [measure() for _ in range(2)]
-            ips, spread = max(runs, key=lambda r: r[0])
-            modes[mode] = {
-                "images_per_sec": round(ips, 2),
-                "spread": round(spread, 4),
-                "mfu": round(ips * flops / 1e12 / peak_tflops_bf16(), 4),
-                "flops_source": flops_src,
-            }
-    finally:
-        EngineConfig.restore(saved)
-    adopted = sum(1 for v in verdicts.values() if v.get("adopted"))
-    return {
-        "off": modes["off"],
-        "autotune": modes["autotune"],
-        "speedup": round(modes["autotune"]["images_per_sec"]
-                         / max(modes["off"]["images_per_sec"], 1e-9), 4),
-        "adopted": adopted,
-        "rejected": len(verdicts) - adopted,
-        "autotune_s": round(autotune_s, 3),
-        "verdicts": verdicts,
-    }
 
 
 def _write_jpegs(directory, n, rng):
@@ -1849,19 +1758,6 @@ def main():
                  durable_off=round(dips_off, 2),
                  durable_off_spread=round(dsp_off, 4),
                  overhead_frac=round(dfrac, 4))
-            # fused Pallas kernels (ISSUE 20): the flagship featurize
-            # with the kernel plane off vs the accept-if-faster
-            # autotune — per-rung verdicts ride along; adopted kernels
-            # must be strictly faster, a host backend records a clean
-            # all-rejected pair
-            ka = bench_kernel_autotune()
-            emit("kernel-autotune featurize images/sec/chip "
-                 "(InceptionV3, fused Pallas off vs autotune)",
-                 ka["autotune"]["images_per_sec"], "images/sec/chip",
-                 off=ka["off"], autotune=ka["autotune"],
-                 speedup=ka["speedup"], adopted=ka["adopted"],
-                 rejected=ka["rejected"], autotune_s=ka["autotune_s"],
-                 verdicts=ka["verdicts"])
             # raw-speed inference (ISSUE 12): the precision ladder —
             # fp32/bf16/int8 throughput AND max output delta, one record
             prec = bench_precision_featurize()

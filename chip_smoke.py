@@ -8,7 +8,6 @@ reference:
 
   featurize  256 seeded JPEGs -> sparkdl_tpu.readImages ->
              DeepImageFeaturizer(InceptionV3, batchSize=128) -> collect()
-  kernels    one audition per fused Pallas kernel at a real site shape
   serving    ResidencyManager + ModelRegistry + ModelServer: single-row and
              batch predicts, evict, cold reload
   train      Trainer.from_flax(ResNet50) b64 224x224 bf16, three fit() steps
@@ -98,8 +97,8 @@ def require_tpu(count):
 class CompileMeter:
     """Seconds JAX spent in backend compiles (or loading them from the
     persistent cache) and how many requests the cache answered, from JAX's
-    own monitoring events — the one clock that covers the engine's jits, the
-    kernel auditions and the Trainer's step alike."""
+    own monitoring events — the one clock that covers the engine's jits
+    and the Trainer's step alike."""
 
     def __init__(self):
         import jax.monitoring
@@ -263,7 +262,7 @@ def phase_featurize(model_name, n_images, batch_size, seed, platform,
     against the plain reference. Returns ``(facts, batch, features)``: the
     facts for the log, and the staged batch and features the serving phase
     answers against."""
-    from sparkdl_tpu.core import kernels, telemetry
+    from sparkdl_tpu.core import telemetry
     from sparkdl_tpu.ml import DeepImageFeaturizer
     from sparkdl_tpu.models import registry
     from sparkdl_tpu.native import loader as native_loader
@@ -301,45 +300,7 @@ def phase_featurize(model_name, n_images, batch_size, seed, platform,
         "decode_path": ("native library" if native_loader.available()
                         else "PIL"),
         "compile_spans": compile_spans,
-        "kernel_verdicts": kernels.verdicts_snapshot(),
     }, batch, features
-
-
-def kernel_sites():
-    from sparkdl_tpu.core import kernels
-
-    return [
-        # Xception middle flow SeparableConvBN
-        kernels.Site("sep2d", "chip_smoke", (128, 19, 19, 728, 728),
-                     "bfloat16"),
-        # InceptionV3 17x17 1x1 ConvBN
-        kernels.Site("pw1x1_relu", "chip_smoke", (128, 17, 17, 768, 192),
-                     "bfloat16"),
-        # fused-preprocess prologue, photo-sized source
-        kernels.Site("preproc", "chip_smoke", (128, 375, 500, 3, 299, 299),
-                     "uint8->bfloat16"),
-    ]
-
-
-def phase_kernels(sites):
-    """One FRESH audition per kernel, in this process: both candidates must
-    really have run on the chip (the verdict carries both timings). Losing
-    to the XLA twin is a legitimate rejection; an exception is not. The
-    shoot-out is called directly, not through ``ensure_verdict``: where the
-    verdict store is warm (``JAX_COMPILATION_CACHE_DIR`` set on the chip
-    machine) that would hand back an earlier process's verdict and prove
-    nothing about this tree."""
-    from sparkdl_tpu.core import kernels
-
-    verdicts = {}
-    for site in sites:
-        verdict = kernels._audition(site)
-        key = kernels._site_key(site)
-        verdicts[key] = verdict
-        check("error" not in verdict, f"{key}: {verdict.get('error')}")
-        check("pallas_s" in verdict and "xla_s" in verdict,
-              f"{key}: a candidate did not run — {verdict.get('reason')}")
-    return {"verdicts": verdicts}
 
 
 def phase_serving(model_name, batch, features, batch_size, platform,
@@ -666,7 +627,6 @@ def main(argv=None):
             return facts
 
         run_phase(meter, "featurize", featurize)
-        run_phase(meter, "kernels", phase_kernels, kernel_sites())
         run_phase(meter, "serving", phase_serving, "InceptionV3",
                   staged["batch"], staged["features"], 128,
                   device["platform"])

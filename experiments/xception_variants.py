@@ -121,17 +121,6 @@ def micro():
     measure("block-sft", cast(lambda v, xx: block(v, xx, dw_shift)), variables, x, BLOCK_FLOPS)
 
 
-def pallas():
-    """Pallas kernels vs XLA at the same shapes — delegates to
-    ``experiments/pallas_probe.py`` (r4). Measured outcome: XLA's grouped
-    depthwise beats the Pallas formulations 3-6x and the fused Pallas
-    sepconv loses 1.6x to XLA's dw+pw pair; no sparkdl_tpu.ops module
-    ships (the ceiling analysis is in docs/PERF.md)."""
-    from experiments import pallas_probe
-
-    pallas_probe.main()
-
-
 def full():
     from sparkdl_tpu.models import registry
 
@@ -147,8 +136,6 @@ if __name__ == "__main__":
     t0 = time.time()
     if mode in ("micro", "all"):
         micro()
-    if mode in ("pallas", "all"):
-        pallas()
     if mode in ("full", "all"):
         full()
     print(f"total {time.time() - t0:.0f}s")

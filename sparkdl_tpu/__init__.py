@@ -52,9 +52,9 @@ def _compile_cache_dir():
 
 
 def _sidecar_store_dir():
-    """Directory of the kernel-verdict and bucket-ladder stores: the
-    compile cache's, but ONLY when ``$JAX_COMPILATION_CACHE_DIR`` names
-    it — under the in-checkout default they stay in-process (None)."""
+    """Directory of the bucket-ladder store: the compile cache's, but
+    ONLY when ``$JAX_COMPILATION_CACHE_DIR`` names it — under the
+    in-checkout default it stays in-process (None)."""
     named = _os.environ.get(COMPILE_CACHE_DIR_ENV)
     if not named or _os.path.abspath(named) == _IN_CHECKOUT_CACHE_DIR:
         return None

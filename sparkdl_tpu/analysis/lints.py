@@ -764,77 +764,3 @@ class ColumnarHotPathRule(Rule):
             "validity masks) or suppress with the ragged/string-column "
             "justification")
             for line, reason in per_row_column_hops(src.tree)]
-
-
-# ---------------------------------------------------------------------------
-# kernel-gate (ISSUE 20)
-# ---------------------------------------------------------------------------
-
-#: The one module allowed to spell ``pallas_call`` / the raw kernel
-#: builders — everything else goes through the autotune routes.
-KERNELS_MODULE_PARTS = ("core", "kernels.py")
-
-
-def _raw_kernel_entry_points() -> frozenset:
-    """The LIVE raw-builder names from core/kernels.py (same live-module
-    resolution as the span/health catalogs — a new kernel is covered
-    without touching the analyzer)."""
-    from sparkdl_tpu.core import kernels as _kernels
-    return _kernels.RAW_KERNEL_ENTRY_POINTS
-
-
-def _names_kernels_module(value: ast.AST) -> bool:
-    return ((isinstance(value, ast.Name) and value.id == "kernels")
-            or (isinstance(value, ast.Attribute)
-                and value.attr == "kernels"))
-
-
-def raw_kernel_calls(tree: ast.AST) -> List[Tuple[int, str]]:
-    """(lineno, what) for every ``pallas_call`` launch and every raw
-    ``core.kernels`` entry-point call in ``tree``."""
-    raw_names = _raw_kernel_entry_points()
-    out = []
-    for node in ast.walk(tree):
-        if not isinstance(node, ast.Call):
-            continue
-        f = node.func
-        if isinstance(f, ast.Name):
-            if f.id == "pallas_call":
-                out.append((node.lineno, "a raw pallas_call launch"))
-            elif f.id in raw_names:
-                out.append((node.lineno,
-                            f"raw kernel entry point {f.id}()"))
-        elif isinstance(f, ast.Attribute):
-            if f.attr == "pallas_call":
-                out.append((node.lineno, "a raw pallas_call launch"))
-            elif f.attr in raw_names and _names_kernels_module(f.value):
-                out.append((node.lineno,
-                            f"raw kernel entry point kernels.{f.attr}()"))
-    return out
-
-
-@register
-class KernelGateRule(Rule):
-    id = "kernel-gate"
-    title = "Pallas kernels ship only through the autotune registry"
-    rationale = (
-        "core/kernels.py is the ONE home for pallas_call and the raw "
-        "kernel builders, because its route_*/ensure_autotuned entry "
-        "points are what enforce the accept-if-faster contract "
-        "(docs/PERF.md 'Fused kernels & AOT warmup'): a kernel runs in "
-        "production only with an adopted per-(kernel, family, shape, "
-        "dtype) verdict — >= 5% faster than its XLA twin AND inside the "
-        "numeric contract. A raw pallas_call elsewhere, or a direct "
-        "call to a kernels.py builder, ships un-auditioned device code "
-        "that can be slower or numerically off with no test failing.")
-
-    def check(self, src: SourceFile) -> List[Finding]:
-        if pathlib.PurePath(src.rel).parts[-2:] == KERNELS_MODULE_PARTS:
-            return []
-        return [self.finding(
-            src, line,
-            f"{what} outside core/kernels.py — fused kernels ship only "
-            "through the autotune registry (kernels.route_* / "
-            "ensure_autotuned), which is what guarantees a losing or "
-            "numerically-off kernel never reaches production")
-            for line, what in raw_kernel_calls(src.tree)]

@@ -99,7 +99,6 @@ SERVING_PREPARE_FAILED = "serving_prepare_failed"  # serving: a cluster
                                          # v1 still serving everywhere
 WARMUP_COMPLETED = "warmup_completed"    # serving: a deployment's full
                                          # bucket ladder was AOT-compiled
-                                         # (and kernel shootouts settled)
                                          # before it took traffic
 CLUSTER_WORKER_STARTED = "cluster_worker_started"  # cluster: a worker
                                          # process was spawned

@@ -69,37 +69,6 @@ def _is_q8_leaf(x) -> bool:
     return isinstance(x, dict) and _Q8_WEIGHTS in x
 
 
-def _kernels_or_none():
-    """``core.kernels`` iff ``EngineConfig.pallas_kernels`` is armed —
-    lazy and knob-gated so ``"off"`` never imports the Pallas machinery
-    (the byte-identity pin asserts it stays out of ``sys.modules``)."""
-    try:
-        from sparkdl_tpu.engine.dataframe import EngineConfig
-    except Exception:
-        return None
-    if getattr(EngineConfig, "pallas_kernels", "off") == "off":
-        return None
-    from sparkdl_tpu.core import kernels
-    return kernels
-
-
-def _route_preproc_or_none(x, target_hw, out_dtype, family: str):
-    kernels = _kernels_or_none()
-    if kernels is None:
-        return None
-    return kernels.route_preproc(x, target_hw, out_dtype, family=family)
-
-
-def _ensure_kernels_autotuned(inner, x, model: str) -> None:
-    """Settle every kernel verdict ``inner(x)`` depends on BEFORE its
-    first trace (core/kernels.py accept-if-faster shootouts, run at the
-    deployment's actual shapes). No-op unless the knob is 'autotune'."""
-    kernels = _kernels_or_none()
-    if kernels is None:
-        return
-    kernels.ensure_autotuned(inner, x, model=model)
-
-
 def _dequantize_tree(variables):
     """In-program dequantize of every quantized leaf to bfloat16 (the
     q · scale multiply fuses into the consuming matmul/conv); remaining
@@ -174,16 +143,6 @@ class ModelFunction:
     # (models/*_fast.py); set post-construction by the registry builders.
     fast_path = False
 
-    # True iff tracing this model's apply can consult a core/kernels.py
-    # route (Flax-backed bodies — ConvBN/SeparableConvBN kernel_family
-    # opt-ins — and resized() wrappers with the preproc route). Gates
-    # the pre-trace autotune collection pass: an arbitrary fromFunction
-    # callable has no routes, and eval_shape-tracing it anyway would run
-    # its Python body a second time — observable (and contract-breaking:
-    # a FATAL error's fn body must run exactly once) when the callable
-    # has side effects.
-    kernel_routable = False
-
     def __init__(self, apply_fn: Callable[[Any, jax.Array], jax.Array],
                  variables: Any, input_spec: TensorSpec,
                  name: str = "model",
@@ -248,10 +207,8 @@ class ModelFunction:
         def apply_fn(vs, x):
             return module.apply(vs, x, **apply_kwargs)
 
-        out = cls(apply_fn, variables, input_spec,
-                  name=name or type(module).__name__)
-        out.kernel_routable = True
-        return out
+        return cls(apply_fn, variables, input_spec,
+                   name=name or type(module).__name__)
 
     @classmethod
     def fromMsgpack(cls, path: str, module, input_spec: TensorSpec,
@@ -391,14 +348,10 @@ class ModelFunction:
     def _propagate_float_source(self, wrapped: "ModelFunction") -> None:
         """Composition wrappers must keep the pre-bf16-cast weights
         reachable, or persistence silently falls back to the truncated
-        variables (the with_compute_dtype contract, ADVICE r4). Kernel
-        routability rides along: a wrapper closes over the parent's
-        apply, so its routes are still in the traced body."""
+        variables (the with_compute_dtype contract, ADVICE r4)."""
         source = getattr(self, "float_source", None)
         if source is not None:
             wrapped.float_source = source
-        if self.kernel_routable:
-            wrapped.kernel_routable = True
 
     def with_postprocess(self, post: Callable[[jax.Array], jax.Array]
                          ) -> "ModelFunction":
@@ -531,7 +484,6 @@ class ModelFunction:
         # inference-only artifact, not a training starting point.
         out = ModelFunction(fn, variables, self.input_spec, name=self.name)
         out.float_source = getattr(self, "float_source", self)
-        out.kernel_routable = self.kernel_routable
         return out
 
     def flattened(self) -> "ModelFunction":
@@ -576,17 +528,9 @@ class ModelFunction:
         cache = self._resize_cache
         key = (tuple(src_size), target)
         if key not in cache:
-            model_name = self.name
             out_dtype = jnp.dtype(self.input_spec.dtype)
 
             def pre(x):
-                # Fused-kernel opt-in (core/kernels.py): one Pallas
-                # launch for cast+resize when the accept-if-faster
-                # autotune adopted this site; None keeps the XLA pair.
-                fused = _route_preproc_or_none(x, (th, tw), out_dtype,
-                                               model_name)
-                if fused is not None:
-                    return fused
                 xf = x.astype(out_dtype)
                 return jax.image.resize(
                     xf, (x.shape[0], th, tw, x.shape[3]),
@@ -595,11 +539,7 @@ class ModelFunction:
             spec = TensorSpec((None, int(src_size[0]), int(src_size[1]),
                                self.input_spec.shape[3]),
                               self.input_spec.dtype)
-            wrapped = self.with_preprocess(pre, input_spec=spec)
-            # pre() consults route_preproc regardless of what the parent
-            # body contains, so the wrapper is always collection-worthy.
-            wrapped.kernel_routable = True
-            cache[key] = wrapped
+            cache[key] = self.with_preprocess(pre, input_spec=spec)
         return cache[key]
 
     # -- residency accounting (sparkdl_tpu/serving/residency.py) -------------
@@ -721,22 +661,12 @@ class ModelFunction:
         # processes.
         seen_shapes: set = set()
         name = self.name
-        routable = self.kernel_routable
 
         def fn(x, _inner=inner, _seen=seen_shapes):
             shape_key = tuple((tuple(leaf.shape), str(leaf.dtype))
                               for leaf in jax.tree_util.tree_leaves(x))
             if shape_key in _seen:
                 return _inner(x)
-            # First sight of a shape: settle the fused-kernel verdicts
-            # for this exact geometry (an abstract pass + at most one
-            # shootout per new site) so the trace below routes against
-            # decided winners — a request never mid-trace-auditions.
-            # Gated on kernel_routable: the collection pass eval_shape-
-            # traces the body, which re-runs Python side effects — only
-            # route-bearing bodies (Flax / resized) may pay that trace.
-            if routable:
-                _ensure_kernels_autotuned(_inner, x, name)
             with telemetry.span(telemetry.SPAN_COMPILE, model=name,
                                 shapes=repr(shape_key)):
                 out = _inner(x)

@@ -248,17 +248,6 @@ M_CLUSTER_REDISPATCH = "sparkdl.cluster.redispatch"    # counter
 M_CLUSTER_WORKERS = "sparkdl.cluster.workers"          # gauge (live,
                                                        # non-draining)
 M_CLUSTER_DRAIN_S = "sparkdl.cluster.drain_s"          # histogram
-# Pallas kernel autotune (core/kernels.py, docs/PERF.md "Fused kernels &
-# AOT warmup"): one histogram observation per shootout (build + numeric
-# check + timing of both candidates) and one adopted/rejected counter
-# bump per settled verdict. An audition that RAISED on a backend with
-# Mosaic lowering (a kernel the chip's compiler refuses, OOM) is neither:
-# it counts under audition_error, so a broken kernel never hides among
-# the honest shoot-out losses.
-M_KERNEL_AUTOTUNE_S = "sparkdl.kernel.autotune_s"      # histogram
-M_KERNEL_ADOPTED = "sparkdl.kernel.adopted"            # counter
-M_KERNEL_REJECTED = "sparkdl.kernel.rejected"          # counter
-M_KERNEL_AUDITION_ERROR = "sparkdl.kernel.audition_error"  # counter
 # Counts that come OUT of a compiled program (models/latent_moe.py): the
 # program returns them as small row-aligned outputs under the reserved
 # output name PROGRAM_COUNTS = {metric name: array, dim 0 = rows}, and the
@@ -333,10 +322,6 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_CLUSTER_REDISPATCH: "counter",
     M_CLUSTER_WORKERS: "gauge",
     M_CLUSTER_DRAIN_S: "histogram",
-    M_KERNEL_AUTOTUNE_S: "histogram",
-    M_KERNEL_ADOPTED: "counter",
-    M_KERNEL_REJECTED: "counter",
-    M_KERNEL_AUDITION_ERROR: "counter",
     M_SEQUENCE_TOKENS: "counter",
     M_MOE_ROUTED_TOKENS: "counter",
     M_MOE_LOCAL_PAIRS: "counter",

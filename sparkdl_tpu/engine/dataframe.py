@@ -238,23 +238,13 @@ class EngineConfig:
     # caller's deadline bounds it anyway; this bounds pathological
     # rolling-death churn).
     serving_failover_max: int = 2
-    # -- Pallas fused kernels (core/kernels.py, docs/PERF.md "Fused
-    # kernels & AOT warmup") ----------------------------------------------------
-    # "autotune" (default): fused Pallas kernels are auditioned per
-    # (kernel, model-family, bucket-shape, dtype) against their XLA
-    # twins at first compile and adopted only on a >= 5% win within the
-    # numeric contract (fp32 exact, bf16 <= 0.05) — a losing kernel
-    # never ships, verdicts persist beside the compile cache. "force"
-    # routes every feasible site unconditionally (tests/benchmarks).
-    # "off": byte-identical XLA programs, core/kernels.py never
-    # imported (subprocess-pinned like the cluster/serving packages).
-    pallas_kernels: str = "autotune"
-    # AOT-compile a deployment's full bucket ladder (running its kernel
-    # shootouts) at deploy/prepare time so the first request pays zero
-    # compile: wired into ModelRegistry.deploy, ResidencyManager cold
-    # loads, and the cluster srv_prepare phase (a replica acks prepared
-    # only after its ladder is warm). False (default) keeps today's
-    # lazy first-request compile.
+    # -- AOT bucket-ladder warmup (serving/registry.py, docs/PERF.md
+    # "AOT bucket-ladder warmup") -----------------------------------------------
+    # AOT-compile a deployment's full bucket ladder at deploy/prepare
+    # time so the first request pays zero compile: wired into
+    # ModelRegistry.deploy, ResidencyManager cold loads, and the cluster
+    # srv_prepare phase (a replica acks prepared only after its ladder
+    # is warm). False (default) keeps today's lazy first-request compile.
     serving_warmup: bool = False
     # -- per-tenant fair queueing (core/executor.py, docs/RESILIENCE.md
     # "Tenant fairness") --------------------------------------------------------
@@ -334,8 +324,7 @@ class EngineConfig:
                  cls.autoscale_rows_per_worker_high,
                  cls.cluster_federation_s,
                  cls.serving_cluster, cls.serving_worker_residency_bytes,
-                 cls.serving_failover_max, cls.pallas_kernels,
-                 cls.serving_warmup,
+                 cls.serving_failover_max, cls.serving_warmup,
                  (None if cls.executor_tenant_weights is None
                   else tuple(sorted(cls.executor_tenant_weights.items()))),
                  cls.executor_default_tenant, cls.job_tenant,
@@ -473,10 +462,6 @@ class EngineConfig:
                 "EngineConfig.serving_failover_max must be >= 0 (0 "
                 "fails a moved request on first replica death), got "
                 f"{cls.serving_failover_max!r}")
-        if cls.pallas_kernels not in ("off", "autotune", "force"):
-            raise ValueError(
-                "EngineConfig.pallas_kernels must be 'off', 'autotune' "
-                f"or 'force', got {cls.pallas_kernels!r}")
         if not isinstance(cls.serving_warmup, bool):
             raise ValueError(
                 "EngineConfig.serving_warmup must be a bool, got "

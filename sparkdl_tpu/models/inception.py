@@ -8,6 +8,11 @@ Every conv is ConvBN (no bias, BN scale=False, eps 1e-3); block structure
 matched line-by-line to keras.src.applications.inception_v3 (mixed0..10).
 ConvBN units are named ``cb{i}`` in call order — the weight converter maps
 Keras's Conv2D/BatchNormalization build order onto the same indices.
+
+Inference through the registry runs ``inception_fast.py`` over the same
+variables tree; this module stays as the definition of that tree (weight
+conversion, training) and as the reference the fast apply is tested
+against (``tests/models/test_inception_fast.py``).
 """
 
 from __future__ import annotations
@@ -34,11 +39,8 @@ class InceptionV3(nn.Module):
         idx = [0]
 
         def cb(h, features, kh, kw, strides=(1, 1), padding="SAME"):
-            # kernel_family opts eligible 1x1 units into the fused pw1x1
-            # registry (core/kernels.py accept-if-faster autotune).
             m = ConvBN(features, (kh, kw), strides=strides, padding=padding,
-                       bn_scale=False, dtype=self.dtype, name=f"cb{idx[0]}",
-                       kernel_family="inception")
+                       bn_scale=False, dtype=self.dtype, name=f"cb{idx[0]}")
             idx[0] += 1
             return m(h, train)
 

@@ -29,22 +29,6 @@ KERAS_BN_EPS = 1e-3          # keras BatchNormalization default
 RESNET_BN_EPS = 1.001e-5     # keras resnet.py blocks
 
 
-def _kernels_or_none():
-    """``core.kernels`` iff ``EngineConfig.pallas_kernels`` is armed.
-
-    Lazy and knob-gated so ``"off"`` (and a model zoo used without the
-    engine) never even imports the Pallas machinery — the byte-identity
-    pin asserts ``core.kernels`` is absent from ``sys.modules``."""
-    try:
-        from sparkdl_tpu.engine.dataframe import EngineConfig
-    except Exception:
-        return None
-    if getattr(EngineConfig, "pallas_kernels", "off") == "off":
-        return None
-    from sparkdl_tpu.core import kernels
-    return kernels
-
-
 def pad2d(x: jnp.ndarray, pad: Union[int, Tuple[Tuple[int, int], Tuple[int, int]]]
           ) -> jnp.ndarray:
     """ZeroPadding2D equivalent on NHWC."""
@@ -103,17 +87,9 @@ class ConvBN(nn.Module):
     bn_eps: float = KERAS_BN_EPS
     act: bool = True
     dtype: Optional[Dtype] = None
-    # Structural opt-in to the fused-kernel registry (core/kernels.py):
-    # a model that sets its family name lets eligible sites (1x1
-    # stride-1 SAME, inference) route through the accept-if-faster
-    # autotune. None (default) never consults the registry.
-    kernel_family: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        # The Flax branch ALWAYS runs structurally — it is what creates
-        # the param tree, so opted-in and opted-out models have
-        # identical checkpoints; when the fused route wins, jit DCEs it.
         y = nn.Conv(self.features, self.kernel, strides=self.strides,
                     padding=self.padding, use_bias=False, dtype=self.dtype,
                     name="conv")(x)
@@ -122,28 +98,7 @@ class ConvBN(nn.Module):
                          dtype=self.dtype, name="bn")(y)
         if self.act:
             y = nn.relu(y)
-        fused = self._fused(x, train)
-        return y if fused is None else fused
-
-    def _fused(self, x, train: bool):
-        if train or self.kernel_family is None:
-            return None
-        if (tuple(self.strides) != (1, 1) or tuple(self.kernel) != (1, 1)
-                or self.padding != "SAME"):
-            return None
-        kernels = _kernels_or_none()
-        if kernels is None:
-            return None
-        params = self.variables.get("params", {})
-        stats = self.variables.get("batch_stats", {})
-        conv_p, bn_p = params.get("conv"), params.get("bn", {})
-        bn_s = stats.get("bn")
-        if conv_p is None or bn_s is None:
-            return None
-        return kernels.route_pw1x1(
-            x, conv_p["kernel"], bn_p.get("scale"), bn_p.get("bias"),
-            bn_s["mean"], bn_s["var"], self.bn_eps, relu=self.act,
-            family=self.kernel_family)
+        return y
 
 
 class SeparableConvBN(nn.Module):
@@ -159,8 +114,6 @@ class SeparableConvBN(nn.Module):
     strides: Tuple[int, int] = (1, 1)
     bn_eps: float = KERAS_BN_EPS
     dtype: Optional[Dtype] = None
-    # Structural opt-in to the fused sep2d kernel (see ConvBN).
-    kernel_family: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, train: bool = False):
@@ -172,27 +125,7 @@ class SeparableConvBN(nn.Module):
                     name="pointwise")(y)
         y = nn.BatchNorm(use_running_average=not train, epsilon=self.bn_eps,
                          momentum=0.99, dtype=self.dtype, name="bn")(y)
-        fused = self._fused(x, train)
-        return y if fused is None else fused
-
-    def _fused(self, x, train: bool):
-        if train or self.kernel_family is None:
-            return None
-        if tuple(self.strides) != (1, 1) or tuple(self.kernel) != (3, 3):
-            return None
-        kernels = _kernels_or_none()
-        if kernels is None:
-            return None
-        params = self.variables.get("params", {})
-        stats = self.variables.get("batch_stats", {})
-        dw, pw = params.get("depthwise"), params.get("pointwise")
-        bn_p, bn_s = params.get("bn", {}), stats.get("bn")
-        if dw is None or pw is None or bn_s is None:
-            return None
-        return kernels.route_sep2d(
-            x, dw["kernel"], pw["kernel"], bn_p.get("scale"),
-            bn_p.get("bias"), bn_s["mean"], bn_s["var"], self.bn_eps,
-            family=self.kernel_family)
+        return y
 
 
 def classifier_head(x, classes: int, activation: Optional[str],

@@ -34,11 +34,8 @@ class Xception(nn.Module):
             momentum=0.99, dtype=self.dtype, name=name)
 
         def sep(h, features, name):
-            # kernel_family opts the block into the fused sep2d registry
-            # (core/kernels.py accept-if-faster autotune); ineligible or
-            # unadopted sites keep the plain Flax body.
-            return SeparableConvBN(features, dtype=self.dtype, name=name,
-                                   kernel_family="xception")(h, train)
+            return SeparableConvBN(features, dtype=self.dtype,
+                                   name=name)(h, train)
 
         # Entry flow: block 1 (plain convs)
         x = nn.Conv(32, (3, 3), strides=(2, 2), padding="VALID",

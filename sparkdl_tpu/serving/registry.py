@@ -56,9 +56,8 @@ def warmup_deployment(model: Any, name: str, version: str,
     """AOT-compile ``model``'s FULL bucket ladder — one dummy batch per
     rung, through the ``executor.execute`` choke point, so each rung's
     exact padded variant (precision cast, donation, planner bucket)
-    compiles and its fused-kernel shootouts settle BEFORE the
-    deployment takes traffic (docs/PERF.md "Fused kernels & AOT
-    warmup").
+    compiles BEFORE the deployment takes traffic (docs/PERF.md "AOT
+    bucket-ladder warmup").
 
     Runs inside the deployment's loader — i.e. under the residency
     single-flight on EVERY cold load: first deploy, reload after

@@ -211,3 +211,94 @@ def test_import_places_compile_cache_without_importing_jax(
     assert got["jax_dir"] == want
     assert got["min_secs"] == 0.0
     assert not os.path.exists(str(tmp_path / "c"))  # placing writes nothing
+
+
+def _plain_bilinear(x, th, tw):
+    """Half-pixel-centre bilinear resize of NHWC ``x`` in float64, edges
+    clamped, no antialiasing: ``tf.image.resize_bilinear``'s arithmetic
+    with ``half_pixel_centers``, written out."""
+    def taps(n_in, n_out):
+        src = (np.arange(n_out) + 0.5) * (n_in / n_out) - 0.5
+        lo = np.floor(src)
+        frac = src - lo
+        return (np.clip(lo, 0, n_in - 1).astype(int),
+                np.clip(lo + 1, 0, n_in - 1).astype(int), frac)
+
+    x = x.astype(np.float64)
+    lo, hi, f = taps(x.shape[1], th)
+    x = x[:, lo] * (1 - f)[None, :, None, None] \
+        + x[:, hi] * f[None, :, None, None]
+    lo, hi, f = taps(x.shape[2], tw)
+    return x[:, :, lo] * (1 - f)[None, None, :, None] \
+        + x[:, :, hi] * f[None, None, :, None]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("src,target", [
+    ((64, 64), (32, 32)), ((224, 224), (299, 299)), ((375, 500), (299, 299)),
+], ids=lambda hw: "x".join(map(str, hw)))
+def test_resized_matches_plain_bilinear(src, target, dtype):
+    """``resized()``'s prologue — uint8 in, cast and bilinear resize inside
+    the compiled program — at a small, a model-sized and a photo-sized
+    source, against the resize written out in numpy."""
+    mf = ModelFunction(lambda vs, a: a, None,
+                       TensorSpec((None,) + target + (3,), dtype),
+                       name="resize_only")
+    x = np.random.default_rng(0).integers(
+        0, 256, size=(2,) + src + (3,), dtype=np.uint8)
+    wrapped = mf.resized(src)
+    assert wrapped is mf.resized(src, target)  # one program per geometry
+    assert wrapped.input_spec.shape == (None,) + src + (3,)
+    got = wrapped.jitted()(x)
+    assert got.dtype == jnp.dtype(dtype)
+    assert got.shape == (2,) + target + (3,)
+    err = np.max(np.abs(np.asarray(got, np.float64)
+                        - _plain_bilinear(x, *target)))
+    # on the 0-255 scale. float32: the weights come from float32
+    # coordinates of up to 500 (2^-24 * 500 = 3e-5 of a weight);
+    # bfloat16: weights of 8 bits, and from 128 up one ulp is 1.0 — a
+    # rounding after each axis
+    assert err <= (255 * 1e-4 if dtype == "float32" else 3.0)
+
+
+@pytest.mark.parametrize("route", ["fromFlax", "resized"])
+def test_first_launch_traces_the_body_once(route):
+    """The first launch of a new shape runs the Python body once, and
+    that run is the trace the ``sparkdl.compile`` span times; a launch at
+    a seen shape runs it not at all."""
+    from sparkdl_tpu.core import telemetry
+    from sparkdl_tpu.core.telemetry import Telemetry
+
+    traced_under = []
+
+    if route == "fromFlax":
+        class Counting(nn.Module):
+            @nn.compact
+            def __call__(self, x):
+                traced_under.append(telemetry.current_context())
+                return nn.Dense(4)(x)
+
+        module, spec = Counting(), TensorSpec((None, 6))
+        variables = module.init(jax.random.PRNGKey(0), jnp.zeros((1, 6)))
+        mf = ModelFunction.fromFlax(module, variables, spec)
+        batches = [np.ones((3, 6), np.float32), np.ones((5, 6), np.float32)]
+    else:
+        def body(vs, a):
+            traced_under.append(telemetry.current_context())
+            return jnp.mean(a, axis=(1, 2))
+
+        mf = ModelFunction.fromFunction(
+            body, None, TensorSpec((None, 8, 8, 3))).resized((12, 16))
+        batches = [np.ones((3, 12, 16, 3), np.uint8),
+                   np.ones((5, 12, 16, 3), np.uint8)]
+    del traced_under[:]  # init traced the module once
+    fn = mf.jitted()
+    with Telemetry() as tel:
+        fn(batches[0])
+        fn(batches[0])
+        assert len(traced_under) == 1
+        fn(batches[1])
+        assert len(traced_under) == 2
+    compiles = tel.tracer.spans(telemetry.SPAN_COMPILE)
+    assert ([ctx.span_id for ctx in traced_under]
+            == [span["span_id"] for span in compiles])

@@ -1,0 +1,120 @@
+"""The product's own programs compile for the TPU v5e at the main path's
+real shapes: the model zoo's convolution units (``models/layers.py``) and
+``ModelFunction.resized()``'s cast-and-resize prologue.
+
+These are the only tier-1 tests that hand the product's code to the TPU's
+compiler: each program is compiled ahead of time for a DESCRIBED
+``v5e:2x2`` (no chip attached, nothing runs). They also hold that the
+program is XLA's own — no hand-written kernel (``tpu_custom_call``) is in
+it; a PR that ships one changes that assertion where it belongs.
+
+The topology is described inside a fixture of this file and nowhere else:
+only one process at a time may load the TPU's library, and under
+pytest-xdist every worker imports every test file, so a call made at
+import (or in a ``skipif``/``parametrize`` argument, or in conftest)
+would take the library in the wrong worker. Compiles run in this test
+process, with the persistent compilation cache off around them (an
+ahead-of-time entry cannot be read back without a chip).
+"""
+
+import os
+
+import jax
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from sparkdl_tpu.core import ModelFunction, TensorSpec
+from sparkdl_tpu.models.layers import ConvBN, SeparableConvBN
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    # or the TPU compiler logs under /tmp; setdefault keeps an outside
+    # choice, and the variable is only read when the library loads here
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    # the library's failure modes (absent, locked by another process) are
+    # not one exception type; any of them means "cannot be described here"
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+# (program, launch geometry, dtype): the Xception middle- and exit-flow
+# separable convolutions (batch, H, W, C in, C out), the InceptionV3 1x1
+# ConvBN units, and resized()'s prologue (batch, H, W, C, target H, target
+# W) at a small, a model-sized and a typical-photo source.
+_SITES = [
+    ("sep", (128, 19, 19, 728, 728), "bfloat16"),
+    ("sep", (8, 19, 19, 728, 728), "float32"),
+    ("sep", (128, 10, 10, 1024, 1536), "bfloat16"),
+    ("conv_relu", (128, 35, 35, 192, 64), "bfloat16"),
+    ("conv_relu", (8, 35, 35, 288, 48), "float32"),
+    ("conv_relu", (128, 17, 17, 768, 192), "bfloat16"),
+    ("conv_relu", (128, 8, 8, 1280, 320), "bfloat16"),
+    ("conv", (8, 8, 8, 2048, 192), "float32"),
+    ("conv_relu", (8, 73, 73, 64, 80), "bfloat16"),
+    ("resized", (8, 64, 64, 3, 32, 32), "float32"),
+    ("resized", (8, 64, 64, 3, 32, 32), "bfloat16"),
+    ("resized", (8, 224, 224, 3, 299, 299), "float32"),
+    ("resized", (8, 224, 224, 3, 299, 299), "bfloat16"),
+    ("resized", (8, 375, 500, 3, 299, 299), "float32"),
+    ("resized", (128, 375, 500, 3, 299, 299), "bfloat16"),
+]
+
+
+def _program_and_arguments(site, sharding):
+    """The jitted program of a site and its arguments as shapes on the
+    described device (there is no device to hold an array)."""
+    program, shape, dtype = site
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+    if program == "resized":
+        b, h, w, c, th, tw = shape
+        mf = ModelFunction(lambda vs, a: a, None,
+                           TensorSpec((None, th, tw, c), dtype),
+                           name="resize_only").resized((h, w))
+        return (jax.jit(lambda a: mf.apply_fn(None, a)),
+                (on_chip(jax.ShapeDtypeStruct((b, h, w, c), "uint8")),))
+    b, h, w, cin, cout = shape
+    module = (SeparableConvBN(cout) if program == "sep"
+              else ConvBN(cout, (1, 1), act=program == "conv_relu"))
+    x = jax.ShapeDtypeStruct((b, h, w, cin), dtype)
+    variables = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)
+    # with_compute_dtype's cast: variables in the compute dtype
+    variables = jax.tree.map(
+        lambda a: on_chip(jax.ShapeDtypeStruct(a.shape, dtype)), variables)
+    return (jax.jit(lambda vs, a: module.apply(vs, a, train=False)),
+            (variables, on_chip(x)))
+
+
+@pytest.mark.parametrize(
+    "site", _SITES,
+    ids=lambda s: f"{s[0]}-{'x'.join(map(str, s[1]))}-{s[2]}")
+def test_program_compiles_for_v5e(site, one_chip, no_persistent_cache):
+    fn, args = _program_and_arguments(site, one_chip)
+    text = fn.lower(*args).compile().as_text()
+    assert ":T(" in text  # tiled layouts: the TPU's compiler made this
+    assert "tpu_custom_call" not in text

@@ -54,11 +54,15 @@ def test_corrupt_rows_yield_null_cells_partition_completes(rng, caplog):
 
 def test_injected_decode_error_yields_null_cell(rng):
     # non-uniform sizes force the per-row (decode) path where the
-    # decode_error injection point lives
+    # decode_error injection point lives — within ONE partition: the
+    # default count follows the machine's cores, and four one-row
+    # partitions are each uniform and take the zero-copy path, which
+    # decodes nothing and so has no such point
     structs = [imageIO.imageArrayToStruct(
         rng.integers(0, 255, (8 + (i == 0), 8, 3), dtype=np.uint8))
         for i in range(4)]
-    df = DataFrame.fromRows([{"image": s} for s in structs])
+    df = DataFrame.fromRows([{"image": s} for s in structs],
+                            numPartitions=1)
     t = TPUImageTransformer(inputCol="image", outputCol="out",
                             modelFunction=_mean_model(), batchSize=4,
                             inputSize=(8, 8))

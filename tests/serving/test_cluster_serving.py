@@ -29,7 +29,9 @@ import pytest
 import jax.numpy as jnp
 
 from sparkdl_tpu.cluster import router as cluster_router
-from sparkdl_tpu.core import executor, health, resilience, telemetry
+from sparkdl_tpu.core import (
+    batching, executor, health, resilience, telemetry,
+)
 from sparkdl_tpu.core.health import HealthMonitor
 from sparkdl_tpu.core.model_function import ModelFunction, TensorSpec
 from sparkdl_tpu.core.resilience import Fault, FaultInjector
@@ -71,8 +73,13 @@ def _model(scale: float, name: str = "served") -> ModelFunction:
                          name=name)
 
 
-def _reference(model: ModelFunction, rows: np.ndarray) -> np.ndarray:
-    return np.asarray(jnp.tanh(jnp.asarray(rows) @ model.variables))
+def _reference(model: ModelFunction, rows: np.ndarray,
+               batch_size: int = 64) -> np.ndarray:
+    """Ground truth at a launch's shape; why the padding matters is in
+    ``test_server_registry._reference``."""
+    padded, n = batching.pad_batch(
+        rows, batching.bucket_size(len(rows), batch_size))
+    return np.asarray(jnp.tanh(jnp.asarray(padded) @ model.variables))[:n]
 
 
 def _stack():

@@ -11,7 +11,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from sparkdl_tpu.core import executor, health, slo, telemetry
+from sparkdl_tpu.core import batching, executor, health, slo, telemetry
 from sparkdl_tpu.core.health import HealthMonitor
 from sparkdl_tpu.core.model_function import ModelFunction, TensorSpec
 from sparkdl_tpu.core.telemetry import Telemetry
@@ -45,10 +45,18 @@ def _model(scale: float, name: str = "served") -> ModelFunction:
                          name=name)
 
 
-def _reference(model: ModelFunction, rows: np.ndarray) -> np.ndarray:
-    """Ground truth computed WITHOUT the serving stack (fp32 conftest
-    pin makes the served outputs bit-identical to this)."""
-    return np.asarray(jnp.tanh(jnp.asarray(rows) @ model.variables))
+def _reference(model: ModelFunction, rows: np.ndarray,
+               batch_size: int = 64) -> np.ndarray:
+    """Ground truth computed WITHOUT the serving stack, at a launch's
+    shape: the executor pads a request to a bucket of at least 8 rows
+    (``batching.bucket_size``), and XLA's CPU dot sums a row of an
+    operand of under 8 rows in another order than the same row of a
+    larger one (6e-8 apart; from 8 rows up every size agrees bit for
+    bit). Padded alike, the fp32 conftest pin makes the served outputs
+    bit-identical to this."""
+    padded, n = batching.pad_batch(
+        rows, batching.bucket_size(len(rows), batch_size))
+    return np.asarray(jnp.tanh(jnp.asarray(padded) @ model.variables))[:n]
 
 
 def _serving_stack(**server_kw):
@@ -392,12 +400,12 @@ def test_transformer_resolves_served_model_name_and_follows_cutover(rng):
                         modelFunction=name, batchSize=4)
     out1 = np.array([r["out"] for r in tr.transform(df).collect()],
                     dtype=np.float32)
-    np.testing.assert_array_equal(out1, _reference(v1, rows))
+    np.testing.assert_array_equal(out1, _reference(v1, rows, batch_size=4))
     # a cutover reaches the NEXT transform call — no new transformer
     reg.deploy(name, "v2", model=v2, activate=True)
     out2 = np.array([r["out"] for r in tr.transform(df).collect()],
                     dtype=np.float32)
-    np.testing.assert_array_equal(out2, _reference(v2, rows))
+    np.testing.assert_array_equal(out2, _reference(v2, rows, batch_size=4))
 
 
 # ---------------------------------------------------------------------------

@@ -91,7 +91,6 @@ EXPECTED_FIXTURE_RULES = {
     "slo_metric_typo.py": {"slo-metrics"},
     "federated_frame_key.py": {"slo-metrics"},
     "state/durability.py": {"atomic-write"},
-    "core/raw_pallas.py": {"kernel-gate"},
     "suppression_no_reason.py": {"blocking-under-lock",
                                  "suppression-hygiene"},
 }

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 import chip_smoke
-from sparkdl_tpu.core import batching, executor, kernels
+from sparkdl_tpu.core import batching, executor
 from sparkdl_tpu.engine.dataframe import EngineConfig
 
 
@@ -22,15 +22,11 @@ def _library_defaults():
     """The smoke runs at library defaults; the suite's conftest pins fp32 +
     pow2 for bit-identity, so put bf16 + the tuned ladder back here."""
     saved = EngineConfig.snapshot()
-    saved_interpret = kernels.INTERPRET
     EngineConfig.inference_precision = "bfloat16"
     EngineConfig.bucket_ladder = "tuned"
-    kernels.reset()
     executor.reset()
     batching.reset_planners()
     yield
-    kernels.INTERPRET = saved_interpret
-    kernels.reset()
     executor.reset()
     batching.reset_planners()
     EngineConfig.restore(saved)
@@ -81,35 +77,6 @@ def test_one_chip_phases_on_testnet(meter, capsys):
              for line in capsys.readouterr().out.splitlines()]
     assert [line["phase"] for line in lines] == ["serving", "train"]
     assert all(line["ok"] and "compile_seconds" in line for line in lines)
-
-
-def test_kernel_phase_needs_both_candidates_to_have_run():
-    sites = [kernels.Site("pw1x1_relu", "rehearsal", (2, 4, 4, 8, 16),
-                          "bfloat16"),
-             kernels.Site("preproc", "rehearsal", (1, 8, 10, 3, 5, 6),
-                          "uint8->bfloat16")]
-    # the CPU has no Mosaic lowering: a clean rejection carries no timings,
-    # which the phase must refuse to pass off as an audition
-    with pytest.raises(AssertionError, match="a candidate did not run"):
-        chip_smoke.phase_kernels(sites)
-    kernels.reset()
-    kernels.INTERPRET = True
-    verdicts = chip_smoke.phase_kernels(sites)["verdicts"]
-    assert len(verdicts) == 2
-    assert all("pallas_s" in v and "xla_s" in v for v in verdicts.values())
-
-
-def test_kernel_phase_fails_on_an_audition_error(monkeypatch):
-    kernels.INTERPRET = True
-
-    def broken(site):
-        raise NotImplementedError("Unsupported cast: uint8 -> float32")
-
-    monkeypatch.setattr(kernels, "_build_shootout", broken)
-    with pytest.raises(AssertionError, match="Unsupported cast"):
-        chip_smoke.phase_kernels(
-            [kernels.Site("preproc", "rehearsal", (1, 8, 10, 3, 5, 6),
-                          "uint8->bfloat16")])
 
 
 def test_failed_phase_prints_not_ok_and_reraises(meter, capsys):
