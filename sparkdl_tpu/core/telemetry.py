@@ -258,6 +258,8 @@ M_CLUSTER_DRAIN_S = "sparkdl.cluster.drain_s"          # histogram
 PROGRAM_COUNTS = "sparkdl.program_counts"
 M_SEQUENCE_TOKENS = "sparkdl.sequence.tokens"          # counter (tokens of
                                                        # the rows scored)
+# counter (per row: the layers whose attention was lowered to the fused kernel)
+M_SEQUENCE_FUSED_ATTENTION_LAYERS = "sparkdl.sequence.fused_attention_layers"
 M_MOE_ROUTED_TOKENS = "sparkdl.moe.routed_tokens"      # counter (tokens
                                                        # routed, once for each
                                                        # expert layer)
@@ -323,6 +325,7 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_CLUSTER_WORKERS: "gauge",
     M_CLUSTER_DRAIN_S: "histogram",
     M_SEQUENCE_TOKENS: "counter",
+    M_SEQUENCE_FUSED_ATTENTION_LAYERS: "counter",
     M_MOE_ROUTED_TOKENS: "counter",
     M_MOE_LOCAL_PAIRS: "counter",
     M_MOE_OVERFLOW_PAIRS: "counter",

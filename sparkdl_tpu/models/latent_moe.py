@@ -22,15 +22,28 @@ Precision follows the weights: matrix products run in the weights' dtype
 the norms' statistics, the soft-maxes, the residual stream and the
 log-probabilities are float32.
 
+**What runs where.** Everything is XLA's own but a window's causal attention,
+which has two paths under one contract (:func:`causal_attention`): a Pallas
+TPU kernel that keeps the scores on the chip (:func:`fused_causal_attention`),
+and the blocked soft-max in XLA, which is also the oracle the kernel is tested
+against. The kernel is taken where the program is lowered for a TPU — a chip,
+or an ahead-of-time compile for a described one — with bfloat16 operands, a
+window of whole tiles and head widths of whole lanes; a CPU run, float32
+weights, a short or ragged window or other widths lower the blocked path. No
+option chooses, and ``jax.experimental.pallas`` is imported where the kernel
+is built, not with this module.
+
 Outputs per window (row): ``pooled`` — the mean over positions of the
 final-norm hidden state; ``logprobs`` — ``log p(x[t+1] | x[≤t])`` under the
 soft-max over the vocabulary slice held (the last is 0); ``expert_counts`` —
 per expert layer and published expert, the tokens of the window routed to it;
-and, under ``telemetry.PROGRAM_COUNTS``, the counters the executor records.
+and, under ``telemetry.PROGRAM_COUNTS``, the counters the executor records
+(among them, per row, the layers whose attention was lowered to the kernel).
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Tuple
 
@@ -86,29 +99,33 @@ def _dot(x, w, out=jnp.float32):
                    ).astype(out)
 
 
-def rotary(x, theta):
-    """x (..., T, rope) float32: the two halves pair up."""
-    half = x.shape[-1] // 2
-    t = jnp.arange(x.shape[-2], dtype=jnp.float32)
+def rotary(x, theta, heads=1):
+    """x (T, heads · rope) float32, head by head as a projection leaves it;
+    a head's two halves pair up."""
+    T = x.shape[0]
+    half = x.shape[1] // heads // 2
+    t = jnp.arange(T, dtype=jnp.float32)
     angle = t[:, None] * theta ** (-jnp.arange(half, dtype=jnp.float32)
                                    / half)
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    a, b = x[..., :half], x[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+    cos, sin = jnp.cos(angle)[:, None], jnp.sin(angle)[:, None]
+    x = x.reshape(T, heads, 2, half)
+    a, b = x[:, :, 0], x[:, :, 1]
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin], 2).reshape(
+        T, -1)
 
 
-def causal_attention(q, k, v, block):
-    """q, k (H, T, d), v (H, T, dv) → (H, T, dv). Blocked over queries, each
-    block against its causal prefix of keys only: the scores of one block are
-    the largest temporary (H · block · T float32), not H · T²."""
+def _blocked_attention(q, k, v, block):
+    """q, k (H, T, d), v (H, T, dv) → (H, T, dv); q carries the scores' scale.
+    Blocked over queries, each block against its causal prefix of keys only:
+    the scores of one block are the largest temporary (H · block · T float32),
+    not H · T²."""
     H, T, d = q.shape
     block = min(block, T)
-    scale = d ** -0.5
     out = []
     for lo in range(0, T, block):
         hi = min(lo + block, T)
         scores = jnp.einsum("hqd,hkd->hqk", q[:, lo:hi], k[:, :hi],
-                            preferred_element_type=jnp.float32) * scale
+                            preferred_element_type=jnp.float32)
         mask = jnp.arange(hi)[None, :] <= jnp.arange(lo, hi)[:, None]
         scores = jnp.where(mask, scores, -jnp.inf)
         weights = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
@@ -119,27 +136,170 @@ def causal_attention(q, k, v, block):
     return jnp.concatenate(out, 1)
 
 
+# The fused kernel's tiles, chosen from chip runs at 128 heads × 4,096
+# positions (PERF.md §6): queries a grid step, keys a tile of scores. A head's
+# keys and values stay in on-chip memory whole, which bounds the window.
+FUSED_QUERY_TILE = 1024
+FUSED_KEY_TILE = 512
+FUSED_MAX_WINDOW = 8192
+_LANES = 128
+_MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+
+def _fused_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, out_ref,
+                  max_ref, sum_ref, acc_ref, *, query_tile, key_tile):
+    """One head's block of ``query_tile`` queries against its causal prefix,
+    a tile of ``key_tile`` keys at a time: the scores, their running maximum
+    and sum (kept across all 128 lanes, so no step re-lays them out) and the
+    weighted values never leave on-chip memory."""
+    from jax.experimental import pallas as pl
+    first = pl.program_id(1) * query_tile
+    max_ref[...] = jnp.full(max_ref.shape, _MASKED, jnp.float32)
+    sum_ref[...] = jnp.zeros(sum_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    transposed = (((1,), (1,)), ((), ()))
+
+    def step(lo, start, diagonal):
+        """Queries [lo, query_tile) of the block against the key tile at
+        ``start``; ``diagonal``: the tile begins at query ``lo``'s position."""
+        rows = slice(lo, query_tile)
+        keys = pl.ds(pl.multiple_of(start, key_tile), key_tile)
+        scores = lax.dot_general(
+            qn_ref[rows, :], kn_ref[keys, :], transposed,
+            preferred_element_type=jnp.float32) + lax.dot_general(
+            qr_ref[rows, :], kr_ref[keys, :], transposed,
+            preferred_element_type=jnp.float32)
+        if diagonal:
+            scores = jnp.where(
+                lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+                <= lax.broadcasted_iota(jnp.int32, scores.shape, 0),
+                scores, _MASKED)
+        before = max_ref[rows, :]
+        highest = jnp.maximum(before, jnp.max(scores, -1, keepdims=True))
+        weights = jnp.exp(scores - jnp.tile(highest, (1, key_tile // _LANES)))
+        decay = jnp.exp(before - highest)
+        max_ref[rows, :] = highest
+        sum_ref[rows, :] = decay * sum_ref[rows, :] + jnp.sum(
+            weights, -1, keepdims=True)
+        values = v_ref[keys, :]
+        acc_ref[rows, :] = jnp.tile(
+            decay, (1, values.shape[1] // _LANES)) * acc_ref[rows, :] + jnp.dot(
+            weights.astype(values.dtype), values,
+            preferred_element_type=jnp.float32)
+
+    lax.fori_loop(0, first // key_tile,
+                  lambda tile, _: step(0, tile * key_tile, False), None)
+    for lo in range(0, query_tile, key_tile):
+        step(lo, first + lo, True)
+    out_ref[...] = (acc_ref[...] * jnp.tile(
+        1.0 / sum_ref[...], (1, acc_ref.shape[1] // _LANES))
+        ).astype(out_ref.dtype)
+
+
+def fused_causal_attention(q_nope, q_rope, k_nope, k_rope, v, *,
+                           query_tile=FUSED_QUERY_TILE,
+                           key_tile=FUSED_KEY_TILE, interpret=False):
+    """:func:`causal_attention`'s contract as one Pallas TPU kernel (an online
+    soft-max): only q, k, v and the output cross HBM, each once, in the layout
+    the projections leave them in. The two parts of the scores are two
+    products, so the rotary key is never copied to every head; the rotary
+    queries come heads first because a block cannot cut a head narrower than
+    the 128 lanes out of a flat row. The window is a multiple of
+    ``query_tile``, that of ``key_tile``, and ``key_tile`` and the widths
+    ``nope`` and ``v`` are multiples of the 128 lanes."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    H, T, rope = q_rope.shape
+    nope, width = q_nope.shape[1] // H, v.shape[1] // H
+    return pl.pallas_call(
+        functools.partial(_fused_kernel, query_tile=query_tile,
+                          key_tile=key_tile),
+        grid=(H, T // query_tile),
+        in_specs=[pl.BlockSpec((query_tile, nope), lambda h, i: (i, h)),
+                  pl.BlockSpec((None, query_tile, rope),
+                               lambda h, i: (h, i, 0)),
+                  pl.BlockSpec((T, nope), lambda h, i: (0, h)),
+                  pl.BlockSpec((T, rope), lambda h, i: (0, 0)),
+                  pl.BlockSpec((T, width), lambda h, i: (0, h))],
+        out_specs=pl.BlockSpec((query_tile, width), lambda h, i: (i, h)),
+        out_shape=jax.ShapeDtypeStruct((T, H * width), v.dtype),
+        scratch_shapes=[pltpu.VMEM((query_tile, _LANES), jnp.float32),
+                        pltpu.VMEM((query_tile, _LANES), jnp.float32),
+                        pltpu.VMEM((query_tile, width), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=32 * 1024 * 1024),
+        name="fused_causal_attention", interpret=interpret,
+    )(q_nope, q_rope, k_nope, k_rope, v)
+
+
+def causal_attention(q_nope, q_rope, k_nope, k_rope, v, block):
+    """One window's causal attention. The scores are ``q_nope·k_nopeᵀ +
+    q_rope·k_ropeᵀ`` and the queries carry their scale; q_nope, k_nope
+    (T, H · nope) and v (T, H · dv) as the projections leave them, head by
+    head; q_rope (H, T, rope) rotated, k_rope (T, rope) one head for all.
+    Operands in their dtype, scores and soft-max float32, the weights cast to
+    v's dtype for the second product, float32 accumulation.
+
+    Returns ``(out (T, H · dv), fused)``. Lowered for a TPU, with bfloat16
+    operands and a window and widths that fit the kernel's tiles, this is
+    :func:`fused_causal_attention` and ``fused`` is 1; everywhere else the
+    blocked path in XLA (queries in blocks of ``block``) and 0. Both come out
+    of one ``lax.platform_dependent``, so ``fused`` says what was lowered."""
+    H, T, _ = q_rope.shape
+    nope, width = q_nope.shape[1] // H, v.shape[1] // H
+
+    def blocked(q_nope, q_rope, k_nope, k_rope, v):
+        def heads_first(a):
+            return jnp.swapaxes(a.reshape(T, H, -1), 0, 1)
+
+        q = jnp.concatenate([heads_first(q_nope), q_rope], -1)
+        k = jnp.concatenate(
+            [heads_first(k_nope),
+             jnp.broadcast_to(k_rope, (H,) + k_rope.shape)], -1)
+        out = _blocked_attention(q, k, heads_first(v), block)
+        return jnp.swapaxes(out, 0, 1).reshape(T, H * width), jnp.int32(0)
+
+    def fused(*operands):
+        return fused_causal_attention(*operands), jnp.int32(1)
+
+    operands = (q_nope, q_rope, k_nope, k_rope, v)
+    fits = (all(a.dtype == jnp.bfloat16 for a in operands)
+            and T % FUSED_QUERY_TILE == 0 and T <= FUSED_MAX_WINDOW
+            and nope % _LANES == 0 and width % _LANES == 0)
+    if not fits:
+        return blocked(*operands)
+    return lax.platform_dependent(*operands, tpu=fused, default=blocked)
+
+
 def latent_attention(p, x, c: LatentMoEConfig):
-    """x (T, hidden) float32, one window → (T, hidden) float32."""
+    """x (T, hidden) float32, one window → ``((T, hidden) float32, fused)``,
+    ``fused`` as :func:`causal_attention` returns it. Each part of q, k and v
+    is its own product of the latents with its columns of the up-projection,
+    so it comes out in the layout the attention reads."""
     T = x.shape[0]
     act = p["q_up"].dtype
-    cq = rms_norm(_dot(x, p["q_down"]), p["q_norm"], c.eps)
-    q = _dot(cq, p["q_up"], act).reshape(T, c.heads, c.nope + c.rope)
+    width = c.nope + c.rope
+    # the scores' scale is linear through q_up and the rotation: in the
+    # float32 latent it costs the queries no second rounding
+    cq = rms_norm(_dot(x, p["q_down"]), p["q_norm"], c.eps) * width ** -0.5
     down = _dot(x, p["kv_down"])
     ckv = rms_norm(down[:, :c.kv_rank], p["kv_norm"], c.eps)
     k_rope = rotary(down[:, c.kv_rank:], c.theta).astype(act)
-    kv = _dot(ckv, p["kv_up"], act).reshape(T, c.heads, c.nope + c.v)
-    q = jnp.swapaxes(q, 0, 1)
-    q = jnp.concatenate(
-        [q[..., :c.nope],
-         rotary(q[..., c.nope:].astype(jnp.float32), c.theta).astype(act)],
-        -1)
-    k = jnp.concatenate(
-        [jnp.swapaxes(kv[..., :c.nope], 0, 1),
-         jnp.broadcast_to(k_rope, (c.heads, T, c.rope))], -1)
-    v = jnp.swapaxes(kv[..., c.nope:], 0, 1)
-    out = causal_attention(q, k, v, c.query_block)
-    return _dot(jnp.swapaxes(out, 0, 1).reshape(T, c.heads * c.v), p["out"])
+
+    def part(latent, w, lo, hi):
+        """The latent times columns lo:hi of every head's group of w."""
+        columns = w.reshape(w.shape[0], c.heads, -1)[:, :, lo:hi]
+        return _dot(latent, columns.reshape(w.shape[0], -1), act)
+
+    q_rope = rotary(part(cq, p["q_up"], c.nope, width).astype(jnp.float32),
+                    c.theta, c.heads).astype(act)
+    q_rope = jnp.swapaxes(q_rope.reshape(T, c.heads, c.rope), 0, 1)
+    out, fused = causal_attention(
+        part(cq, p["q_up"], 0, c.nope), q_rope,
+        part(ckv, p["kv_up"], 0, c.nope), k_rope,
+        part(ckv, p["kv_up"], c.nope, c.nope + c.v), c.query_block)
+    return _dot(out, p["out"]), fused
 
 
 def gated_mlp(p, x):
@@ -223,16 +383,17 @@ def routed_experts(p, x, c: LatentMoEConfig):
 
 def block(layer, h, c: LatentMoEConfig):
     """One sandwich block over windows h (B, T, hidden) float32. Returns
-    ``(h, stats)``; ``stats`` is None for a dense layer."""
+    ``(h, fused, stats)``: ``fused`` (B,) as :func:`causal_attention` returns
+    it for each window; ``stats`` is None for a dense layer."""
     B, T, _ = h.shape
-    attended = lax.map(
+    attended, fused = lax.map(
         lambda row: latent_attention(
             layer["attn"], rms_norm(row, layer["input_norm"], c.eps), c), h)
     h = h + rms_norm(attended, layer["post_attn_norm"], c.eps)
     x = rms_norm(h, layer["pre_mlp_norm"], c.eps)
     if "moe" not in layer:
         m = lax.map(lambda row: gated_mlp(layer["mlp"], row), x)
-        return h + rms_norm(m, layer["post_mlp_norm"], c.eps), None
+        return h + rms_norm(m, layer["post_mlp_norm"], c.eps), fused, None
     moe = layer["moe"]
     flat = x.reshape(B * T, -1)
     routed, chosen, counts, overflow = routed_experts(moe, flat, c)
@@ -248,7 +409,7 @@ def block(layer, h, c: LatentMoEConfig):
         "load_max_over_mean": jnp.max(counts) / jnp.maximum(
             jnp.mean(counts.astype(jnp.float32)), 1.0),
     }
-    return h + rms_norm(m, layer["post_mlp_norm"], c.eps), stats
+    return h + rms_norm(m, layer["post_mlp_norm"], c.eps), fused, stats
 
 
 def forward(params, tokens, c: LatentMoEConfig) -> Dict[str, Any]:
@@ -257,8 +418,10 @@ def forward(params, tokens, c: LatentMoEConfig) -> Dict[str, Any]:
     B, T = tokens.shape
     h = params["embed"][tokens].astype(jnp.float32)
     stats = []
+    fused_layers = jnp.zeros((B,), jnp.int32)
     for layer in params["layers"]:
-        h, layer_stats = block(layer, h, c)
+        h, fused, layer_stats = block(layer, h, c)
+        fused_layers = fused_layers + fused
         if layer_stats is not None:
             stats.append(layer_stats)
     x = rms_norm(h, params["final_norm"], c.eps)
@@ -284,6 +447,7 @@ def forward(params, tokens, c: LatentMoEConfig) -> Dict[str, Any]:
         out["expert_counts"] = stacked("expert_counts")
         out[telemetry.PROGRAM_COUNTS] = {
             telemetry.M_SEQUENCE_TOKENS: jnp.full((B,), T, jnp.int32),
+            telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS: fused_layers,
             telemetry.M_MOE_ROUTED_TOKENS: jnp.full((B,), T * len(stats),
                                                     jnp.int32),
             telemetry.M_MOE_LOCAL_PAIRS: jnp.sum(stacked("local_pairs"), 1),

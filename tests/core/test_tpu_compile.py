@@ -1,12 +1,17 @@
 """The product's own programs compile for the TPU v5e at the main path's
-real shapes: the model zoo's convolution units (``models/layers.py``) and
-``ModelFunction.resized()``'s cast-and-resize prologue.
+real shapes: the model zoo's convolution units (``models/layers.py``),
+``ModelFunction.resized()``'s cast-and-resize prologue, and the sequence
+scorer's latent attention (``models/latent_moe.py``).
 
 These are the only tier-1 tests that hand the product's code to the TPU's
 compiler: each program is compiled ahead of time for a DESCRIBED
-``v5e:2x2`` (no chip attached, nothing runs). They also hold that the
-program is XLA's own — no hand-written kernel (``tpu_custom_call``) is in
-it; a PR that ships one changes that assertion where it belongs.
+``v5e:2x2`` (no chip attached, nothing runs). They also hold which
+programs are XLA's own and which hold a hand-written kernel
+(``tpu_custom_call``): the image programs hold none — every Pallas
+candidate for them lost to XLA's twin on the chip (PERF.md §6, PR 21) — and
+latent attention holds exactly the fused causal-attention kernel, which keeps
+a window's float32 scores out of HBM (PERF.md §6, PR 36). A PR that ships or
+drops a kernel changes the assertion where it belongs.
 
 The topology is described inside a fixture of this file and nowhere else:
 only one process at a time may load the TPU's library, and under
@@ -20,10 +25,12 @@ ahead-of-time entry cannot be read back without a chip).
 import os
 
 import jax
+import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from sparkdl_tpu.core import ModelFunction, TensorSpec
+from sparkdl_tpu.models import latent_moe, registry
 from sparkdl_tpu.models.layers import ConvBN, SeparableConvBN
 
 
@@ -118,3 +125,33 @@ def test_program_compiles_for_v5e(site, one_chip, no_persistent_cache):
     text = fn.lower(*args).compile().as_text()
     assert ":T(" in text  # tiled layouts: the TPU's compiler made this
     assert "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("window", [4096, latent_moe.FUSED_MAX_WINDOW])
+def test_latent_attention_compiles_to_the_fused_kernel_for_v5e(
+        window, one_chip, no_persistent_cache):
+    """One window's latent attention at the published widths (128 heads of
+    128 + 64 / 128, hidden 7,680), bfloat16 weights, shapes only: the .windows
+    cell's window, and the longest the kernel takes (a head's keys and values
+    stay in on-chip memory whole)."""
+    c = registry.SEQUENCE_MODELS["openPangu-Ultra-MoE-718B"]
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {"q_down": on_chip((c.hidden, c.q_rank), jnp.bfloat16),
+         "q_norm": on_chip((c.q_rank,), jnp.float32),
+         "q_up": on_chip((c.q_rank, c.heads * (c.nope + c.rope)),
+                         jnp.bfloat16),
+         "kv_down": on_chip((c.hidden, c.kv_rank + c.rope), jnp.bfloat16),
+         "kv_norm": on_chip((c.kv_rank,), jnp.float32),
+         "kv_up": on_chip((c.kv_rank, c.heads * (c.nope + c.v)),
+                          jnp.bfloat16),
+         "out": on_chip((c.heads * c.v, c.hidden), jnp.bfloat16)}
+    x = on_chip((window, c.hidden), jnp.float32)
+    text = jax.jit(lambda p, x: latent_moe.latent_attention(p, x, c)
+                   ).lower(p, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "fused_causal_attention" in text
+    # the blocked path's scores, 128 heads × a block of 512 queries × keys
+    assert "f32[128,512," not in text

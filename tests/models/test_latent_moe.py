@@ -2,12 +2,18 @@
 the benchmark's plain reference (benchmarks/references/openpangu_moe.py, which
 imports nothing of the program) at small sizes on the CPU: through the
 transformer and collect(); attention alone; the share test; no pair dropped;
-the program's counts in telemetry; the benchmark's FLOP count by hand."""
+the program's counts in telemetry; the benchmark's FLOP count by hand. And the
+fused attention kernel, interpreted on the CPU at the published head widths,
+against the blocked path that stays and a float32 soft-max; and which of the
+two a lowering takes."""
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -94,11 +100,12 @@ def test_latent_attention_alone_matches_reference(key):
     config = dataclasses.replace(MODEL, query_block=8)
     with jax.default_matmul_precision("highest"):
         want = ref.attention(p, x, s, lambda a: a, block=5)
-        got = latent_moe.latent_attention(p, x, config)
+        got, fused = latent_moe.latent_attention(p, x, config)
+    assert fused == 0
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     # causal: a later token does not move an earlier one
     with jax.default_matmul_precision("highest"):
-        moved = latent_moe.latent_attention(p, x.at[-1].add(1.0), config)
+        moved, _ = latent_moe.latent_attention(p, x.at[-1].add(1.0), config)
     np.testing.assert_allclose(moved[:-1], got[:-1], rtol=1e-5, atol=1e-6)
 
 
@@ -166,6 +173,8 @@ def test_program_counts_reach_telemetry_and_not_the_caller(key):
     assert set(out) == {"pooled", "logprobs", "expert_counts"}
     counters = snapshot["counters"]
     assert counters[telemetry.M_SEQUENCE_TOKENS] == 3 * WINDOW
+    # a window of 24 fits no tile, and this is a CPU: the blocked path
+    assert counters[telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS] == 0
     assert counters[telemetry.M_MOE_ROUTED_TOKENS] == 3 * WINDOW
     held = np.asarray(out["expert_counts"])[:, 0, 4:8].sum()
     assert counters[telemetry.M_MOE_LOCAL_PAIRS] == held
@@ -223,6 +232,111 @@ def test_builder_reads_the_share_off_the_weights(key):
         "TestLatentMoE", variables, WINDOW, experts_held=(4, 5, 6, 7))
     assert model.input_spec.shape == (None, WINDOW)
     assert model.input_spec.dtype == "int32"
+
+
+# -- the fused kernel and the choice -----------------------------------------
+
+HEADS, NOPE, ROPE, WIDTH = 4, 128, 64, 128       # the published head widths
+
+
+def attention_operands(window, dtype=jnp.bfloat16, seed=0):
+    """causal_attention's operands, the queries carrying their scale."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    scale = (NOPE + ROPE) ** -0.5
+
+    def draw(key, *shape):
+        return jax.random.normal(key, shape, jnp.float32)
+
+    return tuple(a.astype(dtype) for a in (
+        draw(keys[0], window, HEADS * NOPE) * scale,
+        draw(keys[1], HEADS, window, ROPE) * scale,
+        draw(keys[2], window, HEADS * NOPE), draw(keys[3], window, ROPE),
+        draw(keys[4], window, HEADS * WIDTH)))
+
+
+def exact_attention(q_nope, q_rope, k_nope, k_rope, v):
+    """A float32 soft-max at full precision over the same operands."""
+    q_nope, q_rope, k_nope, k_rope, v = (
+        a.astype(jnp.float32) for a in (q_nope, q_rope, k_nope, k_rope, v))
+    window = v.shape[0]
+
+    def heads(a):
+        return a.reshape(window, HEADS, -1)
+
+    with jax.default_matmul_precision("highest"):
+        scores = jnp.einsum("qhd,khd->hqk", heads(q_nope), heads(k_nope)
+                            ) + jnp.einsum("hqd,kd->hqk", q_rope, k_rope)
+        scores = jnp.where(jnp.tril(jnp.ones((window, window), bool)),
+                           scores, -jnp.inf)
+        return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(scores, -1),
+                          heads(v)).reshape(window, -1)
+
+
+def distance(got, want):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+@pytest.mark.parametrize("window,query_tile", [(256, 128), (512, 128),
+                                               (512, 256)])
+def test_fused_kernel_against_the_blocked_path_and_float32(window,
+                                                           query_tile):
+    operands = attention_operands(window)
+    fused = latent_moe.fused_causal_attention(
+        *operands, query_tile=query_tile, key_tile=128, interpret=True)
+    blocked, engaged = latent_moe.causal_attention(*operands, 128)
+    assert engaged == 0 and fused.dtype == blocked.dtype == jnp.bfloat16
+    exact = exact_attention(*operands)
+    to_exact = distance(fused, exact), distance(blocked, exact)
+    # both are the bfloat16 rounding of the weights and of the output, about
+    # 0.002 of the norm; neither path may be the looser by more than a quarter
+    assert max(to_exact) < 0.003
+    assert max(to_exact) < 1.25 * min(to_exact)
+    assert distance(fused, blocked) < 0.004
+
+
+@pytest.mark.parametrize("window", [256, 512])
+def test_fused_kernel_is_causal(window):
+    """A changed last key (both parts, and its value) moves the last query's
+    row and no other."""
+    q_nope, q_rope, k_nope, k_rope, v = attention_operands(window, seed=1)
+    run = functools.partial(latent_moe.fused_causal_attention,
+                            query_tile=128, key_tile=128, interpret=True)
+    before = np.asarray(run(q_nope, q_rope, k_nope, k_rope, v), np.float32)
+    after = np.asarray(run(
+        q_nope, q_rope, k_nope.at[-1].add(3.0), k_rope.at[-1].add(3.0),
+        v.at[-1].add(3.0)), np.float32)
+    np.testing.assert_array_equal(after[:-1], before[:-1])
+    assert np.abs(after[-1] - before[-1]).max() > 0.1
+
+
+@pytest.mark.parametrize("case", ["window-24", "float32", "lowered-for-cpu",
+                                  "lowered-for-tpu"])
+def test_the_choice_follows_what_the_lowering_can_see(case):
+    """The kernel is taken where the program is lowered for a TPU with
+    bfloat16 operands and a window of whole tiles; the counter says which."""
+    window = 24 if case == "window-24" else latent_moe.FUSED_QUERY_TILE
+    dtype = jnp.float32 if case == "float32" else jnp.bfloat16
+    operands = attention_operands(window, dtype)
+    fn = jax.jit(lambda *a: latent_moe.causal_attention(*a, 512))
+    platform = "tpu" if case == "lowered-for-tpu" else "cpu"
+    text = fn.trace(*operands).lower(lowering_platforms=(platform,)).as_text()
+    assert ("tpu_custom_call" in text) == (case == "lowered-for-tpu")
+    if case == "lowered-for-tpu":
+        return                       # nothing here can run it
+    out, engaged = fn(*operands)
+    assert engaged == 0 and out.shape == (window, HEADS * WIDTH)
+    assert distance(out, exact_attention(*operands)) < 0.003
+
+
+def test_importing_the_registry_loads_no_pallas():
+    """The image cells import models.registry too: the kernel's toolkit is
+    imported where the kernel is built, not with the module."""
+    code = ("import sys, sparkdl_tpu.models.registry; "
+            "sys.exit('jax.experimental.pallas' in sys.modules)")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    assert subprocess.run([sys.executable, "-c", code], env=env,
+                          cwd=os.path.dirname(BENCH)).returncode == 0
 
 
 @pytest.mark.parametrize("window", [16, 32])
