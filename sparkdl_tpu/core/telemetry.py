@@ -248,7 +248,7 @@ M_CLUSTER_REDISPATCH = "sparkdl.cluster.redispatch"    # counter
 M_CLUSTER_WORKERS = "sparkdl.cluster.workers"          # gauge (live,
                                                        # non-draining)
 M_CLUSTER_DRAIN_S = "sparkdl.cluster.drain_s"          # histogram
-# Counts that come OUT of a compiled program (models/latent_moe.py): the
+# Counts that come OUT of a compiled program (the sequence models): the
 # program returns them as small row-aligned outputs under the reserved
 # output name PROGRAM_COUNTS = {metric name: array, dim 0 = rows}, and the
 # executor's choke point records them once the outputs are on the host
@@ -260,6 +260,8 @@ M_SEQUENCE_TOKENS = "sparkdl.sequence.tokens"          # counter (tokens of
                                                        # the rows scored)
 # counter (per row: the layers whose attention was lowered to the fused kernel)
 M_SEQUENCE_FUSED_ATTENTION_LAYERS = "sparkdl.sequence.fused_attention_layers"
+# counter (per row: the layers whose mixer was the gated short convolution)
+M_SEQUENCE_CONV_LAYERS = "sparkdl.sequence.conv_layers"
 M_MOE_ROUTED_TOKENS = "sparkdl.moe.routed_tokens"      # counter (tokens
                                                        # routed, once for each
                                                        # expert layer)
@@ -270,6 +272,11 @@ M_MOE_OVERFLOW_PAIRS = "sparkdl.moe.overflow_pairs"    # counter (pairs beyond
                                                        # the grouped products'
                                                        # buffer: computed in a
                                                        # further round)
+M_MOE_BUFFER_ROWS = "sparkdl.moe.buffer_rows"          # counter (per row its
+                                                       # share of the rows the
+                                                       # grouped products ran:
+                                                       # rounds × the buffer,
+                                                       # per expert layer)
 M_MOE_LOAD_MAX_OVER_MEAN = "sparkdl.moe.load_max_over_mean"  # histogram (per
                                                        # row and expert layer:
                                                        # its launch's fullest
@@ -326,9 +333,11 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_CLUSTER_DRAIN_S: "histogram",
     M_SEQUENCE_TOKENS: "counter",
     M_SEQUENCE_FUSED_ATTENTION_LAYERS: "counter",
+    M_SEQUENCE_CONV_LAYERS: "counter",
     M_MOE_ROUTED_TOKENS: "counter",
     M_MOE_LOCAL_PAIRS: "counter",
     M_MOE_OVERFLOW_PAIRS: "counter",
+    M_MOE_BUFFER_ROWS: "counter",
     M_MOE_LOAD_MAX_OVER_MEAN: "histogram",
 }
 

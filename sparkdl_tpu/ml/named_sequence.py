@@ -12,8 +12,8 @@ set, adds the tokens routed to each expert per expert layer, flattened.
 
 The weights are a variables dict, taken as given: for a model of this size
 they are made or loaded in bfloat16 on the device, and what a chip holds of
-the published model — layers, experts (``expertsHeld``), vocabulary slice —
-is read off them (``registry.build_sequence_scorer``).
+the published model — layers and their kinds, experts (``expertsHeld``),
+vocabulary slice — is read off them (``registry.build_sequence_scorer``).
 """
 
 from __future__ import annotations
@@ -33,7 +33,9 @@ class DeepSequenceScorer(Transformer, HasInputCol, HasBatchSize, HasMesh):
 
     modelName = Param(
         "DeepSequenceScorer", "modelName",
-        f"one of {sorted(registry.SEQUENCE_MODELS)}, or a LatentMoEConfig",
+        f"one of {sorted(registry.SEQUENCE_MODELS)}, or a config of one's own "
+        "of either sequence model's type (LatentMoEConfig, "
+        "ShortConvMoEConfig)",
         typeConverter=TypeConverters.identity)
     weights = Param(
         "DeepSequenceScorer", "weights",

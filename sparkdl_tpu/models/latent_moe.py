@@ -1,6 +1,11 @@
 """Latent-attention sparse-expert decoder as a prefill-only window scorer —
 the sequence models' counterpart of the image zoo (``DeepSequenceScorer``,
-``registry.SEQUENCE_MODELS``).
+``registry.SEQUENCE_MODELS``) — and the pieces the second sequence model
+(``models/shortconv_moe.py``) imports from here: ``rms_norm``, the
+mixed-precision product ``_dot``, ``rotary``, the blocked causal soft-max
+(``_blocked_attention``, which reads grouped keys), ``gated_mlp``, the expert
+layer (``route``, ``buffer_capacity``, ``routed_experts``, ``expert_stats``)
+and the scorer's head and counts (``score_head``, ``expert_outputs``).
 
 One block is: multi-head latent attention (queries and keys/values through
 low-rank latents, a rotary part shared by all heads of the key), sandwich
@@ -12,10 +17,11 @@ top-k renormalised and scaled, one shared expert beside the routed ones.
 chip's share under expert parallelism): it routes over all experts, computes
 its own experts' part for the (token, expert) pairs routed to them and leaves
 out what absent experts would add. On one chip there is no exchange; nothing
-here stands in for absent chips. Pairs are grouped per held expert into a
-buffer of ``capacity`` rows each, and **no pair is dropped**: pairs beyond an
-expert's buffer are computed in further rounds of the same grouped product,
-and counted.
+here stands in for absent chips. Pairs are sorted by held expert into one
+buffer — ``capacity_factor`` × the even share, never more rows than there are
+pairs — and **no pair is dropped**: pairs beyond the buffer are computed in
+further rounds of the same grouped products, and counted; a layer held whole
+runs one round of exactly its pairs.
 
 Precision follows the weights: matrix products run in the weights' dtype
 (bfloat16 as the executor ships them) with float32 accumulation; the router,
@@ -76,6 +82,7 @@ class LatentMoEConfig:
     dense_layers: int
     scaling: float = 1.0
     norm_topk: bool = True
+    topk_eps: float = 0.0     # added to the chosen scores' sum before dividing
     eps: float = 1e-5
     theta: float = 10000.0
     # rows of the grouped products' buffer, as a multiple of the pairs that
@@ -115,25 +122,30 @@ def rotary(x, theta, heads=1):
 
 
 def _blocked_attention(q, k, v, block):
-    """q, k (H, T, d), v (H, T, dv) → (H, T, dv); q carries the scores' scale.
+    """q (H, T, d), k (G, T, d), v (G, T, dv) → (H, T, dv); q carries the
+    scores' scale. Query head a reads key head a // (H / G): with G < H the
+    keys are grouped, and a group's H / G query heads meet its one key head
+    in one product — keys and values are never copied to the query heads.
     Blocked over queries, each block against its causal prefix of keys only:
     the scores of one block are the largest temporary (H · block · T float32),
     not H · T²."""
     H, T, d = q.shape
+    G = k.shape[0]
+    q = q.reshape(G, H // G, T, d)
     block = min(block, T)
     out = []
     for lo in range(0, T, block):
         hi = min(lo + block, T)
-        scores = jnp.einsum("hqd,hkd->hqk", q[:, lo:hi], k[:, :hi],
+        scores = jnp.einsum("grqd,gkd->grqk", q[:, :, lo:hi], k[:, :hi],
                             preferred_element_type=jnp.float32)
         mask = jnp.arange(hi)[None, :] <= jnp.arange(lo, hi)[:, None]
         scores = jnp.where(mask, scores, -jnp.inf)
         weights = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
         total = jnp.sum(weights, -1, keepdims=True)
-        part = jnp.einsum("hqk,hkd->hqd", weights.astype(v.dtype), v[:, :hi],
-                          preferred_element_type=jnp.float32)
+        part = jnp.einsum("grqk,gkd->grqd", weights.astype(v.dtype),
+                          v[:, :hi], preferred_element_type=jnp.float32)
         out.append((part / total).astype(v.dtype))
-    return jnp.concatenate(out, 1)
+    return jnp.concatenate(out, 2).reshape(H, T, -1)
 
 
 # The fused kernel's tiles, chosen from chip runs at 128 heads × 4,096
@@ -308,34 +320,70 @@ def gated_mlp(p, x):
     return _dot(hidden.astype(act), p["down"])
 
 
-def route(router, x, c: LatentMoEConfig):
-    """Float32 at full precision: (chosen ids (N, k), weights (N, k))."""
+def route(p, x, c):
+    """Float32 at full precision: (chosen ids (N, k), weights (N, k)). Where
+    the layer holds a selection bias (``"expert_bias"``), the top-k is taken
+    of ``sigmoid + bias`` and the weights are the unbiased sigmoids of the
+    chosen."""
     scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                                    router.astype(jnp.float32),
+                                    p["router"].astype(jnp.float32),
                                     precision=lax.Precision.HIGHEST))
-    top, chosen = lax.top_k(scores, c.top_k)
+    if "expert_bias" in p:
+        _, chosen = lax.top_k(scores + p["expert_bias"].astype(jnp.float32),
+                              c.top_k)
+        top = jnp.take_along_axis(scores, chosen, -1)
+    else:
+        top, chosen = lax.top_k(scores, c.top_k)
     if c.norm_topk:
-        top = top / jnp.sum(top, -1, keepdims=True)
+        top = top / (jnp.sum(top, -1, keepdims=True) + c.topk_eps)
     return chosen, top * c.scaling
 
 
-def routed_experts(p, x, c: LatentMoEConfig):
-    """The held experts' part of the layer for tokens x (N, hidden) float32.
+# float32 rows the combine's gathers may hold at once (see routed_experts)
+COMBINE_BYTES = 128 * 1024 * 1024
+
+
+def buffer_capacity(tokens, c):
+    """Rows of the grouped products' buffer for a launch of ``tokens``:
+    ``capacity_factor`` × the pairs that meet a held expert when routing is
+    even, and never more than the pairs there are — a layer held whole runs
+    one round of exactly its pairs."""
+    even = c.capacity_factor * tokens * c.top_k * len(c.experts_held) \
+        / c.experts
+    return min(max(8, -(-int(even) // 8) * 8), tokens * c.top_k)
+
+
+def routed_experts(p, x, c):
+    """The held experts' part of the layer for tokens x (N, hidden) float32;
+    ``c`` either sequence model's configuration.
 
     The (token, expert) pairs that meet a held expert are sorted by expert
-    into one flat buffer of ``capacity`` rows; a round gathers the buffer's
-    tokens, runs the three grouped products (``lax.ragged_dot``, group sizes
-    = each expert's pairs in the buffer) and adds every pair's weighted
-    result to its token by a one-hot product on the MXU (a row scatter-add
-    of the same rows takes twice as long on the chip: PERF.md §6). Pairs
-    beyond the buffer take further rounds: none is dropped.
+    into one flat buffer of :func:`buffer_capacity` rows; a round gathers the
+    buffer's tokens and runs the three grouped products (``lax.ragged_dot``,
+    group sizes = each expert's pairs in the buffer). Pairs beyond the buffer
+    take further rounds: none is dropped; a layer held whole has a row for
+    every pair and runs one round without the loop.
+
+    **The combine is linear in the pairs.** The sort is a permutation, so its
+    inverse says where each token's ``top_k`` results lie in the buffer: a
+    round gathers them, a tile of tokens at a time and one gather for each of
+    the ``top_k``, weighs each by its router weight — nought where the expert
+    is absent or the pair lies in another round — and adds them, in float32.
+    On the chip (PERF.md §6, PR 37; the layer alone, 16,384 tokens) the
+    combine takes 2.3 ms where a layer of 32 experts is held whole (65,536
+    pairs of width 2,048; the one-hot product tokens × buffer on the MXU,
+    which this replaced, 24–26 ms, whole or in token tiles) and 10.4 ms where
+    16 of 256 are held (131,072 pairs of width 7,680, most of them absent;
+    the one-hot product 23.0 ms, a row scatter-add 47 ms). The tiles bound
+    what is alive at once, and are no slower: untiled the same gathers took
+    3.0 and 18.5 ms and held 3.5 GiB more in the second case.
 
     Returns ``(y (N, hidden) float32, chosen (N, k), counts (held,) pairs per
     held expert, overflow (N·k,) bool per pair: computed beyond the first
     round)``."""
     N = x.shape[0]
     held = len(c.experts_held)
-    chosen, weights = route(p["router"], x, c)
+    chosen, weights = route(p, x, c)
     slot_of = jnp.full((c.experts,), held, jnp.int32).at[
         jnp.asarray(c.experts_held, jnp.int32)].set(
             jnp.arange(held, dtype=jnp.int32))
@@ -346,20 +394,26 @@ def routed_experts(p, x, c: LatentMoEConfig):
     ends = jnp.cumsum(counts)                      # expert by expert
     starts = ends - counts
     local = ends[-1]
-    capacity = max(8, -(-int(c.capacity_factor * N * c.top_k * held
-                             / c.experts) // 8) * 8)
-    flat_weights = weights.reshape(-1)
+    capacity = buffer_capacity(N, c)
+    # where each pair lies in the sorted order, and its weight if held
+    place = jnp.zeros((pairs,), jnp.int32).at[order].set(
+        jnp.arange(pairs, dtype=jnp.int32))
+    held_weights = jnp.where(place < local, weights.reshape(-1), 0.0)
     experts = p["experts"]
     act = experts["down"].dtype
     xa = x.astype(act)
+    # the gathers of a tile of tokens are alive side by side: in tiles, so
+    # that they stay under COMBINE_BYTES (whole, a launch of 16,384 tokens of
+    # width 7,680 with 8 pairs each held 3.5 GiB of them)
+    tiles = 1
+    while (pairs * x.shape[1] * 4 > COMBINE_BYTES * tiles
+           and N % (2 * tiles) == 0):
+        tiles *= 2
 
     def one_round(r, y):
         lo = r * capacity
-        position = lo + jnp.arange(capacity)
-        valid = position < local
-        pair = order[jnp.clip(position, 0, pairs - 1)]
-        token = pair // c.top_k
-        rows = xa[token]                                   # (capacity, hidden)
+        pair = order[jnp.clip(lo + jnp.arange(capacity), 0, pairs - 1)]
+        rows = xa[pair // c.top_k]                         # (capacity, hidden)
         sizes = (jnp.clip(ends - lo, 0, capacity)
                  - jnp.clip(starts - lo, 0, capacity)).astype(jnp.int32)
         hidden = jax.nn.silu(lax.ragged_dot(
@@ -368,17 +422,50 @@ def routed_experts(p, x, c: LatentMoEConfig):
             rows, experts["up"], sizes, preferred_element_type=jnp.float32)
         out = lax.ragged_dot(hidden.astype(act), experts["down"], sizes,
                              preferred_element_type=jnp.float32)
-        weighted = jnp.where(valid[:, None],
-                             out * flat_weights[pair][:, None], 0.0)
-        to_token = (jnp.arange(N)[:, None] == token[None, :]) & valid[None, :]
-        return y + jnp.dot(to_token.astype(act), weighted.astype(act),
-                           preferred_element_type=jnp.float32)
+        at = (place - lo).reshape(tiles, -1, c.top_k)
+        mine = jnp.where((at >= 0) & (at < capacity),
+                         held_weights.reshape(at.shape), 0.0)
+        at = jnp.clip(at, 0, capacity - 1)
 
-    rounds = -(-local // capacity)          # 1 unless the held experts are full
-    y = lax.fori_loop(0, rounds, one_round, jnp.zeros(x.shape, jnp.float32))
+        def add_tile(i, y):
+            first = i * (N // tiles)
+            tile = lax.dynamic_slice_in_dim(y, first, N // tiles) + sum(
+                out[at[i, :, k]] * mine[i, :, k, None]
+                for k in range(c.top_k))
+            return lax.dynamic_update_slice_in_dim(y, tile, first, 0)
+
+        return lax.fori_loop(0, tiles, add_tile, y)
+
+    y = jnp.zeros(x.shape, jnp.float32)
+    if capacity == pairs:
+        y = one_round(0, y)
+    else:       # rounds: 1 unless the held experts are full
+        y = lax.fori_loop(0, -(-local // capacity), one_round, y)
     overflow = jnp.zeros((pairs,), bool).at[order].set(
         (slots[order] < held) & (jnp.arange(pairs) >= capacity))
     return y, chosen, counts, overflow
+
+
+def expert_stats(chosen, counts, overflow, rows, c):
+    """What an expert layer reports of a launch of ``rows`` windows, from
+    :func:`routed_experts`' returns: per row the tokens per published expert,
+    the pairs that met a held expert, those computed beyond the first round,
+    and its share of the rows the grouped products ran (rounds × the buffer);
+    of the launch, the fullest held expert's pairs over the mean."""
+    held = jnp.asarray(c.experts_held, jnp.int32)
+    per_row = jnp.sum(chosen.reshape(rows, -1, 1) == jnp.arange(c.experts), 1)
+    capacity = buffer_capacity(chosen.shape[0], c)
+    ran = (-(-jnp.sum(counts) // capacity) * capacity).astype(jnp.int32)
+    return {
+        "expert_counts": per_row.astype(jnp.int32),              # (B, experts)
+        "local_pairs": jnp.sum(per_row[:, held], -1).astype(jnp.int32),
+        "overflow_pairs": jnp.sum(overflow.reshape(rows, -1), -1
+                                  ).astype(jnp.int32),
+        # a whole number a row, and the rows' sum is the launch's
+        "buffer_rows": ran // rows + (jnp.arange(rows) < ran % rows),
+        "load_max_over_mean": jnp.max(counts) / jnp.maximum(
+            jnp.mean(counts.astype(jnp.float32)), 1.0),
+    }
 
 
 def block(layer, h, c: LatentMoEConfig):
@@ -386,9 +473,11 @@ def block(layer, h, c: LatentMoEConfig):
     ``(h, fused, stats)``: ``fused`` (B,) as :func:`causal_attention` returns
     it for each window; ``stats`` is None for a dense layer."""
     B, T, _ = h.shape
-    attended, fused = lax.map(
-        lambda row: latent_attention(
-            layer["attn"], rms_norm(row, layer["input_norm"], c.eps), c), h)
+    with jax.named_scope("latent_attention"):
+        attended, fused = lax.map(
+            lambda row: latent_attention(
+                layer["attn"], rms_norm(row, layer["input_norm"], c.eps), c),
+            h)
     h = h + rms_norm(attended, layer["post_attn_norm"], c.eps)
     x = rms_norm(h, layer["pre_mlp_norm"], c.eps)
     if "moe" not in layer:
@@ -396,65 +485,79 @@ def block(layer, h, c: LatentMoEConfig):
         return h + rms_norm(m, layer["post_mlp_norm"], c.eps), fused, None
     moe = layer["moe"]
     flat = x.reshape(B * T, -1)
-    routed, chosen, counts, overflow = routed_experts(moe, flat, c)
+    with jax.named_scope("routed_experts"):
+        routed, chosen, counts, overflow = routed_experts(moe, flat, c)
     m = (gated_mlp(moe["shared"], flat) + routed).reshape(B, T, -1)
-    held = jnp.asarray(c.experts_held, jnp.int32)
-    per_row = jnp.sum(chosen.reshape(B, -1, 1) == jnp.arange(c.experts), 1)
-    stats = {
-        "expert_counts": per_row.astype(jnp.int32),              # (B, experts)
-        "local_pairs": jnp.sum(per_row[:, held], -1).astype(jnp.int32),
-        "overflow_pairs": jnp.sum(overflow.reshape(B, -1), -1
-                                  ).astype(jnp.int32),
-        # of the launch: the fullest held expert's pairs over the mean
-        "load_max_over_mean": jnp.max(counts) / jnp.maximum(
-            jnp.mean(counts.astype(jnp.float32)), 1.0),
-    }
-    return h + rms_norm(m, layer["post_mlp_norm"], c.eps), fused, stats
+    return (h + rms_norm(m, layer["post_mlp_norm"], c.eps), fused,
+            expert_stats(chosen, counts, overflow, B, c))
+
+
+def score_head(params, h, tokens, eps):
+    """The scorer's head over windows h (B, T, hidden) float32 after the last
+    block: ``pooled``, the mean over positions of the final-norm state, and
+    ``logprobs``, ``log p(x[t+1] | x[≤t])`` under the soft-max over the rows
+    of ``head`` (the last 0), a window at a time. An id outside the rows held
+    would be clamped by the lookups: its window's outputs are not a number
+    instead."""
+    with jax.named_scope("head"):
+        x = rms_norm(h, params["final_norm"], eps)
+
+        def row_logprobs(args):
+            row, ids = args
+            logits = jnp.dot(row.astype(params["head"].dtype),
+                             params["head"].T,
+                             preferred_element_type=jnp.float32)
+            logp = jax.nn.log_softmax(logits, -1)
+            nxt = jnp.take_along_axis(logp[:-1], ids[1:, None], -1)[:, 0]
+            return jnp.pad(nxt, (0, 1))
+
+        known = jnp.all((tokens >= 0) & (tokens < params["embed"].shape[0]),
+                        1)
+        return {"pooled": jnp.where(known[:, None], jnp.mean(x, 1), jnp.nan),
+                "logprobs": jnp.where(
+                    known[:, None], lax.map(row_logprobs, (x, tokens)),
+                    jnp.nan)}
+
+
+def expert_outputs(stats, tokens, counts):
+    """The outputs every expert layer's :func:`expert_stats` adds to a
+    scorer's: ``expert_counts`` and, under ``telemetry.PROGRAM_COUNTS``, the
+    model's own ``counts`` (name → (B,) int32) with the expert layers'."""
+    B, T = tokens.shape
+
+    def stacked(name):
+        return jnp.stack([s[name] for s in stats], 1)
+
+    return {"expert_counts": stacked("expert_counts"),
+            telemetry.PROGRAM_COUNTS: {
+                telemetry.M_SEQUENCE_TOKENS: jnp.full((B,), T, jnp.int32),
+                **counts,
+                telemetry.M_MOE_ROUTED_TOKENS: jnp.full(
+                    (B,), T * len(stats), jnp.int32),
+                telemetry.M_MOE_LOCAL_PAIRS: jnp.sum(
+                    stacked("local_pairs"), 1),
+                telemetry.M_MOE_OVERFLOW_PAIRS: jnp.sum(
+                    stacked("overflow_pairs"), 1),
+                telemetry.M_MOE_BUFFER_ROWS: jnp.sum(
+                    stacked("buffer_rows"), 1),
+                telemetry.M_MOE_LOAD_MAX_OVER_MEAN: jnp.broadcast_to(
+                    jnp.stack([s["load_max_over_mean"] for s in stats]),
+                    (B, len(stats)))}}
 
 
 def forward(params, tokens, c: LatentMoEConfig) -> Dict[str, Any]:
     """tokens (B, T) int32 ids of the slice → the outputs of the module's
     docstring."""
-    B, T = tokens.shape
     h = params["embed"][tokens].astype(jnp.float32)
     stats = []
-    fused_layers = jnp.zeros((B,), jnp.int32)
+    fused_layers = jnp.zeros((tokens.shape[0],), jnp.int32)
     for layer in params["layers"]:
         h, fused, layer_stats = block(layer, h, c)
         fused_layers = fused_layers + fused
         if layer_stats is not None:
             stats.append(layer_stats)
-    x = rms_norm(h, params["final_norm"], c.eps)
-
-    def row_logprobs(args):
-        row, ids = args
-        logits = jnp.dot(row.astype(params["head"].dtype), params["head"].T,
-                         preferred_element_type=jnp.float32)
-        logp = jax.nn.log_softmax(logits, -1)
-        nxt = jnp.take_along_axis(logp[:-1], ids[1:, None], -1)[:, 0]
-        return jnp.pad(nxt, (0, 1))
-
-    # an id outside the slice held would be clamped by the lookups: its
-    # window's outputs are not a number instead
-    known = jnp.all((tokens >= 0) & (tokens < params["embed"].shape[0]), 1)
-    out = {"pooled": jnp.where(known[:, None], jnp.mean(x, 1), jnp.nan),
-           "logprobs": jnp.where(known[:, None],
-                                 lax.map(row_logprobs, (x, tokens)), jnp.nan)}
+    out = score_head(params, h, tokens, c.eps)
     if stats:
-        def stacked(name):
-            return jnp.stack([s[name] for s in stats], 1)
-
-        out["expert_counts"] = stacked("expert_counts")
-        out[telemetry.PROGRAM_COUNTS] = {
-            telemetry.M_SEQUENCE_TOKENS: jnp.full((B,), T, jnp.int32),
-            telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS: fused_layers,
-            telemetry.M_MOE_ROUTED_TOKENS: jnp.full((B,), T * len(stats),
-                                                    jnp.int32),
-            telemetry.M_MOE_LOCAL_PAIRS: jnp.sum(stacked("local_pairs"), 1),
-            telemetry.M_MOE_OVERFLOW_PAIRS: jnp.sum(
-                stacked("overflow_pairs"), 1),
-            telemetry.M_MOE_LOAD_MAX_OVER_MEAN: jnp.broadcast_to(
-                jnp.stack([s["load_max_over_mean"] for s in stats]),
-                (B, len(stats))),
-        }
+        out.update(expert_outputs(stats, tokens, {
+            telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS: fused_layers}))
     return out

@@ -17,18 +17,19 @@ from __future__ import annotations
 import dataclasses
 import os
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from sparkdl_tpu.core.model_function import ModelFunction, TensorSpec
-from sparkdl_tpu.models import latent_moe
+from sparkdl_tpu.models import latent_moe, shortconv_moe
 from sparkdl_tpu.models.inception import InceptionV3
 from sparkdl_tpu.models.latent_moe import LatentMoEConfig
 from sparkdl_tpu.models.mobilenet import MobileNetV2
 from sparkdl_tpu.models.resnet import ResNet50, ResNet101, ResNet152
+from sparkdl_tpu.models.shortconv_moe import ShortConvMoEConfig
 from sparkdl_tpu.models.testnet import TestNet
 from sparkdl_tpu.models.vgg import VGG16, VGG19
 from sparkdl_tpu.models.xception import Xception
@@ -217,11 +218,15 @@ _KERAS_BUILDERS = {
 
 
 # Sequence models (token-id windows in, a pooled state and per-token
-# log-probabilities out; models/latent_moe.py): the published sizes, with
-# every expert and the whole vocabulary. What a chip holds of them —
-# how many layers, which experts, which slice of the vocabulary — comes
-# with the weights (build_sequence_scorer).
-SEQUENCE_MODELS: Dict[str, LatentMoEConfig] = {
+# log-probabilities out): the published sizes, with every expert and the
+# whole vocabulary. What a chip holds of them — how many layers and of which
+# kind, which experts, which slice of the vocabulary — comes with the weights
+# (build_sequence_scorer). The config's type names the module that runs it.
+SequenceConfig = Union[LatentMoEConfig, ShortConvMoEConfig]
+_SEQUENCE_FORWARD = {LatentMoEConfig: latent_moe.forward,
+                     ShortConvMoEConfig: shortconv_moe.forward}
+
+SEQUENCE_MODELS: Dict[str, SequenceConfig] = {
     # huggingface.co/FreedomIntelligence/openPangu-Ultra-MoE-718B config.json
     "openPangu-Ultra-MoE-718B": LatentMoEConfig(
         hidden=7680, heads=128, q_rank=1536, kv_rank=512, nope=128, rope=64,
@@ -236,6 +241,18 @@ SEQUENCE_MODELS: Dict[str, LatentMoEConfig] = {
         experts_held=tuple(range(16)), top_k=4, vocab=64, layers=3,
         dense_layers=2, scaling=2.5, norm_topk=True, eps=1e-5,
         theta=25600000.0, query_block=8),
+    # huggingface.co/LiquidAI/LFM2-8B-A1B config.json (model_type lfm2_moe)
+    "LFM2-8B-A1B": ShortConvMoEConfig(
+        hidden=2048, heads=32, kv_heads=8, head_dim=64, dense_width=7168,
+        expert_width=1792, experts=32, experts_held=tuple(range(32)),
+        top_k=4, vocab=65536, scaling=1.0, norm_topk=True, eps=1e-5,
+        theta=1000000.0),
+    # ... and its block at sizes a CPU test runs
+    "TestShortConvMoE": ShortConvMoEConfig(
+        hidden=64, heads=8, kv_heads=2, head_dim=8, dense_width=128,
+        expert_width=32, experts=16, experts_held=tuple(range(16)), top_k=4,
+        vocab=64, scaling=1.0, norm_topk=True, eps=1e-5, theta=1000000.0,
+        query_block=8),
 }
 
 
@@ -244,17 +261,19 @@ def build_sequence_scorer(name, weights: Dict[str, Any], window: int,
     """Named sequence model as a ModelFunction over ``(rows, window)`` int32
     token ids, emitting ``pooled``, ``logprobs`` and ``expert_counts``.
 
-    ``name``: a key of :data:`SEQUENCE_MODELS`, or a ``LatentMoEConfig`` of
-    one's own. ``weights``: the variables dict the model is run with —
-    ``{"embed", "layers": [...], "final_norm", "head"}``, taken as given
-    (bfloat16 on the device for a model of this size; there is no
+    ``name``: a key of :data:`SEQUENCE_MODELS`, or a config of one's own of
+    either of its types. ``weights``: the variables dict the model is run
+    with — ``{"embed", "layers": [...], "final_norm", "head"}``, taken as
+    given (bfloat16 on the device for a model of this size; there is no
     ``"random"``). The chip's share is read off them: as many layers as
-    the list has, dense where a layer has ``"mlp"``, the vocabulary slice of
-    ``embed``'s rows; ``experts_held`` names the expert ids the expert layers'
-    stacked weights stand for (default: all of them, where all are there).
+    the list has, dense where a layer has ``"mlp"``, for a short-convolution
+    model the mixer by whether a layer has ``"conv"`` or ``"attn"``, the
+    vocabulary slice of ``embed``'s rows; ``experts_held`` names the expert
+    ids the expert layers' stacked weights stand for (default: all of them,
+    where all are there).
     """
     config = SEQUENCE_MODELS.get(name) if isinstance(name, str) else name
-    if config is None:
+    if type(config) not in _SEQUENCE_FORWARD:
         raise ValueError(f"Unsupported sequence model {name!r}; supported: "
                          f"{sorted(SEQUENCE_MODELS)}")
     layers = weights["layers"]
@@ -270,12 +289,17 @@ def build_sequence_scorer(name, weights: Dict[str, Any], window: int,
         raise ValueError(
             f"the expert layers hold {sorted(stacked)} experts' weights, "
             f"experts_held names {len(experts_held)}")
-    config = dataclasses.replace(
-        config, layers=len(layers), dense_layers=dense,
-        experts_held=experts_held, vocab=int(weights["embed"].shape[0]))
-    label = name if isinstance(name, str) else "latent_moe"
+    share = {"experts_held": experts_held,
+             "vocab": int(weights["embed"].shape[0])}
+    if isinstance(config, LatentMoEConfig):
+        share.update(layers=len(layers), dense_layers=dense)
+    elif not all(("conv" in layer) != ("attn" in layer) for layer in layers):
+        raise ValueError('each layer holds its mixer, "conv" or "attn"')
+    config = dataclasses.replace(config, **share)
+    forward = _SEQUENCE_FORWARD[type(config)]
+    label = name if isinstance(name, str) else type(config).__name__
     return ModelFunction.fromFunction(
-        lambda vs, tokens: latent_moe.forward(vs, tokens, config), weights,
+        lambda vs, tokens: forward(vs, tokens, config), weights,
         TensorSpec((None, int(window)), "int32"), name=f"{label}_score")
 
 

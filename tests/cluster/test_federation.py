@@ -310,7 +310,10 @@ def test_aggregate_breach_fires_live_and_dumps_a_postmortem(
         try:
             got = _collect(_aggregate_breach_op)
             assert _wait_for(mon, health.SLO_BREACH, 30.0) == 1
-            # the bundle is on disk MID-RUN, before any shutdown path
+            # the bundle is on disk MID-RUN, before any shutdown path (the
+            # recorder writes it after the breach is recorded: wait for its
+            # own event, which follows the rename, not for the breach's)
+            assert _wait_for(mon, health.POSTMORTEM_DUMPED, 30.0) == 1
             mid_run = glob.glob(os.path.join(out, "postmortem_*"))
             assert len(mid_run) == 1
             assert not mid_run[0].endswith(".tmp")  # the atomic rename

@@ -1,7 +1,8 @@
 """The product's own programs compile for the TPU v5e at the main path's
 real shapes: the model zoo's convolution units (``models/layers.py``),
-``ModelFunction.resized()``'s cast-and-resize prologue, and the sequence
-scorer's latent attention (``models/latent_moe.py``).
+``ModelFunction.resized()``'s cast-and-resize prologue, the sequence
+scorer's latent attention (``models/latent_moe.py``), and the second sequence
+model's mixers and the expert layer held whole (``models/shortconv_moe.py``).
 
 These are the only tier-1 tests that hand the product's code to the TPU's
 compiler: each program is compiled ahead of time for a DESCRIBED
@@ -10,8 +11,11 @@ programs are XLA's own and which hold a hand-written kernel
 (``tpu_custom_call``): the image programs hold none — every Pallas
 candidate for them lost to XLA's twin on the chip (PERF.md §6, PR 21) — and
 latent attention holds exactly the fused causal-attention kernel, which keeps
-a window's float32 scores out of HBM (PERF.md §6, PR 36). A PR that ships or
-drops a kernel changes the assertion where it belongs.
+a window's float32 scores out of HBM (PERF.md §6, PR 36); the
+short-convolution model holds none of ours (its head width of 64 is half a
+lane group: the blocked path), only the grouped products' own (XLA lowers
+``lax.ragged_dot`` to kernels of its own). A PR that ships or drops a kernel
+changes the assertion where it belongs.
 
 The topology is described inside a fixture of this file and nowhere else:
 only one process at a time may load the TPU's library, and under
@@ -30,7 +34,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from sparkdl_tpu.core import ModelFunction, TensorSpec
-from sparkdl_tpu.models import latent_moe, registry
+from sparkdl_tpu.models import latent_moe, registry, shortconv_moe
 from sparkdl_tpu.models.layers import ConvBN, SeparableConvBN
 
 
@@ -155,3 +159,64 @@ def test_latent_attention_compiles_to_the_fused_kernel_for_v5e(
     assert "fused_causal_attention" in text
     # the blocked path's scores, 128 heads × a block of 512 queries × keys
     assert "f32[128,512," not in text
+
+
+@pytest.mark.parametrize("part", ["short_conv", "grouped_attention",
+                                  "routed_experts"])
+def test_short_convolution_model_compiles_for_v5e(part, one_chip,
+                                                  no_persistent_cache):
+    """LFM2-8B-A1B's parts at the published widths and the cell's launch (4
+    windows of 4,096), bfloat16 weights, shapes only: the gated short
+    convolution; one window's grouped-query attention, which takes the
+    blocked path with the keys grouped, not repeated; the expert layer held
+    whole, whose buffer is the 65,536 pairs and whose combine builds no
+    tokens × buffer operand."""
+    c = registry.SEQUENCE_MODELS["LFM2-8B-A1B"]
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if part == "short_conv":
+        p = {"in": on_chip((c.hidden, 3 * c.hidden)),
+             "taps": on_chip((c.hidden, 3)),
+             "out": on_chip((c.hidden, c.hidden))}
+        fn = shortconv_moe.short_conv
+        x = on_chip((4, 4096, c.hidden), jnp.float32)
+    elif part == "grouped_attention":
+        narrow = c.kv_heads * c.head_dim
+        p = {"q": on_chip((c.hidden, c.hidden)),
+             "k": on_chip((c.hidden, narrow)),
+             "v": on_chip((c.hidden, narrow)),
+             "q_norm": on_chip((c.head_dim,)),
+             "k_norm": on_chip((c.head_dim,)),
+             "out": on_chip((c.hidden, c.hidden))}
+
+        def fn(p, x):
+            return shortconv_moe.grouped_attention(p, x, c)
+
+        x = on_chip((4096, c.hidden), jnp.float32)
+    else:
+        p = {"router": on_chip((c.hidden, c.experts)),
+             "expert_bias": on_chip((c.experts,)),
+             "experts": {
+                 "gate": on_chip((c.experts, c.hidden, c.expert_width)),
+                 "up": on_chip((c.experts, c.hidden, c.expert_width)),
+                 "down": on_chip((c.experts, c.expert_width, c.hidden))}}
+
+        def fn(p, x):
+            return latent_moe.routed_experts(p, x, c)
+
+        x = on_chip((4 * 4096, c.hidden), jnp.float32)
+    text = jax.jit(fn).lower(p, x).compile().as_text()
+    assert ":T(" in text  # tiled layouts: the TPU's compiler made this
+    assert "fused_causal_attention" not in text
+    if part == "routed_experts":
+        assert latent_moe.buffer_capacity(4 * 4096, c) == 65536
+        assert "ragged-dot" in text
+        assert "[16384,65536]" not in text and "[65536,16384]" not in text
+    else:
+        assert "tpu_custom_call" not in text
+    if part == "grouped_attention":
+        # scores of 8 key heads × their 4 query heads × a block of queries;
+        # no copy of the keys to 32 heads
+        assert "f32[8,4,512," in text
