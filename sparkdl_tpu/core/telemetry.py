@@ -277,6 +277,9 @@ M_MOE_BUFFER_ROWS = "sparkdl.moe.buffer_rows"          # counter (per row its
                                                        # grouped products ran:
                                                        # rounds × the buffer,
                                                        # per expert layer)
+# counter (per row: the expert layers whose grouped products were lowered to
+# the kernel, models/latent_moe.py grouped_product)
+M_MOE_FUSED_PRODUCT_LAYERS = "sparkdl.moe.fused_product_layers"
 M_MOE_LOAD_MAX_OVER_MEAN = "sparkdl.moe.load_max_over_mean"  # histogram (per
                                                        # row and expert layer:
                                                        # its launch's fullest
@@ -338,6 +341,7 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_MOE_LOCAL_PAIRS: "counter",
     M_MOE_OVERFLOW_PAIRS: "counter",
     M_MOE_BUFFER_ROWS: "counter",
+    M_MOE_FUSED_PRODUCT_LAYERS: "counter",
     M_MOE_LOAD_MAX_OVER_MEAN: "histogram",
 }
 

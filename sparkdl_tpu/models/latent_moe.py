@@ -4,8 +4,9 @@ the sequence models' counterpart of the image zoo (``DeepSequenceScorer``,
 (``models/shortconv_moe.py``) imports from here: ``rms_norm``, the
 mixed-precision product ``_dot``, ``rotary``, the blocked causal soft-max
 (``_blocked_attention``, which reads grouped keys), ``gated_mlp``, the expert
-layer (``route``, ``buffer_capacity``, ``routed_experts``, ``expert_stats``)
-and the scorer's head and counts (``score_head``, ``expert_outputs``).
+layer (``route``, ``buffer_capacity``, ``expert_products``,
+``routed_experts``, ``expert_stats``) and the scorer's head and counts
+(``score_head``, ``expert_outputs``).
 
 One block is: multi-head latent attention (queries and keys/values through
 low-rank latents, a rotary part shared by all heads of the key), sandwich
@@ -28,23 +29,30 @@ Precision follows the weights: matrix products run in the weights' dtype
 the norms' statistics, the soft-maxes, the residual stream and the
 log-probabilities are float32.
 
-**What runs where.** Everything is XLA's own but a window's causal attention,
-which has two paths under one contract (:func:`causal_attention`): a Pallas
-TPU kernel that keeps the scores on the chip (:func:`fused_causal_attention`),
-and the blocked soft-max in XLA, which is also the oracle the kernel is tested
-against. The kernel is taken where the program is lowered for a TPU — a chip,
-or an ahead-of-time compile for a described one — with bfloat16 operands, a
-window of whole tiles and head widths of whole lanes; a CPU run, float32
-weights, a short or ragged window or other widths lower the blocked path. No
-option chooses, and ``jax.experimental.pallas`` is imported where the kernel
-is built, not with this module.
+**What runs where.** Everything is XLA's own but two hand-written Pallas TPU
+kernels, each one path of two under one contract, the other being XLA's and
+the oracle the kernel is tested against. A window's causal attention
+(:func:`causal_attention`): :func:`fused_causal_attention` keeps the scores
+on the chip; the blocked soft-max is the other path. The experts' three
+grouped products (:func:`expert_products`): :func:`grouped_product`, twice —
+gate and up in one pass over the buffer's rows with ``silu · mul`` on the
+float32 accumulators, so neither float32 result reaches HBM, then down; three
+``lax.ragged_dot`` are the other path. A kernel is taken where the program is
+lowered for a TPU — a chip, or an ahead-of-time compile for a described one —
+with bfloat16 operands and shapes of whole tiles (attention: a window of
+whole query tiles and head widths of whole lanes; the products: a buffer of
+whole row tiles and widths of whole lanes); a CPU run, float32 weights or
+other shapes lower XLA's path. No option chooses, and
+``jax.experimental.pallas`` is imported where a kernel is built, not with this
+module.
 
 Outputs per window (row): ``pooled`` — the mean over positions of the
 final-norm hidden state; ``logprobs`` — ``log p(x[t+1] | x[≤t])`` under the
 soft-max over the vocabulary slice held (the last is 0); ``expert_counts`` —
 per expert layer and published expert, the tokens of the window routed to it;
 and, under ``telemetry.PROGRAM_COUNTS``, the counters the executor records
-(among them, per row, the layers whose attention was lowered to the kernel).
+(among them, per row, the layers whose attention and the expert layers whose
+grouped products were lowered to a kernel).
 """
 
 from __future__ import annotations
@@ -342,6 +350,196 @@ def route(p, x, c):
 # float32 rows the combine's gathers may hold at once (see routed_experts)
 COMBINE_BYTES = 128 * 1024 * 1024
 
+# The grouped products' tiles, chosen from chip runs at 65,536 rows in 32
+# groups, 2,048 × 1,792, and at 16,384 rows in 16 groups, 7,680 × 2,048
+# (PERF.md §6, PR 38). Rows a grid step, worked in sub-tiles of which a visit
+# skips those without a row of its group: every boundary between two groups
+# costs a second visit of its tile, and what that visit multiplies is what
+# counts — with whole tiles 1,024 / 512 / 256 rows took 12.1 / 9.4 / 8.7 ms
+# for the three products, in sub-tiles of 128 a tile of 512 takes 8.3. The
+# whole contraction in one step; the columns as wide as the blocks' budget of
+# on-chip memory allows (all 1,792 / 2,048 of LFM2's, 512 of openPangu's 2,048
+# and 2,560 of its 7,680: blocks under 48 MiB; at 72 MB the kernel ran twice
+# as slow, though v5e has 128 MiB).
+GROUPED_ROW_TILE = 512
+GROUPED_SUB_TILE = 128
+GROUPED_BLOCK_BYTES = 48 * 1024 * 1024
+GROUPED_VMEM_LIMIT = 100 * 1024 * 1024
+
+
+def _group_visits(sizes, rows, row_tile):
+    """The grouped kernel's grid over its rows: one visit for each (row tile,
+    group) that share a row, group by group, so a tile that straddles a
+    boundary is visited once for each group it touches, one after the other;
+    and one visit, by a group of no rows, for each whole tile past the last
+    group's end, which the kernel has to zero. ``sizes`` (groups,) int32 may
+    be traced; ``rows`` is a multiple of ``row_tile``. Returns ``(group_of,
+    tile_of (rows / row_tile + groups − 1,) per visit, offsets (groups + 2,)
+    rows before each group, visits ())``."""
+    groups = sizes.shape[0]
+    tiles = rows // row_tile
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    covered = -(-ends[-1] // row_tile)          # tiles that hold a group's row
+    first = jnp.append(starts // row_tile, covered)
+    count = jnp.append(
+        jnp.where(sizes > 0, (ends - 1) // row_tile - starts // row_tile + 1,
+                  0), tiles - covered)
+    bound = tiles + groups - 1
+    group_of = jnp.repeat(jnp.arange(groups + 1, dtype=jnp.int32), count,
+                          total_repeat_length=bound)
+    tile_of = first[group_of] + jnp.arange(bound, dtype=jnp.int32) - (
+        jnp.cumsum(count) - count)[group_of]
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends, ends[-1:]])
+    return group_of, jnp.clip(tile_of, 0, tiles - 1), offsets, jnp.sum(count)
+
+
+def _grouped_kernel(group_of, tile_of, offsets, rows_ref, *refs, row_tile,
+                    sub_tile):
+    """One visit of :func:`_group_visits`: the tile's rows times the group's
+    one weight block (``out = rows · w``) or two (``out = silu(rows · gate) ·
+    (rows · up)``, on the float32 accumulators), ``sub_tile`` rows at a time
+    in a loop — one product's code whatever the tile: only the sub-tiles that
+    hold a row of the group are multiplied, and written to the rows that are
+    the group's; the tile's other rows are left as the visit before wrote
+    them, or zeroed on the tile's first visit."""
+    from jax.experimental import pallas as pl
+    weights, out_ref = refs[:-1], refs[-1]
+    visit = pl.program_id(1)
+    group, tile = group_of[visit], tile_of[visit]
+    lo, hi = offsets[group], offsets[group + 1]
+    first = tile * row_tile
+    revisit = (visit > 0) & (tile_of[jnp.maximum(visit - 1, 0)] == tile)
+
+    def part(i, _):
+        at = pl.multiple_of(i * sub_tile, sub_tile)
+        rows = pl.ds(at, sub_tile)
+        shared = (lo < first + at + sub_tile) & (hi > first + at)
+
+        @pl.when(shared)
+        def _():
+            acc = [jnp.dot(rows_ref[rows, :], w[...],
+                           preferred_element_type=jnp.float32)
+                   for w in weights]
+            acc = jax.nn.silu(acc[0]) * acc[1] if len(acc) == 2 else acc[0]
+            row = first + at + lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+            before = jnp.where(
+                revisit, out_ref[rows, :].astype(jnp.float32), 0.0)
+            out_ref[rows, :] = jnp.where((row >= lo) & (row < hi), acc, before
+                                         ).astype(out_ref.dtype)
+
+        @pl.when(jnp.logical_not(shared | revisit))
+        def _():
+            out_ref[rows, :] = jnp.zeros((sub_tile, out_ref.shape[1]),
+                                         out_ref.dtype)
+
+    lax.fori_loop(0, row_tile // sub_tile, part, None)
+
+
+def _column_tile(contraction, columns, operands, row_tile, out_bytes):
+    """The widest tile of whole lanes that divides ``columns`` and keeps a
+    grid step's blocks — rows, ``operands`` weight blocks and the output,
+    each twice for the pipeline — within ``GROUPED_BLOCK_BYTES``."""
+    for parts in range(1, columns // _LANES + 1):
+        tile, rest = divmod(columns, parts)
+        if rest or tile % _LANES:
+            continue
+        blocks = 2 * (row_tile * contraction * 2
+                      + operands * contraction * tile * 2
+                      + row_tile * tile * out_bytes)
+        if blocks <= GROUPED_BLOCK_BYTES:
+            return tile
+    return None
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "out_dtype", "row_tile", "sub_tile", "interpret"))
+def grouped_product(rows, weights, sizes, out_dtype, *,
+                    row_tile=GROUPED_ROW_TILE, sub_tile=GROUPED_SUB_TILE,
+                    interpret=False):
+    """The grouped product as one Pallas TPU kernel (the algorithm of jax's
+    ``megablox.gmm``): ``rows`` (R, K) sorted by group, ``weights`` one array
+    (groups, K, N) or two — ``rows · w[g]``, or ``silu(rows · gate[g]) ·
+    (rows · up[g])`` with both products and the epilogue on float32
+    accumulators on the chip — and ``sizes`` (groups,) int32 each group's
+    rows, which may be traced. Operands in their dtype, float32 accumulation,
+    one rounding to ``out_dtype``. The rows are read once a column tile and a
+    group's weights once a run of visits; **rows past the last group's end
+    come out zero**, as ``lax.ragged_dot`` leaves them on a CPU (the combine
+    multiplies them by a weight of nought, and nought times an unwritten row
+    need not be nought). ``R`` is a multiple of ``row_tile``, that of
+    ``sub_tile``, and ``K`` and ``N`` are multiples of the 128 lanes."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    groups, contraction, columns = weights[0].shape
+    tile = _column_tile(contraction, columns, len(weights), row_tile,
+                        jnp.dtype(out_dtype).itemsize)
+    group_of, tile_of, offsets, visits = _group_visits(
+        sizes, rows.shape[0], row_tile)
+
+    def weight_block(n, v, group_of, tile_of, offsets):
+        return jnp.minimum(group_of[v], groups - 1), 0, n
+
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, row_tile=row_tile,
+                          sub_tile=min(sub_tile, row_tile)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(columns // tile, visits),
+            in_specs=[pl.BlockSpec(
+                (row_tile, contraction),
+                lambda n, v, group_of, tile_of, offsets: (tile_of[v], 0))]
+            + [pl.BlockSpec((None, contraction, tile), weight_block)
+               for _ in weights],
+            out_specs=pl.BlockSpec(
+                (row_tile, tile),
+                lambda n, v, group_of, tile_of, offsets: (tile_of[v], n))),
+        out_shape=jax.ShapeDtypeStruct((rows.shape[0], columns), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=GROUPED_VMEM_LIMIT),
+        name="grouped_product", interpret=interpret,
+    )(group_of, tile_of, offsets, rows, *weights)
+
+
+def expert_products(rows, experts, sizes):
+    """The experts' three grouped products over a buffer sorted by expert:
+    ``silu(rows · gate[g]) · (rows · up[g])``, rounded once to the weights'
+    dtype, times ``down[g]``; ``rows`` (R, hidden) in that dtype, ``sizes``
+    (held,) int32 each expert's rows (it may be traced), float32 accumulation
+    and a float32 result, zero past the last expert's rows.
+
+    Returns ``(out (R, hidden) float32, fused)``. Lowered for a TPU, with
+    bfloat16 operands, a buffer of whole row tiles and widths of whole lanes,
+    this is :func:`grouped_product` twice — gate and up in one pass over the
+    rows, their float32 results never in HBM — and ``fused`` is 1; everywhere
+    else three ``lax.ragged_dot`` and 0. Both come out of one
+    ``lax.platform_dependent``, so ``fused`` says what was lowered."""
+    def ragged(rows, gate, up, down, sizes):
+        hidden = jax.nn.silu(lax.ragged_dot(
+            rows, gate, sizes, preferred_element_type=jnp.float32)
+            ) * lax.ragged_dot(rows, up, sizes,
+                               preferred_element_type=jnp.float32)
+        return lax.ragged_dot(hidden.astype(rows.dtype), down, sizes,
+                              preferred_element_type=jnp.float32
+                              ), jnp.int32(0)
+
+    def kernels(rows, gate, up, down, sizes):
+        hidden = grouped_product(rows, (gate, up), sizes, rows.dtype)
+        return grouped_product(hidden, (down,), sizes, jnp.float32
+                               ), jnp.int32(1)
+
+    operands = (rows, experts["gate"], experts["up"], experts["down"], sizes)
+    _, hidden, width = experts["gate"].shape
+    fits = (all(a.dtype == jnp.bfloat16 for a in operands[:4])
+            and rows.shape[0] % GROUPED_ROW_TILE == 0
+            and hidden % _LANES == 0 and width % _LANES == 0
+            and _column_tile(hidden, width, 2, GROUPED_ROW_TILE, 2)
+            and _column_tile(width, hidden, 1, GROUPED_ROW_TILE, 4))
+    if not fits:
+        return ragged(*operands)
+    return lax.platform_dependent(*operands, tpu=kernels, default=ragged)
+
 
 def buffer_capacity(tokens, c):
     """Rows of the grouped products' buffer for a launch of ``tokens``:
@@ -359,10 +557,20 @@ def routed_experts(p, x, c):
 
     The (token, expert) pairs that meet a held expert are sorted by expert
     into one flat buffer of :func:`buffer_capacity` rows; a round gathers the
-    buffer's tokens and runs the three grouped products (``lax.ragged_dot``,
-    group sizes = each expert's pairs in the buffer). Pairs beyond the buffer
-    take further rounds: none is dropped; a layer held whole has a row for
-    every pair and runs one round without the loop.
+    buffer's tokens and runs the three grouped products
+    (:func:`expert_products`, group sizes = each expert's pairs in the
+    buffer). Pairs beyond the buffer take further rounds: none is dropped; a
+    layer held whole has a row for every pair and runs one round without the
+    loop.
+
+    **The products are a kernel on a TPU.** On the chip (PERF.md §6, PR 38;
+    the three products alone) they take 8.3 ms where a layer of 32 experts is
+    held whole (65,536 rows, 2,048 × 1,792: gate and up in one pass 5.5 ms,
+    down 3.0, 173 TFLOP/s) against 19.6 ms as three ``lax.ragged_dot`` with
+    ``silu · mul`` between them (73 TFLOP/s, and 1.9 GB of float32 gate and up
+    through HBM), and 6.8 ms where 16 of 256 are held (a buffer of 16,384 rows
+    × 7,680 that routing fills by half: a tile without a pair is zeroed, not
+    multiplied) against 10.3.
 
     **The combine is linear in the pairs.** The sort is a permutation, so its
     inverse says where each token's ``top_k`` results lie in the buffer: a
@@ -380,7 +588,7 @@ def routed_experts(p, x, c):
 
     Returns ``(y (N, hidden) float32, chosen (N, k), counts (held,) pairs per
     held expert, overflow (N·k,) bool per pair: computed beyond the first
-    round)``."""
+    round, fused () int32 as :func:`expert_products` returns it)``."""
     N = x.shape[0]
     held = len(c.experts_held)
     chosen, weights = route(p, x, c)
@@ -410,18 +618,14 @@ def routed_experts(p, x, c):
            and N % (2 * tiles) == 0):
         tiles *= 2
 
-    def one_round(r, y):
+    def one_round(r, carry):
+        y, _ = carry
         lo = r * capacity
         pair = order[jnp.clip(lo + jnp.arange(capacity), 0, pairs - 1)]
         rows = xa[pair // c.top_k]                         # (capacity, hidden)
         sizes = (jnp.clip(ends - lo, 0, capacity)
                  - jnp.clip(starts - lo, 0, capacity)).astype(jnp.int32)
-        hidden = jax.nn.silu(lax.ragged_dot(
-            rows, experts["gate"], sizes,
-            preferred_element_type=jnp.float32)) * lax.ragged_dot(
-            rows, experts["up"], sizes, preferred_element_type=jnp.float32)
-        out = lax.ragged_dot(hidden.astype(act), experts["down"], sizes,
-                             preferred_element_type=jnp.float32)
+        out, fused = expert_products(rows, experts, sizes)
         at = (place - lo).reshape(tiles, -1, c.top_k)
         mine = jnp.where((at >= 0) & (at < capacity),
                          held_weights.reshape(at.shape), 0.0)
@@ -434,24 +638,25 @@ def routed_experts(p, x, c):
                 for k in range(c.top_k))
             return lax.dynamic_update_slice_in_dim(y, tile, first, 0)
 
-        return lax.fori_loop(0, tiles, add_tile, y)
+        return lax.fori_loop(0, tiles, add_tile, y), fused
 
-    y = jnp.zeros(x.shape, jnp.float32)
+    none = jnp.zeros(x.shape, jnp.float32), jnp.int32(0)
     if capacity == pairs:
-        y = one_round(0, y)
+        y, fused = one_round(0, none)
     else:       # rounds: 1 unless the held experts are full
-        y = lax.fori_loop(0, -(-local // capacity), one_round, y)
+        y, fused = lax.fori_loop(0, -(-local // capacity), one_round, none)
     overflow = jnp.zeros((pairs,), bool).at[order].set(
         (slots[order] < held) & (jnp.arange(pairs) >= capacity))
-    return y, chosen, counts, overflow
+    return y, chosen, counts, overflow, fused
 
 
-def expert_stats(chosen, counts, overflow, rows, c):
+def expert_stats(chosen, counts, overflow, fused, rows, c):
     """What an expert layer reports of a launch of ``rows`` windows, from
     :func:`routed_experts`' returns: per row the tokens per published expert,
     the pairs that met a held expert, those computed beyond the first round,
-    and its share of the rows the grouped products ran (rounds × the buffer);
-    of the launch, the fullest held expert's pairs over the mean."""
+    its share of the rows the grouped products ran (rounds × the buffer), and
+    whether those products were lowered to the kernel; of the launch, the
+    fullest held expert's pairs over the mean."""
     held = jnp.asarray(c.experts_held, jnp.int32)
     per_row = jnp.sum(chosen.reshape(rows, -1, 1) == jnp.arange(c.experts), 1)
     capacity = buffer_capacity(chosen.shape[0], c)
@@ -463,15 +668,19 @@ def expert_stats(chosen, counts, overflow, rows, c):
                                   ).astype(jnp.int32),
         # a whole number a row, and the rows' sum is the launch's
         "buffer_rows": ran // rows + (jnp.arange(rows) < ran % rows),
+        "fused_products": jnp.broadcast_to(fused, (rows,)),
         "load_max_over_mean": jnp.max(counts) / jnp.maximum(
             jnp.mean(counts.astype(jnp.float32)), 1.0),
     }
 
 
+@functools.partial(jax.jit, static_argnames="c")
 def block(layer, h, c: LatentMoEConfig):
     """One sandwich block over windows h (B, T, hidden) float32. Returns
     ``(h, fused, stats)``: ``fused`` (B,) as :func:`causal_attention` returns
-    it for each window; ``stats`` is None for a dense layer."""
+    it for each window; ``stats`` is None for a dense layer. Jitted, so that
+    a stack's layers of one kind are traced and lowered once, not once each:
+    a kernel's trace is the dearest part of a warm start."""
     B, T, _ = h.shape
     with jax.named_scope("latent_attention"):
         attended, fused = lax.map(
@@ -486,10 +695,10 @@ def block(layer, h, c: LatentMoEConfig):
     moe = layer["moe"]
     flat = x.reshape(B * T, -1)
     with jax.named_scope("routed_experts"):
-        routed, chosen, counts, overflow = routed_experts(moe, flat, c)
+        routed, *told = routed_experts(moe, flat, c)
     m = (gated_mlp(moe["shared"], flat) + routed).reshape(B, T, -1)
     return (h + rms_norm(m, layer["post_mlp_norm"], c.eps), fused,
-            expert_stats(chosen, counts, overflow, B, c))
+            expert_stats(*told, B, c))
 
 
 def score_head(params, h, tokens, eps):
@@ -540,6 +749,8 @@ def expert_outputs(stats, tokens, counts):
                     stacked("overflow_pairs"), 1),
                 telemetry.M_MOE_BUFFER_ROWS: jnp.sum(
                     stacked("buffer_rows"), 1),
+                telemetry.M_MOE_FUSED_PRODUCT_LAYERS: jnp.sum(
+                    stacked("fused_products"), 1),
                 telemetry.M_MOE_LOAD_MAX_OVER_MEAN: jnp.broadcast_to(
                     jnp.stack([s["load_max_over_mean"] for s in stats]),
                     (B, len(stats)))}}

@@ -28,12 +28,15 @@ stream and the log-probabilities float32.
 At a head width of 64 — half a lane group — the fused attention kernel of
 ``latent_moe`` does not apply (whole lanes, one rotary key head): attention
 goes down the blocked path, and ``sparkdl.sequence.fused_attention_layers``
-reads 0. Outputs per window are ``latent_moe``'s; the program's counts gain
-``sparkdl.sequence.conv_layers``.
+reads 0. The expert layers' grouped products do go down ``latent_moe``'s
+grouped-product kernel at the published widths
+(``sparkdl.moe.fused_product_layers``). Outputs per window are
+``latent_moe``'s; the program's counts gain ``sparkdl.sequence.conv_layers``.
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Tuple
 
@@ -111,9 +114,12 @@ def grouped_attention(p, u, c: ShortConvMoEConfig):
     return _dot(jnp.swapaxes(out, 0, 1).reshape(T, -1), p["out"])
 
 
+@functools.partial(jax.jit, static_argnames="c")
 def block(layer, h, c: ShortConvMoEConfig):
     """One pre-norm block over windows h (B, T, hidden) float32. Returns
-    ``(h, stats)``; ``stats`` is None for a dense layer."""
+    ``(h, stats)``; ``stats`` is None for a dense layer. Jitted, as
+    ``latent_moe.block`` is: the stack's layers of one kind are traced and
+    lowered once."""
     B, T, _ = h.shape
     u = rms_norm(h, layer["operator_norm"], c.eps)
     if "conv" in layer:
@@ -127,10 +133,8 @@ def block(layer, h, c: ShortConvMoEConfig):
     if "moe" not in layer:
         return h + lax.map(lambda row: gated_mlp(layer["mlp"], row), x), None
     with jax.named_scope("routed_experts"):
-        routed, chosen, counts, overflow = routed_experts(
-            layer["moe"], x.reshape(B * T, -1), c)
-    return (h + routed.reshape(B, T, -1),
-            expert_stats(chosen, counts, overflow, B, c))
+        routed, *told = routed_experts(layer["moe"], x.reshape(B * T, -1), c)
+    return h + routed.reshape(B, T, -1), expert_stats(*told, B, c)
 
 
 def forward(params, tokens, c: ShortConvMoEConfig) -> Dict[str, Any]:

@@ -12,10 +12,11 @@ programs are XLA's own and which hold a hand-written kernel
 candidate for them lost to XLA's twin on the chip (PERF.md §6, PR 21) — and
 latent attention holds exactly the fused causal-attention kernel, which keeps
 a window's float32 scores out of HBM (PERF.md §6, PR 36); the
-short-convolution model holds none of ours (its head width of 64 is half a
-lane group: the blocked path), only the grouped products' own (XLA lowers
-``lax.ragged_dot`` to kernels of its own). A PR that ships or drops a kernel
-changes the assertion where it belongs.
+short-convolution model's mixers hold none (its head width of 64 is half a
+lane group: the blocked path), and the expert layer of either sequence model
+holds the grouped-product kernel twice, gate and up in one pass and down
+(PERF.md §6, PR 38), in place of three ``lax.ragged_dot``. A PR that ships or
+drops a kernel changes the assertion where it belongs.
 
 The topology is described inside a fixture of this file and nowhere else:
 only one process at a time may load the TPU's library, and under
@@ -169,7 +170,8 @@ def test_short_convolution_model_compiles_for_v5e(part, one_chip,
     windows of 4,096), bfloat16 weights, shapes only: the gated short
     convolution; one window's grouped-query attention, which takes the
     blocked path with the keys grouped, not repeated; the expert layer held
-    whole, whose buffer is the 65,536 pairs and whose combine builds no
+    whole, whose buffer is the 65,536 pairs, whose three grouped products are
+    the grouped kernel twice (PERF.md §6, PR 38) and whose combine builds no
     tokens × buffer operand."""
     c = registry.SEQUENCE_MODELS["LFM2-8B-A1B"]
 
@@ -212,7 +214,11 @@ def test_short_convolution_model_compiles_for_v5e(part, one_chip,
     assert "fused_causal_attention" not in text
     if part == "routed_experts":
         assert latent_moe.buffer_capacity(4 * 4096, c) == 65536
-        assert "ragged-dot" in text
+        # gate and up as one kernel, down as another, and no product of XLA's
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+        assert "grouped_product" in text and "ragged-dot" not in text
+        # gate's and up's float32 results never reach HBM
+        assert "f32[65536,1792]" not in text
         assert "[16384,65536]" not in text and "[65536,16384]" not in text
     else:
         assert "tpu_custom_call" not in text
@@ -220,3 +226,32 @@ def test_short_convolution_model_compiles_for_v5e(part, one_chip,
         # scores of 8 key heads × their 4 query heads × a block of queries;
         # no copy of the keys to 32 heads
         assert "f32[8,4,512," in text
+
+
+def test_a_share_of_the_experts_compiles_to_the_grouped_kernel_for_v5e(
+        one_chip, no_persistent_cache):
+    """openPangu-Ultra-MoE-718B's expert layer as the .windows cell holds it
+    (16 of 256 experts, a buffer of 16,384 rows × 7,680 of which routing
+    fills about half, rounds under a ``fori_loop`` with traced group sizes):
+    the same two kernel calls inside the loop, their column tiles cut to the
+    blocks' budget of on-chip memory, and no product of XLA's."""
+    import dataclasses
+
+    c = dataclasses.replace(
+        registry.SEQUENCE_MODELS["openPangu-Ultra-MoE-718B"],
+        experts_held=tuple(range(16)))
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {"router": on_chip((c.hidden, c.experts)),
+         "experts": {"gate": on_chip((16, c.hidden, c.expert_width)),
+                     "up": on_chip((16, c.hidden, c.expert_width)),
+                     "down": on_chip((16, c.expert_width, c.hidden))}}
+    x = on_chip((4 * 4096, c.hidden), jnp.float32)
+    text = jax.jit(lambda p, x: latent_moe.routed_experts(p, x, c)
+                   ).lower(p, x).compile().as_text()
+    assert latent_moe.buffer_capacity(4 * 4096, c) == 16384
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "grouped_product" in text and "ragged-dot" not in text
+    assert "f32[16384,2048]" not in text

@@ -127,7 +127,7 @@ def test_shares_add_up_to_the_uncut_layer(key):
                 layer["moe"]["experts"]["up"],
                 whole["experts"]["up"][share[0]:share[0] + 4])
             config = dataclasses.replace(MODEL, experts_held=share)
-            part, _, counts, _ = latent_moe.routed_experts(layer["moe"], x,
+            part, _, counts, _, _ = latent_moe.routed_experts(layer["moe"], x,
                                                            config)
             # ... and the reference given the same share gives the same part
             ref_part, _ = ref.routed_part(layer["moe"], x, s, lambda a: a,
@@ -150,7 +150,7 @@ def test_no_pair_is_dropped_under_a_skewed_router(key):
                                  capacity_factor=1.0)
     with jax.default_matmul_precision("highest"):
         want, _ = ref.routed_part(layer, x, s, lambda a: a)
-        got, chosen, counts, overflow = jax.jit(
+        got, chosen, counts, overflow, fused = jax.jit(
             lambda p, x: latent_moe.routed_experts(p, x, config))(layer, x)
     capacity = 96                   # 1.0 × 96 tokens × 4 pairs × 4 of 16
     assert int(counts[1]) == 96     # every token chose expert 5
@@ -179,6 +179,7 @@ def test_program_counts_reach_telemetry_and_not_the_caller(key):
     held = np.asarray(out["expert_counts"])[:, 0, 4:8].sum()
     assert counters[telemetry.M_MOE_LOCAL_PAIRS] == held
     assert counters[telemetry.M_MOE_OVERFLOW_PAIRS] >= 0
+    assert counters[telemetry.M_MOE_FUSED_PRODUCT_LAYERS] == 0
     ratio = snapshot["histograms"][telemetry.M_MOE_LOAD_MAX_OVER_MEAN]
     assert ratio["count"] == 3 and ratio["min"] >= 1.0
     # without a scope the outputs are the same and nothing is recorded
@@ -310,22 +311,58 @@ def test_fused_kernel_is_causal(window):
     assert np.abs(after[-1] - before[-1]).max() > 0.1
 
 
-@pytest.mark.parametrize("case", ["window-24", "float32", "lowered-for-cpu",
-                                  "lowered-for-tpu"])
+def product_operands(case):
+    """expert_products' operands: 4 experts of 256 × 128 over a buffer of two
+    row tiles, but for what the case changes."""
+    rows = latent_moe.GROUPED_ROW_TILE * 2 + (
+        8 if case == "products-rows-off-the-tile" else 0)
+    width = 96 if case == "products-width-off-the-lanes" else 128
+    dtype = jnp.float32 if case == "products-float32" else jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+
+    def draw(key, *shape):
+        return (jax.random.normal(key, shape, jnp.float32)
+                / shape[-2] ** 0.5).astype(dtype)
+
+    return (draw(keys[0], rows, 256),
+            {"gate": draw(keys[1], 4, 256, width),
+             "up": draw(keys[2], 4, 256, width),
+             "down": draw(keys[3], 4, width, 256)},
+            jnp.asarray([300, 0, 411, 200], jnp.int32))
+
+
+@pytest.mark.parametrize("case", [
+    "window-24", "float32", "lowered-for-cpu", "lowered-for-tpu",
+    "products-float32", "products-rows-off-the-tile",
+    "products-width-off-the-lanes", "products-lowered-for-cpu",
+    "products-lowered-for-tpu"])
 def test_the_choice_follows_what_the_lowering_can_see(case):
-    """The kernel is taken where the program is lowered for a TPU with
-    bfloat16 operands and a window of whole tiles; the counter says which."""
-    window = 24 if case == "window-24" else latent_moe.FUSED_QUERY_TILE
-    dtype = jnp.float32 if case == "float32" else jnp.bfloat16
-    operands = attention_operands(window, dtype)
-    fn = jax.jit(lambda *a: latent_moe.causal_attention(*a, 512))
-    platform = "tpu" if case == "lowered-for-tpu" else "cpu"
+    """A kernel is taken where the program is lowered for a TPU with bfloat16
+    operands and shapes of whole tiles — attention's window, the grouped
+    products' buffer and widths; the counter says which."""
+    platform = "tpu" if case.endswith("lowered-for-tpu") else "cpu"
+    if case.startswith("products-"):
+        operands = product_operands(case)
+        fn = jax.jit(latent_moe.expert_products)
+        calls = 2                        # gate and up in one, and down
+    else:
+        window = 24 if case == "window-24" else latent_moe.FUSED_QUERY_TILE
+        dtype = jnp.float32 if case == "float32" else jnp.bfloat16
+        operands = attention_operands(window, dtype)
+        fn = jax.jit(lambda *a: latent_moe.causal_attention(*a, 512))
+        calls = 1
     text = fn.trace(*operands).lower(lowering_platforms=(platform,)).as_text()
-    assert ("tpu_custom_call" in text) == (case == "lowered-for-tpu")
-    if case == "lowered-for-tpu":
+    assert text.count("tpu_custom_call") == calls * (platform == "tpu")
+    if platform == "tpu":
         return                       # nothing here can run it
     out, engaged = fn(*operands)
-    assert engaged == 0 and out.shape == (window, HEADS * WIDTH)
+    assert engaged == 0
+    if case.startswith("products-"):
+        rows, experts, group_sizes = operands
+        assert out.shape == rows.shape and out.dtype == jnp.float32
+        assert np.asarray(out[:911]).any() and not np.asarray(out[911:]).any()
+        return
+    assert out.shape == (window, HEADS * WIDTH)
     assert distance(out, exact_attention(*operands)) < 0.003
 
 

@@ -9,12 +9,14 @@ over one round and several; the program's counts in telemetry; the benchmark's F
 count by hand."""
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -195,14 +197,14 @@ def test_shares_add_up_to_the_uncut_layer(key):
                 layer["moe"]["experts"]["up"],
                 whole["experts"]["up"][share[0]:share[0] + 8])
             config = dataclasses.replace(model, experts_held=share)
-            part, _, counts, _ = latent_moe.routed_experts(layer["moe"], x,
+            part, _, counts, _, _ = latent_moe.routed_experts(layer["moe"], x,
                                                            config)
             ref_part, _ = ref.routed_part(layer["moe"], x, s, identity,
                                           experts_held=share)
             np.testing.assert_allclose(part, ref_part, rtol=1e-4, atol=1e-5)
             total = total + part
             pairs += int(counts.sum())
-        uncut, _, counts, overflow = latent_moe.routed_experts(
+        uncut, _, counts, overflow, _ = latent_moe.routed_experts(
             whole, x, dataclasses.replace(model, experts_held=everything))
     assert pairs == 40 * 4          # every (token, expert) pair, exactly once
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
@@ -221,12 +223,12 @@ def test_no_pair_is_dropped_and_the_buffer_is_the_pairs_when_all_are_held(
     assert latent_moe.buffer_capacity(96, MODEL) == 96 * 4
     with jax.default_matmul_precision("highest"):
         want, _ = ref.routed_part(layer, x, s, identity)
-        got, chosen, counts, overflow = jax.jit(
+        got, chosen, counts, overflow, fused = jax.jit(
             lambda p, x: latent_moe.routed_experts(p, x, MODEL))(layer, x)
     assert int(counts[5]) == 96     # every token chose expert 5
     assert int(counts.sum()) == 96 * 4 and not overflow.any()
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
-    stats = latent_moe.expert_stats(chosen, counts, overflow, 4, MODEL)
+    stats = latent_moe.expert_stats(chosen, counts, overflow, fused, 4, MODEL)
     assert stats["buffer_rows"].tolist() == [96] * 4
     assert stats["local_pairs"].tolist() == [96] * 4
     assert float(stats["load_max_over_mean"]) == 96 / (96 * 4 / 16)
@@ -236,11 +238,119 @@ def test_no_pair_is_dropped_and_the_buffer_is_the_pairs_when_all_are_held(
                                  capacity_factor=1.0)
     part = ref.init_layer(key, s, 2, False, experts_held=(4, 5, 6, 7))["moe"]
     part["router"] = layer["router"]
-    _, chosen, counts, overflow = latent_moe.routed_experts(part, x, config)
-    stats = latent_moe.expert_stats(chosen, counts, overflow, 4, config)
+    _, *told = latent_moe.routed_experts(part, x, config)
+    counts = told[1]
+    stats = latent_moe.expert_stats(*told, 4, config)
     rounds = -(-int(counts.sum()) // 96)
     assert rounds > 1 and int(stats["buffer_rows"].sum()) == rounds * 96
     assert int(stats["overflow_pairs"].sum()) == int(counts.sum()) - 96
+
+
+# -- the grouped products' kernel ---------------------------------------------
+
+HIDDEN, WIDTH = 256, 128        # whole lanes, as the kernel's blocks need
+
+
+def expert_operands(groups, rows, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+
+    def draw(key, *shape):
+        return (jax.random.normal(key, shape, jnp.float32)
+                / shape[-2] ** 0.5).astype(jnp.bfloat16)
+
+    return (jax.random.normal(keys[0], (rows, HIDDEN)).astype(jnp.bfloat16),
+            {"gate": draw(keys[1], groups, HIDDEN, WIDTH),
+             "up": draw(keys[2], groups, HIDDEN, WIDTH),
+             "down": draw(keys[3], groups, WIDTH, HIDDEN)})
+
+
+def ragged(rows, weights, group_sizes):
+    return lax.ragged_dot(rows, weights, group_sizes,
+                          preferred_element_type=jnp.float32)
+
+
+@pytest.mark.parametrize("tile,sub_tile", [(16, 16), (32, 16)])
+@pytest.mark.parametrize("case,group_sizes,rows", [
+    ("uneven-off-the-tiles", (5, 20, 7, 23, 9), 64),
+    ("an-empty-group", (16, 0, 11, 0, 37), 64),
+    ("a-tail-of-no-group", (5, 20, 0, 7, 0), 96),
+    ("no-row-at-all", (0, 0, 0), 32)])
+def test_grouped_kernel_against_ragged_dot(case, group_sizes, rows, tile,
+                                           sub_tile):
+    """Both uses of the kernel, interpreted, against ``lax.ragged_dot`` and
+    ``silu · mul`` on its float32 results: row tiles that the groups'
+    boundaries do not respect (whole, and worked in sub-tiles of which a
+    visit skips those without a row of its group), empty groups (never
+    visited), and whole tiles past the last group's end, which come out zero
+    as ragged_dot's do."""
+    x, experts = expert_operands(len(group_sizes), rows)
+    group_sizes = jnp.asarray(group_sizes, jnp.int32)
+    visits = latent_moe._group_visits(group_sizes, rows, tile)
+    run = functools.partial(latent_moe.grouped_product, row_tile=tile,
+                            sub_tile=sub_tile, interpret=True)
+    hidden = run(x, (experts["gate"], experts["up"]), group_sizes,
+                 jnp.bfloat16)
+    want = (jax.nn.silu(ragged(x, experts["gate"], group_sizes))
+            * ragged(x, experts["up"], group_sizes)).astype(jnp.bfloat16)
+    assert hidden.dtype == jnp.bfloat16 and hidden.shape == (rows, WIDTH)
+    # one rounding of the same float32 numbers: a last place at most
+    np.testing.assert_allclose(np.asarray(hidden, np.float32),
+                               np.asarray(want, np.float32), rtol=2 ** -7,
+                               atol=1e-6)
+    out = run(want, (experts["down"],), group_sizes, jnp.float32)
+    assert out.dtype == jnp.float32
+    np.testing.assert_allclose(out, ragged(want, experts["down"],
+                                           group_sizes), rtol=1e-5, atol=1e-5)
+    used = int(group_sizes.sum())
+    assert not np.asarray(hidden[used:], np.float32).any()
+    assert not np.asarray(out[used:]).any()
+    # every (tile, group) that share a row is visited once, group by group
+    group_of, tile_of, offsets, count = (np.asarray(v) for v in visits)
+    starts, ends = offsets[:-2], offsets[1:-1]
+    shared = {(t, g) for g in range(len(starts)) for t in range(rows // tile)
+              if max(starts[g], tile * t) < min(ends[g], tile * t + tile)}
+    empty = {(t, len(starts)) for t in range(-(-used // tile), rows // tile)}
+    assert sorted(zip(tile_of[:count], group_of[:count])) == sorted(
+        shared | empty) and count == len(shared | empty)
+
+
+@pytest.mark.parametrize("case", ["a-tail-of-no-group", "further-rounds",
+                                  "held-whole"])
+def test_routed_experts_through_the_kernel_equals_the_default_path(
+        case, monkeypatch):
+    """``routed_experts`` with the TPU branch taken (the kernel interpreted,
+    a row tile of 16) against the ``lax.ragged_dot`` branch: a buffer whose
+    tail belongs to no expert, whose rows the combine multiplies by nought —
+    finite all the same, because the kernel zeroes them; the group sizes
+    traced inside the rounds' ``fori_loop``; and a layer held whole."""
+    held = tuple(range(16)) if case == "held-whole" else (4, 5, 6, 7)
+    config = dataclasses.replace(
+        MODEL, hidden=HIDDEN, expert_width=WIDTH, experts_held=held,
+        capacity_factor=0.5 if case == "further-rounds" else 2.0)
+    x, experts = expert_operands(len(held), 96, seed=3)
+    layer = {"experts": experts, "router": jax.random.normal(
+        jax.random.PRNGKey(4), (HIDDEN, 16), jnp.float32)}
+    x = x.astype(jnp.float32)
+    want, _, counts, overflow, fused = jax.jit(
+        lambda p, x: latent_moe.routed_experts(p, x, config))(layer, x)
+    assert fused == 0
+    capacity = latent_moe.buffer_capacity(96, config)
+    assert capacity % 16 == 0
+    assert {"a-tail-of-no-group": int(counts.sum()) < capacity - 16,
+            "further-rounds": capacity < int(counts.sum()),
+            "held-whole": capacity == int(counts.sum()) == 96 * 4}[case]
+    monkeypatch.setattr(latent_moe, "GROUPED_ROW_TILE", 16)
+    monkeypatch.setattr(latent_moe, "grouped_product", functools.partial(
+        latent_moe.grouped_product, row_tile=16, interpret=True))
+    monkeypatch.setattr(lax, "platform_dependent",
+                        lambda *operands, tpu, default: tpu(*operands))
+    got, _, again, _, fused = jax.jit(
+        lambda p, x: latent_moe.routed_experts(p, x, config))(layer, x)
+    assert fused == 1 and np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(again, counts)
+    # the two paths sum in another order, so a few of hidden's bfloat16
+    # roundings fall the other way: a last place of one term of a row's sum
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
 
 
 @pytest.mark.parametrize("held,factor", [(16, 2.0), (16, 0.25), (4, 1.0),
@@ -258,7 +368,7 @@ def test_the_combine_gathers_every_round_back_to_its_tokens(key, held,
     x = jax.random.normal(jax.random.PRNGKey(10), (48, s.hidden))
     with jax.default_matmul_precision("highest"):
         want, _ = ref.routed_part(layer, x, s, identity, experts_held=share)
-        got, chosen, counts, overflow = latent_moe.routed_experts(layer, x,
+        got, chosen, counts, overflow, _ = latent_moe.routed_experts(layer, x,
                                                                   config)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     capacity = latent_moe.buffer_capacity(48, config)
@@ -289,6 +399,8 @@ def test_program_counts_reach_telemetry_and_not_the_caller(key):
     # three expert layers, and the buffers held exactly those
     assert counters[telemetry.M_MOE_LOCAL_PAIRS] == 3 * WINDOW * 3 * 4
     assert counters[telemetry.M_MOE_BUFFER_ROWS] == 3 * WINDOW * 3 * 4
+    # a CPU, and widths under a lane group: three lax.ragged_dot a layer
+    assert counters[telemetry.M_MOE_FUSED_PRODUCT_LAYERS] == 0
     assert counters[telemetry.M_MOE_OVERFLOW_PAIRS] == 0
     ratio = snapshot["histograms"][telemetry.M_MOE_LOAD_MAX_OVER_MEAN]
     assert ratio["count"] == 3 * 3 and ratio["min"] >= 1.0
