@@ -262,6 +262,13 @@ M_SEQUENCE_TOKENS = "sparkdl.sequence.tokens"          # counter (tokens of
 M_SEQUENCE_FUSED_ATTENTION_LAYERS = "sparkdl.sequence.fused_attention_layers"
 # counter (per row: the layers whose mixer was the gated short convolution)
 M_SEQUENCE_CONV_LAYERS = "sparkdl.sequence.conv_layers"
+# counter (per row: the layers whose attention was lowered with a span — a
+# query reads its last keys only; from a stack that names its layers' kinds)
+M_SEQUENCE_WINDOW_ATTENTION_LAYERS = "sparkdl.sequence.window_attention_layers"
+# counter (per row, summed over the attention layers and one head's queries:
+# the keys whose scores the lowered path computes, masked ones inside a
+# visited tile included; beside the former)
+M_SEQUENCE_SCORED_KEYS = "sparkdl.sequence.scored_keys"
 M_MOE_ROUTED_TOKENS = "sparkdl.moe.routed_tokens"      # counter (tokens
                                                        # routed, once for each
                                                        # expert layer)
@@ -337,6 +344,8 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_SEQUENCE_TOKENS: "counter",
     M_SEQUENCE_FUSED_ATTENTION_LAYERS: "counter",
     M_SEQUENCE_CONV_LAYERS: "counter",
+    M_SEQUENCE_WINDOW_ATTENTION_LAYERS: "counter",
+    M_SEQUENCE_SCORED_KEYS: "counter",
     M_MOE_ROUTED_TOKENS: "counter",
     M_MOE_LOCAL_PAIRS: "counter",
     M_MOE_OVERFLOW_PAIRS: "counter",

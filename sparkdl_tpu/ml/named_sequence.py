@@ -12,8 +12,10 @@ set, adds the tokens routed to each expert per expert layer, flattened.
 
 The weights are a variables dict, taken as given: for a model of this size
 they are made or loaded in bfloat16 on the device, and what a chip holds of
-the published model — layers and their kinds, experts (``expertsHeld``),
-vocabulary slice — is read off them (``registry.build_sequence_scorer``).
+the published model — layers and their kinds (the leading ones, where the
+config names a kind of attention for each position), experts
+(``expertsHeld``), vocabulary slice — is read off them
+(``registry.build_sequence_scorer``).
 """
 
 from __future__ import annotations

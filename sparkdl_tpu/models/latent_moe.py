@@ -1,12 +1,14 @@
 """Latent-attention sparse-expert decoder as a prefill-only window scorer —
 the sequence models' counterpart of the image zoo (``DeepSequenceScorer``,
-``registry.SEQUENCE_MODELS``) — and the pieces the second sequence model
-(``models/shortconv_moe.py``) imports from here: ``rms_norm``, the
-mixed-precision product ``_dot``, ``rotary``, the blocked causal soft-max
-(``_blocked_attention``, which reads grouped keys), ``gated_mlp``, the expert
-layer (``route``, ``buffer_capacity``, ``expert_products``,
-``routed_experts``, ``expert_stats``) and the scorer's head and counts
-(``score_head``, ``expert_outputs``).
+``registry.SEQUENCE_MODELS``) — and the pieces the pre-norm stack of the
+other sequence models (``models/shortconv_moe.py``) imports from here:
+``rms_norm``, the mixed-precision product ``_dot``, ``rotary`` with a kind of
+layer's own frequencies (``Rope``), causal attention over grouped keys, with
+a span or without (``grouped_causal_attention``: the fused kernel or
+``_blocked_attention``), ``gated_mlp``, the expert layer (``route``,
+``buffer_capacity``, ``expert_products``, ``routed_experts``,
+``expert_stats``) and the scorer's head and counts (``score_head``,
+``expert_outputs``).
 
 One block is: multi-head latent attention (queries and keys/values through
 low-rank latents, a rotary part shared by all heads of the key), sandwich
@@ -32,15 +34,19 @@ log-probabilities are float32.
 **What runs where.** Everything is XLA's own but two hand-written Pallas TPU
 kernels, each one path of two under one contract, the other being XLA's and
 the oracle the kernel is tested against. A window's causal attention
-(:func:`causal_attention`): :func:`fused_causal_attention` keeps the scores
-on the chip; the blocked soft-max is the other path. The experts' three
+(:func:`causal_attention`, and :func:`grouped_causal_attention` for grouped
+keys without a separate rotary part, where a span may bound the keys a query
+reads): :func:`fused_causal_attention` keeps the scores on the chip and
+visits only the key tiles a span reaches; the blocked soft-max, with the
+same span, is the other path. The experts' three
 grouped products (:func:`expert_products`): :func:`grouped_product`, twice —
 gate and up in one pass over the buffer's rows with ``silu · mul`` on the
 float32 accumulators, so neither float32 result reaches HBM, then down; three
 ``lax.ragged_dot`` are the other path. A kernel is taken where the program is
 lowered for a TPU — a chip, or an ahead-of-time compile for a described one —
 with bfloat16 operands and shapes of whole tiles (attention: a window of
-whole query tiles and head widths of whole lanes; the products: a buffer of
+whole query tiles, no longer than a head's keys and values fit on-chip, and
+head widths of whole lanes; the products: a buffer of
 whole row tiles and widths of whole lanes); a CPU run, float32 weights or
 other shapes lower XLA's path. No option chooses, and
 ``jax.experimental.pallas`` is imported where a kernel is built, not with this
@@ -58,11 +64,13 @@ grouped products were lowered to a kernel).
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from sparkdl_tpu.core import telemetry
@@ -91,6 +99,7 @@ class LatentMoEConfig:
     scaling: float = 1.0
     norm_topk: bool = True
     topk_eps: float = 0.0     # added to the chosen scores' sum before dividing
+    scoring: str = "sigmoid"  # the router's scores: "sigmoid" or "softmax"
     eps: float = 1e-5
     theta: float = 10000.0
     # rows of the grouped products' buffer, as a multiple of the pairs that
@@ -114,29 +123,76 @@ def _dot(x, w, out=jnp.float32):
                    ).astype(out)
 
 
-def rotary(x, theta, heads=1):
+@dataclass(frozen=True)
+class Rope:
+    """One kind of layer's rotary as a model's ``rope_parameters`` state it:
+    plain (``factor`` 1), or YaRN — the inverse frequencies of the dimensions
+    that turn less than ``beta_slow`` times over ``original`` positions
+    divided by ``factor``, those that turn more than ``beta_fast`` times left
+    alone, a linear ramp between, and ``amplitude`` on cos and sin (the
+    scores carry its square)."""
+
+    theta: float
+    factor: float = 1.0
+    original: int = 0         # positions the model was trained on unscaled
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    amplitude: float = 1.0
+
+    def ramp(self, half):
+        """``(low, high)``: the first dimension of a head's ``half`` that is
+        scaled at all, and the first scaled in full."""
+        def dimension(turns):
+            return 2 * half * math.log(
+                self.original / (2 * math.pi * turns)) / (
+                    2 * math.log(self.theta))
+
+        low = math.floor(dimension(self.beta_fast))
+        high = math.ceil(dimension(self.beta_slow))
+        return max(low, 0), min(high, 2 * half - 1)
+
+    def frequencies(self, half):
+        """(half,) float32 inverse frequencies, from float64."""
+        j = np.arange(half, dtype=np.float64)
+        plain = self.theta ** (-j / half)
+        if self.factor == 1.0:
+            return plain.astype(np.float32)
+        low, high = self.ramp(half)
+        scaled = np.clip((j - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return (plain * ((1.0 - scaled) + scaled / self.factor)).astype(
+            np.float32)
+
+
+def rotary(x, theta, heads=1, frequencies=None, amplitude=1.0):
     """x (T, heads · rope) float32, head by head as a projection leaves it;
-    a head's two halves pair up."""
+    a head's two halves pair up. The inverse frequencies are ``theta``'s
+    plain ones unless handed in ((rope / 2,), :meth:`Rope.frequencies`);
+    ``amplitude`` multiplies cos and sin."""
     T = x.shape[0]
     half = x.shape[1] // heads // 2
-    t = jnp.arange(T, dtype=jnp.float32)
-    angle = t[:, None] * theta ** (-jnp.arange(half, dtype=jnp.float32)
-                                   / half)
+    t = jnp.arange(T, dtype=jnp.float32)[:, None]
+    if frequencies is None:
+        frequencies = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = t * frequencies
     cos, sin = jnp.cos(angle)[:, None], jnp.sin(angle)[:, None]
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
     x = x.reshape(T, heads, 2, half)
     a, b = x[:, :, 0], x[:, :, 1]
     return jnp.stack([a * cos - b * sin, b * cos + a * sin], 2).reshape(
         T, -1)
 
 
-def _blocked_attention(q, k, v, block):
+def _blocked_attention(q, k, v, block, span=None):
     """q (H, T, d), k (G, T, d), v (G, T, dv) → (H, T, dv); q carries the
     scores' scale. Query head a reads key head a // (H / G): with G < H the
     keys are grouped, and a group's H / G query heads meet its one key head
     in one product — keys and values are never copied to the query heads.
-    Blocked over queries, each block against its causal prefix of keys only:
-    the scores of one block are the largest temporary (H · block · T float32),
-    not H · T²."""
+    Blocked over queries, each block against its causal prefix of keys only
+    — with a ``span``, from the first key of the block's first query's span
+    on: query t reads keys t − span < j ≤ t, the earlier ones are not
+    multiplied — so the scores of one block are the largest temporary
+    (H · block · T float32), not H · T²."""
     H, T, d = q.shape
     G = k.shape[0]
     q = q.reshape(G, H // G, T, d)
@@ -144,35 +200,76 @@ def _blocked_attention(q, k, v, block):
     out = []
     for lo in range(0, T, block):
         hi = min(lo + block, T)
-        scores = jnp.einsum("grqd,gkd->grqk", q[:, :, lo:hi], k[:, :hi],
+        first = 0 if span is None else max(0, lo - span + 1)
+        scores = jnp.einsum("grqd,gkd->grqk", q[:, :, lo:hi], k[:, first:hi],
                             preferred_element_type=jnp.float32)
-        mask = jnp.arange(hi)[None, :] <= jnp.arange(lo, hi)[:, None]
+        keys, queries = jnp.arange(first, hi)[None, :], jnp.arange(
+            lo, hi)[:, None]
+        mask = keys <= queries
+        if span is not None:
+            mask &= keys > queries - span
         scores = jnp.where(mask, scores, -jnp.inf)
         weights = jnp.exp(scores - jnp.max(scores, -1, keepdims=True))
         total = jnp.sum(weights, -1, keepdims=True)
         part = jnp.einsum("grqk,gkd->grqd", weights.astype(v.dtype),
-                          v[:, :hi], preferred_element_type=jnp.float32)
+                          v[:, first:hi], preferred_element_type=jnp.float32)
         out.append((part / total).astype(v.dtype))
     return jnp.concatenate(out, 2).reshape(H, T, -1)
 
 
+def scored_keys(window, span, query_tile, key_tile=None):
+    """The (query, key) pairs of one head whose scores a path computes over a
+    window, masked ones inside a visited tile included: the blocked path
+    (``key_tile`` None; ``query_tile`` its block) scores each block of
+    queries against every key from its first query's span to its last query;
+    the fused kernel visits whole key tiles — those before the block's first
+    query that hold a key of some query's span, every row of the block
+    against each, then along the diagonal the rows from each tile's first
+    on."""
+    query_tile = min(query_tile, window)
+    total = 0
+    for lo in range(0, window, query_tile):
+        hi = min(lo + query_tile, window)
+        first = 0 if span is None else max(0, lo - span + 1)
+        if key_tile is None:
+            total += (hi - lo) * (hi - first)
+            continue
+        total += (hi - lo) * (lo // key_tile - first // key_tile) * key_tile
+        total += sum((hi - at) * key_tile for at in range(lo, hi, key_tile))
+    return total
+
+
 # The fused kernel's tiles, chosen from chip runs at 128 heads × 4,096
 # positions (PERF.md §6): queries a grid step, keys a tile of scores. A head's
-# keys and values stay in on-chip memory whole, which bounds the window.
+# keys and values stay in on-chip memory whole, which bounds the window: at
+# 16,384 positions they are 4 MB each at a width of 128 in bfloat16, 16 MB
+# with the pipeline's second buffers, under the 32 MiB the kernel asks for
+# (both models' shapes compile for a described v5e). On the chip (PERF.md §6,
+# PR 39; one window, 32 query heads on 4 key heads of 128) 16,384 positions
+# take 15.3 ms without a span — 144 TFLOP/s over the causal pairs, against
+# 4.10 ms at 8,192 and 1.22 at 4,096, and 74.2 on the blocked path — and
+# 3.86 ms with a span of 1,024 (the blocked path 4.90). Nothing longer has
+# been run: the bound is the longest window measured.
 FUSED_QUERY_TILE = 1024
 FUSED_KEY_TILE = 512
-FUSED_MAX_WINDOW = 8192
+FUSED_MAX_WINDOW = 16384
 _LANES = 128
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def _fused_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, out_ref,
-                  max_ref, sum_ref, acc_ref, *, query_tile, key_tile):
+def _fused_kernel(*refs, query_tile, key_tile, rotary_part, span):
     """One head's block of ``query_tile`` queries against its causal prefix,
     a tile of ``key_tile`` keys at a time: the scores, their running maximum
     and sum (kept across all 128 lanes, so no step re-lays them out) and the
-    weighted values never leave on-chip memory."""
+    weighted values never leave on-chip memory. With a ``span`` the prefix
+    begins at the tile that holds the first key of the block's first query's
+    span, and every visited tile is masked to the band."""
     from jax.experimental import pallas as pl
+    if rotary_part:
+        qn_ref, qr_ref, kn_ref, kr_ref, v_ref = refs[:5]
+    else:
+        qn_ref, kn_ref, v_ref = refs[:3]
+    out_ref, max_ref, sum_ref, acc_ref = refs[-4:]
     first = pl.program_id(1) * query_tile
     max_ref[...] = jnp.full(max_ref.shape, _MASKED, jnp.float32)
     sum_ref[...] = jnp.zeros(sum_ref.shape, jnp.float32)
@@ -186,14 +283,23 @@ def _fused_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, out_ref,
         keys = pl.ds(pl.multiple_of(start, key_tile), key_tile)
         scores = lax.dot_general(
             qn_ref[rows, :], kn_ref[keys, :], transposed,
-            preferred_element_type=jnp.float32) + lax.dot_general(
-            qr_ref[rows, :], kr_ref[keys, :], transposed,
             preferred_element_type=jnp.float32)
+        if rotary_part:
+            scores = scores + lax.dot_general(
+                qr_ref[rows, :], kr_ref[keys, :], transposed,
+                preferred_element_type=jnp.float32)
         if diagonal:
             scores = jnp.where(
                 lax.broadcasted_iota(jnp.int32, scores.shape, 1)
                 <= lax.broadcasted_iota(jnp.int32, scores.shape, 0),
                 scores, _MASKED)
+        if span is not None:
+            # a key is read where key − query > −span; by their places in the
+            # tile, whose first key and query lie start − (first + lo) apart
+            scores = jnp.where(
+                lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+                - lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+                > first + lo - start - span, scores, _MASKED)
         before = max_ref[rows, :]
         highest = jnp.maximum(before, jnp.max(scores, -1, keepdims=True))
         weights = jnp.exp(scores - jnp.tile(highest, (1, key_tile // _LANES)))
@@ -207,7 +313,9 @@ def _fused_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, out_ref,
             weights.astype(values.dtype), values,
             preferred_element_type=jnp.float32)
 
-    lax.fori_loop(0, first // key_tile,
+    earliest = 0 if span is None else jnp.maximum(
+        first - span + 1, 0) // key_tile
+    lax.fori_loop(earliest, first // key_tile,
                   lambda tile, _: step(0, tile * key_tile, False), None)
     for lo in range(0, query_tile, key_tile):
         step(lo, first + lo, True)
@@ -216,31 +324,56 @@ def _fused_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, out_ref,
         ).astype(out_ref.dtype)
 
 
-def fused_causal_attention(q_nope, q_rope, k_nope, k_rope, v, *,
-                           query_tile=FUSED_QUERY_TILE,
+def fused_causal_attention(q_nope, q_rope, k_nope, k_rope, v, *, heads=None,
+                           span=None, query_tile=FUSED_QUERY_TILE,
                            key_tile=FUSED_KEY_TILE, interpret=False):
     """:func:`causal_attention`'s contract as one Pallas TPU kernel (an online
     soft-max): only q, k, v and the output cross HBM, each once, in the layout
     the projections leave them in. The two parts of the scores are two
     products, so the rotary key is never copied to every head; the rotary
     queries come heads first because a block cannot cut a head narrower than
-    the 128 lanes out of a flat row. The window is a multiple of
-    ``query_tile``, that of ``key_tile``, and ``key_tile`` and the widths
-    ``nope`` and ``v`` are multiples of the 128 lanes."""
+    the 128 lanes out of a flat row.
+
+    :func:`grouped_causal_attention`'s contract too: without a rotary part
+    (``q_rope`` and ``k_rope`` None, ``heads`` the query heads) the scores are
+    the one product, and ``k_nope`` and ``v`` may hold fewer heads than the
+    queries — a key head's block is fetched once for the query heads that
+    read it, which follow each other on the grid. With a ``span`` only the
+    key tiles that hold a key of some query's span are visited.
+
+    The window is a multiple of ``query_tile``, that of ``key_tile``, and
+    ``key_tile`` and the widths ``nope`` and ``v`` are multiples of the 128
+    lanes."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    H, T, rope = q_rope.shape
-    nope, width = q_nope.shape[1] // H, v.shape[1] // H
+    rotary_part = q_rope is not None
+    T = v.shape[0]
+    H = q_rope.shape[0] if rotary_part else heads
+    nope = q_nope.shape[1] // H
+    group = H // (k_nope.shape[1] // nope)      # query heads a key head
+    width = v.shape[1] * group // H
+
+    def key_head(h, i):
+        return 0, h if group == 1 else h // group
+
+    rows = pl.BlockSpec((query_tile, nope), lambda h, i: (i, h))
+    keys = pl.BlockSpec((T, nope), key_head)
+    values = pl.BlockSpec((T, width), key_head)
+    if rotary_part:
+        rope = q_rope.shape[2]
+        in_specs = [rows, pl.BlockSpec((None, query_tile, rope),
+                                       lambda h, i: (h, i, 0)),
+                    keys, pl.BlockSpec((T, rope), lambda h, i: (0, 0)),
+                    values]
+        operands = (q_nope, q_rope, k_nope, k_rope, v)
+    else:
+        in_specs, operands = [rows, keys, values], (q_nope, k_nope, v)
     return pl.pallas_call(
         functools.partial(_fused_kernel, query_tile=query_tile,
-                          key_tile=key_tile),
+                          key_tile=key_tile, rotary_part=rotary_part,
+                          span=span),
         grid=(H, T // query_tile),
-        in_specs=[pl.BlockSpec((query_tile, nope), lambda h, i: (i, h)),
-                  pl.BlockSpec((None, query_tile, rope),
-                               lambda h, i: (h, i, 0)),
-                  pl.BlockSpec((T, nope), lambda h, i: (0, h)),
-                  pl.BlockSpec((T, rope), lambda h, i: (0, 0)),
-                  pl.BlockSpec((T, width), lambda h, i: (0, h))],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((query_tile, width), lambda h, i: (i, h)),
         out_shape=jax.ShapeDtypeStruct((T, H * width), v.dtype),
         scratch_shapes=[pltpu.VMEM((query_tile, _LANES), jnp.float32),
@@ -250,7 +383,16 @@ def fused_causal_attention(q_nope, q_rope, k_nope, k_rope, v, *,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=32 * 1024 * 1024),
         name="fused_causal_attention", interpret=interpret,
-    )(q_nope, q_rope, k_nope, k_rope, v)
+    )(*operands)
+
+
+def _fused_fits(operands, window, widths):
+    """What a lowering can see of whether the fused kernel applies: bfloat16
+    operands, a window of whole query tiles that a head's keys and values
+    hold on-chip, head widths of whole lanes."""
+    return (all(a.dtype == jnp.bfloat16 for a in operands)
+            and window % FUSED_QUERY_TILE == 0 and window <= FUSED_MAX_WINDOW
+            and all(width % _LANES == 0 for width in widths))
 
 
 def causal_attention(q_nope, q_rope, k_nope, k_rope, v, block):
@@ -284,12 +426,48 @@ def causal_attention(q_nope, q_rope, k_nope, k_rope, v, block):
         return fused_causal_attention(*operands), jnp.int32(1)
 
     operands = (q_nope, q_rope, k_nope, k_rope, v)
-    fits = (all(a.dtype == jnp.bfloat16 for a in operands)
-            and T % FUSED_QUERY_TILE == 0 and T <= FUSED_MAX_WINDOW
-            and nope % _LANES == 0 and width % _LANES == 0)
-    if not fits:
+    if not _fused_fits(operands, T, (nope, width)):
         return blocked(*operands)
     return lax.platform_dependent(*operands, tpu=fused, default=blocked)
+
+
+def grouped_causal_attention(q, k, v, heads, block, span=None):
+    """One window's causal attention over grouped keys: q (T, H · d) and
+    k (T, G · d) float32 as rotated, the queries carrying the scores' scale,
+    v (T, G · d) in the dtype the products run in; head by head, query head a
+    reads key head a // (H / G). With a ``span`` query t reads keys
+    t − span < j ≤ t. Precision as :func:`causal_attention`'s.
+
+    Returns ``(out (T, H · d) in v's dtype, fused, scored)``: ``fused`` as
+    :func:`causal_attention` returns it — the kernel where the program is
+    lowered for a TPU with bfloat16 values, a window of whole query tiles
+    and a head width of whole lanes, the blocked path everywhere else, with
+    the same span — and ``scored`` () int32 the (query, key) pairs of one
+    head whose scores that path computes (:func:`scored_keys`), out of the
+    same ``lax.platform_dependent``."""
+    T = q.shape[0]
+    width = q.shape[1] // heads
+
+    def blocked(q, k, v):
+        def heads_first(a):
+            return jnp.swapaxes(a.reshape(T, -1, width), 0, 1).astype(
+                v.dtype)
+
+        out = _blocked_attention(heads_first(q), heads_first(k),
+                                 heads_first(v), block, span)
+        return (jnp.swapaxes(out, 0, 1).reshape(T, -1), jnp.int32(0),
+                jnp.int32(scored_keys(T, span, block)))
+
+    def fused(q, k, v):
+        out = fused_causal_attention(q.astype(v.dtype), None,
+                                     k.astype(v.dtype), None, v, heads=heads,
+                                     span=span)
+        return out, jnp.int32(1), jnp.int32(scored_keys(
+            T, span, FUSED_QUERY_TILE, FUSED_KEY_TILE))
+
+    if not _fused_fits((v,), T, (width,)):
+        return blocked(q, k, v)
+    return lax.platform_dependent(q, k, v, tpu=fused, default=blocked)
 
 
 def latent_attention(p, x, c: LatentMoEConfig):
@@ -329,13 +507,15 @@ def gated_mlp(p, x):
 
 
 def route(p, x, c):
-    """Float32 at full precision: (chosen ids (N, k), weights (N, k)). Where
-    the layer holds a selection bias (``"expert_bias"``), the top-k is taken
-    of ``sigmoid + bias`` and the weights are the unbiased sigmoids of the
-    chosen."""
-    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                                    p["router"].astype(jnp.float32),
-                                    precision=lax.Precision.HIGHEST))
+    """Float32 at full precision: (chosen ids (N, k), weights (N, k)). The
+    scores are the logits' sigmoids, each expert by itself, or their soft-max
+    over all published experts (``c.scoring``). Where the layer holds a
+    selection bias (``"expert_bias"``), the top-k is taken of ``scores +
+    bias`` and the weights are the unbiased scores of the chosen."""
+    score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[c.scoring]
+    scores = score(jnp.dot(x.astype(jnp.float32),
+                           p["router"].astype(jnp.float32),
+                           precision=lax.Precision.HIGHEST))
     if "expert_bias" in p:
         _, chosen = lax.top_k(scores + p["expert_bias"].astype(jnp.float32),
                               c.top_k)
@@ -701,25 +881,57 @@ def block(layer, h, c: LatentMoEConfig):
             expert_stats(*told, B, c))
 
 
+# float32 logits (positions × rows of the head) a window's head may hold at
+# once: LFM2's 4,096 × 65,536 are whole at this bound; 16,384 × 98,304 (6.4 GB)
+# go in eight blocks of positions
+HEAD_LOGITS_BYTES = 1 << 30
+
+
 def score_head(params, h, tokens, eps):
     """The scorer's head over windows h (B, T, hidden) float32 after the last
     block: ``pooled``, the mean over positions of the final-norm state, and
     ``logprobs``, ``log p(x[t+1] | x[≤t])`` under the soft-max over the rows
-    of ``head`` (the last 0), a window at a time. An id outside the rows held
-    would be clamped by the lookups: its window's outputs are not a number
-    instead."""
+    of ``head`` (the last 0), a window at a time — its positions in blocks
+    where a window's float32 logits would pass ``HEAD_LOGITS_BYTES``. An id
+    outside the rows held would be clamped by the lookups: its window's
+    outputs are not a number instead."""
     with jax.named_scope("head"):
         x = rms_norm(h, params["final_norm"], eps)
+        T, rows = h.shape[1], params["head"].shape[0]
+        blocks = 1
+        while T * rows * 4 > HEAD_LOGITS_BYTES * blocks and T % (
+                2 * blocks) == 0:
+            blocks *= 2
+
+        def logits_of(part):
+            return jnp.dot(part.astype(params["head"].dtype),
+                           params["head"].T,
+                           preferred_element_type=jnp.float32)
 
         def row_logprobs(args):
             row, ids = args
-            logits = jnp.dot(row.astype(params["head"].dtype),
-                             params["head"].T,
-                             preferred_element_type=jnp.float32)
-            logp = jax.nn.log_softmax(logits, -1)
+            logp = jax.nn.log_softmax(logits_of(row), -1)
             nxt = jnp.take_along_axis(logp[:-1], ids[1:, None], -1)[:, 0]
             return jnp.pad(nxt, (0, 1))
 
+        def row_logprobs_in_blocks(args):
+            """The same, ``blocks`` blocks of positions one after the other:
+            a window's float32 logits are never whole."""
+            row, ids = args
+
+            def block_logprobs(args):
+                part, following = args
+                return jnp.take_along_axis(
+                    jax.nn.log_softmax(logits_of(part), -1), following,
+                    -1)[:, 0]
+
+            nxt = lax.map(block_logprobs, (
+                row.reshape(blocks, -1, row.shape[-1]),
+                jnp.pad(ids[1:], (0, 1)).reshape(blocks, -1, 1)))
+            return nxt.reshape(-1).at[-1].set(0.0)
+
+        if blocks > 1:
+            row_logprobs = row_logprobs_in_blocks
         known = jnp.all((tokens >= 0) & (tokens < params["embed"].shape[0]),
                         1)
         return {"pooled": jnp.where(known[:, None], jnp.mean(x, 1), jnp.nan),

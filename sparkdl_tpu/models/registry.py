@@ -253,6 +253,31 @@ SEQUENCE_MODELS: Dict[str, SequenceConfig] = {
         expert_width=32, experts=16, experts_held=tuple(range(16)), top_k=4,
         vocab=64, scaling=1.0, norm_topk=True, eps=1e-5, theta=1000000.0,
         query_block=8),
+    # huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct config.json
+    # (model_type mellum): the same pre-norm stack, attention in every layer,
+    # three sliding-window layers (span 1,024, plain rotary) to a full one
+    # (YaRN), a soft-max router, no dense layer, the head untied
+    "Mellum2-12B-A2.5B-Instruct": ShortConvMoEConfig(
+        hidden=2304, heads=32, kv_heads=4, head_dim=128, dense_width=7168,
+        expert_width=896, experts=64, experts_held=tuple(range(64)), top_k=8,
+        vocab=98304, scaling=1.0, norm_topk=True, topk_eps=0.0,
+        scoring="softmax", eps=1e-6, theta=500000.0,
+        layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 7,
+        span=1024, rope=(("full_attention", latent_moe.Rope(
+            theta=500000.0, factor=16.0, original=8192, beta_fast=32.0,
+            beta_slow=1.0, amplitude=1.2772588722239782)),)),
+    # ... and its stack at sizes a CPU test runs: two periods, a span shorter
+    # than a test's window
+    "TestSpanMoE": ShortConvMoEConfig(
+        hidden=64, heads=8, kv_heads=2, head_dim=8, dense_width=128,
+        expert_width=32, experts=16, experts_held=tuple(range(16)), top_k=4,
+        vocab=64, scaling=1.0, norm_topk=True, topk_eps=0.0,
+        scoring="softmax", eps=1e-6, theta=100.0,
+        layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 2,
+        span=8, rope=(("full_attention", latent_moe.Rope(
+            theta=100.0, factor=16.0, original=32, beta_fast=4.0,
+            beta_slow=1.0, amplitude=1.2772588722239782)),),
+        query_block=8),
 }
 
 
@@ -266,11 +291,12 @@ def build_sequence_scorer(name, weights: Dict[str, Any], window: int,
     with — ``{"embed", "layers": [...], "final_norm", "head"}``, taken as
     given (bfloat16 on the device for a model of this size; there is no
     ``"random"``). The chip's share is read off them: as many layers as
-    the list has, dense where a layer has ``"mlp"``, for a short-convolution
-    model the mixer by whether a layer has ``"conv"`` or ``"attn"``, the
-    vocabulary slice of ``embed``'s rows; ``experts_held`` names the expert
-    ids the expert layers' stacked weights stand for (default: all of them,
-    where all are there).
+    the list has, dense where a layer has ``"mlp"``, for a pre-norm stack the
+    mixer by whether a layer has ``"conv"`` or ``"attn"``, the vocabulary
+    slice of ``embed``'s rows; ``experts_held`` names the expert ids the
+    expert layers' stacked weights stand for (default: all of them, where
+    all are there). Where the config names its layers' kinds the held layers
+    are its leading ones, each of the kind at its position.
     """
     config = SEQUENCE_MODELS.get(name) if isinstance(name, str) else name
     if type(config) not in _SEQUENCE_FORWARD:
@@ -295,6 +321,13 @@ def build_sequence_scorer(name, weights: Dict[str, Any], window: int,
         share.update(layers=len(layers), dense_layers=dense)
     elif not all(("conv" in layer) != ("attn" in layer) for layer in layers):
         raise ValueError('each layer holds its mixer, "conv" or "attn"')
+    elif config.layer_types and not (
+            len(layers) <= len(config.layer_types)
+            and all("attn" in layer for layer in layers)):
+        raise ValueError(
+            f"the config names the kind of attention of its "
+            f"{len(config.layer_types)} layers; the weights hold "
+            f'{len(layers)}, which must be its leading ones, each "attn"')
     config = dataclasses.replace(config, **share)
     forward = _SEQUENCE_FORWARD[type(config)]
     label = name if isinstance(name, str) else type(config).__name__

@@ -1,37 +1,51 @@
-"""Short-convolution sparse-expert decoder (the ``lfm2_moe`` family) as a
-prefill-only window scorer — the second sequence model of
-``registry.SEQUENCE_MODELS``, run by ``DeepSequenceScorer`` exactly as the
-latent-attention one is.
+"""The pre-norm sparse-expert decoder as a prefill-only window scorer — the
+stack of the ``lfm2_moe`` family (gated short convolutions among grouped-query
+attention layers) and of the ``mellum`` family (grouped-query attention in
+every layer, sliding-window layers among full ones, each kind with its own
+rotary), run by ``DeepSequenceScorer`` exactly as the latent-attention model
+of ``registry.SEQUENCE_MODELS`` is.
 
-A pre-norm stack: ``h ← h + mixer(RMSNorm(h))``, ``h ← h + ffn(RMSNorm(h))``.
-The mixer differs by position — a layer's weights say which it is. A layer
-that holds ``"conv"`` runs the **gated short convolution**: one projection to
-three parts ``[B ; C ; x̃]``, a depthwise causal convolution of a few taps
-over ``B ⊙ x̃`` (zeros before the window: nothing outlives a launch), the
-gate ``C``, an output projection. A layer that holds ``"attn"`` runs
-**grouped-query attention**: fewer key heads than query heads, an RMSNorm per
-head on queries and keys, rotary on both; query head ``a`` reads key head
+``h ← h + mixer(RMSNorm(h))``, ``h ← h + ffn(RMSNorm(h))``. The mixer differs
+by position. A layer that holds ``"conv"`` runs the **gated short
+convolution**: one projection to three parts ``[B ; C ; x̃]``, a depthwise
+causal convolution of a few taps over ``B ⊙ x̃`` (zeros before the window:
+nothing outlives a launch), the gate ``C``, an output projection. A layer
+that holds ``"attn"`` runs **grouped-query attention**: fewer key heads than
+query heads, an RMSNorm per head on queries and keys where the weights hold
+one (``"q_norm"``), rotary on both; query head ``a`` reads key head
 ``a // (heads / kv_heads)`` and keys and values are never repeated per query
-head. The ffn is a gated MLP where the layer holds ``"mlp"``, else the sparse
-expert layer — the one the latent-attention model runs
-(``latent_moe.routed_experts``), here without a shared expert, with a
-selection bias in the router, and usually held whole.
+head. What the weights cannot say — a number is no array — comes from the
+config by position, the held layers being the leading ones: where it names
+its layers' kinds (``layer_types``), a ``"sliding_attention"`` layer's query
+reads the last ``span`` keys only, its own among them, and a kind's rotary
+is the one ``rope`` gives it (``latent_moe.Rope``: plain, or YaRN's scaled
+frequencies and amplitude). The ffn is a gated MLP where the layer holds
+``"mlp"``, else the sparse expert layer — the one the latent-attention model
+runs (``latent_moe.routed_experts``), here without a shared expert, its
+router's scores sigmoids with a selection bias or a soft-max
+(``scoring``), and usually held whole.
 
 Shared with ``models/latent_moe.py`` and imported from it: ``rms_norm``, the
-mixed-precision product, ``rotary``, the blocked causal soft-max, ``gated_mlp``,
-``route`` / ``routed_experts`` / ``expert_stats``, and the scorer's head and
-counts (``score_head``, ``expert_outputs``). Precision follows the weights, as
+mixed-precision product, ``rotary``, causal attention over grouped keys with
+its two paths (``grouped_causal_attention``), ``gated_mlp``, ``route`` /
+``routed_experts`` / ``expert_stats``, and the scorer's head and counts
+(``score_head``, ``expert_outputs``). Precision follows the weights, as
 there: bfloat16 products with float32 accumulation; the router, the norms'
-statistics, the soft-max, the gates and taps of the convolution, the residual
-stream and the log-probabilities float32.
+statistics, the soft-max, the rotary tables, the gates and taps of the
+convolution, the residual stream and the log-probabilities float32.
 
-At a head width of 64 — half a lane group — the fused attention kernel of
-``latent_moe`` does not apply (whole lanes, one rotary key head): attention
-goes down the blocked path, and ``sparkdl.sequence.fused_attention_layers``
-reads 0. The expert layers' grouped products do go down ``latent_moe``'s
-grouped-product kernel at the published widths
-(``sparkdl.moe.fused_product_layers``). Outputs per window are
-``latent_moe``'s; the program's counts gain ``sparkdl.sequence.conv_layers``.
+Attention goes down ``latent_moe``'s fused kernel where a lowering for a TPU
+finds bfloat16 weights, a window of whole query tiles and a head width of
+whole lanes — 128: every layer of the ``mellum`` family, a sliding layer
+visiting only the key tiles its span reaches — and down the blocked path
+everywhere else, with the same span: at a head width of 64, half a lane
+group, always (``sparkdl.sequence.fused_attention_layers`` reads 0). The
+expert layers' grouped products go down ``latent_moe``'s grouped-product
+kernel at the published widths (``sparkdl.moe.fused_product_layers``).
+Outputs per window are ``latent_moe``'s; the program's counts gain
+``sparkdl.sequence.conv_layers`` and, from a stack that names its layers'
+kinds, ``sparkdl.sequence.window_attention_layers`` and
+``sparkdl.sequence.scored_keys``.
 """
 
 from __future__ import annotations
@@ -46,15 +60,16 @@ from jax import lax
 
 from sparkdl_tpu.core import telemetry
 from sparkdl_tpu.models.latent_moe import (
-    _blocked_attention, _dot, expert_outputs, expert_stats, gated_mlp,
-    rms_norm, rotary, routed_experts, score_head)
+    Rope, _dot, expert_outputs, expert_stats, gated_mlp,
+    grouped_causal_attention, rms_norm, rotary, routed_experts, score_head)
 
 
 @dataclass(frozen=True)
 class ShortConvMoEConfig:
     """Widths as published; ``experts_held`` and ``vocab`` are what this chip
     holds of a stated deployment. How many layers there are, and which mixer
-    and ffn each has, is read off the weights."""
+    and ffn each has, is read off the weights; the kind of an attention
+    layer, where a model has several, by position off ``layer_types``."""
 
     hidden: int
     heads: int
@@ -69,8 +84,16 @@ class ShortConvMoEConfig:
     scaling: float = 1.0
     norm_topk: bool = True
     topk_eps: float = 1e-6    # added to the chosen scores' sum before dividing
+    scoring: str = "sigmoid"  # the router's scores: "sigmoid" or "softmax"
     eps: float = 1e-5
     theta: float = 1000000.0
+    # the published kind of every layer by position ("sliding_attention",
+    # "full_attention"), where attention layers differ by kind; the keys a
+    # sliding layer's query reads; and the kinds whose rotary is not the
+    # plain one of ``theta``, each with its own
+    layer_types: Tuple[str, ...] = ()
+    span: int = 0
+    rope: Tuple[Tuple[str, Rope], ...] = ()
     capacity_factor: float = 2.0    # see LatentMoEConfig
     query_block: int = 512
 
@@ -89,69 +112,98 @@ def short_conv(p, u):
     return _dot(gate_out * c, p["out"])
 
 
-def grouped_attention(p, u, c: ShortConvMoEConfig):
-    """u (T, hidden) float32, one window → (T, hidden) float32. Queries and
-    keys are normed per head (one gain of ``head_dim`` each) and rotated in
-    float32, the queries carry the scores' scale, and the operands go to the
-    blocked soft-max in the weights' dtype, the keys and values once a key
-    head."""
+def grouped_attention(p, u, c: ShortConvMoEConfig, kind=None):
+    """u (T, hidden) float32, one window → ``((T, hidden) float32, fused,
+    scored)``, the last two as ``latent_moe.grouped_causal_attention``
+    returns them. Queries and keys are normed per head where the weights
+    hold the gains (one of ``head_dim`` each) and rotated in float32 — by the
+    rotary and, for ``"sliding_attention"``, within the span that ``c`` gives
+    the layer's ``kind`` — the queries carry the scores' scale, and the
+    operands go to the attention in the weights' dtype, the keys and values
+    once a key head."""
     T = u.shape[0]
     act = p["out"].dtype
 
     def heads(a):                           # (T, n · d) → (T, n, d)
         return a.reshape(T, -1, c.head_dim)
 
-    def heads_first(a):
-        return jnp.swapaxes(heads(a), 0, 1).astype(act)
+    def projected(name):
+        a = heads(_dot(u, p[name]))
+        gain = p.get(name + "_norm")
+        return a if gain is None else rms_norm(a, gain, c.eps)
 
-    q = rms_norm(heads(_dot(u, p["q"])), p["q_norm"], c.eps) \
-        * c.head_dim ** -0.5
-    k = rms_norm(heads(_dot(u, p["k"])), p["k_norm"], c.eps)
-    out = _blocked_attention(
-        heads_first(rotary(q.reshape(T, -1), c.theta, c.heads)),
-        heads_first(rotary(k.reshape(T, -1), c.theta, c.kv_heads)),
-        heads_first(_dot(u, p["v"], act)), c.query_block)
-    return _dot(jnp.swapaxes(out, 0, 1).reshape(T, -1), p["out"])
+    q = projected("q") * c.head_dim ** -0.5
+    k = projected("k")
+    rope = dict(c.rope).get(kind)       # None: the plain rotary of c.theta
+    turn = {} if rope is None else {
+        "frequencies": rope.frequencies(c.head_dim // 2),
+        "amplitude": rope.amplitude}
+    out, fused, scored = grouped_causal_attention(
+        rotary(q.reshape(T, -1), c.theta, c.heads, **turn),
+        rotary(k.reshape(T, -1), c.theta, c.kv_heads, **turn),
+        _dot(u, p["v"], act), c.heads, c.query_block,
+        c.span if kind == "sliding_attention" else None)
+    return _dot(out, p["out"]), fused, scored
 
 
-@functools.partial(jax.jit, static_argnames="c")
-def block(layer, h, c: ShortConvMoEConfig):
-    """One pre-norm block over windows h (B, T, hidden) float32. Returns
-    ``(h, stats)``; ``stats`` is None for a dense layer. Jitted, as
+@functools.partial(jax.jit, static_argnames=("c", "kind"))
+def block(layer, h, c: ShortConvMoEConfig, kind=None):
+    """One pre-norm block over windows h (B, T, hidden) float32; ``kind`` the
+    layer's published kind where ``c`` names its layers' (else the weights
+    say which mixer it is). Returns ``(h, stats, told)``: ``stats`` is None
+    for a dense layer; ``told`` what a layer of a named kind tells of each
+    window's attention — ``"fused"`` and ``"scored_keys"`` (B,) int32 as
+    ``grouped_attention`` returns them — and empty otherwise. Jitted, as
     ``latent_moe.block`` is: the stack's layers of one kind are traced and
     lowered once."""
     B, T, _ = h.shape
     u = rms_norm(h, layer["operator_norm"], c.eps)
+    told = {}
     if "conv" in layer:
         with jax.named_scope("short_conv"):
             h = h + short_conv(layer["conv"], u)
     else:
-        with jax.named_scope("grouped_attention"):
-            h = h + lax.map(
-                lambda row: grouped_attention(layer["attn"], row, c), u)
+        with jax.named_scope("sliding_attention" if kind == "sliding_attention"
+                             else "grouped_attention"):
+            attended, fused, scored = lax.map(
+                lambda row: grouped_attention(layer["attn"], row, c, kind), u)
+        h = h + attended
+        if kind is not None:
+            told = {"fused": fused, "scored_keys": scored}
     x = rms_norm(h, layer["ffn_norm"], c.eps)
     if "moe" not in layer:
-        return h + lax.map(lambda row: gated_mlp(layer["mlp"], row), x), None
+        return (h + lax.map(lambda row: gated_mlp(layer["mlp"], row), x),
+                None, told)
     with jax.named_scope("routed_experts"):
-        routed, *told = routed_experts(layer["moe"], x.reshape(B * T, -1), c)
-    return h + routed.reshape(B, T, -1), expert_stats(*told, B, c)
+        routed, *stats = routed_experts(layer["moe"], x.reshape(B * T, -1), c)
+    return h + routed.reshape(B, T, -1), expert_stats(*stats, B, c), told
 
 
 def forward(params, tokens, c: ShortConvMoEConfig) -> Dict[str, Any]:
     """tokens (B, T) int32 ids → the outputs of ``latent_moe``'s docstring."""
     h = params["embed"][tokens].astype(jnp.float32)
-    stats = []
-    for layer in params["layers"]:
-        h, layer_stats = block(layer, h, c)
+    layers = params["layers"]
+    kinds = c.layer_types[:len(layers)] or (None,) * len(layers)
+    stats, told = [], []
+    for layer, kind in zip(layers, kinds):
+        h, layer_stats, layer_told = block(layer, h, c, kind)
         if layer_stats is not None:
             stats.append(layer_stats)
+        if layer_told:
+            told.append(layer_told)
     out = score_head(params, h, tokens, c.eps)
     if stats:
         rows = tokens.shape[0]
-        out.update(expert_outputs(stats, tokens, {
-            telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS: jnp.zeros(
-                (rows,), jnp.int32),
+        counts = {
+            telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS: sum(
+                (t["fused"] for t in told), jnp.zeros((rows,), jnp.int32)),
             telemetry.M_SEQUENCE_CONV_LAYERS: jnp.full(
-                (rows,), sum("conv" in layer for layer in params["layers"]),
-                jnp.int32)}))
+                (rows,), sum("conv" in layer for layer in layers),
+                jnp.int32)}
+        if c.layer_types:
+            counts[telemetry.M_SEQUENCE_WINDOW_ATTENTION_LAYERS] = jnp.full(
+                (rows,), kinds.count("sliding_attention"), jnp.int32)
+            counts[telemetry.M_SEQUENCE_SCORED_KEYS] = sum(
+                t["scored_keys"] for t in told)
+        out.update(expert_outputs(stats, tokens, counts))
     return out

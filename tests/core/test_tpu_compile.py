@@ -1,8 +1,10 @@
 """The product's own programs compile for the TPU v5e at the main path's
 real shapes: the model zoo's convolution units (``models/layers.py``),
 ``ModelFunction.resized()``'s cast-and-resize prologue, the sequence
-scorer's latent attention (``models/latent_moe.py``), and the second sequence
-model's mixers and the expert layer held whole (``models/shortconv_moe.py``).
+scorer's latent attention (``models/latent_moe.py``), the second sequence
+model's mixers and the expert layer held whole (``models/shortconv_moe.py``),
+and the third's attention of both kinds, its expert layer and its head at a
+window of 16,384 (the same module).
 
 These are the only tier-1 tests that hand the product's code to the TPU's
 compiler: each program is compiled ahead of time for a DESCRIBED
@@ -15,8 +17,10 @@ a window's float32 scores out of HBM (PERF.md §6, PR 36); the
 short-convolution model's mixers hold none (its head width of 64 is half a
 lane group: the blocked path), and the expert layer of either sequence model
 holds the grouped-product kernel twice, gate and up in one pass and down
-(PERF.md §6, PR 38), in place of three ``lax.ragged_dot``. A PR that ships or
-drops a kernel changes the assertion where it belongs.
+(PERF.md §6, PR 38), in place of three ``lax.ragged_dot``; at a head width of
+128 the pre-norm stack's grouped-query attention holds the fused kernel too,
+with a span or without (PR 39). A PR that ships or drops a kernel changes the
+assertion where it belongs.
 
 The topology is described inside a fixture of this file and nowhere else:
 only one process at a time may load the TPU's library, and under
@@ -255,3 +259,70 @@ def test_a_share_of_the_experts_compiles_to_the_grouped_kernel_for_v5e(
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "grouped_product" in text and "ragged-dot" not in text
     assert "f32[16384,2048]" not in text
+
+
+@pytest.mark.parametrize("part", ["sliding_attention", "full_attention",
+                                  "routed_experts", "head"])
+def test_span_model_compiles_for_v5e(part, one_chip, no_persistent_cache):
+    """Mellum2-12B-A2.5B-Instruct's parts at the published widths and the
+    cell's launch (one window of 16,384), bfloat16 weights, shapes only: one
+    window's grouped-query attention of each kind, which is the fused kernel
+    on grouped keys (32 query heads on 4 key heads of 128) — within its
+    budget of on-chip memory with a head's 16,384 keys and values resident —
+    and builds no scores in HBM; the expert layer held whole (64 experts, a
+    buffer of the 131,072 pairs, the grouped kernel twice at 2,304 × 896);
+    the head, whose float32 logits go in blocks of positions."""
+    c = registry.SEQUENCE_MODELS["Mellum2-12B-A2.5B-Instruct"]
+    window = 16384
+    assert window == latent_moe.FUSED_MAX_WINDOW
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if part.endswith("attention"):
+        wide, narrow = c.heads * c.head_dim, c.kv_heads * c.head_dim
+        p = {"q": on_chip((c.hidden, wide)), "k": on_chip((c.hidden, narrow)),
+             "v": on_chip((c.hidden, narrow)),
+             "out": on_chip((wide, c.hidden))}
+
+        def fn(p, x):
+            return shortconv_moe.grouped_attention(p, x, c, part)
+
+        x = on_chip((window, c.hidden), jnp.float32)
+    elif part == "routed_experts":
+        p = {"router": on_chip((c.hidden, c.experts)),
+             "experts": {
+                 "gate": on_chip((c.experts, c.hidden, c.expert_width)),
+                 "up": on_chip((c.experts, c.hidden, c.expert_width)),
+                 "down": on_chip((c.experts, c.expert_width, c.hidden))}}
+
+        def fn(p, x):
+            return latent_moe.routed_experts(p, x, c)
+
+        x = on_chip((window, c.hidden), jnp.float32)
+    else:
+        p = {"embed": on_chip((c.vocab, c.hidden)),
+             "head": on_chip((c.vocab, c.hidden)),
+             "final_norm": on_chip((c.hidden,))}
+
+        def fn(p, x):
+            return latent_moe.score_head(
+                p, x, jnp.zeros((1, window), jnp.int32), c.eps)
+
+        x = on_chip((1, window, c.hidden), jnp.float32)
+    text = jax.jit(fn).lower(p, x).compile().as_text()
+    assert ":T(" in text  # tiled layouts: the TPU's compiler made this
+    if part.endswith("attention"):
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        assert "fused_causal_attention" in text
+        # no scores of a key head's 8 query heads × a block of queries
+        assert "f32[4,8,512," not in text
+    elif part == "routed_experts":
+        assert latent_moe.buffer_capacity(window, c) == 131072
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+        assert "grouped_product" in text and "ragged-dot" not in text
+        assert "f32[131072,896]" not in text
+    else:
+        assert "tpu_custom_call" not in text
+        # eight blocks of 2,048 positions, never a window's 6.4 GB of logits
+        assert "f32[2048,98304]" in text and "f32[16384,98304]" not in text

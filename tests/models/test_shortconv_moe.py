@@ -133,9 +133,13 @@ def test_grouped_attention_alone_matches_reference(key):
     x = jax.random.normal(jax.random.PRNGKey(6), (WINDOW, s.hidden))
     with jax.default_matmul_precision("highest"):
         want = ref.attention(p, x, s, identity, block=5)
-        got = shortconv_moe.grouped_attention(p, x, MODEL)
-        moved = shortconv_moe.grouped_attention(p, x.at[-1].add(1.0), MODEL)
+        got, fused, scored = shortconv_moe.grouped_attention(p, x, MODEL)
+        moved, _, _ = shortconv_moe.grouped_attention(p, x.at[-1].add(1.0),
+                                                      MODEL)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    # a head width of 8 on a CPU: the blocked path, blocks of 8 queries each
+    # against its causal prefix
+    assert fused == 0 and scored == 8 * (8 + 16 + 24)
     # causal: a later token does not move an earlier one
     np.testing.assert_allclose(moved[:-1], got[:-1], rtol=1e-5, atol=1e-6)
 
