@@ -46,7 +46,9 @@ float32 accumulators, so neither float32 result reaches HBM, then down; three
 lowered for a TPU — a chip, or an ahead-of-time compile for a described one —
 with bfloat16 operands and shapes of whole tiles (attention: a window of
 whole query tiles, no longer than a head's keys and values fit on-chip, and
-head widths of whole lanes; the products: a buffer of
+head widths of whole lanes — or, on grouped keys, of half a lane group, 64,
+where a grid step works a key head's query heads together, their rows
+stacked; the products: a buffer of
 whole row tiles and widths of whole lanes); a CPU run, float32 weights or
 other shapes lower XLA's path. No option chooses, and
 ``jax.experimental.pallas`` is imported where a kernel is built, not with this
@@ -253,17 +255,36 @@ def scored_keys(window, span, query_tile, key_tile=None):
 FUSED_QUERY_TILE = 1024
 FUSED_KEY_TILE = 512
 FUSED_MAX_WINDOW = 16384
+# At a head width of half a lane group a grid step works the query heads of
+# one key head together, their blocks of queries stacked to one operand of
+# rows: the tiles of one head's block, and the most rows a step may stack.
+# Chosen from chip runs at 32 query heads on 8 key heads of 64 × 4,096
+# positions (PERF.md §6, PR 40; one window's call with XLA's transpositions
+# in and out, ms by query tile × key tile): 512 × 256 1.20, 512 × 512 1.30,
+# 512 × 128 1.54, 256 × 256 1.36, 256 × 128 1.86, 1,024 × 512 1.27 and
+# 1,024 × 256 1.14 — the last stacks 4,096 rows, compiles five times as long
+# and is refused for want of on-chip memory with key tiles of 512 at 16,384
+# positions, for 0.06 ms — against 4.60 on the blocked path. The kernel
+# alone is 0.98 ms of the 1.20, 70 TFLOP/s over the causal pairs where half
+# the MXU (a contraction of 64, 64 output columns) is 98.
+FUSED_NARROW_QUERY_TILE = 512
+FUSED_NARROW_KEY_TILE = 256
+FUSED_STACKED_ROWS = 2048
 _LANES = 128
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def _fused_kernel(*refs, query_tile, key_tile, rotary_part, span):
+def _fused_kernel(*refs, query_tile, key_tile, rotary_part, span, stack=1):
     """One head's block of ``query_tile`` queries against its causal prefix,
     a tile of ``key_tile`` keys at a time: the scores, their running maximum
     and sum (kept across all 128 lanes, so no step re-lays them out) and the
     weighted values never leave on-chip memory. With a ``span`` the prefix
     begins at the tile that holds the first key of the block's first query's
-    span, and every visited tile is masked to the band."""
+    span, and every visited tile is masked to the band. With a ``stack`` the
+    rows are that many heads' blocks of the same queries one after the other
+    — the query heads of one key head: both products, the maximum and the sum
+    run over all of them at once, and a row's place among the queries is its
+    place in its own head's block."""
     from jax.experimental import pallas as pl
     if rotary_part:
         qn_ref, qr_ref, kn_ref, kr_ref, v_ref = refs[:5]
@@ -276,42 +297,60 @@ def _fused_kernel(*refs, query_tile, key_tile, rotary_part, span):
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
     transposed = (((1,), (1,)), ((), ()))
 
+    def across(stat, width):
+        """A statistic held across the 128 lanes, ``width`` lanes wide."""
+        if width % _LANES:
+            return stat[:, :width]
+        return jnp.tile(stat, (1, width // _LANES))
+
     def step(lo, start, diagonal):
-        """Queries [lo, query_tile) of the block against the key tile at
-        ``start``; ``diagonal``: the tile begins at query ``lo``'s position."""
-        rows = slice(lo, query_tile)
+        """Queries [lo, query_tile) of the block — of every stacked head's,
+        as one run of rows where ``lo`` is 0 and a run a head otherwise —
+        against the key tile at ``start``; ``diagonal``: the tile begins at
+        query ``lo``'s position."""
+        runs = [slice(0, stack * query_tile)] if lo == 0 else [
+            slice(head * query_tile + lo, (head + 1) * query_tile)
+            for head in range(stack)]
         keys = pl.ds(pl.multiple_of(start, key_tile), key_tile)
-        scores = lax.dot_general(
-            qn_ref[rows, :], kn_ref[keys, :], transposed,
-            preferred_element_type=jnp.float32)
-        if rotary_part:
-            scores = scores + lax.dot_general(
-                qr_ref[rows, :], kr_ref[keys, :], transposed,
+        for rows in runs:
+            scores = lax.dot_general(
+                qn_ref[rows, :], kn_ref[keys, :], transposed,
                 preferred_element_type=jnp.float32)
-        if diagonal:
-            scores = jnp.where(
-                lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-                <= lax.broadcasted_iota(jnp.int32, scores.shape, 0),
-                scores, _MASKED)
-        if span is not None:
-            # a key is read where key − query > −span; by their places in the
-            # tile, whose first key and query lie start − (first + lo) apart
-            scores = jnp.where(
-                lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-                - lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-                > first + lo - start - span, scores, _MASKED)
-        before = max_ref[rows, :]
-        highest = jnp.maximum(before, jnp.max(scores, -1, keepdims=True))
-        weights = jnp.exp(scores - jnp.tile(highest, (1, key_tile // _LANES)))
-        decay = jnp.exp(before - highest)
-        max_ref[rows, :] = highest
-        sum_ref[rows, :] = decay * sum_ref[rows, :] + jnp.sum(
-            weights, -1, keepdims=True)
-        values = v_ref[keys, :]
-        acc_ref[rows, :] = jnp.tile(
-            decay, (1, values.shape[1] // _LANES)) * acc_ref[rows, :] + jnp.dot(
-            weights.astype(values.dtype), values,
-            preferred_element_type=jnp.float32)
+            if rotary_part:
+                scores = scores + lax.dot_general(
+                    qr_ref[rows, :], kr_ref[keys, :], transposed,
+                    preferred_element_type=jnp.float32)
+
+            def query():
+                """Each row's place among the queries from ``lo`` on."""
+                place = lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+                if stack > 1 and lo == 0:   # its place in its own head
+                    place = lax.rem(place, query_tile)
+                return place
+
+            if diagonal:
+                scores = jnp.where(
+                    lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+                    <= query(), scores, _MASKED)
+            if span is not None:
+                # a key is read where key − query > −span; by their places in
+                # the tile, whose first key and query lie start − (first + lo)
+                # apart
+                scores = jnp.where(
+                    lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+                    - query() > first + lo - start - span, scores, _MASKED)
+            before = max_ref[rows, :]
+            highest = jnp.maximum(before, jnp.max(scores, -1, keepdims=True))
+            weights = jnp.exp(scores - across(highest, key_tile))
+            decay = jnp.exp(before - highest)
+            max_ref[rows, :] = highest
+            sum_ref[rows, :] = decay * sum_ref[rows, :] + jnp.sum(
+                weights, -1, keepdims=True)
+            values = v_ref[keys, :]
+            acc_ref[rows, :] = across(
+                decay, values.shape[1]) * acc_ref[rows, :] + jnp.dot(
+                weights.astype(values.dtype), values,
+                preferred_element_type=jnp.float32)
 
     earliest = 0 if span is None else jnp.maximum(
         first - span + 1, 0) // key_tile
@@ -319,14 +358,13 @@ def _fused_kernel(*refs, query_tile, key_tile, rotary_part, span):
                   lambda tile, _: step(0, tile * key_tile, False), None)
     for lo in range(0, query_tile, key_tile):
         step(lo, first + lo, True)
-    out_ref[...] = (acc_ref[...] * jnp.tile(
-        1.0 / sum_ref[...], (1, acc_ref.shape[1] // _LANES))
-        ).astype(out_ref.dtype)
+    out_ref[...] = (acc_ref[...] * across(
+        1.0 / sum_ref[...], acc_ref.shape[1])).astype(out_ref.dtype)
 
 
 def fused_causal_attention(q_nope, q_rope, k_nope, k_rope, v, *, heads=None,
-                           span=None, query_tile=FUSED_QUERY_TILE,
-                           key_tile=FUSED_KEY_TILE, interpret=False):
+                           span=None, query_tile=None, key_tile=None,
+                           interpret=False):
     """:func:`causal_attention`'s contract as one Pallas TPU kernel (an online
     soft-max): only q, k, v and the output cross HBM, each once, in the layout
     the projections leave them in. The two parts of the scores are two
@@ -341,9 +379,19 @@ def fused_causal_attention(q_nope, q_rope, k_nope, k_rope, v, *, heads=None,
     read it, which follow each other on the grid. With a ``span`` only the
     key tiles that hold a key of some query's span are visited.
 
+    **At a head width under the 128 lanes** (64; no rotary part) that block
+    cannot be cut out of the flat rows either, so all three operands are
+    turned heads first on the way in and the output back on the way out
+    (XLA's transpositions, as the blocked path makes them), and a grid step
+    is (key head, block of queries): the ``H / G`` query heads that read the
+    key head are worked together, their blocks stacked to ``H / G ×
+    query_tile`` rows — the MXU sees that many rows in both products and a
+    key tile and a value tile are read once for all of them.
+
     The window is a multiple of ``query_tile``, that of ``key_tile``, and
-    ``key_tile`` and the widths ``nope`` and ``v`` are multiples of the 128
-    lanes."""
+    ``key_tile`` is a multiple of the 128 lanes; the widths ``nope`` and
+    ``v`` are multiples of them or, both, less. The tiles are those chosen on
+    the chip for the width unless given."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     rotary_part = q_rope is not None
@@ -352,47 +400,89 @@ def fused_causal_attention(q_nope, q_rope, k_nope, k_rope, v, *, heads=None,
     nope = q_nope.shape[1] // H
     group = H // (k_nope.shape[1] // nope)      # query heads a key head
     width = v.shape[1] * group // H
+    narrow = nope % _LANES != 0
+    if query_tile is None:
+        query_tile = FUSED_NARROW_QUERY_TILE if narrow else FUSED_QUERY_TILE
+    if key_tile is None:
+        key_tile = FUSED_NARROW_KEY_TILE if narrow else FUSED_KEY_TILE
+    stack = group if narrow else 1
+    blocks = T // query_tile
 
     def key_head(h, i):
         return 0, h if group == 1 else h // group
 
-    rows = pl.BlockSpec((query_tile, nope), lambda h, i: (i, h))
-    keys = pl.BlockSpec((T, nope), key_head)
-    values = pl.BlockSpec((T, width), key_head)
-    if rotary_part:
-        rope = q_rope.shape[2]
-        in_specs = [rows, pl.BlockSpec((None, query_tile, rope),
-                                       lambda h, i: (h, i, 0)),
-                    keys, pl.BlockSpec((T, rope), lambda h, i: (0, 0)),
-                    values]
-        operands = (q_nope, q_rope, k_nope, k_rope, v)
+    if narrow:
+        # heads first: (key head, block of queries, the stacked rows, width)
+        def heads_first(a):
+            return jnp.swapaxes(a.reshape(T, H // stack, -1), 0, 1)
+
+        stacked = jnp.transpose(
+            q_nope.reshape(blocks, query_tile, H // stack, stack, nope),
+            (2, 0, 3, 1, 4)).reshape(-1, blocks, stack * query_tile, nope)
+        operands = (stacked, heads_first(k_nope), heads_first(v))
+        in_specs = [pl.BlockSpec((None, None, stack * query_tile, nope),
+                                 lambda g, i: (g, i, 0, 0)),
+                    pl.BlockSpec((None, T, nope), lambda g, i: (g, 0, 0)),
+                    pl.BlockSpec((None, T, width), lambda g, i: (g, 0, 0))]
+        out_specs = pl.BlockSpec((None, None, stack * query_tile, width),
+                                 lambda g, i: (g, i, 0, 0))
+        out_shape = (H // stack, blocks, stack * query_tile, width)
     else:
-        in_specs, operands = [rows, keys, values], (q_nope, k_nope, v)
-    return pl.pallas_call(
+        rows = pl.BlockSpec((query_tile, nope), lambda h, i: (i, h))
+        keys = pl.BlockSpec((T, nope), key_head)
+        values = pl.BlockSpec((T, width), key_head)
+        if rotary_part:
+            rope = q_rope.shape[2]
+            in_specs = [rows, pl.BlockSpec((None, query_tile, rope),
+                                           lambda h, i: (h, i, 0)),
+                        keys, pl.BlockSpec((T, rope), lambda h, i: (0, 0)),
+                        values]
+            operands = (q_nope, q_rope, k_nope, k_rope, v)
+        else:
+            in_specs, operands = [rows, keys, values], (q_nope, k_nope, v)
+        out_specs = pl.BlockSpec((query_tile, width), lambda h, i: (i, h))
+        out_shape = (T, H * width)
+    out = pl.pallas_call(
         functools.partial(_fused_kernel, query_tile=query_tile,
                           key_tile=key_tile, rotary_part=rotary_part,
-                          span=span),
-        grid=(H, T // query_tile),
+                          span=span, stack=stack),
+        grid=(H // stack, blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((query_tile, width), lambda h, i: (i, h)),
-        out_shape=jax.ShapeDtypeStruct((T, H * width), v.dtype),
-        scratch_shapes=[pltpu.VMEM((query_tile, _LANES), jnp.float32),
-                        pltpu.VMEM((query_tile, _LANES), jnp.float32),
-                        pltpu.VMEM((query_tile, width), jnp.float32)],
+        out_specs=out_specs,
+        out_shape=jax.ShapeDtypeStruct(out_shape, v.dtype),
+        scratch_shapes=[pltpu.VMEM((stack * query_tile, _LANES), jnp.float32),
+                        pltpu.VMEM((stack * query_tile, _LANES), jnp.float32),
+                        pltpu.VMEM((stack * query_tile, width), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=32 * 1024 * 1024),
         name="fused_causal_attention", interpret=interpret,
     )(*operands)
+    if narrow:      # back to the flat rows, head by head
+        out = jnp.transpose(
+            out.reshape(-1, blocks, stack, query_tile, width),
+            (1, 3, 0, 2, 4)).reshape(T, H * width)
+    return out
 
 
-def _fused_fits(operands, window, widths):
-    """What a lowering can see of whether the fused kernel applies: bfloat16
-    operands, a window of whole query tiles that a head's keys and values
-    hold on-chip, head widths of whole lanes."""
-    return (all(a.dtype == jnp.bfloat16 for a in operands)
-            and window % FUSED_QUERY_TILE == 0 and window <= FUSED_MAX_WINDOW
-            and all(width % _LANES == 0 for width in widths))
+def _fused_tiles(operands, window, widths, group=1):
+    """What a lowering can see of whether the fused kernel applies, as the
+    kernel's ``(query_tile, key_tile)`` or None: bfloat16 operands; head
+    widths of whole lanes, or all of half a lane group with the query heads
+    of a key head few enough to stack; a window of whole query tiles that a
+    head's keys and values hold on-chip."""
+    if any(a.dtype != jnp.bfloat16 for a in operands):
+        return None
+    if all(width % _LANES == 0 for width in widths):
+        tiles = FUSED_QUERY_TILE, FUSED_KEY_TILE
+    elif (all(2 * width == _LANES for width in widths)
+          and group * FUSED_NARROW_QUERY_TILE <= FUSED_STACKED_ROWS):
+        tiles = FUSED_NARROW_QUERY_TILE, FUSED_NARROW_KEY_TILE
+    else:
+        return None
+    if window % tiles[0] or window > FUSED_MAX_WINDOW:
+        return None
+    return tiles
 
 
 def causal_attention(q_nope, q_rope, k_nope, k_rope, v, block):
@@ -426,7 +516,7 @@ def causal_attention(q_nope, q_rope, k_nope, k_rope, v, block):
         return fused_causal_attention(*operands), jnp.int32(1)
 
     operands = (q_nope, q_rope, k_nope, k_rope, v)
-    if not _fused_fits(operands, T, (nope, width)):
+    if _fused_tiles(operands, T, (nope, width)) is None:
         return blocked(*operands)
     return lax.platform_dependent(*operands, tpu=fused, default=blocked)
 
@@ -441,10 +531,12 @@ def grouped_causal_attention(q, k, v, heads, block, span=None):
     Returns ``(out (T, H · d) in v's dtype, fused, scored)``: ``fused`` as
     :func:`causal_attention` returns it — the kernel where the program is
     lowered for a TPU with bfloat16 values, a window of whole query tiles
-    and a head width of whole lanes, the blocked path everywhere else, with
-    the same span — and ``scored`` () int32 the (query, key) pairs of one
-    head whose scores that path computes (:func:`scored_keys`), out of the
-    same ``lax.platform_dependent``."""
+    and a head width of whole lanes, or of 64 with no more query heads a key
+    head than a grid step stacks (:func:`_fused_tiles`); the blocked path
+    everywhere else; either with the same span — and ``scored`` () int32 the
+    (query, key) pairs of one head whose scores that path computes
+    (:func:`scored_keys`, of the tiles the kernel takes at that width), out
+    of the same ``lax.platform_dependent``."""
     T = q.shape[0]
     width = q.shape[1] // heads
 
@@ -462,10 +554,10 @@ def grouped_causal_attention(q, k, v, heads, block, span=None):
         out = fused_causal_attention(q.astype(v.dtype), None,
                                      k.astype(v.dtype), None, v, heads=heads,
                                      span=span)
-        return out, jnp.int32(1), jnp.int32(scored_keys(
-            T, span, FUSED_QUERY_TILE, FUSED_KEY_TILE))
+        return out, jnp.int32(1), jnp.int32(scored_keys(T, span, *tiles))
 
-    if not _fused_fits((v,), T, (width,)):
+    tiles = _fused_tiles((v,), T, (width,), heads * width // k.shape[1])
+    if tiles is None:
         return blocked(q, k, v)
     return lax.platform_dependent(q, k, v, tpu=fused, default=blocked)
 

@@ -35,11 +35,13 @@ statistics, the soft-max, the rotary tables, the gates and taps of the
 convolution, the residual stream and the log-probabilities float32.
 
 Attention goes down ``latent_moe``'s fused kernel where a lowering for a TPU
-finds bfloat16 weights, a window of whole query tiles and a head width of
-whole lanes — 128: every layer of the ``mellum`` family, a sliding layer
-visiting only the key tiles its span reaches — and down the blocked path
-everywhere else, with the same span: at a head width of 64, half a lane
-group, always (``sparkdl.sequence.fused_attention_layers`` reads 0). The
+finds bfloat16 weights, a window of whole query tiles and a head width the
+kernel takes — 128, whole lanes: every layer of the ``mellum`` family, a
+sliding layer visiting only the key tiles its span reaches; 64, half a lane
+group: every attention layer of the ``lfm2_moe`` family, a key head's four
+query heads worked together a grid step — and down the blocked path
+everywhere else, with the same span. Every stack reports the layers that
+took the kernel (``sparkdl.sequence.fused_attention_layers``). The
 expert layers' grouped products go down ``latent_moe``'s grouped-product
 kernel at the published widths (``sparkdl.moe.fused_product_layers``).
 Outputs per window are ``latent_moe``'s; the program's counts gain
@@ -151,9 +153,10 @@ def block(layer, h, c: ShortConvMoEConfig, kind=None):
     """One pre-norm block over windows h (B, T, hidden) float32; ``kind`` the
     layer's published kind where ``c`` names its layers' (else the weights
     say which mixer it is). Returns ``(h, stats, told)``: ``stats`` is None
-    for a dense layer; ``told`` what a layer of a named kind tells of each
-    window's attention — ``"fused"`` and ``"scored_keys"`` (B,) int32 as
-    ``grouped_attention`` returns them — and empty otherwise. Jitted, as
+    for a dense layer; ``told`` what an attention layer tells of each
+    window's attention, (B,) int32 as ``grouped_attention`` returns them —
+    ``"fused"`` and, of a named kind, ``"scored_keys"`` — and empty for a
+    convolution. Jitted, as
     ``latent_moe.block`` is: the stack's layers of one kind are traced and
     lowered once."""
     B, T, _ = h.shape
@@ -168,8 +171,9 @@ def block(layer, h, c: ShortConvMoEConfig, kind=None):
             attended, fused, scored = lax.map(
                 lambda row: grouped_attention(layer["attn"], row, c, kind), u)
         h = h + attended
+        told = {"fused": fused}
         if kind is not None:
-            told = {"fused": fused, "scored_keys": scored}
+            told["scored_keys"] = scored
     x = rms_norm(h, layer["ffn_norm"], c.eps)
     if "moe" not in layer:
         return (h + lax.map(lambda row: gated_mlp(layer["mlp"], row), x),

@@ -14,13 +14,13 @@ programs are XLA's own and which hold a hand-written kernel
 candidate for them lost to XLA's twin on the chip (PERF.md §6, PR 21) — and
 latent attention holds exactly the fused causal-attention kernel, which keeps
 a window's float32 scores out of HBM (PERF.md §6, PR 36); the
-short-convolution model's mixers hold none (its head width of 64 is half a
-lane group: the blocked path), and the expert layer of either sequence model
-holds the grouped-product kernel twice, gate and up in one pass and down
-(PERF.md §6, PR 38), in place of three ``lax.ragged_dot``; at a head width of
-128 the pre-norm stack's grouped-query attention holds the fused kernel too,
-with a span or without (PR 39). A PR that ships or drops a kernel changes the
-assertion where it belongs.
+short-convolution model's convolution holds none, and the expert layer of
+either sequence model holds the grouped-product kernel twice, gate and up in
+one pass and down (PERF.md §6, PR 38), in place of three ``lax.ragged_dot``;
+the pre-norm stack's grouped-query attention holds the fused kernel too, at a
+head width of 128 with a span or without (PR 39) and at one of 64, half a
+lane group, with a key head's four query heads stacked a grid step (PR 40). A
+PR that ships or drops a kernel changes the assertion where it belongs.
 
 The topology is described inside a fixture of this file and nowhere else:
 only one process at a time may load the TPU's library, and under
@@ -172,8 +172,10 @@ def test_short_convolution_model_compiles_for_v5e(part, one_chip,
                                                   no_persistent_cache):
     """LFM2-8B-A1B's parts at the published widths and the cell's launch (4
     windows of 4,096), bfloat16 weights, shapes only: the gated short
-    convolution; one window's grouped-query attention, which takes the
-    blocked path with the keys grouped, not repeated; the expert layer held
+    convolution; one window's grouped-query attention, which is the fused
+    kernel at a head width of 64 (32 query heads on 8 key heads, a key
+    head's four stacked a grid step, operands heads first) with the keys
+    grouped, not repeated, and no scores in HBM; the expert layer held
     whole, whose buffer is the 65,536 pairs, whose three grouped products are
     the grouped kernel twice (PERF.md §6, PR 38) and whose combine builds no
     tokens × buffer operand."""
@@ -215,7 +217,7 @@ def test_short_convolution_model_compiles_for_v5e(part, one_chip,
         x = on_chip((4 * 4096, c.hidden), jnp.float32)
     text = jax.jit(fn).lower(p, x).compile().as_text()
     assert ":T(" in text  # tiled layouts: the TPU's compiler made this
-    assert "fused_causal_attention" not in text
+    assert ("fused_causal_attention" in text) == (part == "grouped_attention")
     if part == "routed_experts":
         assert latent_moe.buffer_capacity(4 * 4096, c) == 65536
         # gate and up as one kernel, down as another, and no product of XLA's
@@ -224,12 +226,16 @@ def test_short_convolution_model_compiles_for_v5e(part, one_chip,
         # gate's and up's float32 results never reach HBM
         assert "f32[65536,1792]" not in text
         assert "[16384,65536]" not in text and "[65536,16384]" not in text
+    elif part == "grouped_attention":
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        # the kernel reads a key head's 4,096 keys once for its four query
+        # heads: no copy of the keys to 32 heads, and none of the blocked
+        # path's scores, 8 key heads × their 4 query heads × a block of
+        # queries
+        assert "bf16[8,4096,64]" in text and "[32,4096,64]" not in text
+        assert "f32[8,4,512," not in text
     else:
         assert "tpu_custom_call" not in text
-    if part == "grouped_attention":
-        # scores of 8 key heads × their 4 query heads × a block of queries;
-        # no copy of the keys to 32 heads
-        assert "f32[8,4,512," in text
 
 
 def test_a_share_of_the_experts_compiles_to_the_grouped_kernel_for_v5e(

@@ -155,6 +155,139 @@ def test_blocked_attention_reads_grouped_keys_without_copies():
     np.testing.assert_allclose(grouped, repeated, rtol=1e-5, atol=1e-6)
 
 
+# -- the fused kernel at a head width of 64: a key head's query heads stacked --
+
+NARROW = 64
+
+
+def narrow_operands(window, heads, key_heads, seed=0):
+    """grouped_causal_attention's operands at a head width of 64: float32
+    queries (carrying the scale) and keys as a rotation leaves them,
+    bfloat16 values."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(keys[0], (window, heads * NARROW)) * 3 / 8
+    k = jax.random.normal(keys[1], (window, key_heads * NARROW))
+    v = jax.random.normal(keys[2], (window, key_heads * NARROW))
+    return q, k, v.astype(jnp.bfloat16)
+
+
+def exact_attention(q, k, v, heads, span):
+    """A float32 soft-max at full precision over the operands as the paths
+    round them, keys repeated to the query heads, the band as a mask."""
+    window = q.shape[0]
+    q, k, v = (jnp.swapaxes(a.astype(jnp.bfloat16).astype(
+        jnp.float32).reshape(window, -1, NARROW), 0, 1) for a in (q, k, v))
+    k, v = (jnp.repeat(a, heads // k.shape[0], 0) for a in (k, v))
+    at = jnp.arange(window)
+    seen = at[None, :] <= at[:, None]
+    if span is not None:
+        seen &= at[None, :] > at[:, None] - span
+    with jax.default_matmul_precision("highest"):
+        scores = jnp.where(seen, jnp.einsum("hqd,hkd->hqk", q, k), -jnp.inf)
+        out = jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(scores, -1), v)
+    return jnp.swapaxes(out, 0, 1).reshape(window, -1)
+
+
+def distance(got, want):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+@pytest.mark.parametrize("span", [None, 40, 200])
+@pytest.mark.parametrize("heads,key_heads", [(8, 2), (4, 2)])
+def test_narrow_kernel_against_the_blocked_oracle(heads, key_heads, span):
+    """Query heads of 64 in groups of 4 and of 2 on their key heads, the
+    kernel interpreted at small tiles over a window of three blocks of
+    queries — the loop over the tiles before the diagonal runs, the diagonal
+    is two key tiles, the second a run of rows a stacked head — against the
+    blocked path and against float32 attention."""
+    window = 768
+    q, k, v = narrow_operands(window, heads, key_heads, seed=2)
+    fused = latent_moe.fused_causal_attention(
+        q.astype(v.dtype), None, k.astype(v.dtype), None, v, heads=heads,
+        span=span, query_tile=256, key_tile=128, interpret=True)
+    blocked, engaged, scored = latent_moe.grouped_causal_attention(
+        q, k, v, heads, 64, span)
+    assert engaged == 0 and fused.dtype == blocked.dtype == jnp.bfloat16
+    assert fused.shape == blocked.shape == (window, heads * NARROW)
+    assert scored == latent_moe.scored_keys(window, span, 64)
+    exact = exact_attention(q, k, v, heads, span)
+    to_exact = distance(fused, exact), distance(blocked, exact)
+    # both are the bfloat16 rounding of the weights and of the output;
+    # neither path may be the looser by more than a quarter
+    assert max(to_exact) < 0.004
+    assert max(to_exact) < 1.25 * min(to_exact)
+    assert distance(fused, blocked) < 0.005
+
+
+@pytest.mark.parametrize("span", [None, 200])
+def test_narrow_kernel_is_causal_in_every_stacked_head(span):
+    """A changed key and value at position t move, in each of a key head's
+    four stacked query heads, the queries from t on — to t + span − 1 with a
+    span — and no earlier one: a row's place among the queries is its place
+    in its own head's block."""
+    window, heads, t = 512, 8, 300      # in the second block's first key tile
+    q, k, v = (a.astype(jnp.bfloat16)
+               for a in narrow_operands(window, heads, 2, seed=3))
+    run = functools.partial(latent_moe.fused_causal_attention, heads=heads,
+                            span=span, query_tile=256, key_tile=128,
+                            interpret=True)
+    before = np.asarray(run(q, None, k, None, v), np.float32)
+    after = np.asarray(run(q, None, k.at[t].add(2.0), None,
+                           v.at[t].add(2.0)), np.float32)
+    moved = np.abs(after - before).reshape(window, heads, NARROW).max(-1) > 0
+    last = window - 1 if span is None else t + span - 1
+    for head in range(heads):
+        changed = np.flatnonzero(moved[:, head])
+        assert t <= changed.min() and changed.max() <= last
+        assert len(changed) > (last - t) // 2
+    # the first and the last query that read it: a head's one weight may be
+    # lost to the output's rounding, every head's is not
+    changed = np.flatnonzero(moved.any(1))
+    assert changed.min() == t and changed.max() == last
+
+
+def test_attention_layer_of_an_unnamed_kind_tells_what_was_lowered(
+        monkeypatch):
+    """A stack that names no kinds of layer (LFM2's): with the TPU branch
+    taken at a head width of 64 (the kernel interpreted at small tiles) its
+    attention layer tells ``fused`` 1 for each window and no scored keys, and
+    the block's output is the blocked path's."""
+    window, hidden = 256, 256
+    c = dataclasses.replace(MODEL, hidden=hidden, heads=4, kv_heads=2,
+                            head_dim=NARROW, dense_width=128)
+    keys = jax.random.split(jax.random.PRNGKey(5), 8)
+
+    def weight(key, rows, columns):
+        return (jax.random.normal(key, (rows, columns)) * rows ** -0.5
+                ).astype(jnp.bfloat16)
+
+    layer = {"operator_norm": jnp.ones((hidden,)), "ffn_norm": jnp.ones(
+        (hidden,)), "attn": {
+            "q": weight(keys[0], hidden, 4 * NARROW),
+            "k": weight(keys[1], hidden, 2 * NARROW),
+            "v": weight(keys[2], hidden, 2 * NARROW),
+            "out": weight(keys[3], 4 * NARROW, hidden),
+            "q_norm": jnp.ones((NARROW,)), "k_norm": jnp.ones((NARROW,))},
+        "mlp": {"gate": weight(keys[4], hidden, 128),
+                "up": weight(keys[5], hidden, 128),
+                "down": weight(keys[6], 128, hidden)}}
+    h = jax.random.normal(keys[7], (2, window, hidden))
+    block = shortconv_moe.block.__wrapped__
+    want, _, told = jax.jit(lambda layer, h: block(layer, h, c))(layer, h)
+    assert told["fused"].tolist() == [0, 0] and set(told) == {"fused"}
+    monkeypatch.setattr(latent_moe, "FUSED_NARROW_QUERY_TILE", 128)
+    monkeypatch.setattr(latent_moe, "FUSED_NARROW_KEY_TILE", 128)
+    monkeypatch.setattr(
+        latent_moe, "fused_causal_attention", functools.partial(
+            latent_moe.fused_causal_attention, interpret=True))
+    monkeypatch.setattr(lax, "platform_dependent",
+                        lambda *operands, tpu, default: tpu(*operands))
+    got, _, told = jax.jit(lambda layer, h: block(layer, h, c))(layer, h)
+    assert told["fused"].tolist() == [1, 1] and set(told) == {"fused"}
+    assert distance(got, want) < 0.002
+
+
 def test_selection_bias_turns_a_choice_and_leaves_the_weights_unbiased(key):
     s = sizes()
     layer = ref.init_layer(key, s, 2, False)["moe"]

@@ -433,32 +433,43 @@ def test_the_visited_tiles_are_those_that_hold_a_key_of_a_span(
 
 
 def choice_operands(case):
+    """``(heads, q, k, v)`` of a case: 4 query heads on 2 key heads (16 on 1
+    where the group is to be too large to stack) at the case's head width,
+    window and dtype of the values."""
     window = {"window-40": 40, "window-over-the-bound": 2
               * latent_moe.FUSED_MAX_WINDOW}.get(
         case, latent_moe.FUSED_QUERY_TILE)
-    width = 64 if case == "head-width-64" else 128
-    dtype = jnp.float32 if case == "float32" else jnp.bfloat16
-    q = jax.ShapeDtypeStruct((window, 4 * width), jnp.float32)
-    k = jax.ShapeDtypeStruct((window, 2 * width), jnp.float32)
-    return q, k, jax.ShapeDtypeStruct((window, 2 * width), dtype)
+    width = int(case.split("-")[2]) if case.startswith("head-width") else 128
+    heads, key_heads = (16, 1) if case.endswith("group-16") else (4, 2)
+    dtype = jnp.float32 if case.endswith("float32") else jnp.bfloat16
+    q = jax.ShapeDtypeStruct((window, heads * width), jnp.float32)
+    k = jax.ShapeDtypeStruct((window, key_heads * width), jnp.float32)
+    return heads, q, k, jax.ShapeDtypeStruct((window, key_heads * width),
+                                             dtype)
 
 
 @pytest.mark.parametrize("span", [None, 256])
 @pytest.mark.parametrize("case", [
-    "window-40", "window-over-the-bound", "head-width-64", "float32",
-    "lowered-for-cpu", "lowered-for-tpu"])
+    "window-40", "window-over-the-bound", "head-width-32", "head-width-96",
+    "float32", "lowered-for-cpu", "lowered-for-tpu", "head-width-64",
+    "head-width-64-float32", "head-width-64-lowered-for-cpu",
+    "head-width-64-group-16"])
 def test_the_choice_follows_what_the_lowering_can_see(case, span):
     """Grouped keys, with a span or without, beside PR 36's and PR 38's
     cases: the kernel is taken where the program is lowered for a TPU with
     bfloat16 values, a window of whole query tiles under the bound and a head
-    width of whole lanes; the blocked path everywhere else, with the same
-    span."""
-    platform = "cpu" if case == "lowered-for-cpu" else "tpu"
+    width of whole lanes — or of half a lane group, 64, where a key head's
+    query heads are few enough to stack (4 × a block of 512 rows; 16 are
+    not): with a span too, which the stacked rows are masked to as one
+    head's are; the blocked path everywhere else — a CPU, float32 values, a
+    head width of 32 or 96 — with the same span."""
+    platform = "cpu" if case.endswith("lowered-for-cpu") else "tpu"
+    heads, *operands = choice_operands(case)
     fn = jax.jit(lambda q, k, v: latent_moe.grouped_causal_attention(
-        q, k, v, 4, 512, span))
-    text = fn.trace(*choice_operands(case)).lower(
-        lowering_platforms=(platform,)).as_text()
-    assert text.count("tpu_custom_call") == (case == "lowered-for-tpu")
+        q, k, v, heads, 512, span))
+    text = fn.trace(*operands).lower(lowering_platforms=(platform,)).as_text()
+    assert text.count("tpu_custom_call") == (
+        case in ("lowered-for-tpu", "head-width-64"))
 
 
 # -- counts, the builder, the FLOP count ---------------------------------------
@@ -493,7 +504,8 @@ def test_program_counts_reach_telemetry_and_not_the_caller(key):
 
 def test_the_other_stacks_report_no_span_counts(key):
     """The short-convolution model names no kinds of attention: its program's
-    counts are what they were."""
+    counts hold no span counts — and the layers whose attention was lowered
+    to the kernel, which every stack reports."""
     lfm2 = _load("references/lfm2_moe.py")
     config = json.load(open(os.path.join(
         BENCH, "tests", "rehearsal", "configs", "testshortconv-windows.json")))
@@ -510,6 +522,9 @@ def test_the_other_stacks_report_no_span_counts(key):
     assert telemetry.M_SEQUENCE_SCORED_KEYS not in counts
     assert telemetry.M_SEQUENCE_WINDOW_ATTENTION_LAYERS not in counts
     assert counts[telemetry.M_SEQUENCE_CONV_LAYERS].tolist() == [3, 3]
+    # what its attention layers lowered to it does tell: on a CPU, no kernel
+    assert counts[telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS].tolist() == [
+        0, 0]
 
 
 def test_builder_takes_the_kinds_by_position(key):
