@@ -68,9 +68,13 @@ def _configure_compile_cache():
     (and which spawned workers inherit); only when JAX is ALREADY
     imported are the ones the environment did not carry applied with
     ``jax.config.update``. A directory named from outside is never
-    overridden in code. First-launch compiles are visible as
-    ``sparkdl.compile`` spans in the telemetry run report either
-    way."""
+    overridden in code. Either way the first launch of each compiled
+    program is a ``sparkdl.compile`` span of the telemetry scope open
+    around it, and — for a scope opened only after the model was built
+    and first launched, which sees no such span — part of the start-up
+    record every scope mirrors (``sparkdl.startup.*`` gauges) and the
+    run report carries as ``startup``: compile seconds, retrieval
+    seconds, cache hits and misses (core/profiling.py)."""
     import sys as _sys
 
     late = {}
@@ -145,7 +149,13 @@ def __getattr__(name):
         raise AttributeError(f"module 'sparkdl_tpu' has no attribute {name!r}") from None
     import importlib
 
-    module = importlib.import_module(module_name)
+    from sparkdl_tpu.core import profiling
+
+    started = profiling.import_begin()      # import_s of the start-up record
+    try:
+        module = importlib.import_module(module_name)
+    finally:
+        profiling.import_end(started)
     value = getattr(module, attr)
     globals()[name] = value
     return value

@@ -75,9 +75,16 @@ def __getattr__(name):
             f"module 'sparkdl_tpu.core' has no attribute {name!r}") from None
     import importlib
 
-    if module_name == "sparkdl_tpu.core":
-        value = importlib.import_module(f"sparkdl_tpu.core.{attr}")
-    else:
-        value = getattr(importlib.import_module(module_name), attr)
+    # by its full name: ``from sparkdl_tpu.core import …`` would ask this
+    # resolver for it
+    profiling = importlib.import_module("sparkdl_tpu.core.profiling")
+    started = profiling.import_begin()      # import_s of the start-up record
+    try:
+        if module_name == "sparkdl_tpu.core":
+            value = importlib.import_module(f"sparkdl_tpu.core.{attr}")
+        else:
+            value = getattr(importlib.import_module(module_name), attr)
+    finally:
+        profiling.import_end(started)
     globals()[name] = value
     return value

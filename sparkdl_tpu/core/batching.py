@@ -161,15 +161,19 @@ def fetch(tree, rows: int):
     whatever other threads put in front of it), then copy them over
     (``sparkdl.fetch``, counted in ``sparkdl.executor.fetched_bytes``).
     The wait is what the copy would have blocked on anyway; an execution
-    error of the launch raises here, in whichever of the two comes first."""
+    error of the launch raises here, in whichever of the two comes first.
+    Where this thread's last launch compiled (``sparkdl.compile``), both
+    steps are that first launch's and go to the start-up record too."""
     import jax
 
+    first_launch = profiling.first_launch_wait()
     with profiling.annotate(profiling.DEVICE_SYNC, rows=rows):
         jax.block_until_ready(tree)
     nbytes = tree_nbytes(tree)
     with profiling.annotate(telemetry.SPAN_FETCH, rows=rows, bytes=nbytes):
         host = jax.tree_util.tree_map(np.asarray, tree)
     telemetry.count(telemetry.M_FETCHED_BYTES, nbytes)
+    profiling.first_launch_done(first_launch)
     return host
 
 

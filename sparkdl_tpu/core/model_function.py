@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sparkdl_tpu.core import batching, telemetry
+from sparkdl_tpu.core import batching, profiling
 from sparkdl_tpu.core.mesh import batch_sharding, replicated
 
 
@@ -439,10 +439,11 @@ class ModelFunction:
             # coalescing state is keyed on the variant's jitted fn
             # identity, so two racing winners would silently split
             # coalescing. A losing build is discarded unused.
-            if precision == "bfloat16":
-                built = self.with_compute_dtype(jnp.bfloat16)
-            else:
-                built = self._quantized_int8()
+            with profiling.model_build(self.name, precision=precision):
+                if precision == "bfloat16":
+                    built = self.with_compute_dtype(jnp.bfloat16)
+                else:
+                    built = self._quantized_int8()
             built.compute_dtype = precision
             with self._jit_lock:
                 out = self._precision_cache.setdefault(precision, built)
@@ -654,11 +655,12 @@ class ModelFunction:
 
         # First launch of a new input shape traces+compiles synchronously
         # inside the call — record it as a `sparkdl.compile` span so
-        # bucket-ladder compile storms are visible in the run report
-        # (set membership per dispatch otherwise; races at worst record a
-        # duplicate span). jax's persistent compilation cache (placed by
-        # the package __init__) makes these spans near-zero on warm
-        # processes.
+        # bucket-ladder compile storms are visible in the run report, and
+        # in the start-up record (profiling.compile_span) so a scope
+        # opened later still shows them (set membership per dispatch
+        # otherwise; races at worst record a duplicate span). jax's
+        # persistent compilation cache (placed by the package __init__)
+        # turns the compile into a retrieval on warm processes.
         seen_shapes: set = set()
         name = self.name
 
@@ -667,8 +669,7 @@ class ModelFunction:
                               for leaf in jax.tree_util.tree_leaves(x))
             if shape_key in _seen:
                 return _inner(x)
-            with telemetry.span(telemetry.SPAN_COMPILE, model=name,
-                                shapes=repr(shape_key)):
+            with profiling.compile_span(model=name, shapes=repr(shape_key)):
                 out = _inner(x)
             _seen.add(shape_key)
             return out

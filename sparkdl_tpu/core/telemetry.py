@@ -100,7 +100,18 @@ SPAN_COLLECT = "sparkdl.collect"              # estimator collected decode
 SPAN_MATERIALIZE = "sparkdl.materialize"      # DataFrame._materialize barrier
 SPAN_TASK = "sparkdl.task"                    # one pool attempt (or hedge)
 SPAN_TASK_ATTEMPT = "sparkdl.task_attempt"    # one retry-loop attempt
-SPAN_COMPILE = "sparkdl.compile"              # first launch of a new shape
+SPAN_COMPILE = "sparkdl.compile"              # first launch of a compiled
+                                              # program: a new shape of
+                                              # ModelFunction.jitted, the
+                                              # trainer's step and eval
+                                              # programs
+                                              # (profiling.compile_span)
+SPAN_MODEL_BUILD = "sparkdl.model_build"      # making a model ready to
+                                              # launch: weights resolved,
+                                              # folded and cast, the apply
+                                              # function or the trainer's
+                                              # state built
+                                              # (profiling.model_build)
 SPAN_COALESCED_LAUNCH = "sparkdl.coalesced_launch"  # core/executor.py
 SPAN_DECODE_POOL = "sparkdl.decode_pool"      # one pooled decode fan-out
                                               # (core/decode_pool.py)
@@ -143,7 +154,7 @@ CANONICAL_SPAN_NAMES = frozenset({
     SPAN_RUN, SPAN_RUNNER_ATTEMPT, SPAN_FIT, SPAN_EPOCH,
     SPAN_CHECKPOINT_SAVE, SPAN_ESTIMATOR_FIT, SPAN_COLLECT,
     SPAN_MATERIALIZE, SPAN_TASK, SPAN_TASK_ATTEMPT,
-    SPAN_COMPILE, SPAN_COALESCED_LAUNCH, SPAN_DECODE_POOL,
+    SPAN_COMPILE, SPAN_MODEL_BUILD, SPAN_COALESCED_LAUNCH, SPAN_DECODE_POOL,
     SPAN_MODEL_LOAD, SPAN_CLUSTER_DISPATCH, SPAN_CLUSTER_TASK,
     SPAN_DECODE_CHUNK, SPAN_SERVING_SHADOW, SPAN_SERVING_PREDICT,
     SPAN_SERVING_WARMUP,
@@ -292,6 +303,15 @@ M_MOE_LOAD_MAX_OVER_MEAN = "sparkdl.moe.load_max_over_mean"  # histogram (per
                                                        # its launch's fullest
                                                        # held expert's pairs
                                                        # over the mean)
+# The start-up record (core/profiling.py ``startup_stats``), mirrored into
+# every scope as gauges "sparkdl.startup.<key>" — when the scope opens and
+# whenever the record grows — so a scope opened after the model was built
+# and compiled still shows what set-up took. Seconds, but for the three
+# counts (compile-cache hits and misses, ``sparkdl.compile`` spans closed).
+STARTUP_METRIC_PREFIX = "sparkdl.startup."
+STARTUP_KEYS = ("import_s", "model_build_s", "trace_lower_s",
+                "backend_compile_s", "cache_retrieval_s", "cache_hits",
+                "cache_misses", "first_launch_s", "compile_spans")
 # Per-tenant fair queueing (core/executor.py, docs/RESILIENCE.md): each
 # tenant's queue-wait histogram gets a per-tenant NAME (metrics carry no
 # labels), declared dynamically as "sparkdl.executor.queue_wait_s.<tenant>"
@@ -352,6 +372,7 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_MOE_BUFFER_ROWS: "counter",
     M_MOE_FUSED_PRODUCT_LAYERS: "counter",
     M_MOE_LOAD_MAX_OVER_MEAN: "histogram",
+    **{STARTUP_METRIC_PREFIX + key: "gauge" for key in STARTUP_KEYS},
 }
 
 CANONICAL_METRIC_NAMES = frozenset(CANONICAL_METRIC_KINDS)
@@ -1766,6 +1787,10 @@ class Telemetry:
         self._root = self.tracer.span(SPAN_RUN, parent=ROOT,
                                       run=self.name)
         self._root.__enter__()
+        # lazy: profiling imports this module at module level
+        from sparkdl_tpu.core import profiling as _profiling
+
+        _profiling.mirror_startup(self)
         if self.export_interval_s is not None:
             # lazy: core.slo imports this module for the metric catalog
             from sparkdl_tpu.core import slo as _slo
@@ -1985,7 +2010,8 @@ def clock_handshake(conn: Any, timeout_s: float = 5.0) -> int:
 
 class RunReport:
     """Builder for the single end-of-run JSON artifact: trace summary +
-    metric snapshot + phase/overlap stats + health report."""
+    metric snapshot + phase/overlap stats + the start-up record + health
+    report."""
 
     @staticmethod
     def build(tel: Telemetry,
@@ -2004,6 +2030,10 @@ class RunReport:
             "metrics": tel.metrics.snapshot(),
             "phases": _profiling.phase_stats(),
             "overlap": _profiling.overlap_stats(),
+            # what set-up took over the process's life, whenever the
+            # scope opened (the same numbers as the sparkdl.startup.*
+            # gauges)
+            "startup": _profiling.startup_stats(),
             "health": mon.report() if mon is not None else None,
             # the live plane's view of the same run: one compact entry
             # per periodic snapshot (None without an exporter)

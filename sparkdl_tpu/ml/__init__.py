@@ -8,19 +8,25 @@ This package rebuilds that surface on the in-repo engine with TPU-native
 execution underneath (jitted Flax apply instead of TF sessions).
 """
 
-from sparkdl_tpu.ml.base import (
+# import_s of the start-up record: this package's first import, with what
+# it pulls in (core/profiling.py; stdlib only, so it costs nothing itself)
+from sparkdl_tpu.core import profiling as _profiling
+
+_import_started = _profiling.import_begin()
+
+from sparkdl_tpu.ml.base import (  # noqa: E402
     Estimator,
     Model,
     Pipeline,
     PipelineModel,
     Transformer,
 )
-from sparkdl_tpu.ml.classification import (
+from sparkdl_tpu.ml.classification import (  # noqa: E402
     LogisticRegression,
     LogisticRegressionModel,
 )
-from sparkdl_tpu.ml.estimator import KerasImageFileEstimator, KerasImageFileModel
-from sparkdl_tpu.ml.feature import (
+from sparkdl_tpu.ml.estimator import KerasImageFileEstimator, KerasImageFileModel  # noqa: E402
+from sparkdl_tpu.ml.feature import (  # noqa: E402
     Binarizer,
     Imputer,
     ImputerModel,
@@ -36,34 +42,36 @@ from sparkdl_tpu.ml.feature import (
     StringIndexerModel,
     VectorAssembler,
 )
-from sparkdl_tpu.ml.regression import (
+from sparkdl_tpu.ml.regression import (  # noqa: E402
     LinearRegression,
     LinearRegressionModel,
 )
-from sparkdl_tpu.ml.evaluation import (
+from sparkdl_tpu.ml.evaluation import (  # noqa: E402
     BinaryClassificationEvaluator,
     MulticlassClassificationEvaluator,
     RegressionEvaluator,
 )
-from sparkdl_tpu.ml.tuning import (
+from sparkdl_tpu.ml.tuning import (  # noqa: E402
     CrossValidator,
     CrossValidatorModel,
     ParamGridBuilder,
     TrainValidationSplit,
     TrainValidationSplitModel,
 )
-from sparkdl_tpu.ml.image_transformer import TPUImageTransformer
-from sparkdl_tpu.ml.keras_image import KerasImageFileTransformer
-from sparkdl_tpu.ml.keras_tensor import KerasTransformer
-from sparkdl_tpu.ml.named_image import DeepImageFeaturizer, DeepImagePredictor
-from sparkdl_tpu.ml.named_sequence import DeepSequenceScorer
-from sparkdl_tpu.ml.persistence import load
-from sparkdl_tpu.ml.tensor_transformer import TPUTransformer
+from sparkdl_tpu.ml.image_transformer import TPUImageTransformer  # noqa: E402
+from sparkdl_tpu.ml.keras_image import KerasImageFileTransformer  # noqa: E402
+from sparkdl_tpu.ml.keras_tensor import KerasTransformer  # noqa: E402
+from sparkdl_tpu.ml.named_image import DeepImageFeaturizer, DeepImagePredictor  # noqa: E402
+from sparkdl_tpu.ml.named_sequence import DeepSequenceScorer  # noqa: E402
+from sparkdl_tpu.ml.persistence import load  # noqa: E402
+from sparkdl_tpu.ml.tensor_transformer import TPUTransformer  # noqa: E402
 
 # Reference-compatible aliases: the reference's names execute TF graphs;
 # here the payload is a ModelFunction, but the pipeline role is identical.
 TFImageTransformer = TPUImageTransformer
 TFTransformer = TPUTransformer
+
+_profiling.import_end(_import_started)
 
 __all__ = [
     "BinaryClassificationEvaluator",

@@ -6,13 +6,19 @@ modules with optional bf16 compute (``dtype=jnp.bfloat16`` — fp32 params,
 MXU-friendly activations).
 """
 
-from sparkdl_tpu.models.inception import InceptionV3
-from sparkdl_tpu.models.mobilenet import MobileNetV2
-from sparkdl_tpu.models.resnet import ResNet, ResNet50, ResNet101, ResNet152
-from sparkdl_tpu.models.testnet import TestNet
-from sparkdl_tpu.models.vgg import VGG, VGG16, VGG19
-from sparkdl_tpu.models.xception import Xception
-from sparkdl_tpu.models.registry import (
+# import_s of the start-up record: this package's first import, with what
+# it pulls in (core/profiling.py; stdlib only, so it costs nothing itself)
+from sparkdl_tpu.core import profiling as _profiling
+
+_import_started = _profiling.import_begin()
+
+from sparkdl_tpu.models.inception import InceptionV3  # noqa: E402
+from sparkdl_tpu.models.mobilenet import MobileNetV2  # noqa: E402
+from sparkdl_tpu.models.resnet import ResNet, ResNet50, ResNet101, ResNet152  # noqa: E402
+from sparkdl_tpu.models.testnet import TestNet  # noqa: E402
+from sparkdl_tpu.models.vgg import VGG, VGG16, VGG19  # noqa: E402
+from sparkdl_tpu.models.xception import Xception  # noqa: E402
+from sparkdl_tpu.models.registry import (  # noqa: E402
     SUPPORTED_MODELS,
     SUPPORTED_MODEL_NAMES,
     ModelSpec,
@@ -20,6 +26,8 @@ from sparkdl_tpu.models.registry import (
     build_predictor,
     get_model_spec,
 )
+
+_profiling.import_end(_import_started)
 
 __all__ = [
     "InceptionV3", "MobileNetV2", "ResNet", "ResNet50", "ResNet101",

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from sparkdl_tpu.core import profiling
 from sparkdl_tpu.core.model_function import ModelFunction, TensorSpec
 from sparkdl_tpu.models import latent_moe, shortconv_moe
 from sparkdl_tpu.models.inception import InceptionV3
@@ -299,41 +300,45 @@ def build_sequence_scorer(name, weights: Dict[str, Any], window: int,
     are its leading ones, each of the kind at its position.
     """
     config = SEQUENCE_MODELS.get(name) if isinstance(name, str) else name
-    if type(config) not in _SEQUENCE_FORWARD:
-        raise ValueError(f"Unsupported sequence model {name!r}; supported: "
-                         f"{sorted(SEQUENCE_MODELS)}")
-    layers = weights["layers"]
-    dense = sum(1 for layer in layers if "mlp" in layer)
-    if any("mlp" in layer for layer in layers[dense:]):
-        raise ValueError("dense layers must lead the expert layers")
-    stacked = {layer["moe"]["experts"]["down"].shape[0]
-               for layer in layers[dense:]}
-    if experts_held is None:
-        experts_held = range(config.experts)
-    experts_held = tuple(int(e) for e in experts_held)
-    if stacked - {len(experts_held)}:
-        raise ValueError(
-            f"the expert layers hold {sorted(stacked)} experts' weights, "
-            f"experts_held names {len(experts_held)}")
-    share = {"experts_held": experts_held,
-             "vocab": int(weights["embed"].shape[0])}
-    if isinstance(config, LatentMoEConfig):
-        share.update(layers=len(layers), dense_layers=dense)
-    elif not all(("conv" in layer) != ("attn" in layer) for layer in layers):
-        raise ValueError('each layer holds its mixer, "conv" or "attn"')
-    elif config.layer_types and not (
-            len(layers) <= len(config.layer_types)
-            and all("attn" in layer for layer in layers)):
-        raise ValueError(
-            f"the config names the kind of attention of its "
-            f"{len(config.layer_types)} layers; the weights hold "
-            f'{len(layers)}, which must be its leading ones, each "attn"')
-    config = dataclasses.replace(config, **share)
-    forward = _SEQUENCE_FORWARD[type(config)]
     label = name if isinstance(name, str) else type(config).__name__
-    return ModelFunction.fromFunction(
-        lambda vs, tokens: forward(vs, tokens, config), weights,
-        TensorSpec((None, int(window)), "int32"), name=f"{label}_score")
+    with profiling.model_build(f"{label}_score") as span:
+        if type(config) not in _SEQUENCE_FORWARD:
+            raise ValueError(f"Unsupported sequence model {name!r}; "
+                             f"supported: {sorted(SEQUENCE_MODELS)}")
+        layers = weights["layers"]
+        dense = sum(1 for layer in layers if "mlp" in layer)
+        if any("mlp" in layer for layer in layers[dense:]):
+            raise ValueError("dense layers must lead the expert layers")
+        stacked = {layer["moe"]["experts"]["down"].shape[0]
+                   for layer in layers[dense:]}
+        if experts_held is None:
+            experts_held = range(config.experts)
+        experts_held = tuple(int(e) for e in experts_held)
+        if stacked - {len(experts_held)}:
+            raise ValueError(
+                f"the expert layers hold {sorted(stacked)} experts' weights, "
+                f"experts_held names {len(experts_held)}")
+        share = {"experts_held": experts_held,
+                 "vocab": int(weights["embed"].shape[0])}
+        if isinstance(config, LatentMoEConfig):
+            share.update(layers=len(layers), dense_layers=dense)
+        elif not all(("conv" in layer) != ("attn" in layer)
+                     for layer in layers):
+            raise ValueError('each layer holds its mixer, "conv" or "attn"')
+        elif config.layer_types and not (
+                len(layers) <= len(config.layer_types)
+                and all("attn" in layer for layer in layers)):
+            raise ValueError(
+                f"the config names the kind of attention of its "
+                f"{len(config.layer_types)} layers; the weights hold "
+                f'{len(layers)}, which must be its leading ones, each "attn"')
+        config = dataclasses.replace(config, **share)
+        forward = _SEQUENCE_FORWARD[type(config)]
+        mf = ModelFunction.fromFunction(
+            lambda vs, tokens: forward(vs, tokens, config), weights,
+            TensorSpec((None, int(window)), "int32"), name=f"{label}_score")
+        span.set_attribute("bytes", mf.weight_bytes())
+        return mf
 
 
 def get_model_spec(name: str) -> ModelSpec:
@@ -500,6 +505,46 @@ def _fast_inference_apply(name: str, include_top: bool, dtype):
     return apply_fn
 
 
+def _build_named(name: str, include_top: bool, weights, seed: int, dtype,
+                 preprocess: bool, fast: bool,
+                 precision: Optional[str]) -> ModelFunction:
+    """What :func:`build_featurizer` and :func:`build_predictor` share,
+    under one ``sparkdl.model_build`` span (attributes ``model``,
+    ``bytes``): weights resolved, the apply function chosen, preprocess
+    and precision applied."""
+    spec = get_model_spec(name)
+    label = f"{name}_{'predict' if include_top else 'featurize'}"
+    with profiling.model_build(label) as span:
+        fast_apply = None
+        if is_ingested_model(name):
+            mf = _build_ingested(name, weights, include_top=include_top,
+                                 dtype=dtype)
+        else:
+            if include_top:
+                kwargs = {"include_top": True, "classes": spec.classes}
+            else:
+                kwargs = dict(spec.featurize_kwargs
+                              or {"include_top": False, "pooling": "avg"})
+            module = spec.builder(dtype=dtype, **kwargs)
+            input_spec = _spec_input(spec)
+            variables = _resolve_variables(spec, module, weights, seed,
+                                           input_spec)
+            if fast:
+                fast_apply = _fast_inference_apply(name, include_top, dtype)
+            if fast_apply is not None:
+                mf = ModelFunction.fromFunction(fast_apply, variables,
+                                                input_spec, name=label)
+            else:
+                mf = ModelFunction.fromFlax(module, variables, input_spec,
+                                            name=label, train=False)
+        if preprocess:
+            mf = mf.with_preprocess(spec.preprocess)
+        mf.fast_path = fast_apply is not None
+        mf = _apply_precision(mf, precision)
+        span.set_attribute("bytes", mf.weight_bytes())
+    return mf
+
+
 def build_featurizer(name: str, weights="random", seed: int = 0,
                      dtype=None, preprocess: bool = True,
                      fast: bool = True,
@@ -516,30 +561,8 @@ def build_featurizer(name: str, weights="random", seed: int = 0,
     ``EngineConfig.inference_precision`` itself, so this parameter is for
     standalone (non-engine) use of the registry.
     """
-    spec = get_model_spec(name)
-    if is_ingested_model(name):
-        mf = _build_ingested(name, weights, include_top=False, dtype=dtype)
-        if preprocess:
-            mf = mf.with_preprocess(spec.preprocess)
-        mf.fast_path = False
-        return _apply_precision(mf, precision)
-    kwargs = dict(spec.featurize_kwargs or {"include_top": False,
-                                            "pooling": "avg"})
-    kwargs["dtype"] = dtype
-    module = spec.builder(**kwargs)
-    input_spec = _spec_input(spec)
-    variables = _resolve_variables(spec, module, weights, seed, input_spec)
-    fast_apply = _fast_inference_apply(name, False, dtype) if fast else None
-    if fast_apply is not None:
-        mf = ModelFunction.fromFunction(fast_apply, variables, input_spec,
-                                        name=f"{name}_featurize")
-    else:
-        mf = ModelFunction.fromFlax(module, variables, input_spec,
-                                    name=f"{name}_featurize", train=False)
-    if preprocess:
-        mf = mf.with_preprocess(spec.preprocess)
-    mf.fast_path = fast_apply is not None
-    return _apply_precision(mf, precision)
+    return _build_named(name, False, weights, seed, dtype, preprocess, fast,
+                        precision)
 
 
 def build_predictor(name: str, weights="random", seed: int = 0,
@@ -549,27 +572,8 @@ def build_predictor(name: str, weights="random", seed: int = 0,
     """Full named model (softmax probabilities) as a ModelFunction.
 
     ``precision``: see :func:`build_featurizer`."""
-    spec = get_model_spec(name)
-    if is_ingested_model(name):
-        mf = _build_ingested(name, weights, include_top=True, dtype=dtype)
-        if preprocess:
-            mf = mf.with_preprocess(spec.preprocess)
-        mf.fast_path = False
-        return _apply_precision(mf, precision)
-    module = spec.builder(include_top=True, classes=spec.classes, dtype=dtype)
-    input_spec = _spec_input(spec)
-    variables = _resolve_variables(spec, module, weights, seed, input_spec)
-    fast_apply = _fast_inference_apply(name, True, dtype) if fast else None
-    if fast_apply is not None:
-        mf = ModelFunction.fromFunction(fast_apply, variables, input_spec,
-                                        name=f"{name}_predict")
-    else:
-        mf = ModelFunction.fromFlax(module, variables, input_spec,
-                                    name=f"{name}_predict", train=False)
-    if preprocess:
-        mf = mf.with_preprocess(spec.preprocess)
-    mf.fast_path = fast_apply is not None
-    return _apply_precision(mf, precision)
+    return _build_named(name, True, weights, seed, dtype, preprocess, fast,
+                        precision)
 
 
 def _apply_precision(mf: ModelFunction,

@@ -15,11 +15,19 @@ all-reduce). Here:
   injection hook to test it.
 """
 
-from sparkdl_tpu.train.checkpoint import CheckpointManager
-from sparkdl_tpu.train.metrics import MetricsLogger
-from sparkdl_tpu.train.optimizers import make_loss, make_optimizer
-from sparkdl_tpu.train.runner import TPURunner
-from sparkdl_tpu.train.trainer import Trainer, TrainState
+# import_s of the start-up record: this package's first import, with what
+# it pulls in (core/profiling.py; stdlib only, so it costs nothing itself)
+from sparkdl_tpu.core import profiling as _profiling
+
+_import_started = _profiling.import_begin()
+
+from sparkdl_tpu.train.checkpoint import CheckpointManager  # noqa: E402
+from sparkdl_tpu.train.metrics import MetricsLogger  # noqa: E402
+from sparkdl_tpu.train.optimizers import make_loss, make_optimizer  # noqa: E402
+from sparkdl_tpu.train.runner import TPURunner  # noqa: E402
+from sparkdl_tpu.train.trainer import Trainer, TrainState  # noqa: E402
+
+_profiling.import_end(_import_started)
 
 __all__ = [
     "CheckpointManager",

@@ -27,7 +27,14 @@ import numpy as np
 import optax
 from flax import struct
 
-from sparkdl_tpu.core import health, pipeline, profiling, resilience, telemetry
+from sparkdl_tpu.core import (
+    batching,
+    health,
+    pipeline,
+    profiling,
+    resilience,
+    telemetry,
+)
 from sparkdl_tpu.core.mesh import batch_sharding, replicated
 from sparkdl_tpu.train.checkpoint import CheckpointManager
 from sparkdl_tpu.train.metrics import MetricsLogger
@@ -36,6 +43,27 @@ from sparkdl_tpu.train.optimizers import (
     make_loss,
     make_optimizer,
 )
+
+
+def _first_launch_spanned(jitted: Callable, name: str) -> Callable:
+    """``jitted(state, *batch)`` with ``sparkdl.compile`` around the first
+    launch of each batch shape — where JAX traces, lowers and compiles (or
+    retrieves) synchronously inside the call. The shapes seen ride on the
+    jitted function itself, so a step shared through a ``step_cache`` is
+    spanned once over all its fits; a warm step pays one set lookup on
+    its batch's shapes."""
+    seen = jitted.__dict__.setdefault("_sparkdl_seen_shapes", set())
+
+    def step(state, *batch):
+        key = tuple((b.shape, b.dtype) for b in batch)
+        if key in seen:
+            return jitted(state, *batch)
+        with profiling.compile_span(model=name, shapes=repr(key)):
+            out = jitted(state, *batch)
+        seen.add(key)
+        return out
+
+    return step
 
 
 class TrainState(struct.PyTreeNode):
@@ -111,12 +139,14 @@ class Trainer:
                 return out, updates
             return module.apply(vs, x, train=train, rngs=rngs)
 
-        trainer = cls(apply_fn=apply_fn,
-                      loss=make_loss(loss, from_logits=from_logits),
-                      optimizer=make_optimizer(optimizer, learning_rate),
-                      mesh=mesh, has_model_state=bool(mutable_keys),
-                      accuracy_from_logits=from_logits, **kwargs)
-        state = trainer.init_state(params, model_state)
+        with profiling.model_build(type(module).__name__) as span:
+            trainer = cls(apply_fn=apply_fn,
+                          loss=make_loss(loss, from_logits=from_logits),
+                          optimizer=make_optimizer(optimizer, learning_rate),
+                          mesh=mesh, has_model_state=bool(mutable_keys),
+                          accuracy_from_logits=from_logits, **kwargs)
+            state = trainer.init_state(params, model_state)
+            span.set_attribute("bytes", batching.tree_nbytes(state))
         return trainer, state
 
     @classmethod
@@ -170,11 +200,15 @@ class Trainer:
                 cache_key = None
             if cache_key is not None:
                 cache = mf.__dict__.setdefault("_train_step_cache", {})
-        trainer = cls(apply_fn=apply_fn, loss=make_loss(loss, from_logits=from_logits),
-                      optimizer=tx, mesh=mesh, has_model_state=False,
-                      accuracy_from_logits=from_logits,
-                      step_cache=cache, step_cache_key=cache_key, **kwargs)
-        state = trainer.init_state(mf.variables, {})
+        with profiling.model_build(mf.name) as span:
+            trainer = cls(apply_fn=apply_fn,
+                          loss=make_loss(loss, from_logits=from_logits),
+                          optimizer=tx, mesh=mesh, has_model_state=False,
+                          accuracy_from_logits=from_logits,
+                          step_cache=cache, step_cache_key=cache_key,
+                          **kwargs)
+            state = trainer.init_state(mf.variables, {})
+            span.set_attribute("bytes", batching.tree_nbytes(state))
         return trainer, state
 
     # -- state ---------------------------------------------------------------
@@ -362,15 +396,18 @@ class Trainer:
                 raise NotImplementedError(
                     "multi-host evaluate requires fully-replicated train "
                     f"state (every host must hold a full copy): {e}") from e
-        eval_step = self.make_eval_metrics_step()
+        eval_step = _first_launch_spanned(self.make_eval_metrics_step(),
+                                          "eval_metrics_step")
         totals: Dict[str, float] = {}
         n = 0
         for x, y in batches:
             xd = jnp.asarray(np.asarray(x))
             if xd.dtype == jnp.uint8:  # same contract as stage_batch
                 xd = xd.astype(jnp.float32)
-            m = jax.device_get(eval_step(state, xd,
-                                         jnp.asarray(np.asarray(y))))
+            out = eval_step(state, xd, jnp.asarray(np.asarray(y)))
+            first_launch = profiling.first_launch_wait()
+            m = jax.device_get(out)
+            profiling.first_launch_done(first_launch)
             k = len(x)
             n += k
             for key, value in m.items():
@@ -428,7 +465,8 @@ class Trainer:
                 state = checkpoint.restore(state)
                 state = jax.tree.map(jnp.asarray, state)
                 health.record(health.FIT_RESUMED, step=int(state.step))
-        train_step = self.make_train_step()
+        train_step = _first_launch_spanned(self.make_train_step(),
+                                           "train_step")
         multihost = self.mesh is not None and jax.process_count() > 1
         if jax.process_count() > 1:
             # Multi-process: force inline staging. The batch source may run
@@ -492,10 +530,13 @@ class Trainer:
             throughput number the deferred pipeline obscures per step.
             """
             nonlocal last_sync_t, last_sync_step
+            # where the step that compiled is awaited, the wait is set-up's
+            first_launch = profiling.first_launch_wait()
             if metrics_logger is not None:
                 metrics_logger.flush()
             with profiling.annotate(profiling.DEVICE_SYNC):
                 device_step = int(st.step)
+            profiling.first_launch_done(first_launch)
             if device_step != host_step:
                 raise RuntimeError(
                     f"pipelined fit desynchronized: device step "

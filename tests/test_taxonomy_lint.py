@@ -15,6 +15,8 @@ concurrency pack) is ``tests/test_analysis.py``.
 import ast
 import pathlib
 
+import pytest
+
 from sparkdl_tpu import analysis
 from sparkdl_tpu.analysis import framework, lints
 from sparkdl_tpu.core import health as _health
@@ -151,6 +153,36 @@ def test_span_name_lint_catches_typo_and_resolves_constants():
     assert ("sparkdl.stage_batch", 6) in names
     assert len(names) == 3  # the dynamic name is not checkable
     assert "sparkdl.train_stepp" not in _telemetry.CANONICAL_SPAN_NAMES
+
+
+@pytest.mark.parametrize("helper, name", [
+    ("model_build", _telemetry.SPAN_MODEL_BUILD),
+    ("compile_span", _telemetry.SPAN_COMPILE),
+])
+def test_startup_helpers_open_canonical_spans(helper, name):
+    """ISSUE 41: the start-up record's two spans go through
+    ``profiling.annotate`` with catalogued names, so the lint sees them."""
+    assert name in _telemetry.CANONICAL_SPAN_NAMES
+    tree = ast.parse((ROOT / "core" / "profiling.py").read_text())
+    body = next(n for n in ast.walk(tree)
+                if isinstance(n, ast.FunctionDef) and n.name == helper)
+    assert name in {found for found, _ in lints.span_names_in(body)}
+
+
+@pytest.mark.parametrize("key", _telemetry.STARTUP_KEYS)
+def test_startup_gauges_are_catalogued(key):
+    """Every key of the start-up record is a declared gauge — an SLO rule
+    may watch it, and one with a histogram's stat is refused."""
+    from sparkdl_tpu.core import profiling, slo
+
+    name = _telemetry.STARTUP_METRIC_PREFIX + key
+    assert _telemetry.CANONICAL_METRIC_KINDS[name] == "gauge"
+    assert key in profiling.startup_stats()
+    slo.SLORule(f"startup-{key}", metric=name, window_s=30.0,
+                threshold=60.0, stat="value")
+    with pytest.raises(ValueError):
+        slo.SLORule(f"startup-{key}-p99", metric=name, window_s=30.0,
+                    threshold=60.0, stat="p99")
 
 
 # ---------------------------------------------------------------------------
