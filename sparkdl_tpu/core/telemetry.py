@@ -271,6 +271,9 @@ M_SEQUENCE_TOKENS = "sparkdl.sequence.tokens"          # counter (tokens of
                                                        # the rows scored)
 # counter (per row: the layers whose attention was lowered to the fused kernel)
 M_SEQUENCE_FUSED_ATTENTION_LAYERS = "sparkdl.sequence.fused_attention_layers"
+# counter (per row: 1 where the scorer's head was lowered to the fused kernel,
+# models/latent_moe.py fused_scoring_head — no logit in HBM)
+M_SEQUENCE_FUSED_HEAD_WINDOWS = "sparkdl.sequence.fused_head_windows"
 # counter (per row: the layers whose mixer was the gated short convolution)
 M_SEQUENCE_CONV_LAYERS = "sparkdl.sequence.conv_layers"
 # counter (per row: the layers whose attention was lowered with a span — a
@@ -363,6 +366,7 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_CLUSTER_DRAIN_S: "histogram",
     M_SEQUENCE_TOKENS: "counter",
     M_SEQUENCE_FUSED_ATTENTION_LAYERS: "counter",
+    M_SEQUENCE_FUSED_HEAD_WINDOWS: "counter",
     M_SEQUENCE_CONV_LAYERS: "counter",
     M_SEQUENCE_WINDOW_ATTENTION_LAYERS: "counter",
     M_SEQUENCE_SCORED_KEYS: "counter",

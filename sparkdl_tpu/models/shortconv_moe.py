@@ -43,7 +43,9 @@ query heads worked together a grid step — and down the blocked path
 everywhere else, with the same span. Every stack reports the layers that
 took the kernel (``sparkdl.sequence.fused_attention_layers``). The
 expert layers' grouped products go down ``latent_moe``'s grouped-product
-kernel at the published widths (``sparkdl.moe.fused_product_layers``).
+kernel at the published widths (``sparkdl.moe.fused_product_layers``), and
+the head — tied or not, read as it lies — down its fused scoring head
+(``sparkdl.sequence.fused_head_windows``).
 Outputs per window are ``latent_moe``'s; the program's counts gain
 ``sparkdl.sequence.conv_layers`` and, from a stack that names its layers'
 kinds, ``sparkdl.sequence.window_attention_layers`` and
@@ -195,12 +197,13 @@ def forward(params, tokens, c: ShortConvMoEConfig) -> Dict[str, Any]:
             stats.append(layer_stats)
         if layer_told:
             told.append(layer_told)
-    out = score_head(params, h, tokens, c.eps)
+    out, fused_head = score_head(params, h, tokens, c.eps)
     if stats:
         rows = tokens.shape[0]
         counts = {
             telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS: sum(
                 (t["fused"] for t in told), jnp.zeros((rows,), jnp.int32)),
+            telemetry.M_SEQUENCE_FUSED_HEAD_WINDOWS: fused_head,
             telemetry.M_SEQUENCE_CONV_LAYERS: jnp.full(
                 (rows,), sum("conv" in layer for layer in layers),
                 jnp.int32)}

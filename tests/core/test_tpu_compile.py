@@ -19,7 +19,9 @@ either sequence model holds the grouped-product kernel twice, gate and up in
 one pass and down (PERF.md §6, PR 38), in place of three ``lax.ragged_dot``;
 the pre-norm stack's grouped-query attention holds the fused kernel too, at a
 head width of 128 with a span or without (PR 39) and at one of 64, half a
-lane group, with a key head's four query heads stacked a grid step (PR 40). A
+lane group, with a key head's four query heads stacked a grid step (PR 40);
+and the head of each of the three sequence models holds the fused scoring
+head, which keeps a window's float32 logits out of HBM (PR 43). A
 PR that ships or drops a kernel changes the assertion where it belongs.
 
 The topology is described inside a fixture of this file and nowhere else:
@@ -167,7 +169,7 @@ def test_latent_attention_compiles_to_the_fused_kernel_for_v5e(
 
 
 @pytest.mark.parametrize("part", ["short_conv", "grouped_attention",
-                                  "routed_experts"])
+                                  "routed_experts", "head"])
 def test_short_convolution_model_compiles_for_v5e(part, one_chip,
                                                   no_persistent_cache):
     """LFM2-8B-A1B's parts at the published widths and the cell's launch (4
@@ -178,7 +180,9 @@ def test_short_convolution_model_compiles_for_v5e(part, one_chip,
     grouped, not repeated, and no scores in HBM; the expert layer held
     whole, whose buffer is the 65,536 pairs, whose three grouped products are
     the grouped kernel twice (PERF.md §6, PR 38) and whose combine builds no
-    tokens × buffer operand."""
+    tokens × buffer operand; the head over all 65,536 ids, tied — the
+    embedding as it lies is the kernel's operand — which is the fused scoring
+    head over the launch's 16,384 positions and builds no logits in HBM."""
     c = registry.SEQUENCE_MODELS["LFM2-8B-A1B"]
 
     def on_chip(shape, dtype=jnp.bfloat16):
@@ -203,6 +207,16 @@ def test_short_convolution_model_compiles_for_v5e(part, one_chip,
             return shortconv_moe.grouped_attention(p, x, c)
 
         x = on_chip((4096, c.hidden), jnp.float32)
+    elif part == "head":
+        p = {"embed": on_chip((c.vocab, c.hidden)),
+             "final_norm": on_chip((c.hidden,))}
+
+        def fn(p, x):
+            return latent_moe.score_head(
+                dict(p, head=p["embed"]), x, jnp.zeros((4, 4096), jnp.int32),
+                c.eps)
+
+        x = on_chip((4, 4096, c.hidden), jnp.float32)
     else:
         p = {"router": on_chip((c.hidden, c.experts)),
              "expert_bias": on_chip((c.experts,)),
@@ -234,8 +248,40 @@ def test_short_convolution_model_compiles_for_v5e(part, one_chip,
         # queries
         assert "bf16[8,4096,64]" in text and "[32,4096,64]" not in text
         assert "f32[8,4,512," not in text
+    elif part == "head":
+        _holds_the_fused_head_and_no_logits(text, c.vocab)
     else:
         assert "tpu_custom_call" not in text
+
+
+def _holds_the_fused_head_and_no_logits(text, rows):
+    """One kernel call, the fused scoring head's, and no buffer of positions
+    × the head's rows, float32 or other — nor a transposed copy of the head,
+    which the kernel reads as it lies."""
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "fused_scoring_head" in text
+    assert f",{rows}]" not in text
+
+
+def test_a_share_of_the_vocabulary_compiles_to_the_fused_head_for_v5e(
+        one_chip, no_persistent_cache):
+    """openPangu-Ultra-MoE-718B's head as the .windows cell holds it (19,200
+    of 153,600 rows = 2**8 × 75, hidden 7,680, 4 windows of 4,096): the
+    fused scoring head at blocks this contraction leaves room for."""
+    c = registry.SEQUENCE_MODELS["openPangu-Ultra-MoE-718B"]
+    rows = 19200
+    assert latent_moe._head_blocks(4096, c.hidden, rows, jnp.bfloat16)
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {"embed": on_chip((rows, c.hidden)), "head": on_chip((rows, c.hidden)),
+         "final_norm": on_chip((c.hidden,))}
+    x = on_chip((4, 4096, c.hidden), jnp.float32)
+    text = jax.jit(lambda p, x: latent_moe.score_head(
+        p, x, jnp.zeros((4, 4096), jnp.int32), c.eps)
+        ).lower(p, x).compile().as_text()
+    _holds_the_fused_head_and_no_logits(text, rows)
 
 
 def test_a_share_of_the_experts_compiles_to_the_grouped_kernel_for_v5e(
@@ -277,7 +323,8 @@ def test_span_model_compiles_for_v5e(part, one_chip, no_persistent_cache):
     budget of on-chip memory with a head's 16,384 keys and values resident —
     and builds no scores in HBM; the expert layer held whole (64 experts, a
     buffer of the 131,072 pairs, the grouped kernel twice at 2,304 × 896);
-    the head, whose float32 logits go in blocks of positions."""
+    the head over 98,304 ids, which is the fused scoring head and builds none
+    of the window's 6.4 GB of float32 logits, whole or in blocks."""
     c = registry.SEQUENCE_MODELS["Mellum2-12B-A2.5B-Instruct"]
     window = 16384
     assert window == latent_moe.FUSED_MAX_WINDOW
@@ -329,6 +376,4 @@ def test_span_model_compiles_for_v5e(part, one_chip, no_persistent_cache):
         assert "grouped_product" in text and "ragged-dot" not in text
         assert "f32[131072,896]" not in text
     else:
-        assert "tpu_custom_call" not in text
-        # eight blocks of 2,048 positions, never a window's 6.4 GB of logits
-        assert "f32[2048,98304]" in text and "f32[16384,98304]" not in text
+        _holds_the_fused_head_and_no_logits(text, c.vocab)

@@ -531,6 +531,7 @@ def test_program_counts_reach_telemetry_and_not_the_caller(key):
     assert counters[telemetry.M_SEQUENCE_TOKENS] == 3 * WINDOW
     assert counters[telemetry.M_SEQUENCE_CONV_LAYERS] == 3 * 3
     assert counters[telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS] == 0
+    assert counters[telemetry.M_SEQUENCE_FUSED_HEAD_WINDOWS] == 0
     assert counters[telemetry.M_MOE_ROUTED_TOKENS] == 3 * WINDOW * 3
     # every expert is held: each token's four pairs stay, in each of the
     # three expert layers, and the buffers held exactly those
@@ -590,6 +591,42 @@ def test_bfloat16_weights_are_taken_as_they_are_and_the_head_is_tied(key):
     assert out["expert_counts"].dtype == np.int32   # counts are not cast
     assert np.abs(out["logprobs"] - logprobs).max() < 0.15
     assert np.abs(out["pooled"] - pooled).max() < 0.1
+
+
+def test_the_stack_counts_the_windows_its_tied_head_fused(key, monkeypatch):
+    """The pre-norm stack's forward with a tied head of 128 rows in
+    bfloat16: the count reads 0 a window on the path that writes the logits
+    and 1 with the head's TPU branch taken (the kernel interpreted: blocks
+    of 8 positions, one tile; a window of 24 gives attention and the
+    experts' products no tiles, so they lower what they lowered), and the
+    log-probabilities agree. The head is the embedding itself, as it lies."""
+    s = sizes(vocab_size=128)
+    variables = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                             make_variables(key, s))
+    variables["head"] = variables["embed"]
+    config = dataclasses.replace(MODEL, vocab=128)
+    tokens = tokens_of(9, 2, vocab=128)
+
+    def run():
+        out = jax.jit(lambda p, t: shortconv_moe.forward(p, t, config))(
+            variables, tokens)
+        return out, out[telemetry.PROGRAM_COUNTS][
+            telemetry.M_SEQUENCE_FUSED_HEAD_WINDOWS].tolist()
+
+    want, count = run()
+    assert count == [0, 0]
+    monkeypatch.setattr(latent_moe, "_head_blocks", lambda *shapes: (8, 128))
+    monkeypatch.setattr(latent_moe, "fused_scoring_head", functools.partial(
+        latent_moe.fused_scoring_head, interpret=True))
+    monkeypatch.setattr(lax, "platform_dependent",
+                        lambda *operands, tpu, default: tpu(*operands))
+    got, count = run()
+    assert count == [1, 1]
+    assert got[telemetry.PROGRAM_COUNTS][
+        telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS].tolist() == [0, 0]
+    np.testing.assert_allclose(got["logprobs"], want["logprobs"], rtol=1e-4,
+                               atol=1e-4)
+    np.testing.assert_array_equal(got["pooled"], want["pooled"])
 
 
 def test_an_id_outside_the_vocabulary_gives_no_number(key):

@@ -297,10 +297,11 @@ def test_head_in_blocks_of_positions_equals_the_head_whole(key, monkeypatch):
     params = {**ref.init_embed(key, s), **ref.init_head(key, s)}
     h = jax.random.normal(jax.random.PRNGKey(11), (2, WINDOW, s.hidden))
     tokens = tokens_of(3, 2)
-    whole = latent_moe.score_head(params, h, tokens, s.eps)
+    whole, fused = latent_moe.score_head(params, h, tokens, s.eps)
+    assert fused.tolist() == [0, 0]
     # 40 positions × 32 rows × 4 bytes: a bound of a quarter makes 4 blocks
     monkeypatch.setattr(latent_moe, "HEAD_LOGITS_BYTES", WINDOW * 32)
-    blocks = latent_moe.score_head(params, h, tokens, s.eps)
+    blocks, _ = latent_moe.score_head(params, h, tokens, s.eps)
     np.testing.assert_allclose(blocks["logprobs"], whole["logprobs"],
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_array_equal(blocks["pooled"], whole["pooled"])
@@ -490,6 +491,7 @@ def test_program_counts_reach_telemetry_and_not_the_caller(key):
     # layers 0–2 and 4 of the five held are sliding ones
     assert counters[telemetry.M_SEQUENCE_WINDOW_ATTENTION_LAYERS] == 3 * 4
     assert counters[telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS] == 0
+    assert counters[telemetry.M_SEQUENCE_FUSED_HEAD_WINDOWS] == 0
     assert counters[telemetry.M_SEQUENCE_CONV_LAYERS] == 0
     # the blocked path, blocks of 8 queries: a sliding layer scores its block
     # and the 7 keys before it, the full layer each block's whole prefix
