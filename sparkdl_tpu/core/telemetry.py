@@ -283,6 +283,12 @@ M_SEQUENCE_WINDOW_ATTENTION_LAYERS = "sparkdl.sequence.window_attention_layers"
 # the keys whose scores the lowered path computes, masked ones inside a
 # visited tile included; beside the former)
 M_SEQUENCE_SCORED_KEYS = "sparkdl.sequence.scored_keys"
+# counter (per row: the layers whose mixer was the state-space one,
+# models/state_space.py; from a stack that has such layers)
+M_SEQUENCE_SSM_LAYERS = "sparkdl.sequence.ssm_layers"
+# counter (per row: the state-space layers whose recurrence was lowered to
+# the kernel, models/state_space.py fused_selective_scan; beside the former)
+M_SEQUENCE_FUSED_SCAN_LAYERS = "sparkdl.sequence.fused_scan_layers"
 M_MOE_ROUTED_TOKENS = "sparkdl.moe.routed_tokens"      # counter (tokens
                                                        # routed, once for each
                                                        # expert layer)
@@ -370,6 +376,8 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_SEQUENCE_CONV_LAYERS: "counter",
     M_SEQUENCE_WINDOW_ATTENTION_LAYERS: "counter",
     M_SEQUENCE_SCORED_KEYS: "counter",
+    M_SEQUENCE_SSM_LAYERS: "counter",
+    M_SEQUENCE_FUSED_SCAN_LAYERS: "counter",
     M_MOE_ROUTED_TOKENS: "counter",
     M_MOE_LOCAL_PAIRS: "counter",
     M_MOE_OVERFLOW_PAIRS: "counter",

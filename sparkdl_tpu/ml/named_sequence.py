@@ -8,13 +8,17 @@ and the executor's choke point, like every other model, and two columns come
 out per row: ``pooledCol``, the mean over positions of the final hidden
 state (embedding extraction), and ``logprobsCol``, ``log p(x[t+1] | x[≤t])``
 per position, the last 0 (perplexity filtering). ``expertCountsCol``, when
-set, adds the tokens routed to each expert per expert layer, flattened.
+set, adds the tokens routed to each expert per expert layer, flattened; on a
+model whose weights hold no expert layer it is refused where the model is
+built.
 
 The weights are a variables dict, taken as given: for a model of this size
 they are made or loaded in bfloat16 on the device, and what a chip holds of
-the published model — layers and their kinds (the leading ones, where the
-config names a kind of attention for each position), experts
-(``expertsHeld``), vocabulary slice — is read off them
+the published model — layers and their mixers (a gated short convolution,
+attention or a state-space mixer) and ffns (a gated MLP or, optionally, an
+expert layer), the leading ones where the config names a kind of attention
+for each position, experts (``expertsHeld``), vocabulary slice — is read off
+them
 (``registry.build_sequence_scorer``).
 """
 
@@ -36,8 +40,8 @@ class DeepSequenceScorer(Transformer, HasInputCol, HasBatchSize, HasMesh):
     modelName = Param(
         "DeepSequenceScorer", "modelName",
         f"one of {sorted(registry.SEQUENCE_MODELS)}, or a config of one's own "
-        "of either sequence model's type (LatentMoEConfig, "
-        "ShortConvMoEConfig)",
+        "of either sequence stack's type (LatentMoEConfig, or "
+        "ShortConvMoEConfig: three mixers and an optional expert layer)",
         typeConverter=TypeConverters.identity)
     weights = Param(
         "DeepSequenceScorer", "weights",
@@ -106,6 +110,12 @@ class DeepSequenceScorer(Transformer, HasInputCol, HasBatchSize, HasMesh):
                    "logprobs": self.getOrDefault(self.logprobsCol)}
         counts = self.getOrDefault(self.expertCountsCol)
         if counts:
+            if not any("moe" in layer for layer in self.getOrDefault(
+                    self.weights)["layers"]):
+                raise ValueError(
+                    f"expertCountsCol={counts!r}: the weights of "
+                    f"{self.getOrDefault(self.modelName)!r} hold no expert "
+                    "layer, so the model has no expert counts to give")
             outputs["expert_counts"] = counts
         return TPUTransformer(
             inputCol=self.getInputCol(), outputMapping=outputs,

@@ -7,8 +7,9 @@ layer's own frequencies (``Rope``), causal attention over grouped keys, with
 a span or without (``grouped_causal_attention``: the fused kernel or
 ``_blocked_attention``), ``gated_mlp``, the expert layer (``route``,
 ``buffer_capacity``, ``expert_products``, ``routed_experts``,
-``expert_stats``) and the scorer's head — the fused kernel or the written
-logits' ``log_softmax`` — and counts (``score_head``, ``expert_outputs``).
+``expert_stats``), the causal taps of a depthwise convolution
+(``causal_taps``) and the scorer's head — the fused kernel or the written
+logits' ``log_softmax`` — and counts (``score_head``, ``program_counts``).
 
 One block is: multi-head latent attention (queries and keys/values through
 low-rank latents, a rotary part shared by all heads of the key), sandwich
@@ -190,6 +191,20 @@ def rotary(x, theta, heads=1, frequencies=None, amplitude=1.0):
     a, b = x[:, :, 0], x[:, :, 1]
     return jnp.stack([a * cos - b * sin, b * cos + a * sin], 2).reshape(
         T, -1)
+
+
+def causal_taps(z, taps, bias=None):
+    """A depthwise causal convolution over windows z (B, T, channels)
+    float32, zeros before the window: ``c[t] = Σ_j taps[:, j] ⊙ z[t − (L − 1)
+    + j]`` with ``taps`` (channels, L), plus ``bias`` (channels,) where given;
+    taps and bias float32. The gated short convolution's loop and the
+    state-space mixer's."""
+    T = z.shape[1]
+    taps = taps.astype(jnp.float32)
+    L = taps.shape[1]
+    z = jnp.pad(z, ((0, 0), (L - 1, 0), (0, 0)))
+    c = sum(taps[:, j] * z[:, j:j + T] for j in range(L))
+    return c if bias is None else c + bias.astype(jnp.float32)
 
 
 def _blocked_attention(q, k, v, block, span=None):
@@ -1170,32 +1185,34 @@ def score_head(params, h, tokens, eps):
                 }, jnp.broadcast_to(engaged, (B,))
 
 
-def expert_outputs(stats, tokens, counts):
-    """The outputs every expert layer's :func:`expert_stats` adds to a
-    scorer's: ``expert_counts`` and, under ``telemetry.PROGRAM_COUNTS``, the
-    model's own ``counts`` (name → (B,) int32) with the expert layers'."""
+def program_counts(stats, tokens, counts):
+    """What a scorer's outputs gain beside ``pooled`` and ``logprobs``: under
+    ``telemetry.PROGRAM_COUNTS`` the tokens scored and the model's own
+    ``counts`` (name → (B,) int32), from every stack; and from one with
+    expert layers — ``stats`` their :func:`expert_stats`, one a layer —
+    ``expert_counts`` and the expert layers' counts beside them."""
     B, T = tokens.shape
 
     def stacked(name):
         return jnp.stack([s[name] for s in stats], 1)
 
-    return {"expert_counts": stacked("expert_counts"),
-            telemetry.PROGRAM_COUNTS: {
-                telemetry.M_SEQUENCE_TOKENS: jnp.full((B,), T, jnp.int32),
-                **counts,
-                telemetry.M_MOE_ROUTED_TOKENS: jnp.full(
-                    (B,), T * len(stats), jnp.int32),
-                telemetry.M_MOE_LOCAL_PAIRS: jnp.sum(
-                    stacked("local_pairs"), 1),
-                telemetry.M_MOE_OVERFLOW_PAIRS: jnp.sum(
-                    stacked("overflow_pairs"), 1),
-                telemetry.M_MOE_BUFFER_ROWS: jnp.sum(
-                    stacked("buffer_rows"), 1),
-                telemetry.M_MOE_FUSED_PRODUCT_LAYERS: jnp.sum(
-                    stacked("fused_products"), 1),
-                telemetry.M_MOE_LOAD_MAX_OVER_MEAN: jnp.broadcast_to(
-                    jnp.stack([s["load_max_over_mean"] for s in stats]),
-                    (B, len(stats)))}}
+    out = {"expert_counts": stacked("expert_counts")} if stats else {}
+    program = {telemetry.M_SEQUENCE_TOKENS: jnp.full((B,), T, jnp.int32),
+               **counts}
+    if stats:
+        program.update({
+            telemetry.M_MOE_ROUTED_TOKENS: jnp.full(
+                (B,), T * len(stats), jnp.int32),
+            telemetry.M_MOE_LOCAL_PAIRS: jnp.sum(stacked("local_pairs"), 1),
+            telemetry.M_MOE_OVERFLOW_PAIRS: jnp.sum(
+                stacked("overflow_pairs"), 1),
+            telemetry.M_MOE_BUFFER_ROWS: jnp.sum(stacked("buffer_rows"), 1),
+            telemetry.M_MOE_FUSED_PRODUCT_LAYERS: jnp.sum(
+                stacked("fused_products"), 1),
+            telemetry.M_MOE_LOAD_MAX_OVER_MEAN: jnp.broadcast_to(
+                jnp.stack([s["load_max_over_mean"] for s in stats]),
+                (B, len(stats)))})
+    return {**out, telemetry.PROGRAM_COUNTS: program}
 
 
 def forward(params, tokens, c: LatentMoEConfig) -> Dict[str, Any]:
@@ -1210,8 +1227,7 @@ def forward(params, tokens, c: LatentMoEConfig) -> Dict[str, Any]:
         if layer_stats is not None:
             stats.append(layer_stats)
     out, fused_head = score_head(params, h, tokens, c.eps)
-    if stats:
-        out.update(expert_outputs(stats, tokens, {
-            telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS: fused_layers,
-            telemetry.M_SEQUENCE_FUSED_HEAD_WINDOWS: fused_head}))
+    out.update(program_counts(stats, tokens, {
+        telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS: fused_layers,
+        telemetry.M_SEQUENCE_FUSED_HEAD_WINDOWS: fused_head}))
     return out

@@ -279,21 +279,39 @@ SEQUENCE_MODELS: Dict[str, SequenceConfig] = {
             theta=100.0, factor=16.0, original=32, beta_fast=4.0,
             beta_slow=1.0, amplitude=1.2772588722239782)),),
         query_block=8),
+    # huggingface.co/ai21labs/AI21-Jamba2-3B config.json (model_type jamba):
+    # the same pre-norm stack without an expert layer (num_experts 1: every
+    # ffn the gated MLP), state-space (Mamba-1) mixers with an attention
+    # layer where i % 14 == 7, 20 query heads on one key head, no rotary —
+    # nothing tells positions apart but the recurrence and the causal mask —
+    # and the head tied
+    "AI21-Jamba2-3B": ShortConvMoEConfig(
+        hidden=2560, heads=20, kv_heads=1, head_dim=128, dense_width=8192,
+        vocab=65536, eps=1e-6, theta=None, d_inner=5120, d_state=16,
+        dt_rank=160, d_conv=4),
+    # ... and one period of it cut to a few layers at sizes a CPU test runs:
+    # state-space layers around one attention layer
+    "TestStateSpace": ShortConvMoEConfig(
+        hidden=64, heads=4, kv_heads=1, head_dim=16, dense_width=128,
+        vocab=64, eps=1e-6, theta=None, d_inner=128, d_state=16, dt_rank=8,
+        d_conv=4, query_block=8),
 }
 
 
 def build_sequence_scorer(name, weights: Dict[str, Any], window: int,
                           experts_held=None) -> ModelFunction:
     """Named sequence model as a ModelFunction over ``(rows, window)`` int32
-    token ids, emitting ``pooled``, ``logprobs`` and ``expert_counts``.
+    token ids, emitting ``pooled``, ``logprobs`` and, from a stack with
+    expert layers, ``expert_counts``.
 
     ``name``: a key of :data:`SEQUENCE_MODELS`, or a config of one's own of
     either of its types. ``weights``: the variables dict the model is run
     with — ``{"embed", "layers": [...], "final_norm", "head"}``, taken as
     given (bfloat16 on the device for a model of this size; there is no
     ``"random"``). The chip's share is read off them: as many layers as
-    the list has, dense where a layer has ``"mlp"``, for a pre-norm stack the
-    mixer by whether a layer has ``"conv"`` or ``"attn"``, the vocabulary
+    the list has, dense where a layer has ``"mlp"`` (every layer may be: a
+    stack needs no expert layer), for a pre-norm stack the mixer by which
+    one of ``"conv"``, ``"attn"`` and ``"ssm"`` a layer has, the vocabulary
     slice of ``embed``'s rows; ``experts_held`` names the expert ids the
     expert layers' stacked weights stand for (default: all of them, where
     all are there). Where the config names its layers' kinds the held layers
@@ -322,9 +340,14 @@ def build_sequence_scorer(name, weights: Dict[str, Any], window: int,
                  "vocab": int(weights["embed"].shape[0])}
         if isinstance(config, LatentMoEConfig):
             share.update(layers=len(layers), dense_layers=dense)
-        elif not all(("conv" in layer) != ("attn" in layer)
-                     for layer in layers):
-            raise ValueError('each layer holds its mixer, "conv" or "attn"')
+        elif not all(sum(mixer in layer for mixer in ("conv", "attn", "ssm"))
+                     == 1 for layer in layers):
+            raise ValueError('each layer holds its one mixer: "ssm", '
+                             '"conv" or "attn"')
+        elif not config.d_inner and any("ssm" in layer for layer in layers):
+            raise ValueError('a layer holds "ssm" and the config gives no '
+                             "state-space sizes (d_inner, d_state, dt_rank, "
+                             "d_conv)")
         elif config.layer_types and not (
                 len(layers) <= len(config.layer_types)
                 and all("attn" in layer for layer in layers)):

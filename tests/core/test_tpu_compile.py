@@ -21,7 +21,10 @@ the pre-norm stack's grouped-query attention holds the fused kernel too, at a
 head width of 128 with a span or without (PR 39) and at one of 64, half a
 lane group, with a key head's four query heads stacked a grid step (PR 40);
 and the head of each of the three sequence models holds the fused scoring
-head, which keeps a window's float32 logits out of HBM (PR 43). A
+head, which keeps a window's float32 logits out of HBM (PR 43); the
+state-space mixer of the fourth holds the selective-scan kernel, which keeps
+a window's states on the chip, and its attention — 20 query heads on one key
+head — the fused kernel (PR 44). A
 PR that ships or drops a kernel changes the assertion where it belongs.
 
 The topology is described inside a fixture of this file and nowhere else:
@@ -41,7 +44,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from sparkdl_tpu.core import ModelFunction, TensorSpec
-from sparkdl_tpu.models import latent_moe, registry, shortconv_moe
+from sparkdl_tpu.models import (latent_moe, registry, shortconv_moe,
+                                state_space)
 from sparkdl_tpu.models.layers import ConvBN, SeparableConvBN
 
 
@@ -375,5 +379,77 @@ def test_span_model_compiles_for_v5e(part, one_chip, no_persistent_cache):
         assert text.count('custom_call_target="tpu_custom_call"') == 2
         assert "grouped_product" in text and "ragged-dot" not in text
         assert "f32[131072,896]" not in text
+    else:
+        _holds_the_fused_head_and_no_logits(text, c.vocab)
+
+
+@pytest.mark.parametrize("part", ["state_space", "attention", "head"])
+def test_state_space_model_compiles_for_v5e(part, one_chip,
+                                            no_persistent_cache):
+    """AI21-Jamba2-3B's parts at the published widths and the cell's launch
+    (one window of 16,384), bfloat16 weights, shapes only: the state-space
+    mixer, which holds the selective-scan kernel — 128 blocks of 128
+    positions, the state of 5,120 × 16 values on the chip throughout — and
+    builds no window's states (5.4 GB) nor a block's coefficients in HBM;
+    attention without a rotary, 20 query heads on one key head of 128, which
+    is the fused kernel; the tied head over 65,536 ids at a hidden of 2,560,
+    which is the fused scoring head."""
+    c = registry.SEQUENCE_MODELS["AI21-Jamba2-3B"]
+    window = 16384
+    assert window % state_space.SCAN_TIME_BLOCK == 0
+    assert (c.d_inner, c.d_state, c.dt_rank, c.d_conv) == (5120, 16, 160, 4)
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if part == "state_space":
+        p = {"in": on_chip((c.hidden, 2 * c.d_inner)),
+             "taps": on_chip((c.d_inner, c.d_conv)),
+             "conv_bias": on_chip((c.d_inner,)),
+             "x": on_chip((c.d_inner, c.dt_rank + 2 * c.d_state)),
+             "dt_norm": on_chip((c.dt_rank,)),
+             "b_norm": on_chip((c.d_state,)), "c_norm": on_chip((c.d_state,)),
+             "dt": on_chip((c.dt_rank, c.d_inner)),
+             "dt_bias": on_chip((c.d_inner,)),
+             "a_log": on_chip((c.d_inner, c.d_state)),
+             "d": on_chip((c.d_inner,)),
+             "out": on_chip((c.d_inner, c.hidden))}
+
+        def fn(p, x):
+            return state_space.state_space(p, x, c)
+
+        x = on_chip((1, window, c.hidden), jnp.float32)
+    elif part == "attention":
+        wide, narrow = c.heads * c.head_dim, c.kv_heads * c.head_dim
+        assert (wide, narrow) == (2560, 128)
+        p = {"q": on_chip((c.hidden, wide)), "k": on_chip((c.hidden, narrow)),
+             "v": on_chip((c.hidden, narrow)),
+             "out": on_chip((wide, c.hidden))}
+
+        def fn(p, x):
+            return shortconv_moe.grouped_attention(p, x, c)
+
+        x = on_chip((window, c.hidden), jnp.float32)
+    else:
+        embed = on_chip((c.vocab, c.hidden))
+        p = {"embed": embed, "head": embed,
+             "final_norm": on_chip((c.hidden,))}
+
+        def fn(p, x):
+            return latent_moe.score_head(
+                p, x, jnp.zeros((1, window), jnp.int32), c.eps)
+
+        x = on_chip((1, window, c.hidden), jnp.float32)
+    text = jax.jit(fn).lower(p, x).compile().as_text()
+    assert ":T(" in text  # tiled layouts: the TPU's compiler made this
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    if part == "state_space":
+        assert "selective_scan" in text
+        assert "f32[16384,16,5120]" not in text
+        assert f"f32[{state_space.PLAIN_BLOCK},16,5120]" not in text
+    elif part == "attention":
+        assert "fused_causal_attention" in text
+        # no scores of the key head's 20 query heads × a block of queries
+        assert "f32[1,20,512," not in text
     else:
         _holds_the_fused_head_and_no_logits(text, c.vocab)

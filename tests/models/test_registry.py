@@ -81,3 +81,60 @@ def test_featurizer_weights_roundtrip_msgpack(tmp_path, rng):
     x = rng.uniform(0, 255, size=(2, 32, 32, 3)).astype(np.float32)
     np.testing.assert_allclose(np.asarray(mf(x)), np.asarray(mf2(x)),
                                rtol=1e-6)
+
+
+# -- sequence models: what the registry names, by shape only -------------------
+
+_SEQUENCE_STACKS = {
+    # name → (config type, has expert layers, has state-space sizes, rotary)
+    "openPangu-Ultra-MoE-718B": ("LatentMoEConfig", True, False, True),
+    "TestLatentMoE": ("LatentMoEConfig", True, False, True),
+    "LFM2-8B-A1B": ("ShortConvMoEConfig", True, False, True),
+    "TestShortConvMoE": ("ShortConvMoEConfig", True, False, True),
+    "Mellum2-12B-A2.5B-Instruct": ("ShortConvMoEConfig", True, False, True),
+    "TestSpanMoE": ("ShortConvMoEConfig", True, False, True),
+    "AI21-Jamba2-3B": ("ShortConvMoEConfig", False, True, False),
+    "TestStateSpace": ("ShortConvMoEConfig", False, True, False),
+}
+
+
+def test_sequence_models_are_the_eight_the_docs_name():
+    assert set(registry.SEQUENCE_MODELS) == set(_SEQUENCE_STACKS)
+
+
+@pytest.mark.parametrize("name", sorted(_SEQUENCE_STACKS))
+def test_sequence_model_config_says_what_its_stack_has(name):
+    """The expert fields are at none exactly where the model has no expert
+    layer, the state-space sizes set exactly where it has such layers, and a
+    model without a rotary gives no ``theta``."""
+    kind, experts, state_space, rotary = _SEQUENCE_STACKS[name]
+    c = registry.SEQUENCE_MODELS[name]
+    assert type(c).__name__ == kind
+    assert (c.experts > 0 and c.top_k > 0 and len(c.experts_held)
+            == c.experts and c.expert_width > 0) == experts
+    assert (c.experts == 0 and c.experts_held == () and c.top_k == 0
+            and c.expert_width == 0) != experts
+    if kind == "ShortConvMoEConfig":
+        assert (c.d_inner > 0 and c.d_state > 0 and c.dt_rank > 0
+                and c.d_conv > 0) == state_space
+        if not state_space:
+            assert (c.d_inner, c.d_state, c.dt_rank, c.d_conv) == (0, 0, 0, 0)
+        else:
+            assert c.d_inner == 2 * c.hidden and c.d_inner % 128 == 0
+    assert (c.theta is not None) == rotary
+    assert c.hidden > 0 and c.vocab > 0 and c.dense_width > 0
+
+
+def test_a_test_twin_differs_from_its_model_by_size_only():
+    """``TestStateSpace`` is ``AI21-Jamba2-3B`` at sizes a CPU runs: the same
+    fields set and unset, one key head, no rotary, the published eps."""
+    import dataclasses
+
+    big = dataclasses.asdict(registry.SEQUENCE_MODELS["AI21-Jamba2-3B"])
+    small = dataclasses.asdict(registry.SEQUENCE_MODELS["TestStateSpace"])
+    assert {k for k, v in big.items() if not v} == {
+        k for k, v in small.items() if not v}
+    assert (big["kv_heads"], big["eps"], big["theta"], big["d_state"],
+            big["d_conv"]) == (small["kv_heads"], small["eps"],
+                               small["theta"], small["d_state"],
+                               small["d_conv"]) == (1, 1e-6, None, 16, 4)
