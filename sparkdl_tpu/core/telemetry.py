@@ -289,6 +289,9 @@ M_SEQUENCE_SSM_LAYERS = "sparkdl.sequence.ssm_layers"
 # counter (per row: the state-space layers whose recurrence was lowered to
 # the kernel, models/state_space.py fused_selective_scan; beside the former)
 M_SEQUENCE_FUSED_SCAN_LAYERS = "sparkdl.sequence.fused_scan_layers"
+# counter (per row: the attention layers whose heads' outputs a sigmoid gate
+# scaled, models/shortconv_moe.py; from a stack whose layers hold one)
+M_SEQUENCE_GATED_ATTENTION_LAYERS = "sparkdl.sequence.gated_attention_layers"
 M_MOE_ROUTED_TOKENS = "sparkdl.moe.routed_tokens"      # counter (tokens
                                                        # routed, once for each
                                                        # expert layer)
@@ -307,6 +310,15 @@ M_MOE_BUFFER_ROWS = "sparkdl.moe.buffer_rows"          # counter (per row its
 # counter (per row: the expert layers whose grouped products were lowered to
 # the kernel, models/latent_moe.py grouped_product)
 M_MOE_FUSED_PRODUCT_LAYERS = "sparkdl.moe.fused_product_layers"
+# counters (per row its share, summed over the expert layers: the grouped
+# kernel's visits by its grid's rule — a row tile once for each group it
+# holds a row of — and the buffer's row tiles, rounds × the buffer; counted
+# on either path, models/latent_moe.py product_visits)
+M_MOE_PRODUCT_VISITS = "sparkdl.moe.product_visits"
+M_MOE_PRODUCT_ROW_TILES = "sparkdl.moe.product_row_tiles"
+# counter (per row: the expert layers with a shared expert beside the routed
+# ones, models/shortconv_moe.py; from a stack whose layers hold one)
+M_MOE_SHARED_EXPERT_LAYERS = "sparkdl.moe.shared_expert_layers"
 M_MOE_LOAD_MAX_OVER_MEAN = "sparkdl.moe.load_max_over_mean"  # histogram (per
                                                        # row and expert layer:
                                                        # its launch's fullest
@@ -378,11 +390,15 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_SEQUENCE_SCORED_KEYS: "counter",
     M_SEQUENCE_SSM_LAYERS: "counter",
     M_SEQUENCE_FUSED_SCAN_LAYERS: "counter",
+    M_SEQUENCE_GATED_ATTENTION_LAYERS: "counter",
     M_MOE_ROUTED_TOKENS: "counter",
     M_MOE_LOCAL_PAIRS: "counter",
     M_MOE_OVERFLOW_PAIRS: "counter",
     M_MOE_BUFFER_ROWS: "counter",
     M_MOE_FUSED_PRODUCT_LAYERS: "counter",
+    M_MOE_PRODUCT_VISITS: "counter",
+    M_MOE_PRODUCT_ROW_TILES: "counter",
+    M_MOE_SHARED_EXPERT_LAYERS: "counter",
     M_MOE_LOAD_MAX_OVER_MEAN: "histogram",
     **{STARTUP_METRIC_PREFIX + key: "gauge" for key in STARTUP_KEYS},
 }

@@ -140,7 +140,9 @@ class Rope:
     that turn less than ``beta_slow`` times over ``original`` positions
     divided by ``factor``, those that turn more than ``beta_fast`` times left
     alone, a linear ramp between, and ``amplitude`` on cos and sin (the
-    scores carry its square)."""
+    scores carry its square). ``partial`` is the share of a head that turns
+    (``partial_rotary_factor``): its first dimensions, over which the
+    frequencies and the ramp are; the rest pass through."""
 
     theta: float
     factor: float = 1.0
@@ -148,6 +150,11 @@ class Rope:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     amplitude: float = 1.0
+    partial: float = 1.0
+
+    def turned(self, head_dim):
+        """The leading dimensions of a head of ``head_dim`` that turn."""
+        return int(head_dim * self.partial)
 
     def ramp(self, half):
         """``(low, high)``: the first dimension of a head's ``half`` that is
@@ -688,6 +695,20 @@ def _group_visits(sizes, rows, row_tile):
     return group_of, jnp.clip(tile_of, 0, tiles - 1), offsets, jnp.sum(count)
 
 
+def product_visits(sizes, rows, row_tile=GROUPED_ROW_TILE):
+    """The visits of :func:`_group_visits`' grid over a buffer of ``rows``
+    whose groups have ``sizes`` (traced), counted on either path: one for
+    each (row tile, group) that share a row and one for each whole tile past
+    the last group's end. A last tile of fewer rows counts as a tile."""
+    tiles = -(-rows // row_tile)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    touched = jnp.where(sizes > 0,
+                        (ends - 1) // row_tile - starts // row_tile + 1, 0)
+    covered = -(-ends[-1] // row_tile)          # tiles that hold a group's row
+    return jnp.sum(touched) + tiles - covered
+
+
 def _grouped_kernel(group_of, tile_of, offsets, rows_ref, *refs, row_tile,
                     sub_tile):
     """One visit of :func:`_group_visits`: the tile's rows times the group's
@@ -948,20 +969,37 @@ def expert_stats(chosen, counts, overflow, fused, rows, c):
     """What an expert layer reports of a launch of ``rows`` windows, from
     :func:`routed_experts`' returns: per row the tokens per published expert,
     the pairs that met a held expert, those computed beyond the first round,
-    its share of the rows the grouped products ran (rounds × the buffer), and
-    whether those products were lowered to the kernel; of the launch, the
+    its share of the rows the grouped products ran (rounds × the buffer), of
+    the grouped kernel's visits (:func:`product_visits`, each round's groups
+    the held experts' pairs that fall in it) and of the buffer's row tiles,
+    and whether those products were lowered to the kernel; of the launch, the
     fullest held expert's pairs over the mean."""
     held = jnp.asarray(c.experts_held, jnp.int32)
     per_row = jnp.sum(chosen.reshape(rows, -1, 1) == jnp.arange(c.experts), 1)
     capacity = buffer_capacity(chosen.shape[0], c)
-    ran = (-(-jnp.sum(counts) // capacity) * capacity).astype(jnp.int32)
+    rounds = -(-jnp.sum(counts) // capacity)
+    ran = (rounds * capacity).astype(jnp.int32)
+    first = jnp.arange(-(-chosen.size // capacity))[:, None] * capacity
+    ends = jnp.cumsum(counts)
+    sizes = (jnp.clip(ends - first, 0, capacity)
+             - jnp.clip(ends - counts - first, 0, capacity))
+    visits = jnp.sum(jnp.where(
+        jnp.arange(sizes.shape[0]) < rounds,
+        jax.vmap(lambda s: product_visits(s, capacity))(sizes), 0))
+    tiles = (rounds * -(-capacity // GROUPED_ROW_TILE)).astype(jnp.int32)
+
+    def spread(total):
+        """A whole number a row, and the rows' sum is the launch's."""
+        return total // rows + (jnp.arange(rows) < total % rows)
+
     return {
         "expert_counts": per_row.astype(jnp.int32),              # (B, experts)
         "local_pairs": jnp.sum(per_row[:, held], -1).astype(jnp.int32),
         "overflow_pairs": jnp.sum(overflow.reshape(rows, -1), -1
                                   ).astype(jnp.int32),
-        # a whole number a row, and the rows' sum is the launch's
-        "buffer_rows": ran // rows + (jnp.arange(rows) < ran % rows),
+        "buffer_rows": spread(ran),
+        "product_visits": spread(visits.astype(jnp.int32)),
+        "product_row_tiles": spread(tiles),
         "fused_products": jnp.broadcast_to(fused, (rows,)),
         "load_max_over_mean": jnp.max(counts) / jnp.maximum(
             jnp.mean(counts.astype(jnp.float32)), 1.0),
@@ -1207,6 +1245,10 @@ def program_counts(stats, tokens, counts):
             telemetry.M_MOE_OVERFLOW_PAIRS: jnp.sum(
                 stacked("overflow_pairs"), 1),
             telemetry.M_MOE_BUFFER_ROWS: jnp.sum(stacked("buffer_rows"), 1),
+            telemetry.M_MOE_PRODUCT_VISITS: jnp.sum(
+                stacked("product_visits"), 1),
+            telemetry.M_MOE_PRODUCT_ROW_TILES: jnp.sum(
+                stacked("product_row_tiles"), 1),
             telemetry.M_MOE_FUSED_PRODUCT_LAYERS: jnp.sum(
                 stacked("fused_products"), 1),
             telemetry.M_MOE_LOAD_MAX_OVER_MEAN: jnp.broadcast_to(

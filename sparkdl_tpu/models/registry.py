@@ -295,6 +295,41 @@ SEQUENCE_MODELS: Dict[str, SequenceConfig] = {
         hidden=64, heads=4, kv_heads=1, head_dim=16, dense_width=128,
         vocab=64, eps=1e-6, theta=None, d_inner=128, d_state=16, dt_rank=8,
         d_conv=4, query_block=8),
+    # huggingface.co/poolside/Laguna-XS.2 config.json (model_type laguna): the
+    # same pre-norm stack, a full layer (48 query heads, YaRN over the first
+    # half of a head) where i % 4 == 0 and three sliding ones (64 query heads,
+    # span 512, the plain rotary over the whole head) to it, 8 key heads
+    # throughout — the query heads come with the weights —, a sigmoid gate
+    # on each head's output, a dense gated MLP in layer 0 and after it 256
+    # experts of 512 with a shared one of 512, a sigmoid router renormalised
+    # and scaled by 2.5, the head untied
+    "Laguna-XS.2": ShortConvMoEConfig(
+        hidden=2048, heads=48, kv_heads=8, head_dim=128, dense_width=8192,
+        expert_width=512, experts=256, experts_held=tuple(range(256)),
+        top_k=8, vocab=100352, scaling=2.5, norm_topk=True, topk_eps=0.0,
+        scoring="sigmoid", eps=1e-6, theta=10000.0,
+        layer_types=tuple("full_attention" if i % 4 == 0
+                          else "sliding_attention" for i in range(40)),
+        span=512, rope=(
+            ("full_attention", latent_moe.Rope(
+                theta=500000.0, factor=64.0, original=4096, beta_fast=64.0,
+                beta_slow=1.0, amplitude=1.4158883083359672, partial=0.5)),
+            ("sliding_attention", latent_moe.Rope(theta=10000.0)))),
+    # ... and its stack at sizes a CPU test runs: two periods, 6 and 8 query
+    # heads on 2 key heads, a span shorter than a test's window, a gate on
+    # every attention layer, a shared expert beside the routed ones
+    "TestLaguna": ShortConvMoEConfig(
+        hidden=64, heads=6, kv_heads=2, head_dim=16, dense_width=128,
+        expert_width=32, experts=16, experts_held=tuple(range(16)), top_k=4,
+        vocab=64, scaling=2.5, norm_topk=True, topk_eps=0.0,
+        scoring="sigmoid", eps=1e-6, theta=100.0,
+        layer_types=(("full_attention",) + ("sliding_attention",) * 3) * 2,
+        span=8, rope=(
+            ("full_attention", latent_moe.Rope(
+                theta=1000.0, factor=4.0, original=64, beta_fast=4.0,
+                beta_slow=1.0, amplitude=1.1386294361119891, partial=0.5)),
+            ("sliding_attention", latent_moe.Rope(theta=100.0))),
+        query_block=8),
 }
 
 
@@ -315,7 +350,9 @@ def build_sequence_scorer(name, weights: Dict[str, Any], window: int,
     slice of ``embed``'s rows; ``experts_held`` names the expert ids the
     expert layers' stacked weights stand for (default: all of them, where
     all are there). Where the config names its layers' kinds the held layers
-    are its leading ones, each of the kind at its position.
+    are its leading ones, each of the kind at its position. A pre-norm
+    layer's query and key heads are its weights' (columns ÷ ``head_dim``),
+    the query heads a multiple of the key heads.
     """
     config = SEQUENCE_MODELS.get(name) if isinstance(name, str) else name
     label = name if isinstance(name, str) else type(config).__name__
@@ -355,6 +392,10 @@ def build_sequence_scorer(name, weights: Dict[str, Any], window: int,
                 f"the config names the kind of attention of its "
                 f"{len(config.layer_types)} layers; the weights hold "
                 f'{len(layers)}, which must be its leading ones, each "attn"')
+        elif any(layer["attn"]["q"].shape[1] % layer["attn"]["k"].shape[1]
+                 for layer in layers if "attn" in layer):
+            raise ValueError("a layer's query heads must be a multiple of "
+                             "its key heads")
         config = dataclasses.replace(config, **share)
         forward = _SEQUENCE_FORWARD[type(config)]
         mf = ModelFunction.fromFunction(

@@ -1,10 +1,13 @@
 """The pre-norm decoder as a prefill-only window scorer — the stack of the
 ``lfm2_moe`` family (gated short convolutions among grouped-query attention
 layers), of the ``mellum`` family (grouped-query attention in every layer,
-sliding-window layers among full ones, each kind with its own rotary) and of
+sliding-window layers among full ones, each kind with its own rotary), of
 the ``jamba`` family (state-space mixers with an attention layer among them,
-no rotary, no expert layer), run by ``DeepSequenceScorer`` exactly as the
-latent-attention model of ``registry.SEQUENCE_MODELS`` is.
+no rotary, no expert layer) and of the ``laguna`` family (full and sliding
+layers with their own query heads and rotaries, a sigmoid gate on each
+head's output, a shared expert beside the routed ones), run by
+``DeepSequenceScorer`` exactly as the latent-attention model of
+``registry.SEQUENCE_MODELS`` is.
 
 ``h ← h + mixer(RMSNorm(h))``, ``h ← h + ffn(RMSNorm(h))``. **Three mixers**,
 by position, and a layer's weights say which it has. A layer that holds
@@ -15,21 +18,25 @@ parts ``[B ; C ; x̃]``, a depthwise causal convolution of a few taps over
 **state-space mixer** (``models/state_space.py``: the same taps with a bias,
 inner norms, and a recurrence along the window whose state starts from zero
 in each). A layer that holds ``"attn"`` runs **grouped-query attention**:
-fewer key heads than query heads, an RMSNorm per head on queries and keys
-where the weights hold one (``"q_norm"``), rotary on both where the config
-gives a ``theta``; query head ``a`` reads key head ``a // (heads /
-kv_heads)`` and keys and values are never repeated per query head. What the
-weights cannot say — a number is no array — comes from the
-config by position, the held layers being the leading ones: where it names
-its layers' kinds (``layer_types``), a ``"sliding_attention"`` layer's query
-reads the last ``span`` keys only, its own among them, and a kind's rotary
-is the one ``rope`` gives it (``latent_moe.Rope``: plain, or YaRN's scaled
-frequencies and amplitude). The ffn is a gated MLP where the layer holds
-``"mlp"`` — every layer may: **the expert layer is optional** — else the
-sparse expert layer, the one the latent-attention model runs
-(``latent_moe.routed_experts``), here without a shared expert, its router's
-scores sigmoids with a selection bias or a soft-max (``scoring``), and
-usually held whole.
+fewer key heads than query heads, as many of each as the layer's weights
+have (a kind of layer may have its own), an RMSNorm per head on queries and
+keys where the weights hold one (``"q_norm"``), rotary on both where the
+config gives a ``theta``; query head ``a`` reads key head ``a // (heads /
+kv_heads)`` and keys and values are never repeated per query head; where the
+weights hold a ``"gate"``, each head's output is scaled by a sigmoid of its
+own before the output projection. What the weights cannot say — a number is
+no array — comes from the config by position, the held layers being the
+leading ones: where it names its layers' kinds (``layer_types``), a
+``"sliding_attention"`` layer's query reads the last ``span`` keys only, its
+own among them, and a kind's rotary is the one ``rope`` gives it
+(``latent_moe.Rope``: plain, or YaRN's scaled frequencies and amplitude,
+over the whole head or over its leading share). The ffn is a gated MLP where
+the layer holds ``"mlp"`` — every layer may: **the expert layer is
+optional** — else the sparse expert layer, the one the latent-attention
+model runs (``latent_moe.routed_experts``), with a shared expert beside the
+routed ones where the layer holds ``"shared"`` and without one otherwise,
+its router's scores sigmoids with a selection bias or a soft-max
+(``scoring``), and usually held whole.
 
 Shared with ``models/latent_moe.py`` and imported from it: ``rms_norm``, the
 mixed-precision product, ``rotary``, the causal taps, causal attention over
@@ -38,14 +45,15 @@ grouped keys with its two paths (``grouped_causal_attention``),
 scorer's head and counts (``score_head``, ``program_counts``). Precision
 follows the weights, as there: bfloat16 products with float32 accumulation;
 the router, the norms' statistics, the soft-max, the rotary tables, the gates
-and taps of the convolution, the state-space mixer's recurrence, the
-residual stream and the log-probabilities float32.
+and taps of the convolution, attention's gates, the state-space mixer's
+recurrence, the residual stream and the log-probabilities float32.
 
 Attention goes down ``latent_moe``'s fused kernel where a lowering for a TPU
 finds bfloat16 weights, a window of whole query tiles and a head width the
 kernel takes — 128, whole lanes: every layer of the ``mellum`` family, a
 sliding layer visiting only the key tiles its span reaches, and the ``jamba``
-family's, 20 query heads reading one key head; 64, half a lane group: every
+family's, 20 query heads reading one key head, and the ``laguna`` family's,
+6 (full) or 8 (sliding) of them, rotated before; 64, half a lane group: every
 attention layer of the ``lfm2_moe`` family, a key head's four query heads
 worked together a grid step — and down the blocked path everywhere else,
 with the same span. Every stack, with an expert layer or without, reports the
@@ -61,8 +69,11 @@ Outputs per window are ``latent_moe``'s, ``expert_counts`` only from a stack
 with expert layers; the program's counts gain
 ``sparkdl.sequence.conv_layers``, from a stack that names its layers' kinds
 ``sparkdl.sequence.window_attention_layers`` and
-``sparkdl.sequence.scored_keys``, and from one with state-space layers
-``sparkdl.sequence.ssm_layers`` and ``sparkdl.sequence.fused_scan_layers``.
+``sparkdl.sequence.scored_keys``, from one with state-space layers
+``sparkdl.sequence.ssm_layers`` and ``sparkdl.sequence.fused_scan_layers``,
+from one whose layers hold gates ``sparkdl.sequence.gated_attention_layers``
+and from one whose layers hold shared experts
+``sparkdl.moe.shared_expert_layers`` (under the ``shared_expert`` scope).
 """
 
 from __future__ import annotations
@@ -88,10 +99,12 @@ class ShortConvMoEConfig:
     """Widths as published; ``experts_held`` and ``vocab`` are what this chip
     holds of a stated deployment. How many layers there are, and which mixer
     and ffn each has, is read off the weights; the kind of an attention
-    layer, where a model has several, by position off ``layer_types``. A
-    model without expert layers leaves the expert fields at none, one
-    without state-space layers the state-space sizes, and one whose
-    attention turns nothing gives no ``theta``."""
+    layer, where a model has several, by position off ``layer_types``; a
+    layer's query and key heads off its weights too (``heads`` is the
+    published ``num_attention_heads``). A model without expert layers leaves
+    the expert fields at none, one without state-space layers the
+    state-space sizes, and one whose attention turns nothing gives no
+    ``theta``."""
 
     hidden: int
     heads: int
@@ -139,15 +152,20 @@ def short_conv(p, u):
 def grouped_attention(p, u, c: ShortConvMoEConfig, kind=None):
     """u (T, hidden) float32, one window → ``((T, hidden) float32, fused,
     scored)``, the last two as ``latent_moe.grouped_causal_attention``
-    returns them. Queries and keys are normed per head where the weights
-    hold the gains (one of ``head_dim`` each) and, where ``c`` gives a
-    ``theta``, rotated in float32 — by the rotary and, for
-    ``"sliding_attention"``, within the span that ``c`` gives the layer's
-    ``kind``; without one nothing tells positions apart but the causal mask —
-    the queries carry the scores' scale, and the operands go to the attention in the weights' dtype, the keys and values
-    once a key head."""
+    returns them. The query and key heads are read off the weights (their
+    columns ÷ ``head_dim``), so a model's kinds of layer may differ in them.
+    Queries and keys are normed per head where the weights hold the gains
+    (one of ``head_dim`` each) and, where ``c`` gives a ``theta``, rotated in
+    float32 — by the rotary that ``c`` gives the layer's ``kind``, over the
+    share of a head it turns, and for ``"sliding_attention"`` within the
+    span; without one nothing tells positions apart but the causal mask —
+    the queries carry the scores' scale, and the operands go to the attention
+    in the weights' dtype, the keys and values once a key head. Where the
+    weights hold a ``"gate"`` (hidden × query heads), each head's output is
+    scaled by ``σ(u W_g)`` of its own, in float32, before ``W_o``."""
     T = u.shape[0]
     act = p["out"].dtype
+    query_heads = p["q"].shape[1] // c.head_dim
 
     def heads(a):                           # (T, n · d) → (T, n, d)
         return a.reshape(T, -1, c.head_dim)
@@ -158,20 +176,31 @@ def grouped_attention(p, u, c: ShortConvMoEConfig, kind=None):
         return a if gain is None else rms_norm(a, gain, c.eps)
 
     rope = dict(c.rope).get(kind)       # None: the plain rotary of c.theta
+    turned = c.head_dim if rope is None else rope.turned(c.head_dim)
     turn = {} if rope is None else {
-        "frequencies": rope.frequencies(c.head_dim // 2),
+        "frequencies": rope.frequencies(turned // 2),
         "amplitude": rope.amplitude}
 
-    def placed(a, heads):           # (T, n, d) → (T, n · d), turned or not
-        a = a.reshape(T, -1)
-        return a if c.theta is None else rotary(a, c.theta, heads, **turn)
+    def placed(a):                  # (T, n, d) → (T, n · d), turned or not
+        n = a.shape[1]
+        if c.theta is None:
+            return a.reshape(T, -1)
+        if turned == c.head_dim:
+            return rotary(a.reshape(T, -1), c.theta, n, **turn)
+        # a head's leading dimensions turn, by halves of them; the rest pass
+        part = rotary(a[..., :turned].reshape(T, -1), c.theta, n, **turn)
+        return jnp.concatenate([part.reshape(T, n, turned),
+                                a[..., turned:]], -1).reshape(T, -1)
 
     q = projected("q") * c.head_dim ** -0.5
     k = projected("k")
     out, fused, scored = grouped_causal_attention(
-        placed(q, c.heads), placed(k, c.kv_heads), _dot(u, p["v"], act),
-        c.heads, c.query_block,
-        c.span if kind == "sliding_attention" else None)
+        placed(q), placed(k), _dot(u, p["v"], act), query_heads,
+        c.query_block, c.span if kind == "sliding_attention" else None)
+    if "gate" in p:
+        gate = jax.nn.sigmoid(_dot(u, p["gate"]))       # (T, query heads)
+        out = (out.reshape(T, query_heads, -1) * gate[..., None]).reshape(
+            T, -1)
     return _dot(out, p["out"]), fused, scored
 
 
@@ -210,8 +239,12 @@ def block(layer, h, c: ShortConvMoEConfig, kind=None):
     if "moe" not in layer:
         return (h + lax.map(lambda row: gated_mlp(layer["mlp"], row), x),
                 None, told)
+    flat = x.reshape(B * T, -1)
     with jax.named_scope("routed_experts"):
-        routed, *stats = routed_experts(layer["moe"], x.reshape(B * T, -1), c)
+        routed, *stats = routed_experts(layer["moe"], flat, c)
+    if "shared" in layer["moe"]:
+        with jax.named_scope("shared_expert"):
+            routed = routed + gated_mlp(layer["moe"]["shared"], flat)
     return h + routed.reshape(B, T, -1), expert_stats(*stats, B, c), told
 
 
@@ -233,9 +266,11 @@ def forward(params, tokens, c: ShortConvMoEConfig) -> Dict[str, Any]:
         return sum((t[name] for t in told if name in t),
                    jnp.zeros((rows,), jnp.int32))
 
-    def layers_with(mixer):
-        return jnp.full((rows,), sum(mixer in layer for layer in layers),
-                        jnp.int32)
+    def layers_with(mixer, part=None):
+        """Each row: the layers that hold ``mixer`` (within ``part``)."""
+        return jnp.full((rows,), sum(
+            mixer in (layer if part is None else layer.get(part, ()))
+            for layer in layers), jnp.int32)
 
     counts = {
         telemetry.M_SEQUENCE_FUSED_ATTENTION_LAYERS: summed("fused"),
@@ -248,5 +283,11 @@ def forward(params, tokens, c: ShortConvMoEConfig) -> Dict[str, Any]:
     if any("ssm" in layer for layer in layers):
         counts[telemetry.M_SEQUENCE_SSM_LAYERS] = layers_with("ssm")
         counts[telemetry.M_SEQUENCE_FUSED_SCAN_LAYERS] = summed("fused_scan")
+    if any("gate" in layer.get("attn", ()) for layer in layers):
+        counts[telemetry.M_SEQUENCE_GATED_ATTENTION_LAYERS] = layers_with(
+            "gate", "attn")
+    if any("shared" in layer.get("moe", ()) for layer in layers):
+        counts[telemetry.M_MOE_SHARED_EXPERT_LAYERS] = layers_with(
+            "shared", "moe")
     out.update(program_counts(stats, tokens, counts))
     return out

@@ -4,7 +4,9 @@ real shapes: the model zoo's convolution units (``models/layers.py``),
 scorer's latent attention (``models/latent_moe.py``), the second sequence
 model's mixers and the expert layer held whole (``models/shortconv_moe.py``),
 and the third's attention of both kinds, its expert layer and its head at a
-window of 16,384 (the same module).
+window of 16,384 (the same module), and the fifth's gated attention of both
+kinds with their own query heads and its expert layer of 256 narrow experts
+(the same module).
 
 These are the only tier-1 tests that hand the product's code to the TPU's
 compiler: each program is compiled ahead of time for a DESCRIBED
@@ -453,3 +455,57 @@ def test_state_space_model_compiles_for_v5e(part, one_chip,
         assert "f32[1,20,512," not in text
     else:
         _holds_the_fused_head_and_no_logits(text, c.vocab)
+
+
+@pytest.mark.parametrize("part", ["full_attention", "sliding_attention",
+                                  "routed_experts"])
+def test_gated_attention_and_narrow_experts_compile_for_v5e(
+        part, one_chip, no_persistent_cache):
+    """Laguna-XS.2's parts at the published widths and the cell's launch (4
+    windows of 4,096), bfloat16 weights, shapes only: one window's gated
+    attention of each kind — 48 query heads (a full layer, half of each head
+    turned) or 64 (a sliding one, span 512) on 8 key heads of 128, the query
+    heads read off the weights — which is the fused kernel on grouped keys
+    and builds no scores in HBM; the expert layer of 256 experts of 512 held
+    whole, a buffer of the 131,072 pairs, the grouped kernel twice."""
+    c = registry.SEQUENCE_MODELS["Laguna-XS.2"]
+    window = 4096
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if part.endswith("attention"):
+        heads = 48 if part == "full_attention" else 64
+        wide, narrow = heads * c.head_dim, c.kv_heads * c.head_dim
+        p = {"q": on_chip((c.hidden, wide)), "k": on_chip((c.hidden, narrow)),
+             "v": on_chip((c.hidden, narrow)),
+             "gate": on_chip((c.hidden, heads)),
+             "out": on_chip((wide, c.hidden))}
+
+        def fn(p, x):
+            return shortconv_moe.grouped_attention(p, x, c, part)
+
+        x = on_chip((window, c.hidden), jnp.float32)
+    else:
+        p = {"router": on_chip((c.hidden, c.experts)),
+             "experts": {
+                 "gate": on_chip((c.experts, c.hidden, c.expert_width)),
+                 "up": on_chip((c.experts, c.hidden, c.expert_width)),
+                 "down": on_chip((c.experts, c.expert_width, c.hidden))}}
+
+        def fn(p, x):
+            return latent_moe.routed_experts(p, x, c)
+
+        x = on_chip((4 * window, c.hidden), jnp.float32)
+    text = jax.jit(fn).lower(p, x).compile().as_text()
+    assert ":T(" in text  # tiled layouts: the TPU's compiler made this
+    if part.endswith("attention"):
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        assert "fused_causal_attention" in text
+        # no scores of a key head's query heads × a block of queries
+        assert f"f32[8,{heads // 8},512," not in text
+    else:
+        assert latent_moe.buffer_capacity(4 * window, c) == 131072
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+        assert "grouped_product" in text and "ragged-dot" not in text
+        assert "f32[131072,512]" not in text

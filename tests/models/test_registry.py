@@ -95,10 +95,12 @@ _SEQUENCE_STACKS = {
     "TestSpanMoE": ("ShortConvMoEConfig", True, False, True),
     "AI21-Jamba2-3B": ("ShortConvMoEConfig", False, True, False),
     "TestStateSpace": ("ShortConvMoEConfig", False, True, False),
+    "Laguna-XS.2": ("ShortConvMoEConfig", True, False, True),
+    "TestLaguna": ("ShortConvMoEConfig", True, False, True),
 }
 
 
-def test_sequence_models_are_the_eight_the_docs_name():
+def test_sequence_models_are_the_ten_the_docs_name():
     assert set(registry.SEQUENCE_MODELS) == set(_SEQUENCE_STACKS)
 
 
