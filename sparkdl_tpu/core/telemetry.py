@@ -189,7 +189,7 @@ M_PLANNER_WASTE = "sparkdl.batching.planner_waste"     # gauge (pad fraction)
 M_ENGINE_ROWS_OUT = "sparkdl.engine.rows_out"          # counter
 M_ENGINE_BYTES_OUT = "sparkdl.engine.bytes_out"        # counter
 # Leaf values collect() turned into Python numbers through numpy instead of
-# an Arrow scalar each (engine/dataframe.py _table_rows): rows × width of a
+# an Arrow scalar each (engine/dataframe.py _append_rows): rows × width of a
 # featurizer's output column; 0 for a frame with no vector column.
 M_COLLECT_VECTORIZED_VALUES = "sparkdl.collect.vectorized_values"  # counter
 # Device execution service (core/executor.py, docs/PERF.md coalescing):

@@ -28,8 +28,8 @@ import concurrent.futures as _futures
 import itertools
 import os
 import threading
-from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
-                    Tuple, Union)
+from typing import (Any, Callable, Container, Dict, Iterable, List, Optional,
+                    Sequence, Tuple, Union)
 
 import numpy as np
 import pandas as pd
@@ -592,9 +592,20 @@ def _cluster_dispatch() -> Callable[..., pa.RecordBatch]:
 # catalog module is imported by paths that never see a DataFrame.
 M_COLLECT_OVERLAPPED_ROWS = telemetry.declare_metric(
     "sparkdl.collect.overlapped_rows", "counter")
+# Rows whose carried cells (the columns the ops pass through from the source
+# partition unchanged) collect() built while the partition's task still ran,
+# and took once it resolved: only the computed columns were left to convert.
+M_COLLECT_CARRIED_ROWS = telemetry.declare_metric(
+    "sparkdl.collect.carried_rows", "counter")
+
+# Rows of one slice of the carried cells built ahead: a resolved partition
+# waits for at most one slice, and the partitions' threads for the GIL no
+# longer than the interpreter's own switch interval, about 5 ms.
+_AHEAD_ROWS = 64
 
 
-def _row_assembly(table: pa.Table, to: str, **attributes: Any):
+def _row_assembly(table: Union[pa.Table, pa.RecordBatch], to: str,
+                  **attributes: Any):
     """The ``sparkdl.row_assembly`` span (and phase timer) around turning an
     Arrow table — the whole frame's, or one partition's in ``collect()`` —
     into Python rows or pandas: ``collect()``'s and ``toPandas()``'s own
@@ -653,43 +664,179 @@ def _vector_cells(chunk: pa.Array) -> Optional[Tuple[List[Any], int]]:
     return cells, values.size
 
 
-def _table_rows(table: pa.Table) -> Tuple[List[Dict[str, Any]], int, int]:
-    """``table.to_pylist()``, assembled column by column and chunk by
-    chunk: vector columns through numpy, every other column through
-    Arrow's own ``to_pylist()``. Returns the rows, the number of columns
-    that went through numpy whole, and the leaf values numpy converted."""
+def _column_cells(chunks: Iterable[pa.Array]
+                  ) -> Tuple[List[Any], bool, int]:
+    """One column's cells, chunk after chunk, as ``to_pylist()`` gives
+    them: a vector column (:func:`_is_vector_type`) through numpy, every
+    other through Arrow's own ``to_pylist()``. Returns the cells, whether
+    every chunk went through numpy, and the leaf values numpy converted."""
+    cells: List[Any] = []
+    whole, values = True, 0
+    for chunk in chunks:
+        got = _vector_cells(chunk) if _is_vector_type(chunk.type) else None
+        if got is None:
+            whole = False
+            # sparkdl: allow(columnar-hot-path): collect's CONTRACT is
+            # per-row Python dicts (Spark Row analog); batch callers use
+            # streamPartitions/toArrow
+            cells.extend(chunk.to_pylist())
+        else:
+            cells.extend(got[0])
+            values += got[1]
+    return cells, whole, values
+
+
+def _append_rows(rows: List[Dict[str, Any]],
+                 data: Union[pa.Table, pa.RecordBatch]) -> Tuple[int, int]:
+    """Append ``data.to_pylist()`` to ``rows``, assembled column by column
+    (:func:`_column_cells`). Returns the number of columns that went
+    through numpy whole and the leaf values numpy converted."""
     columns, vector_columns, values = [], 0, 0
-    for column in table.columns:
-        eligible = whole = _is_vector_type(column.type)
-        cells: List[Any] = []
-        for chunk in column.chunks:
-            got = _vector_cells(chunk) if eligible else None
-            if got is None:
-                whole = False
-                # sparkdl: allow(columnar-hot-path): collect's CONTRACT is
-                # per-row Python dicts (Spark Row analog); batch callers use
-                # streamPartitions/toArrow
-                cells.extend(chunk.to_pylist())
-            else:
-                cells.extend(got[0])
-                values += got[1]
-        vector_columns += whole
+    for column in data.columns:
+        cells, whole, converted = _column_cells(
+            getattr(column, "chunks", [column]))
+        vector_columns += whole and _is_vector_type(column.type)
+        values += converted
         columns.append(cells)
-    names = table.column_names
-    return ([dict(zip(names, row)) for row in zip(*columns)],
-            vector_columns, values)
+    names = data.schema.names
+    rows.extend(dict(zip(names, row)) for row in zip(*columns))
+    return vector_columns, values
 
 
 def _assemble_rows(table: pa.Table, **attributes: Any
                    ) -> List[Dict[str, Any]]:
-    """``collect()``'s rows of ``table`` (:func:`_table_rows`) under one
+    """``collect()``'s rows of ``table`` (:func:`_append_rows`) under one
     ``sparkdl.row_assembly`` span; ``attributes`` ride on the span."""
+    rows: List[Dict[str, Any]] = []
     with _row_assembly(table, "pylist", **attributes) as span:
-        rows, vector_columns, values = _table_rows(table)
+        vector_columns, values = _append_rows(rows, table)
         span.set_attribute("vector_columns", vector_columns)
         span.set_attribute("fallback_columns",
                            table.num_columns - vector_columns)
         telemetry.count(telemetry.M_COLLECT_VECTORIZED_VALUES, values)
+        return rows
+
+
+def _same_data(a: pa.Array, b: pa.Array) -> bool:
+    """Whether two arrays are views of the same memory: the same type,
+    offset and length, the same addresses and sizes of their buffers, and
+    so of their children's. No value is compared."""
+    if a.type != b.type or a.offset != b.offset or len(a) != len(b):
+        return False
+    if [None if x is None else (x.address, x.size) for x in a.buffers()] != \
+            [None if y is None else (y.address, y.size) for y in b.buffers()]:
+        return False
+    # a child has an offset of its own: the same buffers from elsewhere in
+    # them would be other cells
+    t = a.type
+    if pa.types.is_struct(t):
+        return all(_same_data(a.field(i), b.field(i))
+                   for i in range(t.num_fields))
+    if (pa.types.is_list(t) or pa.types.is_large_list(t)
+            or pa.types.is_fixed_size_list(t) or pa.types.is_map(t)):
+        return _same_data(a.values, b.values)
+    if pa.types.is_dictionary(t):
+        return _same_data(a.dictionary, b.dictionary)
+    return True
+
+
+class _CarriedRows:
+    """``collect()``'s rows of the columns its frame's ops carry through
+    from the source partitions unchanged, built from the source partitions
+    while their tasks run, and completed with the computed columns once a
+    partition resolves.
+
+    Built ahead only where the declared schema is every column of the
+    source partitions, in their order, followed by the columns the ops
+    compute — what ``withColumn``, ``withColumnBatch`` and an op that
+    declares its schema with ``_schema_with`` give for a new name. A
+    partition's cells are taken only if its result's leading columns are
+    the source's own Arrow data (:func:`_same_data`); otherwise — a column
+    an op replaced, a cluster worker's or a journal's new buffers — the
+    partition is assembled as it was before, from its result alone."""
+
+    def __init__(self, partitions: Sequence[pa.RecordBatch], width: int):
+        self._sources = partitions
+        self._width = width
+        self._rows: Dict[int, List[Dict[str, Any]]] = {}   # built so far
+        self._values: Dict[int, int] = {}   # leaf values numpy converted
+        self._next = 0      # no partition before it has work left
+        self.started = False
+        self.taken = 0      # rows built ahead and taken
+
+    @classmethod
+    def plan(cls, partitions: Sequence[pa.RecordBatch], schema: pa.Schema
+             ) -> Optional["_CarriedRows"]:
+        """What the frame's ops carry, or ``None`` where they may carry no
+        column or compute none after those they carry."""
+        if not partitions:
+            return None
+        source = partitions[0].schema
+        width = len(source)
+        if not 0 < width < len(schema) or any(
+                p.schema != source for p in partitions[1:]):
+            return None
+        if any(schema.field(j) != source.field(j) for j in range(width)):
+            return None
+        return cls(partitions, width)
+
+    def step(self, done: Container[int]) -> bool:
+        """Build one slice of at most ``_AHEAD_ROWS`` rows of the first
+        partition, in index order, that is not ``done`` and not yet built.
+        Returns whether any is left."""
+        self.started = True
+        while self._next < len(self._sources):
+            index, built = self._next, len(self._rows.get(self._next, ()))
+            if index in done or built >= self._sources[index].num_rows:
+                self._next += 1
+                continue
+            part = self._sources[index].slice(built, _AHEAD_ROWS)
+            with _row_assembly(part, "pylist", partition=index,
+                               ahead=True) as span:
+                vector_columns, values = _append_rows(
+                    self._rows.setdefault(index, []), part)
+                self._values[index] = self._values.get(index, 0) + values
+                span.set_attribute("vector_columns", vector_columns)
+                span.set_attribute("fallback_columns",
+                                   self._width - vector_columns)
+            return True
+        return False
+
+    def drop(self) -> None:
+        """Build nothing more and take nothing built."""
+        self._rows.clear()
+        self._next = len(self._sources)
+
+    def take(self, index: int, table: pa.Table
+             ) -> Optional[List[Dict[str, Any]]]:
+        """The partition's rows: the cells built ahead, the rest of the
+        carried ones and the computed columns of ``table``, its result —
+        or ``None`` where nothing was built ahead or the result does not
+        carry the source's columns."""
+        rows = self._rows.pop(index, None)
+        values = self._values.pop(index, 0)
+        source = self._sources[index]
+        if not rows or not all(
+                _same_data(table.column(j).chunk(0), source.column(j))
+                for j in range(self._width)):
+            return None
+        ahead = len(rows)
+        computed = table.select(range(self._width, table.num_columns))
+        with _row_assembly(computed, "pylist", partition=index) as span:
+            if ahead < source.num_rows:     # resolved before all was built
+                values += _append_rows(rows, source.slice(ahead))[1]
+            vector_columns = 0
+            for name, column in zip(computed.column_names, computed.columns):
+                cells, whole, converted = _column_cells(column.chunks)
+                vector_columns += whole and _is_vector_type(column.type)
+                values += converted
+                for row, cell in zip(rows, cells):
+                    row[name] = cell
+            span.set_attribute("vector_columns", vector_columns)
+            span.set_attribute("fallback_columns",
+                               computed.num_columns - vector_columns)
+        telemetry.count(telemetry.M_COLLECT_VECTORIZED_VALUES, values)
+        self.taken += ahead
         return rows
 
 
@@ -793,13 +940,15 @@ class DataFrame:
         return out
 
     def _materialize(self, on_result: Optional[
-            Callable[[int, pa.RecordBatch], None]] = None
+            Callable[[int, pa.RecordBatch], None]] = None,
+            on_idle: Optional[Callable[[], bool]] = None
             ) -> List[pa.RecordBatch]:
         """The computed partitions, in order; computed once and kept.
-        ``on_result`` is :meth:`PartitionSupervisor.run_all`'s, and is
-        used only where the partitions run as supervised tasks of this
-        call: a frame already materialized or without ops, a nested call
-        run inline and the durable path hand nothing over."""
+        ``on_result`` and ``on_idle`` are
+        :meth:`PartitionSupervisor.run_all`'s, and are used only where the
+        partitions run as supervised tasks of this call: a frame already
+        materialized or without ops, a nested call run inline and the
+        durable path hand nothing over and call nothing."""
         with self._lock:
             if self._materialized is not None:
                 return self._materialized
@@ -844,7 +993,8 @@ class DataFrame:
                 self._materialized = sup.run_all(
                     [(i, lambda cancel, i=i, b=b: dispatch(i, b, ops,
                                                            cancel))
-                     for i, b in enumerate(self._partitions)], on_result)
+                     for i, b in enumerate(self._partitions)], on_result,
+                    on_idle)
             return self._materialized
 
     def _durable_supervisor(self, journal) -> PartitionSupervisor:
@@ -965,9 +1115,26 @@ class DataFrame:
         partition's thread and the durable path, where nothing runs
         beside the assembly. A failing partition raises what
         ``toArrow()`` raises, and the rows assembled until then go with
-        it."""
+        it.
+
+        Before a partition resolves, while the supervised tasks run and no
+        resolved partition waits to be handed over (``run_all``'s
+        ``on_idle``), the cells of the columns the ops carry through from
+        the source partition unchanged are built from the source
+        partition, in slices of ``_AHEAD_ROWS`` rows, partition after
+        partition in index order (:class:`_CarriedRows`: where they are
+        taken and where they are dropped); each slice has its own
+        ``sparkdl.row_assembly`` span (attributes ``partition`` and
+        ``ahead``). A partition whose carried cells were built is
+        assembled on its own even where the run handed it nothing over,
+        and only its computed columns are left to convert once it
+        resolves; ``sparkdl.collect.carried_rows`` counts the rows whose
+        carried cells were built ahead and taken. Every cell and every
+        dict is this call's own: nothing is kept from one call to the
+        next."""
         assembled: Dict[int, List[Dict[str, Any]]] = {}
         declared = True     # every batch so far has the declared schema
+        carried = _CarriedRows.plan(self._partitions, self._schema)
 
         def assemble(index: int, batch: pa.RecordBatch) -> None:
             nonlocal declared
@@ -977,19 +1144,27 @@ class DataFrame:
                 table = pa.Table.from_batches([batch], schema=self._schema)
             except (pa.ArrowInvalid, pa.ArrowTypeError):
                 declared = False
+                if carried is not None:
+                    carried.drop()
                 return
-            assembled[index] = _assemble_rows(table, partition=index)
+            rows = None if carried is None else carried.take(index, table)
+            assembled[index] = (_assemble_rows(table, partition=index)
+                                if rows is None else rows)
 
-        batches = self._materialize(assemble)
+        batches = self._materialize(
+            assemble, None if carried is None else
+            lambda: carried.step(assembled))
         # what run_all handed over, it handed over beside unresolved tasks
         overlapped = sum(map(len, assembled.values()))
-        if assembled:
+        if assembled or (carried is not None and carried.started):
             for index, batch in enumerate(batches):
                 if index not in assembled:
                     assemble(index, batch)
         if not (assembled and declared):
             return _assemble_rows(self.toArrow())
         telemetry.count(M_COLLECT_OVERLAPPED_ROWS, overlapped)
+        if carried is not None and carried.started:
+            telemetry.count(M_COLLECT_CARRIED_ROWS, carried.taken)
         return list(itertools.chain.from_iterable(
             assembled[index] for index in range(len(batches))))
 

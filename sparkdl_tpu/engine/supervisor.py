@@ -398,7 +398,8 @@ class PartitionSupervisor:
 
     def run_all(self, indexed_runners:
                 Sequence[Tuple[int, Callable[[threading.Event], Any]]],
-                on_result: Optional[Callable[[int, Any], None]] = None
+                on_result: Optional[Callable[[int, Any], None]] = None,
+                on_idle: Optional[Callable[[], bool]] = None
                 ) -> List[Any]:
         """Run every task; results in input order. First failure raises
         (after the barrier drain), unless quarantine absorbs it. Each
@@ -419,7 +420,17 @@ class PartitionSupervisor:
         ``poll_interval_s``, which keeps its meaning (with a result
         waiting to be handed over the tick polls without blocking). An
         exception out of the callback unwinds like any other: barrier
-        drain first, then it propagates."""
+        drain first, then it propagates.
+
+        ``on_idle()`` is work the caller does while it waits
+        (``DataFrame.collect`` builds the rows of the columns the tasks
+        carry through unchanged): called on this thread, between two
+        ticks, whenever no resolved result is waiting to be handed over —
+        so a resolved result waits for at most one call — and while some
+        task is still unresolved. It returns whether it has work left;
+        until it says it has none the tick polls without blocking, and
+        after that it is not called again. Each call is one short slice:
+        like ``on_result``'s, its duration is what a check comes late."""
         tasks: List[_Task] = []
         outstanding: Dict[_futures.Future, _Task] = {}
         for index, runner in indexed_runners:
@@ -427,16 +438,20 @@ class PartitionSupervisor:
             outstanding[task.launch()] = task
             tasks.append(task)
         ready: "deque[_Task]" = deque()  # resolved, not yet handed over
+        idle_work = on_idle is not None
         try:
             while not all(t.done for t in tasks):
                 resolved = self._tick(outstanding, tasks, len(tasks),
-                                      block=not ready)
-                if on_result is None:
-                    continue
-                ready.extend(resolved)
-                if ready and not all(t.done for t in tasks):
+                                      block=not (ready or idle_work))
+                if on_result is not None:
+                    ready.extend(resolved)
+                if all(t.done for t in tasks):
+                    break
+                if ready:
                     task = ready.popleft()
                     on_result(task.index, task.result)
+                elif idle_work:
+                    idle_work = bool(on_idle())
         except BaseException:
             self._drain(outstanding, include_lingering=True)
             raise

@@ -564,11 +564,16 @@ def test_row_assembly_span_follows_materialize(method, to):
 
     with telemetry.Telemetry() as tel:
         getattr(_mapped_df(), method)()
-    assembly = tel.tracer.spans(telemetry.SPAN_ROW_ASSEMBLY)
+    spans = tel.tracer.spans(telemetry.SPAN_ROW_ASSEMBLY)
     (materialize,) = tel.tracer.spans(telemetry.SPAN_MATERIALIZE)
+    # collect(): the carried columns' slices built ahead lie inside
+    # materialize; every row is in exactly one of the other spans
+    ahead = [s for s in spans if s["attributes"].get("ahead")]
+    assembly = [s for s in spans if s not in ahead]
+    assert all(s["parent_id"] == materialize["span_id"] for s in ahead)
     assert sum(s["attributes"]["rows"] for s in assembly) == 12
     assert all(s["attributes"]["to"] == to and s["attributes"]["bytes"] > 0
-               for s in assembly)
+               for s in spans)
     # toPandas(): the table is whole before the one assembly starts.
     # collect(): a partition assembled while others still ran lies inside
     # materialize, the rest (the last to resolve among them) after it
@@ -595,11 +600,13 @@ def test_row_assembly_without_scope_feeds_the_phase_timer_only(monkeypatch):
     before = profiling.phase_stats().get(telemetry.SPAN_ROW_ASSEMBLY,
                                          {"count": 0})["count"]
     df = _mapped_df()
-    df.collect()    # one timing a partition, or one for the whole table
+    # one timing a partition and one a slice of carried cells built ahead
+    # (a partition's 4 rows are one slice), or one for the whole table
+    df.collect()
     mid = profiling.phase_stats()[telemetry.SPAN_ROW_ASSEMBLY]["count"]
     df.toPandas()
     after = profiling.phase_stats()[telemetry.SPAN_ROW_ASSEMBLY]
-    assert mid - before in (1, 3)
+    assert mid - before in (1, 3, 4, 5, 6)
     assert after["count"] == mid + 1 and after["total_s"] > 0
     assert made == []
 
@@ -825,13 +832,13 @@ def in_order(monkeypatch):
     gates = {}      # id(first batch of the frame) → (gate by partition, next)
     real = PartitionSupervisor.run_all
 
-    def run_all(sup, runners, on_result=None):
+    def run_all(sup, runners, on_result=None, on_idle=None):
         def handed(index, result):
             on_result(index, result)
             for events, after in gates.values():
                 if index in after:
                     events[after[index]].set()
-        return real(sup, runners, handed if on_result else None)
+        return real(sup, runners, handed if on_result else None, on_idle)
 
     monkeypatch.setattr(PartitionSupervisor, "run_all", run_all)
 
@@ -1072,6 +1079,238 @@ def test_nested_collect_from_a_partition_thread_runs_inline(in_order):
     # four inner collects over the whole table, four outer partitions
     assert sorted(s["attributes"].get("partition", -1) for s in spans) == \
         [-1, -1, -1, -1, 0, 1, 2, 3]
+
+
+# ---------------------------------------------------------------------------
+# collect() builds the cells of the columns the ops carry through from the
+# source partitions unchanged while the partitions' tasks run, and converts
+# only the computed columns once a partition resolves. The tests hold every
+# partition back until collect() has built all it builds ahead, so which
+# rows were built ahead is decided and not left to the threads
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def built_first(monkeypatch):
+    """``built_first(frame)``: ``frame`` with a pass-through op that holds
+    each partition back until ``collect()`` has nothing left to build ahead
+    (at once where it builds nothing ahead)."""
+    from sparkdl_tpu.engine import dataframe
+
+    monkeypatch.setattr(EngineConfig, "max_workers", 8)
+    done = threading.Event()
+    plan, step = dataframe._CarriedRows.plan, dataframe._CarriedRows.step
+
+    def planned(partitions, schema):
+        carried = plan(partitions, schema)
+        if carried is None:
+            done.set()
+        return carried
+
+    def stepped(self, finished):
+        more = step(self, finished)
+        if not more:
+            done.set()
+        return more
+
+    monkeypatch.setattr(dataframe._CarriedRows, "plan",
+                        staticmethod(planned))
+    monkeypatch.setattr(dataframe._CarriedRows, "step", stepped)
+
+    def held(batch):
+        assert done.wait(20), "collect() never finished building ahead"
+        return batch
+
+    yield lambda frame: frame.mapPartitions(held)
+    done.set()
+
+
+def _collect_carried(df):
+    """``_collect_traced`` with the spans built ahead apart and the
+    carried-rows counter (None: not bumped)."""
+    from sparkdl_tpu.core import telemetry
+    from sparkdl_tpu.engine import dataframe
+
+    with telemetry.Telemetry() as tel:
+        rows = df.collect()
+    spans = sorted(tel.tracer.spans(telemetry.SPAN_ROW_ASSEMBLY),
+                   key=lambda s: s["start_ns"])
+    ahead = [s for s in spans if s["attributes"].get("ahead")]
+    counters = tel.metrics.snapshot()["counters"]
+    return (rows, ahead, [s for s in spans if s not in ahead],
+            counters.get(dataframe.M_COLLECT_CARRIED_ROWS),
+            counters.get(telemetry.M_COLLECT_VECTORIZED_VALUES))
+
+
+def _carried_table():
+    """Carried columns of every kind collect() converts: an image struct
+    with a binary child (null rows among them), a vector, a string and an
+    all-null column."""
+    from sparkdl_tpu.image import imageIO
+
+    n = 150
+    pixels = np.arange(n * 4 * 5 * 3, dtype=np.uint32).astype(
+        np.uint8).reshape(n, 4, 5, 3)
+    image = imageIO.imageArraysToStructColumn(
+        pixels, [f"mem://{i}" for i in range(n)])
+    image = pa.StructArray.from_arrays(
+        image.flatten(), fields=list(image.type),
+        mask=pa.array([i % 11 == 3 for i in range(n)]))
+    return pa.table({
+        "image": image,
+        "v": pa.array([None if i % 7 == 0 else [i / 3, -i] for i in range(n)],
+                      pa.list_(pa.float32())),
+        "s": pa.array([None if i % 5 == 0 else f"row {i}" for i in range(n)]),
+        "z": pa.array([None] * n, pa.list_(pa.float64()))})
+
+
+def _features(batch):
+    """A computed vector column: two floats a row, null every ninth."""
+    rows = batch.column(1).to_pylist()
+    return pa.array([None if i % 9 == 4 else [float(i), 0.5]
+                     for i in range(len(rows))], pa.list_(pa.float32()))
+
+
+@pytest.mark.parametrize("parts", [1, 2, 4, "own"])
+def test_collect_with_carried_columns_is_to_pylist(parts, built_first):
+    table = _carried_table()
+    if parts == "own":  # slices of one batch, offsets and all
+        batch = table.combine_chunks().to_batches()[0]
+        base = DataFrame([batch.slice(0, 70), batch.slice(70, 80)],
+                         batch.schema)
+    else:
+        base = DataFrame.fromArrow(table, numPartitions=parts)
+    sizes = [b.num_rows for b in base._partitions]
+    df = built_first(base).withColumnBatch("features", _features,
+                                           pa.list_(pa.float32()))
+    rows, ahead, spans, carried, values = _collect_carried(df)
+    _assert_same_cells(rows, df.toArrow().to_pylist())
+    assert [list(r) for r in rows[:1]] == [["image", "v", "s", "z",
+                                            "features"]]
+    # every row's carried cells were built ahead, in slices of 64 rows,
+    # partition after partition, and only the computed column was left
+    assert carried == len(rows) == sum(sizes)
+    assert [(s["attributes"]["partition"], s["attributes"]["rows"])
+            for s in ahead] == [
+        (i, min(64, size - start)) for i, size in enumerate(sizes)
+        for start in range(0, size, 64)]
+    assert sorted(s["attributes"]["partition"] for s in spans) == \
+        list(range(len(sizes)))
+    assert {s["attributes"]["vector_columns"] for s in spans} == {1}
+    # numpy's values: the carried vector's, built ahead, and the computed
+    assert values == 2 * sum(r["v"] is not None for r in rows) + \
+        2 * sum(r["features"] is not None for r in rows)
+
+
+def test_collect_takes_no_column_an_op_replaced(built_first):
+    # `v` is replaced under its name and type: the declared schema cannot
+    # tell, the result's buffers do
+    base = DataFrame.fromArrow(_carried_table(), numPartitions=3)
+    df = built_first(base).withColumnBatch(
+        "v", lambda b: pa.array([[1.0, 2.0]] * b.num_rows,
+                                pa.list_(pa.float32())),
+        pa.list_(pa.float32())).withColumnBatch(
+        "features", _features, pa.list_(pa.float32()))
+    rows, ahead, spans, carried, _ = _collect_carried(df)
+    _assert_same_cells(rows, df.toArrow().to_pylist())
+    assert {tuple(r["v"]) for r in rows} == {(1.0, 2.0)}
+    assert ahead and carried == 0
+    # every partition assembled from its result alone, as before
+    assert [s["attributes"]["rows"] for s in spans] == [50, 50, 50]
+    assert {s["attributes"]["fallback_columns"] +
+            s["attributes"]["vector_columns"] for s in spans} == {5}
+
+
+def test_collect_with_a_computed_column_in_the_middle_falls_back(
+        built_first):
+    # x computed between the source's a and b: b is no longer where the
+    # source had it, so nothing is built ahead
+    base = DataFrame.fromArrow(pa.table({
+        "a": pa.array(range(12)), "b": pa.array([f"b{i}" for i in range(12)])
+    }), numPartitions=3)
+    schema = pa.schema([("a", pa.int64()), ("x", pa.float64()),
+                        ("b", pa.string())])
+
+    def middle(batch):
+        return pa.RecordBatch.from_arrays(
+            [batch.column(0), pa.array([0.5] * batch.num_rows),
+             batch.column(1)], schema=schema)
+
+    df = built_first(base).mapPartitions(middle, schema)
+    rows, ahead, spans, carried, _ = _collect_carried(df)
+    _assert_same_cells(rows, df.toArrow().to_pylist())
+    assert [list(r) for r in rows[:1]] == [["a", "x", "b"]]
+    assert ahead == [] and carried is None
+
+
+def test_collect_with_carried_columns_raises_what_a_partition_raised(
+        built_first):
+    def fail(batch):
+        if batch.column(0)[0].as_py() == 4:
+            raise ValueError("deliberate")
+        return pa.array([1] * batch.num_rows)
+
+    base = DataFrame.fromArrow(pa.table({"i": pa.array(range(12))}),
+                               numPartitions=3)
+    df = built_first(base).withColumnBatch("f", fail, pa.int64())
+    with pytest.raises(TaskFailure, match="deliberate") as failure:
+        df.collect()
+    assert failure.value.index == 1 and df._materialized is None
+
+
+def test_collect_with_carried_columns_falls_back_on_an_undeclared_type(
+        built_first):
+    # no outputType: the declared column is null-typed, every batch has
+    # another type, and the rows are toArrow()'s unified table's
+    base = DataFrame.fromArrow(_carried_table(), numPartitions=3)
+    df = built_first(base).withColumnBatch("features", _features)
+    rows, ahead, spans, carried, _ = _collect_carried(df)
+    _assert_same_cells(rows, df.toArrow().to_pylist())
+    (whole,) = [s for s in spans if "partition" not in s["attributes"]]
+    assert whole["attributes"]["rows"] == 150 and carried is None
+
+
+def test_two_collects_share_no_object(built_first):
+    def objects(value, out):
+        if isinstance(value, (dict, list, bytes)):
+            out.append(value)
+        for child in (value.values() if isinstance(value, dict) else
+                      value if isinstance(value, list) else ()):
+            objects(child, out)
+        return out
+
+    df = built_first(DataFrame.fromArrow(_carried_table(), numPartitions=2)
+                     ).withColumnBatch("features", _features,
+                                       pa.list_(pa.float32()))
+    first = df.collect()
+    fresh = built_first(DataFrame.fromArrow(
+        _carried_table(), numPartitions=2)).withColumnBatch(
+        "features", _features, pa.list_(pa.float32()))
+    for second in (df.collect(), fresh.collect()):
+        assert second == first
+        mine = {id(o) for o in objects(first, [])}
+        assert not mine & {id(o) for o in objects(second, [])}
+
+
+def test_same_data_is_the_same_memory_and_not_equal_values():
+    from sparkdl_tpu.engine.dataframe import _same_data
+
+    column = _carried_table().combine_chunks().column("image").chunk(0)
+    assert _same_data(column, column.slice(0))
+    assert not _same_data(column, column.slice(1))
+    assert not _same_data(column.slice(0, 10), column.slice(0, 11))
+    assert not _same_data(column, pa.concat_arrays([column]))   # a copy
+    # the struct's own buffers and offset alike, its children shifted
+    shifted = pa.StructArray.from_arrays(
+        [f.slice(1, len(column) - 1) for f in column.flatten()],
+        fields=list(column.type))
+    assert not _same_data(column.slice(0, len(column) - 1), shifted)
+    offsets = pa.array([0, 1, 3], pa.int32())
+    values = pa.array([1.0, 2.0, 3.0, 4.0], pa.float32())
+    vector = pa.ListArray.from_arrays(offsets, values)
+    assert _same_data(vector, pa.ListArray.from_arrays(offsets, values))
+    assert not _same_data(vector, pa.ListArray.from_arrays(
+        offsets, values.slice(1)))
 
 
 _FIT_CELL_IMPORTS = """

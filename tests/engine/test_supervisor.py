@@ -750,3 +750,86 @@ def test_an_error_out_of_on_result_drains_then_propagates():
         assert finished == [1]      # the barrier: no user op still running
     finally:
         pool.shutdown()
+
+
+# -- run_all's on_idle: work done while no resolved result waits ---------
+
+def test_a_resolved_result_is_handed_over_before_any_further_idle_slice():
+    sup, pool = _supervisor()
+    gates, runners = _gated(2)
+    log = []
+
+    def on_idle():
+        log.append("idle")
+        if log.count("idle") == 3:
+            gates[0].set()
+            time.sleep(0.1)     # task 0 resolves during this slice
+        return log.count("idle") < 50
+
+    def on_result(index, result):
+        log.append(index)
+        gates[1].set()
+
+    try:
+        assert sup.run_all(runners, on_result, on_idle) == [0, 10]
+    finally:
+        pool.shutdown()
+    # the slice that saw task 0 resolve is followed by its hand-over
+    assert log.index(0) == 3 and log[:3] == ["idle"] * 3
+
+
+def test_on_idle_runs_without_blocking_until_it_has_no_work_left():
+    sup, pool = _supervisor()       # poll_interval_s 0.5: a blocking tick
+    gates, runners = _gated(2)
+    calls = []
+    gates[0].set()
+
+    def on_idle():
+        calls.append(time.monotonic())
+        if len(calls) == 20:
+            threading.Timer(0.3, gates[1].set).start()
+        return len(calls) < 20
+
+    t0 = time.monotonic()
+    try:
+        assert sup.run_all(runners, None, on_idle) == [0, 10]
+    finally:
+        pool.shutdown()
+    # twenty calls back to back, none once it said it had no work left,
+    # none after the last task resolved
+    assert len(calls) == 20 and calls[-1] - t0 < 0.4
+
+
+def test_on_idle_is_not_called_once_every_task_has_resolved():
+    sup, pool = _supervisor()
+    calls = []
+    try:
+        assert sup.run_all([(0, lambda cancel: 0)], None,
+                           lambda: calls.append(1) or True) == [0]
+        done = len(calls)
+        assert sup.run_all([], None, lambda: calls.append(1) or True) == []
+    finally:
+        pool.shutdown()
+    assert len(calls) == done
+
+
+def test_an_error_out_of_on_idle_drains_then_propagates():
+    sup, pool = _supervisor()
+    started, finished = threading.Event(), []
+
+    def slow(cancel):
+        started.set()
+        time.sleep(0.3)
+        finished.append(1)
+        return 1
+
+    def on_idle():
+        assert started.wait(20)
+        raise KeyError("building ahead broke")
+
+    try:
+        with pytest.raises(KeyError, match="building ahead broke"):
+            sup.run_all([(0, slow)], None, on_idle)
+        assert finished == [1]      # the barrier: no user op still running
+    finally:
+        pool.shutdown()

@@ -318,10 +318,18 @@ def test_collect_assembles_the_features_column_through_numpy(rng):
     counters = tel.metrics.snapshot()["counters"]
     assert counters[telemetry.M_COLLECT_VECTORIZED_VALUES] == 4 * width
     spans = tel.tracer.spans(telemetry.SPAN_ROW_ASSEMBLY)
+    ahead = [s for s in spans if s["attributes"].get("ahead")]
+    spans = [s for s in spans if s not in ahead]
     # one span a partition where the first was assembled under the second's
-    # program, one over the table where both resolved in one tick
+    # program or the image cells were built ahead, one over the table where
+    # both resolved in one tick before any was built
     assert sorted(s["attributes"]["rows"] for s in spans) in ([5], [2, 3])
-    for span in spans:
-        assert span["attributes"]["vector_columns"] == 1
+    built = {s["attributes"]["partition"] for s in ahead}
+    for span in ahead:      # the image struct, ahead of its program's end
+        assert span["attributes"]["vector_columns"] == 0
         assert span["attributes"]["fallback_columns"] == 1
+    for span in spans:      # the struct falls back to Arrow where not built
+        assert span["attributes"]["vector_columns"] == 1
+        assert span["attributes"]["fallback_columns"] == int(
+            span["attributes"].get("partition") not in built)
     assert rows == out.toArrow().to_pylist()
