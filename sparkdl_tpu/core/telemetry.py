@@ -310,6 +310,9 @@ M_MOE_BUFFER_ROWS = "sparkdl.moe.buffer_rows"          # counter (per row its
 # counter (per row: the expert layers whose grouped products were lowered to
 # the kernel, models/latent_moe.py grouped_product)
 M_MOE_FUSED_PRODUCT_LAYERS = "sparkdl.moe.fused_product_layers"
+# counter (per row: the expert layers whose combine was lowered to the
+# kernel, models/latent_moe.py fused_combine)
+M_MOE_FUSED_COMBINE_LAYERS = "sparkdl.moe.fused_combine_layers"
 # counters (per row its share, summed over the expert layers: the grouped
 # kernel's visits by its grid's rule — a row tile once for each group it
 # holds a row of — and the buffer's row tiles, rounds × the buffer; counted
@@ -396,6 +399,7 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_MOE_OVERFLOW_PAIRS: "counter",
     M_MOE_BUFFER_ROWS: "counter",
     M_MOE_FUSED_PRODUCT_LAYERS: "counter",
+    M_MOE_FUSED_COMBINE_LAYERS: "counter",
     M_MOE_PRODUCT_VISITS: "counter",
     M_MOE_PRODUCT_ROW_TILES: "counter",
     M_MOE_SHARED_EXPERT_LAYERS: "counter",

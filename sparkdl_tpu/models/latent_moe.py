@@ -6,7 +6,7 @@ other sequence models (``models/shortconv_moe.py``) imports from here:
 layer's own frequencies (``Rope``), causal attention over grouped keys, with
 a span or without (``grouped_causal_attention``: the fused kernel or
 ``_blocked_attention``), ``gated_mlp``, the expert layer (``route``,
-``buffer_capacity``, ``expert_products``, ``routed_experts``,
+``buffer_capacity``, ``expert_products``, ``combine``, ``routed_experts``,
 ``expert_stats``), the causal taps of a depthwise convolution
 (``causal_taps``) and the scorer's head — the fused kernel or the written
 logits' ``log_softmax`` — and counts (``score_head``, ``program_counts``).
@@ -32,7 +32,7 @@ Precision follows the weights: matrix products run in the weights' dtype
 the norms' statistics, the soft-maxes, the residual stream and the
 log-probabilities are float32.
 
-**What runs where.** Everything is XLA's own but three hand-written Pallas TPU
+**What runs where.** Everything is XLA's own but four hand-written Pallas TPU
 kernels, each one path of two under one contract, the other being XLA's and
 the oracle the kernel is tested against. A window's causal attention
 (:func:`causal_attention`, and :func:`grouped_causal_attention` for grouped
@@ -43,7 +43,10 @@ same span, is the other path. The experts' three
 grouped products (:func:`expert_products`): :func:`grouped_product`, twice —
 gate and up in one pass over the buffer's rows with ``silu · mul`` on the
 float32 accumulators, so neither float32 result reaches HBM, then down; three
-``lax.ragged_dot`` are the other path. The scorer's head
+``lax.ragged_dot`` are the other path. The experts' combine
+(:func:`combine`): :func:`fused_combine` copies to the chip only the rows
+of the pairs it weighs; XLA's gathers of every pair's row are the other
+path. The scorer's head
 (:func:`score_head`): :func:`fused_scoring_head` multiplies a block of
 positions with a tile of the head's rows at a time and keeps the soft-max's
 running maximum and sum and the next id's logit beside it, so no logit
@@ -55,9 +58,10 @@ whole query tiles, no longer than a head's keys and values fit on-chip, and
 head widths of whole lanes — or, on grouped keys, of half a lane group, 64,
 where a grid step works a key head's query heads together, their rows
 stacked; the products: a buffer of
-whole row tiles and widths of whole lanes; the head: a window of whole blocks
-of positions, a ``hidden`` of whole lanes and rows that a tile of whole lanes
-divides); a CPU run, float32 weights or
+whole row tiles and widths of whole lanes; the combine: a ``hidden`` of
+whole lanes, tokens and buffer rows of whole eights; the head: a window of
+whole blocks of positions, a ``hidden`` of whole lanes and rows that a tile
+of whole lanes divides); a CPU run, float32 weights or
 other shapes lower XLA's path. No option chooses, and
 ``jax.experimental.pallas`` is imported where a kernel is built, not with this
 module.
@@ -68,7 +72,8 @@ soft-max over the vocabulary slice held (the last is 0); ``expert_counts`` —
 per expert layer and published expert, the tokens of the window routed to it;
 and, under ``telemetry.PROGRAM_COUNTS``, the counters the executor records
 (among them, per row, the layers whose attention and the expert layers whose
-grouped products were lowered to a kernel, and whether the head was).
+grouped products and combine were lowered to a kernel, and whether the head
+was).
 """
 
 from __future__ import annotations
@@ -648,7 +653,8 @@ def route(p, x, c):
     return chosen, top * c.scaling
 
 
-# float32 rows the combine's gathers may hold at once (see routed_experts)
+# float32 rows the combine's gathers — XLA's path, everywhere the combine
+# kernel is not taken (see combine) — may hold at once
 COMBINE_BYTES = 128 * 1024 * 1024
 
 # The grouped products' tiles, chosen from chip runs at 65,536 rows in 32
@@ -856,6 +862,273 @@ def expert_products(rows, experts, sizes):
     return lax.platform_dependent(*operands, tpu=kernels, default=ragged)
 
 
+# The combine kernel's tiles: tokens a grid step, the most of these that
+# divide the tokens and keep a step's blocks — the fetched rows of two tiles
+# and the tile of ``y`` in and out, each twice for the pipeline — within
+# ``COMBINE_BLOCK_BYTES``. Chosen from chip runs of the kernel alone at the
+# four expert cells' layers (PERF.md §6): 128 tokens took 2.48 /
+# 4.58 / 4.65 ms where 256 took 2.64 / 4.75 / 4.84 (layers held whole), 64
+# and 128 the same 2.21–2.23 where 16 of 256 are held; 256 of openPangu's
+# 7,680 wide rows overflow the chip's 128 MiB.
+COMBINE_TOKEN_TILES = (128, 64, 32, 16, 8)
+COMBINE_BLOCK_BYTES = 48 * 1024 * 1024
+
+
+def _combine_tile(tokens, hidden, top_k):
+    """The most tokens a grid step of :func:`fused_combine` among
+    ``COMBINE_TOKEN_TILES`` that divide ``tokens``, or None."""
+    for tile in COMBINE_TOKEN_TILES:
+        if tokens % tile == 0 and 4 * tile * hidden * (2 * top_k + 4) \
+                <= COMBINE_BLOCK_BYTES:
+            return tile
+    return None
+
+
+def _lane_rows(a):
+    """``a`` (R, hidden), R a multiple of 8 and ``hidden`` of the 128 lanes,
+    as the (R / 8, hidden / 128, 8, 1, 128) array its (8, 128) tiles already
+    are in HBM — XLA lowers this to a bitcast. A row is then one slice
+    ``[r // 8, :, r % 8]`` of whole (1, 128) tiles, which a DMA may copy,
+    where a row of the (8, 128)-tiled (R, hidden) is a slice across tiles
+    that the kernel's compiler refuses."""
+    rows, hidden = a.shape
+    return a.reshape(rows // 8, 8, hidden // _LANES, _LANES).transpose(
+        0, 2, 1, 3).reshape(rows // 8, hidden // _LANES, 8, 1, _LANES)
+
+
+def _lane_block(lanes):
+    """Lane groups of a token's row summed together between two skips: the
+    most, up to 16, that divide ``lanes``."""
+    return max(b for b in range(1, 17) if lanes % b == 0)
+
+
+def _combine_bits(tile, top_k):
+    """Bits of a pair's place in its tile of :func:`fused_combine`: its
+    token's (``tile`` a power of two), then, above them, its k's."""
+    t_bits = tile.bit_length() - 1
+    return t_bits, t_bits + (top_k - 1).bit_length()
+
+
+def _combine_kernel(pairs, starts, weights_ref, out_ref, y_ref, y_out_ref,
+                    fetched, copies, held, *, top_k, tile):
+    """One tile of tokens: starts the copies of the next tile's rows, waits
+    for this tile's (started a step before), and adds ``Σ_k w · row`` in k
+    order, in float32, to the tile of ``y``, 8 tokens and a block of lane
+    groups at a time. ``pairs[starts[i]:starts[i + 1]]`` are tile i's pairs
+    that fetch a row, each its row and its place in the tile in the bits
+    :func:`_combine_bits` gives; a k at which none of 8 tokens fetched a row
+    is skipped: ``held[slot]`` notes, per 8 tokens and k, whether one did."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    step = pl.program_id(0)
+    groups = tile // 8
+    places = tile * top_k
+    t_bits, place_bits = _combine_bits(tile, top_k)
+
+    def start(index, slot):
+        def clear(i, _):
+            held[slot, i] = 0
+
+        def pair(j, _):
+            packed = pairs[j]
+            row = lax.shift_right_logical(packed, place_bits)
+            k = lax.shift_right_logical(packed, t_bits) & (
+                (1 << (place_bits - t_bits)) - 1)
+            t = packed & (tile - 1)
+            group = lax.shift_right_logical(t, 3)
+            pltpu.make_async_copy(
+                out_ref.at[lax.shift_right_logical(row, 3), :, row & 7],
+                fetched.at[slot, k, group, :, t & 7], copies.at[slot]).start()
+            held[slot, group * top_k + k] = 1
+
+        lax.fori_loop(0, groups * top_k, clear, None)
+        lax.fori_loop(starts[index], starts[index + 1], pair, None)
+
+    @pl.when(step == 0)
+    def _():
+        start(0, 0)
+
+    @pl.when(step + 1 < pl.num_programs(0))
+    def _():
+        start(step + 1, (step + 1) % 2)
+
+    slot = step % 2
+    # a DMA semaphore counts bytes: the tile's copies are waited for as
+    # whole powers of two of rows, one wait for each bit of their number
+    started = starts[step + 1] - starts[step]
+    for bit in range(places.bit_length()):
+        copied = 1 << bit
+        if copied < 8:
+            whole = fetched.at[slot, 0, 0, :, pl.ds(0, copied)]
+        elif copied <= tile:
+            whole = fetched.at[slot, 0, pl.ds(0, copied // 8)]
+        else:
+            whole = fetched.at[slot, pl.ds(0, copied // tile)]
+
+        @pl.when((started >> bit) & 1 == 1)
+        def _():
+            pltpu.make_async_copy(whole, whole, copies.at[slot]).wait()
+
+    # the same memory as (8, 128) tiles: a tile holds 8 tokens' 128 lanes
+    rows = fetched.reshape(fetched.shape[:5] + (_LANES,))
+    lanes = rows.shape[3]
+    block = _lane_block(lanes)
+
+    def eight_tokens(a, _):
+        tokens = pl.ds(pl.multiple_of(a * 8, 8), 8)
+        weights = [jnp.broadcast_to(weights_ref[a, :, k:k + 1], (8, _LANES))
+                   for k in range(top_k)]
+        for first in range(0, lanes, block):
+            total = tuple(jnp.zeros((8, _LANES), jnp.float32)
+                          for _ in range(block))
+            for k, w in enumerate(weights):
+                def add(total, k=k, w=w):
+                    return tuple(
+                        part + jnp.where(w != 0, rows[slot, k, a, first + i],
+                                         0.0) * w
+                        for i, part in enumerate(total))
+
+                total = lax.cond(held[slot, a * top_k + k] == 1, add,
+                                 lambda total: total, total)
+            for i, part in enumerate(total):
+                at = pl.ds((first + i) * _LANES, _LANES)
+                y_out_ref[tokens, at] = y_ref[tokens, at] + part
+
+    lax.fori_loop(0, groups, eight_tokens, None)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def fused_combine(y, out, rows_of, weights, *, tile, interpret=False):
+    """The expert layer's combine as one Pallas TPU kernel: ``y`` (N, hidden)
+    float32 plus, for each token n, ``Σ_k weights[n, k] · out[rows_of[n ·
+    top_k + k]]`` over its ``top_k`` pairs in k order, in float32. ``out``
+    (rows, hidden) float32 stays in HBM, and only the rows of pairs whose
+    ``rows_of`` is not −1 are copied to the chip, one DMA a row, a tile's
+    copies started while the tile before is summed; a pair of −1 (its weight
+    0) fetches nothing, and a token with none leaves its row of ``y`` as it
+    was. ``y`` is updated in place. ``N`` is a multiple of ``tile``, that and
+    ``rows`` of 8, and ``hidden`` of the 128 lanes.
+
+    On the chip (PERF.md §6; 16,384 tokens, each with the gathers'
+    ms): 2.21 against 12.19 where 16 of 256 experts are held (131,072 pairs
+    of width 7,680 of which 8,205 fetch a row: the gathers fetch every row,
+    95 % of them to multiply by nought; the kernel's floor is ``y``'s 1.0
+    GB through HBM); where a layer is held whole, every row fetched either
+    way, 2.48 against 2.78 (65,536 × 2,048), 4.58 against 4.77 (131,072 ×
+    2,304) and 4.65 against 4.93 (131,072 × 2,048) — bound by issuing a
+    copy a row, about 35 ns each, not by the bytes. Bit-equal to the
+    gathers at all four."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    tokens, hidden = y.shape
+    top_k = weights.shape[1]
+    places = tile * top_k
+    t_bits, place_bits = _combine_bits(tile, top_k)
+    # the pairs that fetch a row, tile by tile, in (token, k) order, each
+    # its row and its place in the tile in the bits of one int32
+    fetch = rows_of >= 0
+    before = jnp.cumsum(fetch, dtype=jnp.int32)
+    place = jnp.arange(rows_of.shape[0], dtype=jnp.int32) % places
+    pairs = jnp.zeros_like(rows_of).at[
+        jnp.where(fetch, before - 1, rows_of.shape[0])].set(
+            (rows_of << place_bits) | (place % top_k << t_bits)
+            | place // top_k, mode="drop")
+    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                              before.reshape(-1, places)[:, -1]])
+
+    def tokens_of(i, pairs, starts):
+        return i, 0
+
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, top_k=top_k, tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(tokens // tile,),
+            in_specs=[pl.BlockSpec((tile // 8, 8, top_k),
+                                   lambda i, pairs, starts: (i, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec((tile, hidden), tokens_of)],
+            out_specs=pl.BlockSpec((tile, hidden), tokens_of),
+            scratch_shapes=[
+                pltpu.VMEM((2, top_k) + _lane_rows(y[:tile]).shape,
+                           jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2, tile // 8 * top_k), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct(y.shape, jnp.float32),
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=GROUPED_VMEM_LIMIT),
+        name="fused_combine", interpret=interpret,
+    )(pairs, starts, weights.reshape(tokens // 8, 8, top_k), _lane_rows(out),
+      y)
+
+
+def gathered_combine(y, out, at, weights, top_k):
+    """``y`` (N, hidden) float32 plus each token's ``top_k`` rows of ``out``
+    (rows, hidden) float32 weighed, as XLA's gathers: ``at`` (N·top_k,)
+    int32 each pair's row, ``weights`` (N·top_k,) float32 each pair's weight
+    — nought where the pair has no row in ``out``, whose ``at`` may then be
+    anything (it is clipped into ``out``, and its row weighed by nought). A
+    tile of tokens at a time, one gather for each of the ``top_k``, added in
+    k order."""
+    N, hidden = y.shape
+    # the gathers of a tile of tokens are alive side by side: in tiles, so
+    # that they stay under COMBINE_BYTES (whole, a launch of 16,384 tokens of
+    # width 7,680 with 8 pairs each held 3.5 GiB of them)
+    tiles = 1
+    while (at.shape[0] * hidden * 4 > COMBINE_BYTES * tiles
+           and N % (2 * tiles) == 0):
+        tiles *= 2
+    rows = jnp.clip(at, 0, out.shape[0] - 1).reshape(tiles, -1, top_k)
+    weights = weights.reshape(rows.shape)
+
+    def add_tile(i, y):
+        first = i * (N // tiles)
+        tile = lax.dynamic_slice_in_dim(y, first, N // tiles) + sum(
+            out[rows[i, :, k]] * weights[i, :, k, None] for k in range(top_k))
+        return lax.dynamic_update_slice_in_dim(y, tile, first, 0)
+
+    return lax.fori_loop(0, tiles, add_tile, y)
+
+
+def combine(y, out, at, weights, top_k):
+    """:func:`gathered_combine`'s sum. Lowered for a TPU, where the buffer
+    ``out`` has fewer rows than there are pairs — a layer that holds a share
+    of the experts, whose absent pairs are rows the kernel does not fetch —
+    with a ``hidden`` of whole lanes, tokens of a tile :func:`_combine_tile`
+    finds and buffer rows of whole eights, it is :func:`fused_combine` — a
+    pair of weight nought fetches no row — and ``fused`` is 1; everywhere
+    else the gathers, and 0. Both come out of one ``lax.platform_dependent``,
+    so ``fused`` says what was lowered. The same products added in the same
+    order: equal on the chip to the bit, but for the sign of a zero.
+
+    Where the buffer has a row for every pair (a layer held whole) both
+    fetch every row, and the gathers stay: alone the kernel was 4–11 %
+    quicker there, but in the program it left Mellum2's launch 5.6 ms
+    slower (289.6 → 295.2), LFM2's 0.7 ms (281.5 → 282.2) and Laguna's
+    0.7 ms quicker (212.0 → 211.2) (PERF.md §6), no gain to keep.
+
+    Returns ``(y, fused)``."""
+    N, hidden = y.shape
+
+    def gathers(y, out, at, weights):
+        return gathered_combine(y, out, at, weights, top_k), jnp.int32(0)
+
+    def kernel(y, out, at, weights):
+        rows_of = jnp.where(weights != 0, at, -1)
+        return fused_combine(y, out, rows_of, weights.reshape(N, top_k),
+                             tile=tile), jnp.int32(1)
+
+    tile = _combine_tile(N, hidden, top_k)
+    operands = (y, out, at, weights)
+    if (out.shape[0] >= at.shape[0] or out.dtype != jnp.float32
+            or hidden % _LANES or out.shape[0] % 8 or tile is None
+            or out.shape[0] << _combine_bits(tile, top_k)[1] >= 2 ** 31):
+        return gathers(*operands)
+    return lax.platform_dependent(*operands, tpu=kernel, default=gathers)
+
+
 def buffer_capacity(tokens, c):
     """Rows of the grouped products' buffer for a launch of ``tokens``:
     ``capacity_factor`` × the pairs that meet a held expert when routing is
@@ -887,23 +1160,29 @@ def routed_experts(p, x, c):
     × 7,680 that routing fills by half: a tile without a pair is zeroed, not
     multiplied) against 10.3.
 
-    **The combine is linear in the pairs.** The sort is a permutation, so its
-    inverse says where each token's ``top_k`` results lie in the buffer: a
-    round gathers them, a tile of tokens at a time and one gather for each of
-    the ``top_k``, weighs each by its router weight — nought where the expert
-    is absent or the pair lies in another round — and adds them, in float32.
-    On the chip (PERF.md §6, PR 37; the layer alone, 16,384 tokens) the
-    combine takes 2.3 ms where a layer of 32 experts is held whole (65,536
-    pairs of width 2,048; the one-hot product tokens × buffer on the MXU,
-    which this replaced, 24–26 ms, whole or in token tiles) and 10.4 ms where
-    16 of 256 are held (131,072 pairs of width 7,680, most of them absent;
-    the one-hot product 23.0 ms, a row scatter-add 47 ms). The tiles bound
-    what is alive at once, and are no slower: untiled the same gathers took
-    3.0 and 18.5 ms and held 3.5 GiB more in the second case.
+    **The combine is linear in the pairs, and a kernel on a TPU.** The sort
+    is a permutation, so its inverse says where each token's ``top_k``
+    results lie in the buffer; a round weighs each by its router weight —
+    nought where the expert is absent or the pair lies in another round —
+    and adds them to ``y`` in k order, in float32 (:func:`combine`): either
+    :func:`fused_combine`, which copies to the chip only the rows of the
+    pairs it weighs, one DMA a row, or :func:`gathered_combine`, XLA's
+    gathers of every pair's row, a tile of tokens at a time — the second
+    everywhere but on a TPU. On a TPU the rule is what the layer shows: the
+    kernel where the buffer has fewer rows than there are pairs (a share of
+    the experts is held: 5.5 × quicker than the gathers alone, and
+    openPangu's launch 32 ms shorter), the gathers where it has a row for
+    every pair (a layer held whole, where the kernel gained nothing in the
+    program); :func:`combine` and :func:`fused_combine` have the numbers.
+    The gathers had replaced a one-hot product tokens × buffer on
+    the MXU (24–26 ms held whole, 23.0 ms in part; a row scatter-add 47
+    ms); their tiles bound what is alive at once (untiled, 3.5 GiB more in
+    part).
 
     Returns ``(y (N, hidden) float32, chosen (N, k), counts (held,) pairs per
     held expert, overflow (N·k,) bool per pair: computed beyond the first
-    round, fused () int32 as :func:`expert_products` returns it)``."""
+    round, fused () int32 as :func:`expert_products` returns it,
+    fused_combine () int32 as :func:`combine` returns it)``."""
     N = x.shape[0]
     held = len(c.experts_held)
     chosen, weights = route(p, x, c)
@@ -925,55 +1204,40 @@ def routed_experts(p, x, c):
     experts = p["experts"]
     act = experts["down"].dtype
     xa = x.astype(act)
-    # the gathers of a tile of tokens are alive side by side: in tiles, so
-    # that they stay under COMBINE_BYTES (whole, a launch of 16,384 tokens of
-    # width 7,680 with 8 pairs each held 3.5 GiB of them)
-    tiles = 1
-    while (pairs * x.shape[1] * 4 > COMBINE_BYTES * tiles
-           and N % (2 * tiles) == 0):
-        tiles *= 2
 
     def one_round(r, carry):
-        y, _ = carry
+        y = carry[0]
         lo = r * capacity
         pair = order[jnp.clip(lo + jnp.arange(capacity), 0, pairs - 1)]
         rows = xa[pair // c.top_k]                         # (capacity, hidden)
         sizes = (jnp.clip(ends - lo, 0, capacity)
                  - jnp.clip(starts - lo, 0, capacity)).astype(jnp.int32)
         out, fused = expert_products(rows, experts, sizes)
-        at = (place - lo).reshape(tiles, -1, c.top_k)
-        mine = jnp.where((at >= 0) & (at < capacity),
-                         held_weights.reshape(at.shape), 0.0)
-        at = jnp.clip(at, 0, capacity - 1)
+        at = place - lo
+        mine = jnp.where((at >= 0) & (at < capacity), held_weights, 0.0)
+        return (*combine(y, out, at, mine, c.top_k), fused)
 
-        def add_tile(i, y):
-            first = i * (N // tiles)
-            tile = lax.dynamic_slice_in_dim(y, first, N // tiles) + sum(
-                out[at[i, :, k]] * mine[i, :, k, None]
-                for k in range(c.top_k))
-            return lax.dynamic_update_slice_in_dim(y, tile, first, 0)
-
-        return lax.fori_loop(0, tiles, add_tile, y), fused
-
-    none = jnp.zeros(x.shape, jnp.float32), jnp.int32(0)
+    none = jnp.zeros(x.shape, jnp.float32), jnp.int32(0), jnp.int32(0)
     if capacity == pairs:
-        y, fused = one_round(0, none)
+        y, fused_combine, fused = one_round(0, none)
     else:       # rounds: 1 unless the held experts are full
-        y, fused = lax.fori_loop(0, -(-local // capacity), one_round, none)
+        y, fused_combine, fused = lax.fori_loop(
+            0, -(-local // capacity), one_round, none)
     overflow = jnp.zeros((pairs,), bool).at[order].set(
         (slots[order] < held) & (jnp.arange(pairs) >= capacity))
-    return y, chosen, counts, overflow, fused
+    return y, chosen, counts, overflow, fused, fused_combine
 
 
-def expert_stats(chosen, counts, overflow, fused, rows, c):
+def expert_stats(chosen, counts, overflow, fused, fused_combine, rows, c):
     """What an expert layer reports of a launch of ``rows`` windows, from
     :func:`routed_experts`' returns: per row the tokens per published expert,
     the pairs that met a held expert, those computed beyond the first round,
     its share of the rows the grouped products ran (rounds × the buffer), of
     the grouped kernel's visits (:func:`product_visits`, each round's groups
     the held experts' pairs that fall in it) and of the buffer's row tiles,
-    and whether those products were lowered to the kernel; of the launch, the
-    fullest held expert's pairs over the mean."""
+    and whether those products and the combine were lowered to their
+    kernels; of the launch, the fullest held expert's pairs over the
+    mean."""
     held = jnp.asarray(c.experts_held, jnp.int32)
     per_row = jnp.sum(chosen.reshape(rows, -1, 1) == jnp.arange(c.experts), 1)
     capacity = buffer_capacity(chosen.shape[0], c)
@@ -1001,6 +1265,7 @@ def expert_stats(chosen, counts, overflow, fused, rows, c):
         "product_visits": spread(visits.astype(jnp.int32)),
         "product_row_tiles": spread(tiles),
         "fused_products": jnp.broadcast_to(fused, (rows,)),
+        "fused_combine": jnp.broadcast_to(fused_combine, (rows,)),
         "load_max_over_mean": jnp.max(counts) / jnp.maximum(
             jnp.mean(counts.astype(jnp.float32)), 1.0),
     }
@@ -1251,6 +1516,8 @@ def program_counts(stats, tokens, counts):
                 stacked("product_row_tiles"), 1),
             telemetry.M_MOE_FUSED_PRODUCT_LAYERS: jnp.sum(
                 stacked("fused_products"), 1),
+            telemetry.M_MOE_FUSED_COMBINE_LAYERS: jnp.sum(
+                stacked("fused_combine"), 1),
             telemetry.M_MOE_LOAD_MAX_OVER_MEAN: jnp.broadcast_to(
                 jnp.stack([s["load_max_over_mean"] for s in stats]),
                 (B, len(stats)))})

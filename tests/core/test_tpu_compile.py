@@ -29,6 +29,10 @@ a window's states on the chip, and its attention — 20 query heads on one key
 head — the fused kernel (PR 44). A
 PR that ships or drops a kernel changes the assertion where it belongs.
 
+An expert layer that holds a share of the experts holds the combine kernel
+beside its two grouped products, which copies only the rows of the pairs the
+chip computed in place of XLA's gathers; one held whole keeps the gathers.
+
 The topology is described inside a fixture of this file and nowhere else:
 only one process at a time may load the TPU's library, and under
 pytest-xdist every worker imports every test file, so a call made at
@@ -186,7 +190,8 @@ def test_short_convolution_model_compiles_for_v5e(part, one_chip,
     grouped, not repeated, and no scores in HBM; the expert layer held
     whole, whose buffer is the 65,536 pairs, whose three grouped products are
     the grouped kernel twice (PERF.md §6, PR 38) and whose combine builds no
-    tokens × buffer operand; the head over all 65,536 ids, tied — the
+    tokens × buffer operand — a row for every pair: XLA's gathers, not the
+    combine kernel; the head over all 65,536 ids, tied — the
     embedding as it lies is the kernel's operand — which is the fused scoring
     head over the launch's 16,384 positions and builds no logits in HBM."""
     c = registry.SEQUENCE_MODELS["LFM2-8B-A1B"]
@@ -240,7 +245,8 @@ def test_short_convolution_model_compiles_for_v5e(part, one_chip,
     assert ("fused_causal_attention" in text) == (part == "grouped_attention")
     if part == "routed_experts":
         assert latent_moe.buffer_capacity(4 * 4096, c) == 65536
-        # gate and up as one kernel, down as another, and no product of XLA's
+        # gate and up as one kernel, down as another, and no product of
+        # XLA's; a row for every pair: the combine's gathers, not its kernel
         assert text.count('custom_call_target="tpu_custom_call"') == 2
         assert "grouped_product" in text and "ragged-dot" not in text
         # gate's and up's float32 results never reach HBM
@@ -296,7 +302,9 @@ def test_a_share_of_the_experts_compiles_to_the_grouped_kernel_for_v5e(
     (16 of 256 experts, a buffer of 16,384 rows × 7,680 of which routing
     fills about half, rounds under a ``fori_loop`` with traced group sizes):
     the same two kernel calls inside the loop, their column tiles cut to the
-    blocks' budget of on-chip memory, and no product of XLA's."""
+    blocks' budget of on-chip memory, and no product of XLA's; and the
+    combine kernel beside them, which fetches only the rows of the pairs
+    this chip computed, in place of XLA's gathers."""
     import dataclasses
 
     c = dataclasses.replace(
@@ -314,8 +322,9 @@ def test_a_share_of_the_experts_compiles_to_the_grouped_kernel_for_v5e(
     text = jax.jit(lambda p, x: latent_moe.routed_experts(p, x, c)
                    ).lower(p, x).compile().as_text()
     assert latent_moe.buffer_capacity(4 * 4096, c) == 16384
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
     assert "grouped_product" in text and "ragged-dot" not in text
+    assert "fused_combine" in text
     assert "f32[16384,2048]" not in text
 
 
@@ -328,7 +337,8 @@ def test_span_model_compiles_for_v5e(part, one_chip, no_persistent_cache):
     on grouped keys (32 query heads on 4 key heads of 128) — within its
     budget of on-chip memory with a head's 16,384 keys and values resident —
     and builds no scores in HBM; the expert layer held whole (64 experts, a
-    buffer of the 131,072 pairs, the grouped kernel twice at 2,304 × 896);
+    buffer of the 131,072 pairs, the grouped kernel twice at 2,304 × 896 and
+    the combine's gathers);
     the head over 98,304 ids, which is the fused scoring head and builds none
     of the window's 6.4 GB of float32 logits, whole or in blocks."""
     c = registry.SEQUENCE_MODELS["Mellum2-12B-A2.5B-Instruct"]
@@ -467,7 +477,8 @@ def test_gated_attention_and_narrow_experts_compile_for_v5e(
     turned) or 64 (a sliding one, span 512) on 8 key heads of 128, the query
     heads read off the weights — which is the fused kernel on grouped keys
     and builds no scores in HBM; the expert layer of 256 experts of 512 held
-    whole, a buffer of the 131,072 pairs, the grouped kernel twice."""
+    whole, a buffer of the 131,072 pairs, the grouped kernel twice and the
+    combine's gathers."""
     c = registry.SEQUENCE_MODELS["Laguna-XS.2"]
     window = 4096
 
@@ -509,3 +520,37 @@ def test_gated_attention_and_narrow_experts_compile_for_v5e(
         assert text.count('custom_call_target="tpu_custom_call"') == 2
         assert "grouped_product" in text and "ragged-dot" not in text
         assert "f32[131072,512]" not in text
+
+
+@pytest.mark.parametrize("model,held", [
+    ("openPangu-Ultra-MoE-718B", 16), ("LFM2-8B-A1B", None),
+    ("Mellum2-12B-A2.5B-Instruct", None), ("Laguna-XS.2", None)])
+def test_the_combine_compiles_to_its_kernel_for_v5e(model, held, one_chip,
+                                                    no_persistent_cache):
+    """The combine alone at each expert cell's layer (a launch of 16,384
+    tokens, the grouped products' float32 output of ``buffer_capacity``
+    rows): where a share of the experts is held, the combine kernel, which
+    gathers no row with XLA's gathers — a row of ``out`` reaches the chip
+    only by the kernel's own DMA; where a layer is held whole, a row for
+    every pair, XLA's gathers and no kernel."""
+    import dataclasses
+
+    c = registry.SEQUENCE_MODELS[model]
+    if held is not None:
+        c = dataclasses.replace(c, experts_held=tuple(range(held)))
+    tokens = 4 * 4096
+    rows = latent_moe.buffer_capacity(tokens, c)
+    assert (rows < tokens * c.top_k) == (held is not None)
+
+    def on_chip(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lambda *a: latent_moe.combine(*a, c.top_k)).lower(
+        on_chip((tokens, c.hidden)), on_chip((rows, c.hidden)),
+        on_chip((tokens * c.top_k,), jnp.int32),
+        on_chip((tokens * c.top_k,))).compile().as_text()
+    kernel = held is not None
+    assert text.count('custom_call_target="tpu_custom_call"') == kernel
+    assert (" gather(" in text) != kernel
+    if kernel:
+        assert "fused_combine" in text

@@ -418,12 +418,12 @@ BEFORE_THE_VISIT_COUNTS = {
     "TestLatentMoE": ("openpangu_moe", "testmoe-windows"),
 }
 DIGESTS = {
-    ("TestLatentMoE", "tpu"): "c5b130a5da1a568c",
-    ("TestLatentMoE", "cpu"): "1de8086fdb96e09b",
-    ("TestShortConvMoE", "tpu"): "cbf3262ce9df028d",
-    ("TestShortConvMoE", "cpu"): "59f3397be1f57fa7",
-    ("TestSpanMoE", "tpu"): "267203cf3fd0cdb8",
-    ("TestSpanMoE", "cpu"): "6475e801c9b9f7b7",
+    ("TestLatentMoE", "tpu"): "5a954e8a43953e3e",
+    ("TestLatentMoE", "cpu"): "4f89cf6186888b19",
+    ("TestShortConvMoE", "tpu"): "049ee87f0619f42e",
+    ("TestShortConvMoE", "cpu"): "34ff37fcd8eeee45",
+    ("TestSpanMoE", "tpu"): "40b8c5df13b2043b",
+    ("TestSpanMoE", "cpu"): "4ccc815f9b929f94",
     ("TestStateSpace", "tpu"): "734e02ab47085d0c",
     ("TestStateSpace", "cpu"): "734e02ab47085d0c",
 }

@@ -129,8 +129,8 @@ def test_shares_add_up_to_the_uncut_layer(key):
                 layer["moe"]["experts"]["up"],
                 whole["experts"]["up"][share[0]:share[0] + 4])
             config = dataclasses.replace(MODEL, experts_held=share)
-            part, _, counts, _, _ = latent_moe.routed_experts(layer["moe"], x,
-                                                           config)
+            part, _, counts, *_ = latent_moe.routed_experts(layer["moe"], x,
+                                                            config)
             # ... and the reference given the same share gives the same part
             ref_part, _ = ref.routed_part(layer["moe"], x, s, lambda a: a,
                                           experts_held=share)
@@ -152,7 +152,7 @@ def test_no_pair_is_dropped_under_a_skewed_router(key):
                                  capacity_factor=1.0)
     with jax.default_matmul_precision("highest"):
         want, _ = ref.routed_part(layer, x, s, lambda a: a)
-        got, chosen, counts, overflow, fused = jax.jit(
+        got, chosen, counts, overflow, *_ = jax.jit(
             lambda p, x: latent_moe.routed_experts(p, x, config))(layer, x)
     capacity = 96                   # 1.0 × 96 tokens × 4 pairs × 4 of 16
     assert int(counts[1]) == 96     # every token chose expert 5
@@ -183,6 +183,7 @@ def test_program_counts_reach_telemetry_and_not_the_caller(key):
     assert counters[telemetry.M_MOE_LOCAL_PAIRS] == held
     assert counters[telemetry.M_MOE_OVERFLOW_PAIRS] >= 0
     assert counters[telemetry.M_MOE_FUSED_PRODUCT_LAYERS] == 0
+    assert counters[telemetry.M_MOE_FUSED_COMBINE_LAYERS] == 0
     ratio = snapshot["histograms"][telemetry.M_MOE_LOAD_MAX_OVER_MEAN]
     assert ratio["count"] == 3 and ratio["min"] >= 1.0
     # without a scope the outputs are the same and nothing is recorded
@@ -367,6 +368,166 @@ def test_the_choice_follows_what_the_lowering_can_see(case):
         return
     assert out.shape == (window, HEADS * WIDTH)
     assert distance(out, exact_attention(*operands)) < 0.003
+
+
+# -- the combine kernel ---------------------------------------------------------
+
+
+def combine_operands(tokens, hidden, top_k, rows, share, seed=0,
+                     exact=False):
+    """``combine``'s operands: each pair at its own row of ``out`` (rows ≥
+    the pairs), a ``share`` of them held — the rest weighed by nought at a
+    row anywhere, in ``out`` or not — and the first 8 tokens and token 9
+    without a held pair. ``exact``: weights that are powers of two, so that
+    every product is exact and only the order of the sums can tell two
+    paths apart."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    pairs = tokens * top_k
+    at = jax.random.permutation(keys[0], rows)[:pairs].astype(jnp.int32)
+    held = jax.random.uniform(keys[1], (pairs,)) < share
+    held = held.reshape(tokens, top_k).at[:8].set(False).at[9].set(False)
+    weights = (2.0 ** jax.random.randint(keys[2], (pairs,), -3, 2)
+               if exact else jax.random.uniform(keys[2], (pairs,)) + 0.01)
+    weights = jnp.where(held.reshape(-1), weights, 0.0)
+    stray = jax.random.randint(keys[3], (pairs,), -rows, 2 * rows)
+    at = jnp.where(held.reshape(-1), at, stray)
+    out = jax.random.normal(keys[4], (rows, hidden), jnp.float32)
+    y = jax.random.normal(keys[0], (tokens, hidden), jnp.float32)
+    return y, out, at, weights
+
+
+def kernel_combine(y, out, at, weights, top_k, tile):
+    return latent_moe.fused_combine(
+        y, out, jnp.where(weights != 0, at, -1),
+        weights.reshape(-1, top_k), tile=tile, interpret=True)
+
+
+@pytest.mark.parametrize("case,top_k,tile", [
+    ("held-whole", 8, 16), ("held-whole", 4, 8), ("a-share", 8, 16),
+    ("a-share", 4, 32), ("a-share", 4, 8)])
+@pytest.mark.parametrize("exact", [True, False])
+def test_fused_combine_against_the_gathers(case, top_k, tile, exact):
+    """The kernel interpreted against the tiled gathers: with products that
+    are exact, the same numbers to the bit (but for a zero's sign), so the
+    same k order; with any weights within a last place, which is how far
+    the CPU's fused multiply-adds, placed by each program's own fusions,
+    can move them; a token with no held pair keeps its row of ``y``."""
+    tokens, hidden = 64, 256
+    share = 1.0 if case == "held-whole" else 0.3
+    y, out, at, weights = combine_operands(
+        tokens, hidden, top_k, tokens * top_k + 8 * (case == "a-share"),
+        share, exact=exact)
+    want = latent_moe.gathered_combine(y, out, at, weights, top_k)
+    got = kernel_combine(y, out, at, weights, top_k, tile)
+    assert got.shape == y.shape and got.dtype == jnp.float32
+    if exact:
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    fetched = np.asarray(weights != 0).reshape(tokens, top_k).any(1)
+    assert fetched.all() == (case == "held-whole") or (
+        not fetched[:8].any() and not fetched[9])
+    np.testing.assert_array_equal(got[~fetched], y[~fetched])
+
+
+@pytest.mark.parametrize("top_k", [4, 8])
+def test_fused_combine_adds_round_after_round(top_k):
+    """Pairs in more rows than a round's buffer holds (a capacity under the
+    held pairs): each round hands the kernel its own rows and weighs the
+    others' by nought; ``y`` is carried from round to round, as the rounds'
+    ``fori_loop`` carries it, and ends where the gathers' rounds end."""
+    tokens, hidden = 64, 128
+    capacity = tokens * top_k // 2
+    y, out, at, weights = combine_operands(
+        tokens, hidden, top_k, 3 * capacity, 0.5, seed=1, exact=True)
+    got = want = jnp.zeros_like(y)
+    rounds = 0
+    for lo in range(0, 3 * capacity, capacity):
+        mine = jnp.where((at >= lo) & (at < lo + capacity), weights, 0.0)
+        rounds += bool((mine != 0).any())
+        part = out[lo:lo + capacity]
+        want = latent_moe.gathered_combine(want, part, at - lo, mine, top_k)
+        got = kernel_combine(got, part, at - lo, mine, top_k, 16)
+    assert rounds == 3
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("tokens,hidden,top_k,want", [
+    (16384, 7680, 8, 64),           # openPangu: two tiles' rows and y, 39 MB
+    (16384, 2048, 4, 128), (16384, 2304, 8, 128), (16384, 2048, 8, 128),
+    (24, 128, 4, 8), (60, 128, 4, None), (16384, 7680 * 16, 8, None)])
+def test_the_combines_tile_follows_the_shapes(tokens, hidden, top_k, want):
+    tile = latent_moe._combine_tile(tokens, hidden, top_k)
+    assert tile == want
+    if tile:
+        assert 4 * tile * hidden * (2 * top_k + 4) \
+            <= latent_moe.COMBINE_BLOCK_BYTES
+
+
+@pytest.mark.parametrize("case", [
+    "lowered-for-tpu", "lowered-for-cpu", "held-whole-lowered-for-tpu",
+    "hidden-off-the-lanes", "rows-off-the-eights", "tokens-off-the-tiles"])
+def test_the_combine_follows_what_the_lowering_can_see(case):
+    """The combine kernel is taken where the program is lowered for a TPU,
+    the buffer has fewer rows than there are pairs (a share of the experts
+    is held) and ``hidden`` is whole lanes and tokens and buffer rows whole
+    eights; the count says which; everywhere else the gathers — also where
+    the buffer has a row for every pair (a layer held whole)."""
+    platform = "tpu" if case.endswith("lowered-for-tpu") else "cpu"
+    tokens = 60 if case == "tokens-off-the-tiles" else 64
+    hidden = 96 if case == "hidden-off-the-lanes" else 128
+    rows = {"held-whole-lowered-for-tpu": tokens * 4,
+            "rows-off-the-eights": tokens * 4 - 4}.get(case, tokens * 2)
+    y, out, at, weights = combine_operands(tokens, hidden, 4, tokens * 4,
+                                           0.4)
+    operands = y, out[:rows], at, jnp.where(at < rows, weights, 0.0)
+    fn = jax.jit(lambda *a: latent_moe.combine(*a, 4))
+    text = fn.trace(*operands).lower(lowering_platforms=(platform,)).as_text()
+    kernel = case == "lowered-for-tpu"
+    assert text.count("tpu_custom_call") == kernel
+    assert ("fused_combine" in text) == kernel
+    if platform == "tpu":
+        return                       # nothing here can run it
+    y, engaged = fn(*operands)
+    assert engaged == 0
+    np.testing.assert_array_equal(
+        y, latent_moe.gathered_combine(*operands, 4))
+
+
+@pytest.mark.parametrize("factor", [2.0, 0.25])
+def test_routed_experts_combine_through_the_kernel(factor, monkeypatch):
+    """``routed_experts`` over a share of the experts with the TPU branch
+    taken and the combine kernel interpreted — float32 experts, so the
+    products stay ``lax.ragged_dot`` and the combine is all that changes —
+    in one round and in several: the default path's numbers, and the
+    count 1 where it was 0."""
+    hidden, width, held = 128, 64, (4, 5, 6, 7)
+    config = dataclasses.replace(MODEL, hidden=hidden, expert_width=width,
+                                 experts_held=held, capacity_factor=factor)
+    keys = jax.random.split(jax.random.PRNGKey(12), 5)
+    layer = {"router": jax.random.normal(keys[0], (hidden, 16)),
+             "experts": {
+                 "gate": jax.random.normal(keys[1], (4, hidden, width)) / 11,
+                 "up": jax.random.normal(keys[2], (4, hidden, width)) / 11,
+                 "down": jax.random.normal(keys[3], (4, width, hidden)) / 8}}
+    x = jax.random.normal(keys[4], (64, hidden))
+
+    def run():
+        return jax.jit(lambda p, x: latent_moe.routed_experts(p, x, config))(
+            layer, x)
+
+    want, _, counts, _, fused, fused_combine = run()
+    assert fused == fused_combine == 0
+    capacity = latent_moe.buffer_capacity(64, config)
+    assert (capacity < int(counts.sum())) == (factor < 1)
+    monkeypatch.setattr(latent_moe, "fused_combine", functools.partial(
+        latent_moe.fused_combine, interpret=True))
+    monkeypatch.setattr(lax, "platform_dependent",
+                        lambda *operands, tpu, default: tpu(*operands))
+    got, _, again, _, fused, fused_combine = run()
+    assert fused == 0 and fused_combine == 1
+    np.testing.assert_array_equal(again, counts)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
 
 # -- the fused head -------------------------------------------------------------

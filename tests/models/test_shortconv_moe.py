@@ -334,14 +334,14 @@ def test_shares_add_up_to_the_uncut_layer(key):
                 layer["moe"]["experts"]["up"],
                 whole["experts"]["up"][share[0]:share[0] + 8])
             config = dataclasses.replace(model, experts_held=share)
-            part, _, counts, _, _ = latent_moe.routed_experts(layer["moe"], x,
-                                                           config)
+            part, _, counts, *_ = latent_moe.routed_experts(layer["moe"], x,
+                                                            config)
             ref_part, _ = ref.routed_part(layer["moe"], x, s, identity,
                                           experts_held=share)
             np.testing.assert_allclose(part, ref_part, rtol=1e-4, atol=1e-5)
             total = total + part
             pairs += int(counts.sum())
-        uncut, _, counts, overflow, _ = latent_moe.routed_experts(
+        uncut, _, counts, overflow, *_ = latent_moe.routed_experts(
             whole, x, dataclasses.replace(model, experts_held=everything))
     assert pairs == 40 * 4          # every (token, expert) pair, exactly once
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
@@ -360,12 +360,13 @@ def test_no_pair_is_dropped_and_the_buffer_is_the_pairs_when_all_are_held(
     assert latent_moe.buffer_capacity(96, MODEL) == 96 * 4
     with jax.default_matmul_precision("highest"):
         want, _ = ref.routed_part(layer, x, s, identity)
-        got, chosen, counts, overflow, fused = jax.jit(
+        got, chosen, counts, overflow, fused, fused_combine = jax.jit(
             lambda p, x: latent_moe.routed_experts(p, x, MODEL))(layer, x)
     assert int(counts[5]) == 96     # every token chose expert 5
     assert int(counts.sum()) == 96 * 4 and not overflow.any()
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
-    stats = latent_moe.expert_stats(chosen, counts, overflow, fused, 4, MODEL)
+    stats = latent_moe.expert_stats(chosen, counts, overflow, fused,
+                                    fused_combine, 4, MODEL)
     assert stats["buffer_rows"].tolist() == [96] * 4
     assert stats["local_pairs"].tolist() == [96] * 4
     assert float(stats["load_max_over_mean"]) == 96 / (96 * 4 / 16)
@@ -455,11 +456,13 @@ def test_grouped_kernel_against_ragged_dot(case, group_sizes, rows, tile,
                                   "held-whole"])
 def test_routed_experts_through_the_kernel_equals_the_default_path(
         case, monkeypatch):
-    """``routed_experts`` with the TPU branch taken (the kernel interpreted,
-    a row tile of 16) against the ``lax.ragged_dot`` branch: a buffer whose
-    tail belongs to no expert, whose rows the combine multiplies by nought —
-    finite all the same, because the kernel zeroes them; the group sizes
-    traced inside the rounds' ``fori_loop``; and a layer held whole."""
+    """``routed_experts`` with the TPU branch taken (the kernels
+    interpreted, a row tile of 16) against the ``lax.ragged_dot`` branch and
+    the combine's gathers: a buffer whose tail belongs to no expert, whose
+    rows the combine multiplies by nought — finite all the same, because the
+    kernel zeroes them; the group sizes traced inside the rounds'
+    ``fori_loop``; and a layer held whole, whose combine stays the
+    gathers."""
     held = tuple(range(16)) if case == "held-whole" else (4, 5, 6, 7)
     config = dataclasses.replace(
         MODEL, hidden=HIDDEN, expert_width=WIDTH, experts_held=held,
@@ -468,9 +471,9 @@ def test_routed_experts_through_the_kernel_equals_the_default_path(
     layer = {"experts": experts, "router": jax.random.normal(
         jax.random.PRNGKey(4), (HIDDEN, 16), jnp.float32)}
     x = x.astype(jnp.float32)
-    want, _, counts, overflow, fused = jax.jit(
+    want, _, counts, overflow, fused, fused_combine = jax.jit(
         lambda p, x: latent_moe.routed_experts(p, x, config))(layer, x)
-    assert fused == 0
+    assert fused == fused_combine == 0
     capacity = latent_moe.buffer_capacity(96, config)
     assert capacity % 16 == 0
     assert {"a-tail-of-no-group": int(counts.sum()) < capacity - 16,
@@ -479,11 +482,15 @@ def test_routed_experts_through_the_kernel_equals_the_default_path(
     monkeypatch.setattr(latent_moe, "GROUPED_ROW_TILE", 16)
     monkeypatch.setattr(latent_moe, "grouped_product", functools.partial(
         latent_moe.grouped_product, row_tile=16, interpret=True))
+    monkeypatch.setattr(latent_moe, "fused_combine", functools.partial(
+        latent_moe.fused_combine, interpret=True))
     monkeypatch.setattr(lax, "platform_dependent",
                         lambda *operands, tpu, default: tpu(*operands))
-    got, _, again, _, fused = jax.jit(
+    got, _, again, _, fused, fused_combine = jax.jit(
         lambda p, x: latent_moe.routed_experts(p, x, config))(layer, x)
-    assert fused == 1 and np.isfinite(np.asarray(got)).all()
+    # a layer held whole has a row for every pair: the combine's gathers
+    assert fused == 1 and fused_combine == (case != "held-whole")
+    assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_array_equal(again, counts)
     # the two paths sum in another order, so a few of hidden's bfloat16
     # roundings fall the other way: a last place of one term of a row's sum
@@ -505,8 +512,8 @@ def test_the_combine_gathers_every_round_back_to_its_tokens(key, held,
     x = jax.random.normal(jax.random.PRNGKey(10), (48, s.hidden))
     with jax.default_matmul_precision("highest"):
         want, _ = ref.routed_part(layer, x, s, identity, experts_held=share)
-        got, chosen, counts, overflow, _ = latent_moe.routed_experts(layer, x,
-                                                                  config)
+        got, chosen, counts, overflow, *_ = latent_moe.routed_experts(
+            layer, x, config)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     capacity = latent_moe.buffer_capacity(48, config)
     # factor × the even share, in whole eights, and never more than the pairs
@@ -539,6 +546,7 @@ def test_program_counts_reach_telemetry_and_not_the_caller(key):
     assert counters[telemetry.M_MOE_BUFFER_ROWS] == 3 * WINDOW * 3 * 4
     # a CPU, and widths under a lane group: three lax.ragged_dot a layer
     assert counters[telemetry.M_MOE_FUSED_PRODUCT_LAYERS] == 0
+    assert counters[telemetry.M_MOE_FUSED_COMBINE_LAYERS] == 0
     assert counters[telemetry.M_MOE_OVERFLOW_PAIRS] == 0
     ratio = snapshot["histograms"][telemetry.M_MOE_LOAD_MAX_OVER_MEAN]
     assert ratio["count"] == 3 * 3 and ratio["min"] >= 1.0

@@ -274,14 +274,14 @@ def test_shares_add_up_to_the_uncut_layer(key):
                 layer["experts"]["up"],
                 whole["experts"]["up"][share[0]:share[0] + 4])
             config = dataclasses.replace(MODEL, experts_held=share)
-            part, _, counts, _, _ = latent_moe.routed_experts(layer, x,
-                                                           config)
+            part, _, counts, *_ = latent_moe.routed_experts(layer, x,
+                                                            config)
             ref_part, _ = ref.routed_part(layer, x, s, identity,
                                           experts_held=share)
             np.testing.assert_allclose(part, ref_part, rtol=1e-4, atol=1e-5)
             total = total + part
             pairs += int(counts.sum())
-        uncut, _, counts, overflow, _ = latent_moe.routed_experts(
+        uncut, _, counts, overflow, *_ = latent_moe.routed_experts(
             whole, x, MODEL)
     assert pairs == 40 * 4          # every (token, expert) pair, exactly once
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
