@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from sparkdl_tpu.models import latent_moe, registry
+from sparkdl_tpu.models import experts, registry
 
 TOKENS = 16384
 REPEATS = 10
@@ -28,9 +28,10 @@ CELLS = {"openpangu": ("openPangu-Ultra-MoE-718B", 16),
          "laguna": ("Laguna-XS.2", None)}
 
 
-def operands(c, seed=0):
-    """``combine``'s operands for the first round of a layer of ``c`` over
-    TOKENS tokens, the pairs placed as ``routed_experts`` places them."""
+def operands(c, hidden, seed=0):
+    """``combine``'s operands for the first round of an expert layer ``c``
+    of width ``hidden`` over TOKENS tokens, the pairs placed as
+    ``routed_experts`` places them."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
     held = len(c.experts_held)
     scores = jax.random.uniform(keys[0], (TOKENS, c.experts))
@@ -45,10 +46,10 @@ def operands(c, seed=0):
     local = jnp.sum(slots < held)
     place = jnp.zeros((pairs,), jnp.int32).at[order].set(
         jnp.arange(pairs, dtype=jnp.int32))
-    capacity = latent_moe.buffer_capacity(TOKENS, c)
+    capacity = experts.buffer_capacity(TOKENS, c)
     mine = jnp.where((place < local) & (place < capacity), weights, 0.0)
-    out = jax.random.normal(keys[1], (capacity, c.hidden), jnp.float32)
-    y = jax.random.normal(keys[2], (TOKENS, c.hidden), jnp.float32)
+    out = jax.random.normal(keys[1], (capacity, hidden), jnp.float32)
+    y = jax.random.normal(keys[2], (TOKENS, hidden), jnp.float32)
     return y, out, place, mine
 
 
@@ -71,24 +72,25 @@ def main(argv):
         raise SystemExit("combine_kernel_probe: needs a TPU")
     asked = [int(a) for a in argv]
     for cell, (model, held) in CELLS.items():
-        c = registry.SEQUENCE_MODELS[model]
+        hidden, c = (registry.SEQUENCE_MODELS[model].hidden,
+                     registry.SEQUENCE_MODELS[model].moe)
         if held is not None:
             c = dataclasses.replace(c, experts_held=tuple(range(held)))
-        y, out, at, mine = operands(c)
+        y, out, at, mine = operands(c, hidden)
         k = c.top_k
 
         def gathers(y, out, at, mine):
-            return latent_moe.gathered_combine(y, out, at, mine, k)
+            return experts.gathered_combine(y, out, at, mine, k)
 
         want = jax.jit(gathers)(y, out, at, mine)
-        line = {"cell": cell, "hidden": c.hidden, "top_k": k,
+        line = {"cell": cell, "hidden": hidden, "top_k": k,
                 "rows": out.shape[0],
                 "fetched_pairs": int(jnp.sum(mine != 0)),
                 "gathers_ms": per_call_ms(gathers, y, out, at, mine),
-                "rule_tile": latent_moe._combine_tile(TOKENS, c.hidden, k)}
+                "rule_tile": experts._combine_tile(TOKENS, hidden, k)}
         for tile in asked or [line["rule_tile"]]:
             def kernel(y, out, at, mine, tile=tile):
-                return latent_moe.fused_combine(
+                return experts.fused_combine(
                     y, out, jnp.where(mine != 0, at, -1),
                     mine.reshape(TOKENS, k), tile=tile)
 

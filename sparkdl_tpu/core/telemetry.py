@@ -269,10 +269,11 @@ M_CLUSTER_DRAIN_S = "sparkdl.cluster.drain_s"          # histogram
 PROGRAM_COUNTS = "sparkdl.program_counts"
 M_SEQUENCE_TOKENS = "sparkdl.sequence.tokens"          # counter (tokens of
                                                        # the rows scored)
-# counter (per row: the layers whose attention was lowered to the fused kernel)
+# counter (per row: the layers whose attention was lowered to the fused
+# kernel, models/attention.py fused_causal_attention)
 M_SEQUENCE_FUSED_ATTENTION_LAYERS = "sparkdl.sequence.fused_attention_layers"
 # counter (per row: 1 where the scorer's head was lowered to the fused kernel,
-# models/latent_moe.py fused_scoring_head — no logit in HBM)
+# models/scoring_head.py fused_scoring_head — no logit in HBM)
 M_SEQUENCE_FUSED_HEAD_WINDOWS = "sparkdl.sequence.fused_head_windows"
 # counter (per row: the layers whose mixer was the gated short convolution)
 M_SEQUENCE_CONV_LAYERS = "sparkdl.sequence.conv_layers"
@@ -308,15 +309,15 @@ M_MOE_BUFFER_ROWS = "sparkdl.moe.buffer_rows"          # counter (per row its
                                                        # rounds × the buffer,
                                                        # per expert layer)
 # counter (per row: the expert layers whose grouped products were lowered to
-# the kernel, models/latent_moe.py grouped_product)
+# the kernel, models/experts.py grouped_product)
 M_MOE_FUSED_PRODUCT_LAYERS = "sparkdl.moe.fused_product_layers"
 # counter (per row: the expert layers whose combine was lowered to the
-# kernel, models/latent_moe.py fused_combine)
+# kernel, models/experts.py fused_combine)
 M_MOE_FUSED_COMBINE_LAYERS = "sparkdl.moe.fused_combine_layers"
 # counters (per row its share, summed over the expert layers: the grouped
 # kernel's visits by its grid's rule — a row tile once for each group it
 # holds a row of — and the buffer's row tiles, rounds × the buffer; counted
-# on either path, models/latent_moe.py product_visits)
+# on either path, models/experts.py product_visits)
 M_MOE_PRODUCT_VISITS = "sparkdl.moe.product_visits"
 M_MOE_PRODUCT_ROW_TILES = "sparkdl.moe.product_row_tiles"
 # counter (per row: the expert layers with a shared expert beside the routed

@@ -26,6 +26,8 @@ import numpy as np
 from sparkdl_tpu.core import profiling
 from sparkdl_tpu.core.model_function import ModelFunction, TensorSpec
 from sparkdl_tpu.models import latent_moe, shortconv_moe
+from sparkdl_tpu.models.attention import Rope
+from sparkdl_tpu.models.experts import ExpertConfig
 from sparkdl_tpu.models.inception import InceptionV3
 from sparkdl_tpu.models.latent_moe import LatentMoEConfig
 from sparkdl_tpu.models.mobilenet import MobileNetV2
@@ -231,51 +233,61 @@ SEQUENCE_MODELS: Dict[str, SequenceConfig] = {
     # huggingface.co/FreedomIntelligence/openPangu-Ultra-MoE-718B config.json
     "openPangu-Ultra-MoE-718B": LatentMoEConfig(
         hidden=7680, heads=128, q_rank=1536, kv_rank=512, nope=128, rope=64,
-        v=128, dense_width=18432, expert_width=2048, experts=256,
-        experts_held=tuple(range(256)), top_k=8, vocab=153600, layers=61,
-        dense_layers=3, scaling=2.5, norm_topk=True, eps=1e-5,
-        theta=25600000.0),
+        v=128, dense_width=18432, vocab=153600, layers=61, dense_layers=3,
+        moe=ExpertConfig(expert_width=2048, experts=256,
+                         experts_held=tuple(range(256)), top_k=8, scaling=2.5,
+                         norm_topk=True, topk_eps=0.0),
+        eps=1e-5, theta=25600000.0),
     # the same block at sizes a CPU test runs (TestNet's counterpart)
     "TestLatentMoE": LatentMoEConfig(
         hidden=64, heads=4, q_rank=32, kv_rank=16, nope=16, rope=8, v=16,
-        dense_width=128, expert_width=32, experts=16,
-        experts_held=tuple(range(16)), top_k=4, vocab=64, layers=3,
-        dense_layers=2, scaling=2.5, norm_topk=True, eps=1e-5,
-        theta=25600000.0, query_block=8),
+        dense_width=128, vocab=64, layers=3, dense_layers=2,
+        moe=ExpertConfig(expert_width=32, experts=16,
+                         experts_held=tuple(range(16)), top_k=4, scaling=2.5,
+                         norm_topk=True, topk_eps=0.0),
+        eps=1e-5, theta=25600000.0, query_block=8),
     # huggingface.co/LiquidAI/LFM2-8B-A1B config.json (model_type lfm2_moe)
     "LFM2-8B-A1B": ShortConvMoEConfig(
         hidden=2048, heads=32, kv_heads=8, head_dim=64, dense_width=7168,
-        expert_width=1792, experts=32, experts_held=tuple(range(32)),
-        top_k=4, vocab=65536, scaling=1.0, norm_topk=True, eps=1e-5,
-        theta=1000000.0),
+        vocab=65536,
+        moe=ExpertConfig(expert_width=1792, experts=32,
+                         experts_held=tuple(range(32)), top_k=4, scaling=1.0,
+                         norm_topk=True, topk_eps=1e-6),
+        eps=1e-5, theta=1000000.0),
     # ... and its block at sizes a CPU test runs
     "TestShortConvMoE": ShortConvMoEConfig(
         hidden=64, heads=8, kv_heads=2, head_dim=8, dense_width=128,
-        expert_width=32, experts=16, experts_held=tuple(range(16)), top_k=4,
-        vocab=64, scaling=1.0, norm_topk=True, eps=1e-5, theta=1000000.0,
-        query_block=8),
+        vocab=64,
+        moe=ExpertConfig(expert_width=32, experts=16,
+                         experts_held=tuple(range(16)), top_k=4, scaling=1.0,
+                         norm_topk=True, topk_eps=1e-6),
+        eps=1e-5, theta=1000000.0, query_block=8),
     # huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct config.json
     # (model_type mellum): the same pre-norm stack, attention in every layer,
     # three sliding-window layers (span 1,024, plain rotary) to a full one
     # (YaRN), a soft-max router, no dense layer, the head untied
     "Mellum2-12B-A2.5B-Instruct": ShortConvMoEConfig(
         hidden=2304, heads=32, kv_heads=4, head_dim=128, dense_width=7168,
-        expert_width=896, experts=64, experts_held=tuple(range(64)), top_k=8,
-        vocab=98304, scaling=1.0, norm_topk=True, topk_eps=0.0,
-        scoring="softmax", eps=1e-6, theta=500000.0,
+        vocab=98304,
+        moe=ExpertConfig(expert_width=896, experts=64,
+                         experts_held=tuple(range(64)), top_k=8, scaling=1.0,
+                         norm_topk=True, topk_eps=0.0, scoring="softmax"),
+        eps=1e-6, theta=500000.0,
         layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 7,
-        span=1024, rope=(("full_attention", latent_moe.Rope(
+        span=1024, rope=(("full_attention", Rope(
             theta=500000.0, factor=16.0, original=8192, beta_fast=32.0,
             beta_slow=1.0, amplitude=1.2772588722239782)),)),
     # ... and its stack at sizes a CPU test runs: two periods, a span shorter
     # than a test's window
     "TestSpanMoE": ShortConvMoEConfig(
         hidden=64, heads=8, kv_heads=2, head_dim=8, dense_width=128,
-        expert_width=32, experts=16, experts_held=tuple(range(16)), top_k=4,
-        vocab=64, scaling=1.0, norm_topk=True, topk_eps=0.0,
-        scoring="softmax", eps=1e-6, theta=100.0,
+        vocab=64,
+        moe=ExpertConfig(expert_width=32, experts=16,
+                         experts_held=tuple(range(16)), top_k=4, scaling=1.0,
+                         norm_topk=True, topk_eps=0.0, scoring="softmax"),
+        eps=1e-6, theta=100.0,
         layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 2,
-        span=8, rope=(("full_attention", latent_moe.Rope(
+        span=8, rope=(("full_attention", Rope(
             theta=100.0, factor=16.0, original=32, beta_fast=4.0,
             beta_slow=1.0, amplitude=1.2772588722239782)),),
         query_block=8),
@@ -305,30 +317,34 @@ SEQUENCE_MODELS: Dict[str, SequenceConfig] = {
     # and scaled by 2.5, the head untied
     "Laguna-XS.2": ShortConvMoEConfig(
         hidden=2048, heads=48, kv_heads=8, head_dim=128, dense_width=8192,
-        expert_width=512, experts=256, experts_held=tuple(range(256)),
-        top_k=8, vocab=100352, scaling=2.5, norm_topk=True, topk_eps=0.0,
-        scoring="sigmoid", eps=1e-6, theta=10000.0,
+        vocab=100352,
+        moe=ExpertConfig(expert_width=512, experts=256,
+                         experts_held=tuple(range(256)), top_k=8, scaling=2.5,
+                         norm_topk=True, topk_eps=0.0, scoring="sigmoid"),
+        eps=1e-6, theta=10000.0,
         layer_types=tuple("full_attention" if i % 4 == 0
                           else "sliding_attention" for i in range(40)),
         span=512, rope=(
-            ("full_attention", latent_moe.Rope(
+            ("full_attention", Rope(
                 theta=500000.0, factor=64.0, original=4096, beta_fast=64.0,
                 beta_slow=1.0, amplitude=1.4158883083359672, partial=0.5)),
-            ("sliding_attention", latent_moe.Rope(theta=10000.0)))),
+            ("sliding_attention", Rope(theta=10000.0)))),
     # ... and its stack at sizes a CPU test runs: two periods, 6 and 8 query
     # heads on 2 key heads, a span shorter than a test's window, a gate on
     # every attention layer, a shared expert beside the routed ones
     "TestLaguna": ShortConvMoEConfig(
         hidden=64, heads=6, kv_heads=2, head_dim=16, dense_width=128,
-        expert_width=32, experts=16, experts_held=tuple(range(16)), top_k=4,
-        vocab=64, scaling=2.5, norm_topk=True, topk_eps=0.0,
-        scoring="sigmoid", eps=1e-6, theta=100.0,
+        vocab=64,
+        moe=ExpertConfig(expert_width=32, experts=16,
+                         experts_held=tuple(range(16)), top_k=4, scaling=2.5,
+                         norm_topk=True, topk_eps=0.0, scoring="sigmoid"),
+        eps=1e-6, theta=100.0,
         layer_types=(("full_attention",) + ("sliding_attention",) * 3) * 2,
         span=8, rope=(
-            ("full_attention", latent_moe.Rope(
+            ("full_attention", Rope(
                 theta=1000.0, factor=4.0, original=64, beta_fast=4.0,
                 beta_slow=1.0, amplitude=1.1386294361119891, partial=0.5)),
-            ("sliding_attention", latent_moe.Rope(theta=100.0))),
+            ("sliding_attention", Rope(theta=100.0))),
         query_block=8),
 }
 
@@ -367,14 +383,16 @@ def build_sequence_scorer(name, weights: Dict[str, Any], window: int,
         stacked = {layer["moe"]["experts"]["down"].shape[0]
                    for layer in layers[dense:]}
         if experts_held is None:
-            experts_held = range(config.experts)
+            experts_held = range(config.moe.experts if config.moe else 0)
         experts_held = tuple(int(e) for e in experts_held)
         if stacked - {len(experts_held)}:
             raise ValueError(
                 f"the expert layers hold {sorted(stacked)} experts' weights, "
                 f"experts_held names {len(experts_held)}")
-        share = {"experts_held": experts_held,
-                 "vocab": int(weights["embed"].shape[0])}
+        share = {"vocab": int(weights["embed"].shape[0])}
+        if config.moe is not None:
+            share["moe"] = dataclasses.replace(config.moe,
+                                               experts_held=experts_held)
         if isinstance(config, LatentMoEConfig):
             share.update(layers=len(layers), dense_layers=dense)
         elif not all(sum(mixer in layer for mixer in ("conv", "attn", "ssm"))

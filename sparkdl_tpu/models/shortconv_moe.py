@@ -17,63 +17,26 @@ parts ``[B ; C ; x̃]``, a depthwise causal convolution of a few taps over
 ``C``, an output projection. A layer that holds ``"ssm"`` runs the
 **state-space mixer** (``models/state_space.py``: the same taps with a bias,
 inner norms, and a recurrence along the window whose state starts from zero
-in each). A layer that holds ``"attn"`` runs **grouped-query attention**:
-fewer key heads than query heads, as many of each as the layer's weights
-have (a kind of layer may have its own), an RMSNorm per head on queries and
-keys where the weights hold one (``"q_norm"``), rotary on both where the
-config gives a ``theta``; query head ``a`` reads key head ``a // (heads /
-kv_heads)`` and keys and values are never repeated per query head; where the
-weights hold a ``"gate"``, each head's output is scaled by a sigmoid of its
-own before the output projection. What the weights cannot say — a number is
-no array — comes from the config by position, the held layers being the
+in each). A layer that holds ``"attn"`` runs **grouped-query attention**
+(:func:`grouped_attention` over ``models/attention.py``): fewer key heads
+than query heads, as many of each as the layer's weights have, per-head
+norms and a per-head sigmoid gate where the weights hold them, rotary where
+the config gives a ``theta``. What the weights cannot say — a number is no
+array — comes from the config by position, the held layers being the
 leading ones: where it names its layers' kinds (``layer_types``), a
 ``"sliding_attention"`` layer's query reads the last ``span`` keys only, its
-own among them, and a kind's rotary is the one ``rope`` gives it
-(``latent_moe.Rope``: plain, or YaRN's scaled frequencies and amplitude,
-over the whole head or over its leading share). The ffn is a gated MLP where
-the layer holds ``"mlp"`` — every layer may: **the expert layer is
-optional** — else the sparse expert layer, the one the latent-attention
-model runs (``latent_moe.routed_experts``), with a shared expert beside the
-routed ones where the layer holds ``"shared"`` and without one otherwise,
-its router's scores sigmoids with a selection bias or a soft-max
-(``scoring``), and usually held whole.
+own among them, and a kind's rotary is the one ``rope`` gives it. The ffn is a gated MLP where the layer holds ``"mlp"`` —
+every layer may: **the expert layer is optional** — else the sparse expert
+layer (``models/experts.py``), with a shared expert beside the routed ones
+where the layer holds ``"shared"``.
 
-Shared with ``models/latent_moe.py`` and imported from it: ``rms_norm``, the
-mixed-precision product, ``rotary``, the causal taps, causal attention over
-grouped keys with its two paths (``grouped_causal_attention``),
-``gated_mlp``, ``route`` / ``routed_experts`` / ``expert_stats``, and the
-scorer's head and counts (``score_head``, ``program_counts``). Precision
-follows the weights, as there: bfloat16 products with float32 accumulation;
+Precision follows the weights: bfloat16 products with float32 accumulation;
 the router, the norms' statistics, the soft-max, the rotary tables, the gates
 and taps of the convolution, attention's gates, the state-space mixer's
-recurrence, the residual stream and the log-probabilities float32.
-
-Attention goes down ``latent_moe``'s fused kernel where a lowering for a TPU
-finds bfloat16 weights, a window of whole query tiles and a head width the
-kernel takes — 128, whole lanes: every layer of the ``mellum`` family, a
-sliding layer visiting only the key tiles its span reaches, and the ``jamba``
-family's, 20 query heads reading one key head, and the ``laguna`` family's,
-6 (full) or 8 (sliding) of them, rotated before; 64, half a lane group: every
-attention layer of the ``lfm2_moe`` family, a key head's four query heads
-worked together a grid step — and down the blocked path everywhere else,
-with the same span. Every stack, with an expert layer or without, reports the
-tokens it scored, the layers that took the attention kernel
-(``sparkdl.sequence.fused_attention_layers``) and whether the head — tied or
-not, read as it lies — went down its fused scoring head
-(``sparkdl.sequence.fused_head_windows``). The expert layers' grouped
-products go down ``latent_moe``'s grouped-product kernel at the published
-widths (``sparkdl.moe.fused_product_layers``), and a state-space layer's
-recurrence down the selective-scan kernel
-(``sparkdl.sequence.fused_scan_layers``).
-Outputs per window are ``latent_moe``'s, ``expert_counts`` only from a stack
-with expert layers; the program's counts gain
-``sparkdl.sequence.conv_layers``, from a stack that names its layers' kinds
-``sparkdl.sequence.window_attention_layers`` and
-``sparkdl.sequence.scored_keys``, from one with state-space layers
-``sparkdl.sequence.ssm_layers`` and ``sparkdl.sequence.fused_scan_layers``,
-from one whose layers hold gates ``sparkdl.sequence.gated_attention_layers``
-and from one whose layers hold shared experts
-``sparkdl.moe.shared_expert_layers`` (under the ``shared_expert`` scope).
+recurrence, the residual stream and the log-probabilities float32. Outputs
+per window are the latent-attention model's (``models/latent_moe.py``),
+``expert_counts`` only from a stack with expert layers, and the counts that
+each kind of layer adds are :func:`forward`'s.
 """
 
 from __future__ import annotations
@@ -87,39 +50,35 @@ import jax.numpy as jnp
 from jax import lax
 
 from sparkdl_tpu.core import telemetry
-from sparkdl_tpu.models.latent_moe import (
-    Rope, _dot, causal_taps, expert_stats, gated_mlp,
-    grouped_causal_attention, program_counts, rms_norm, rotary,
-    routed_experts, score_head)
+from sparkdl_tpu.models.attention import (Rope, grouped_causal_attention,
+                                          rotary)
+from sparkdl_tpu.models.experts import (ExpertConfig, expert_stats,
+                                        routed_experts)
+from sparkdl_tpu.models.scoring_head import program_counts, score_head
+from sparkdl_tpu.models.sequence_ops import (causal_taps, gated_mlp,
+                                             mixed_dot, rms_norm)
 from sparkdl_tpu.models.state_space import state_space
 
 
 @dataclass(frozen=True)
 class ShortConvMoEConfig:
-    """Widths as published; ``experts_held`` and ``vocab`` are what this chip
-    holds of a stated deployment. How many layers there are, and which mixer
-    and ffn each has, is read off the weights; the kind of an attention
-    layer, where a model has several, by position off ``layer_types``; a
-    layer's query and key heads off its weights too (``heads`` is the
-    published ``num_attention_heads``). A model without expert layers leaves
-    the expert fields at none, one without state-space layers the
-    state-space sizes, and one whose attention turns nothing gives no
-    ``theta``."""
+    """Widths as published; the expert layer's ``experts_held`` and
+    ``vocab`` are what this chip holds of a stated deployment. How many
+    layers there are, and which mixer and ffn each has, is read off the
+    weights; the kind of an attention layer, where a model has several, by
+    position off ``layer_types``; a layer's query and key heads off its
+    weights too (``heads`` is the published ``num_attention_heads``). A
+    model without expert layers leaves ``moe`` at none, one without
+    state-space layers the state-space sizes, and one whose attention turns
+    nothing gives no ``theta``."""
 
     hidden: int
     heads: int
     kv_heads: int
     head_dim: int
     dense_width: int
-    expert_width: int = 0
-    experts: int = 0          # published: the router's width
-    experts_held: Tuple[int, ...] = ()
-    top_k: int = 0
     vocab: int = 0            # rows of the embedding (and tied head) held
-    scaling: float = 1.0
-    norm_topk: bool = True
-    topk_eps: float = 1e-6    # added to the chosen scores' sum before dividing
-    scoring: str = "sigmoid"  # the router's scores: "sigmoid" or "softmax"
+    moe: Optional[ExpertConfig] = None      # None: no expert layer
     eps: float = 1e-5
     theta: Optional[float] = 1000000.0      # None: no rotary
     # the published kind of every layer by position ("sliding_attention",
@@ -135,7 +94,6 @@ class ShortConvMoEConfig:
     d_state: int = 0
     dt_rank: int = 0
     d_conv: int = 0
-    capacity_factor: float = 2.0    # see LatentMoEConfig
     query_block: int = 512
 
 
@@ -145,14 +103,14 @@ def short_conv(p, u):
     ``p["taps"]`` (hidden, L) weigh positions t − (L − 1) … t of each channel;
     gates and taps are float32."""
     taps = p["taps"].astype(jnp.float32)
-    gate_in, gate_out, carried = jnp.split(_dot(u, p["in"]), 3, -1)
-    return _dot(gate_out * causal_taps(gate_in * carried, taps), p["out"])
+    gate_in, gate_out, carried = jnp.split(mixed_dot(u, p["in"]), 3, -1)
+    return mixed_dot(gate_out * causal_taps(gate_in * carried, taps), p["out"])
 
 
 def grouped_attention(p, u, c: ShortConvMoEConfig, kind=None):
     """u (T, hidden) float32, one window → ``((T, hidden) float32, fused,
-    scored)``, the last two as ``latent_moe.grouped_causal_attention``
-    returns them. The query and key heads are read off the weights (their
+    scored)``, the last two as ``grouped_causal_attention`` returns
+    them. The query and key heads are read off the weights (their
     columns ÷ ``head_dim``), so a model's kinds of layer may differ in them.
     Queries and keys are normed per head where the weights hold the gains
     (one of ``head_dim`` each) and, where ``c`` gives a ``theta``, rotated in
@@ -171,7 +129,7 @@ def grouped_attention(p, u, c: ShortConvMoEConfig, kind=None):
         return a.reshape(T, -1, c.head_dim)
 
     def projected(name):
-        a = heads(_dot(u, p[name]))
+        a = heads(mixed_dot(u, p[name]))
         gain = p.get(name + "_norm")
         return a if gain is None else rms_norm(a, gain, c.eps)
 
@@ -195,13 +153,13 @@ def grouped_attention(p, u, c: ShortConvMoEConfig, kind=None):
     q = projected("q") * c.head_dim ** -0.5
     k = projected("k")
     out, fused, scored = grouped_causal_attention(
-        placed(q), placed(k), _dot(u, p["v"], act), query_heads,
+        placed(q), placed(k), mixed_dot(u, p["v"], act), query_heads,
         c.query_block, c.span if kind == "sliding_attention" else None)
     if "gate" in p:
-        gate = jax.nn.sigmoid(_dot(u, p["gate"]))       # (T, query heads)
+        gate = jax.nn.sigmoid(mixed_dot(u, p["gate"]))       # (T, query heads)
         out = (out.reshape(T, query_heads, -1) * gate[..., None]).reshape(
             T, -1)
-    return _dot(out, p["out"]), fused, scored
+    return mixed_dot(out, p["out"]), fused, scored
 
 
 @functools.partial(jax.jit, static_argnames=("c", "kind"))
@@ -241,11 +199,12 @@ def block(layer, h, c: ShortConvMoEConfig, kind=None):
                 None, told)
     flat = x.reshape(B * T, -1)
     with jax.named_scope("routed_experts"):
-        routed, *stats = routed_experts(layer["moe"], flat, c)
+        routed, moe_told = routed_experts(layer["moe"], flat, c.moe)
     if "shared" in layer["moe"]:
         with jax.named_scope("shared_expert"):
             routed = routed + gated_mlp(layer["moe"]["shared"], flat)
-    return h + routed.reshape(B, T, -1), expert_stats(*stats, B, c), told
+    return (h + routed.reshape(B, T, -1), expert_stats(moe_told, B, c.moe),
+            told)
 
 
 def forward(params, tokens, c: ShortConvMoEConfig) -> Dict[str, Any]:

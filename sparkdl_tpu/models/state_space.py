@@ -7,7 +7,7 @@ A layer that holds ``"ssm"`` runs, over its normed input ``u``:
 
   ``[x ; z] = W_in u`` (``d_inner`` each, no bias);
   ``x ← silu(conv(x))``, a depthwise causal convolution of ``d_conv`` taps with
-  a bias, zeros before the window (``latent_moe.causal_taps``, the loop the
+  a bias, zeros before the window (``sequence_ops.causal_taps``, the loop the
   gated short convolution runs);
   ``[δ ; B ; C] = W_x x`` (``dt_rank`` + ``d_state`` + ``d_state``), each
   through an RMSNorm with a gain of its own;
@@ -50,7 +50,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from sparkdl_tpu.models.latent_moe import _dot, causal_taps, rms_norm
+from sparkdl_tpu.models.sequence_ops import (LANES, causal_taps, mixed_dot,
+                                             rms_norm)
 
 # The kernel's blocks: positions a grid step — a step's ``Δ`` (float32), ``x``,
 # ``z`` and ``y`` blocks, each twice for the pipeline, and its float32
@@ -69,7 +70,6 @@ SCAN_CHANNEL_CHUNKS = (512, 256)
 SCAN_UNROLL = 8
 SCAN_VMEM_LIMIT = 64 * 1024 * 1024
 PLAIN_BLOCK = 64
-_LANES = 128
 
 
 def scan_blocks(x, delta, a, b, c, d, z, state, block=PLAIN_BLOCK):
@@ -140,12 +140,12 @@ def _scan_kernel(x_ref, delta_ref, z_ref, b_ref, c_ref, a_ref, d_ref, s0_ref,
         for narrow, wide in ((b_ref, b_wide), (c_ref, c_wide)):
             row = jnp.broadcast_to(narrow[pl.ds(t, 1), :], (states, states))
             column = jnp.sum(jnp.where(diagonal, row, 0.0), 1, keepdims=True)
-            wide[rows, :] = jnp.broadcast_to(column, (states, _LANES))
+            wide[rows, :] = jnp.broadcast_to(column, (states, LANES))
 
     lax.fori_loop(0, block, widen, None)
 
     def across(tile):
-        return jnp.tile(tile, (1, chunk // _LANES))
+        return jnp.tile(tile, (1, chunk // LANES))
 
     for lo in range(0, channels, chunk):
         columns = slice(lo, lo + chunk)
@@ -212,8 +212,8 @@ def fused_selective_scan(x, delta, a, b, c, d, z, state, *,
                    jax.ShapeDtypeStruct((N, D), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block, D), jnp.float32),
                         pltpu.VMEM((block, D), jnp.float32),
-                        pltpu.VMEM((block * N, _LANES), jnp.float32),
-                        pltpu.VMEM((block * N, _LANES), jnp.float32)],
+                        pltpu.VMEM((block * N, LANES), jnp.float32),
+                        pltpu.VMEM((block * N, LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=SCAN_VMEM_LIMIT),
@@ -263,16 +263,16 @@ def state_space(p, u, c, state=None):
     f32 = jnp.float32
     act = p["out"].dtype
     inner, states, rank = c.d_inner, c.d_state, c.dt_rank
-    x = jax.nn.silu(causal_taps(_dot(u, p["in"][:, :inner]), p["taps"],
+    x = jax.nn.silu(causal_taps(mixed_dot(u, p["in"][:, :inner]), p["taps"],
                                 p["conv_bias"])).astype(act)
-    z = _dot(u, p["in"][:, inner:], act)
-    projected = _dot(x, p["x"])
+    z = mixed_dot(u, p["in"][:, inner:], act)
+    projected = mixed_dot(x, p["x"])
     dt, b, carried = (
         rms_norm(projected[..., lo:hi], p[name], c.eps)
         for name, lo, hi in (("dt_norm", 0, rank),
                              ("b_norm", rank, rank + states),
                              ("c_norm", rank + states, rank + 2 * states)))
-    delta = jax.nn.softplus(_dot(dt, p["dt"]) + p["dt_bias"].astype(f32))
+    delta = jax.nn.softplus(mixed_dot(dt, p["dt"]) + p["dt_bias"].astype(f32))
     a = -jnp.exp(p["a_log"].astype(f32)).T
     d = p["d"].astype(f32)
     if state is None:
@@ -283,4 +283,4 @@ def state_space(p, u, c, state=None):
         return selective_scan(x, delta, a, b, carried, d, z, state)
 
     y, last, fused = lax.map(one_window, (x, delta, b, carried, z, state))
-    return _dot(y, p["out"]), last, fused
+    return mixed_dot(y, p["out"]), last, fused

@@ -50,8 +50,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from sparkdl_tpu.core import ModelFunction, TensorSpec
-from sparkdl_tpu.models import (latent_moe, registry, shortconv_moe,
-                                state_space)
+from sparkdl_tpu.models import (attention, experts, latent_moe, registry,
+                                scoring_head, shortconv_moe, state_space)
 from sparkdl_tpu.models.layers import ConvBN, SeparableConvBN
 
 
@@ -148,7 +148,7 @@ def test_program_compiles_for_v5e(site, one_chip, no_persistent_cache):
     assert "tpu_custom_call" not in text
 
 
-@pytest.mark.parametrize("window", [4096, latent_moe.FUSED_MAX_WINDOW])
+@pytest.mark.parametrize("window", [4096, attention.FUSED_MAX_WINDOW])
 def test_latent_attention_compiles_to_the_fused_kernel_for_v5e(
         window, one_chip, no_persistent_cache):
     """One window's latent attention at the published widths (128 heads of
@@ -223,28 +223,29 @@ def test_short_convolution_model_compiles_for_v5e(part, one_chip,
              "final_norm": on_chip((c.hidden,))}
 
         def fn(p, x):
-            return latent_moe.score_head(
+            return scoring_head.score_head(
                 dict(p, head=p["embed"]), x, jnp.zeros((4, 4096), jnp.int32),
                 c.eps)
 
         x = on_chip((4, 4096, c.hidden), jnp.float32)
     else:
-        p = {"router": on_chip((c.hidden, c.experts)),
-             "expert_bias": on_chip((c.experts,)),
+        n, width = c.moe.experts, c.moe.expert_width
+        p = {"router": on_chip((c.hidden, n)),
+             "expert_bias": on_chip((n,)),
              "experts": {
-                 "gate": on_chip((c.experts, c.hidden, c.expert_width)),
-                 "up": on_chip((c.experts, c.hidden, c.expert_width)),
-                 "down": on_chip((c.experts, c.expert_width, c.hidden))}}
+                 "gate": on_chip((n, c.hidden, width)),
+                 "up": on_chip((n, c.hidden, width)),
+                 "down": on_chip((n, width, c.hidden))}}
 
         def fn(p, x):
-            return latent_moe.routed_experts(p, x, c)
+            return experts.routed_experts(p, x, c.moe)
 
         x = on_chip((4 * 4096, c.hidden), jnp.float32)
     text = jax.jit(fn).lower(p, x).compile().as_text()
     assert ":T(" in text  # tiled layouts: the TPU's compiler made this
     assert ("fused_causal_attention" in text) == (part == "grouped_attention")
     if part == "routed_experts":
-        assert latent_moe.buffer_capacity(4 * 4096, c) == 65536
+        assert experts.buffer_capacity(4 * 4096, c.moe) == 65536
         # gate and up as one kernel, down as another, and no product of
         # XLA's; a row for every pair: the combine's gathers, not its kernel
         assert text.count('custom_call_target="tpu_custom_call"') == 2
@@ -282,7 +283,7 @@ def test_a_share_of_the_vocabulary_compiles_to_the_fused_head_for_v5e(
     fused scoring head at blocks this contraction leaves room for."""
     c = registry.SEQUENCE_MODELS["openPangu-Ultra-MoE-718B"]
     rows = 19200
-    assert latent_moe._head_blocks(4096, c.hidden, rows, jnp.bfloat16)
+    assert scoring_head._head_blocks(4096, c.hidden, rows, jnp.bfloat16)
 
     def on_chip(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -290,7 +291,7 @@ def test_a_share_of_the_vocabulary_compiles_to_the_fused_head_for_v5e(
     p = {"embed": on_chip((rows, c.hidden)), "head": on_chip((rows, c.hidden)),
          "final_norm": on_chip((c.hidden,))}
     x = on_chip((4, 4096, c.hidden), jnp.float32)
-    text = jax.jit(lambda p, x: latent_moe.score_head(
+    text = jax.jit(lambda p, x: scoring_head.score_head(
         p, x, jnp.zeros((4, 4096), jnp.int32), c.eps)
         ).lower(p, x).compile().as_text()
     _holds_the_fused_head_and_no_logits(text, rows)
@@ -307,21 +308,21 @@ def test_a_share_of_the_experts_compiles_to_the_grouped_kernel_for_v5e(
     this chip computed, in place of XLA's gathers."""
     import dataclasses
 
-    c = dataclasses.replace(
-        registry.SEQUENCE_MODELS["openPangu-Ultra-MoE-718B"],
-        experts_held=tuple(range(16)))
+    published = registry.SEQUENCE_MODELS["openPangu-Ultra-MoE-718B"]
+    c = dataclasses.replace(published, moe=dataclasses.replace(
+        published.moe, experts_held=tuple(range(16))))
 
     def on_chip(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    p = {"router": on_chip((c.hidden, c.experts)),
-         "experts": {"gate": on_chip((16, c.hidden, c.expert_width)),
-                     "up": on_chip((16, c.hidden, c.expert_width)),
-                     "down": on_chip((16, c.expert_width, c.hidden))}}
+    p = {"router": on_chip((c.hidden, c.moe.experts)),
+         "experts": {"gate": on_chip((16, c.hidden, c.moe.expert_width)),
+                     "up": on_chip((16, c.hidden, c.moe.expert_width)),
+                     "down": on_chip((16, c.moe.expert_width, c.hidden))}}
     x = on_chip((4 * 4096, c.hidden), jnp.float32)
-    text = jax.jit(lambda p, x: latent_moe.routed_experts(p, x, c)
+    text = jax.jit(lambda p, x: experts.routed_experts(p, x, c.moe)
                    ).lower(p, x).compile().as_text()
-    assert latent_moe.buffer_capacity(4 * 4096, c) == 16384
+    assert experts.buffer_capacity(4 * 4096, c.moe) == 16384
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     assert "grouped_product" in text and "ragged-dot" not in text
     assert "fused_combine" in text
@@ -343,7 +344,7 @@ def test_span_model_compiles_for_v5e(part, one_chip, no_persistent_cache):
     of the window's 6.4 GB of float32 logits, whole or in blocks."""
     c = registry.SEQUENCE_MODELS["Mellum2-12B-A2.5B-Instruct"]
     window = 16384
-    assert window == latent_moe.FUSED_MAX_WINDOW
+    assert window == attention.FUSED_MAX_WINDOW
 
     def on_chip(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -359,14 +360,15 @@ def test_span_model_compiles_for_v5e(part, one_chip, no_persistent_cache):
 
         x = on_chip((window, c.hidden), jnp.float32)
     elif part == "routed_experts":
-        p = {"router": on_chip((c.hidden, c.experts)),
+        n, width = c.moe.experts, c.moe.expert_width
+        p = {"router": on_chip((c.hidden, n)),
              "experts": {
-                 "gate": on_chip((c.experts, c.hidden, c.expert_width)),
-                 "up": on_chip((c.experts, c.hidden, c.expert_width)),
-                 "down": on_chip((c.experts, c.expert_width, c.hidden))}}
+                 "gate": on_chip((n, c.hidden, width)),
+                 "up": on_chip((n, c.hidden, width)),
+                 "down": on_chip((n, width, c.hidden))}}
 
         def fn(p, x):
-            return latent_moe.routed_experts(p, x, c)
+            return experts.routed_experts(p, x, c.moe)
 
         x = on_chip((window, c.hidden), jnp.float32)
     else:
@@ -375,7 +377,7 @@ def test_span_model_compiles_for_v5e(part, one_chip, no_persistent_cache):
              "final_norm": on_chip((c.hidden,))}
 
         def fn(p, x):
-            return latent_moe.score_head(
+            return scoring_head.score_head(
                 p, x, jnp.zeros((1, window), jnp.int32), c.eps)
 
         x = on_chip((1, window, c.hidden), jnp.float32)
@@ -387,7 +389,7 @@ def test_span_model_compiles_for_v5e(part, one_chip, no_persistent_cache):
         # no scores of a key head's 8 query heads × a block of queries
         assert "f32[4,8,512," not in text
     elif part == "routed_experts":
-        assert latent_moe.buffer_capacity(window, c) == 131072
+        assert experts.buffer_capacity(window, c.moe) == 131072
         assert text.count('custom_call_target="tpu_custom_call"') == 2
         assert "grouped_product" in text and "ragged-dot" not in text
         assert "f32[131072,896]" not in text
@@ -448,7 +450,7 @@ def test_state_space_model_compiles_for_v5e(part, one_chip,
              "final_norm": on_chip((c.hidden,))}
 
         def fn(p, x):
-            return latent_moe.score_head(
+            return scoring_head.score_head(
                 p, x, jnp.zeros((1, window), jnp.int32), c.eps)
 
         x = on_chip((1, window, c.hidden), jnp.float32)
@@ -498,14 +500,15 @@ def test_gated_attention_and_narrow_experts_compile_for_v5e(
 
         x = on_chip((window, c.hidden), jnp.float32)
     else:
-        p = {"router": on_chip((c.hidden, c.experts)),
+        n, width = c.moe.experts, c.moe.expert_width
+        p = {"router": on_chip((c.hidden, n)),
              "experts": {
-                 "gate": on_chip((c.experts, c.hidden, c.expert_width)),
-                 "up": on_chip((c.experts, c.hidden, c.expert_width)),
-                 "down": on_chip((c.experts, c.expert_width, c.hidden))}}
+                 "gate": on_chip((n, c.hidden, width)),
+                 "up": on_chip((n, c.hidden, width)),
+                 "down": on_chip((n, width, c.hidden))}}
 
         def fn(p, x):
-            return latent_moe.routed_experts(p, x, c)
+            return experts.routed_experts(p, x, c.moe)
 
         x = on_chip((4 * window, c.hidden), jnp.float32)
     text = jax.jit(fn).lower(p, x).compile().as_text()
@@ -516,7 +519,7 @@ def test_gated_attention_and_narrow_experts_compile_for_v5e(
         # no scores of a key head's query heads × a block of queries
         assert f"f32[8,{heads // 8},512," not in text
     else:
-        assert latent_moe.buffer_capacity(4 * window, c) == 131072
+        assert experts.buffer_capacity(4 * window, c.moe) == 131072
         assert text.count('custom_call_target="tpu_custom_call"') == 2
         assert "grouped_product" in text and "ragged-dot" not in text
         assert "f32[131072,512]" not in text
@@ -537,18 +540,19 @@ def test_the_combine_compiles_to_its_kernel_for_v5e(model, held, one_chip,
 
     c = registry.SEQUENCE_MODELS[model]
     if held is not None:
-        c = dataclasses.replace(c, experts_held=tuple(range(held)))
+        c = dataclasses.replace(c, moe=dataclasses.replace(
+            c.moe, experts_held=tuple(range(held))))
     tokens = 4 * 4096
-    rows = latent_moe.buffer_capacity(tokens, c)
-    assert (rows < tokens * c.top_k) == (held is not None)
+    rows = experts.buffer_capacity(tokens, c.moe)
+    assert (rows < tokens * c.moe.top_k) == (held is not None)
 
     def on_chip(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    text = jax.jit(lambda *a: latent_moe.combine(*a, c.top_k)).lower(
+    text = jax.jit(lambda *a: experts.combine(*a, c.moe.top_k)).lower(
         on_chip((tokens, c.hidden)), on_chip((rows, c.hidden)),
-        on_chip((tokens * c.top_k,), jnp.int32),
-        on_chip((tokens * c.top_k,))).compile().as_text()
+        on_chip((tokens * c.moe.top_k,), jnp.int32),
+        on_chip((tokens * c.moe.top_k,))).compile().as_text()
     kernel = held is not None
     assert text.count('custom_call_target="tpu_custom_call"') == kernel
     assert (" gather(" in text) != kernel

@@ -10,13 +10,12 @@ with the gate and without; the fused kernel, interpreted, against the
 blocked path at 6 and 8 query heads a key head with the partial rotary
 applied; the partial rotary and YaRN over the turned part against a hand
 count; the sigmoid router with its scaling; the counts in telemetry; the
-grouped kernel's visits; the parameter counts; the benchmark's FLOP count by
-hand; and the other sequence models' lowered programs, which are what they
-were but for the visit counts."""
+parameter counts; the benchmark's FLOP count by hand. The grouped kernel's
+visits are test_experts.py's, and every stack's lowered program
+test_program_identity.py's."""
 
 import dataclasses
 import functools
-import hashlib
 import importlib.util
 import json
 import math
@@ -32,7 +31,8 @@ import pytest
 from sparkdl_tpu.core import telemetry
 from sparkdl_tpu.engine.dataframe import DataFrame
 from sparkdl_tpu.ml import DeepSequenceScorer
-from sparkdl_tpu.models import latent_moe, registry, shortconv_moe
+from sparkdl_tpu.models import (attention, experts, registry, sequence_ops,
+                                shortconv_moe)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), "benchmarks")
@@ -210,8 +210,8 @@ def test_partial_rotary_turns_the_leading_share_and_passes_the_rest(key):
 
     def spy(q, k, v, heads, block, span=None):
         seen.update(q=q, k=k)
-        return latent_moe.grouped_causal_attention(q, k, v, heads, block,
-                                                   span)
+        return attention.grouped_causal_attention(q, k, v, heads, block,
+                                                  span)
 
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(shortconv_moe, "grouped_causal_attention", spy)
@@ -285,7 +285,7 @@ def test_fused_kernel_matches_the_blocked_path_with_the_partial_rotary(
     x = jax.random.normal(jax.random.PRNGKey(9), (512, 256))
 
     def interpreted(q, k, v, heads, block, span=None):
-        out = latent_moe.fused_causal_attention(
+        out = attention.fused_causal_attention(
             q.astype(v.dtype), None, k.astype(v.dtype), None, v, heads=heads,
             span=span, query_tile=256, key_tile=128, interpret=True)
         return out, jnp.int32(1), jnp.int32(0)
@@ -307,7 +307,7 @@ def test_sigmoid_router_renormalised_and_scaled_matches_reference(key):
     layer = ref.init_layer(key, s, 2)["moe"]
     x = jax.random.normal(jax.random.PRNGKey(9), (40, s.hidden))
     with jax.default_matmul_precision("highest"):
-        chosen, weights = latent_moe.route(layer, x, MODEL)
+        chosen, weights = experts.route(layer, x, MODEL.moe)
         ref_chosen, ref_weights = ref.route(layer, x, s)
     assert np.array_equal(chosen, ref_chosen)
     np.testing.assert_allclose(weights, ref_weights, rtol=1e-5)
@@ -324,13 +324,13 @@ def test_shares_and_the_shared_expert_add_up_to_the_uncut_layer(key):
     with jax.default_matmul_precision("highest"):
         whole = ref.init_layer(key, s, 2)["moe"]
         want, _ = ref.expert_layer(whole, x, s, identity)
-        total = latent_moe.gated_mlp(whole["shared"], x)
+        total = sequence_ops.gated_mlp(whole["shared"], x)
         for share in (tuple(range(8)), tuple(range(8, 16))):
             layer = ref.init_layer(key, s, 2, experts_held=share)["moe"]
             np.testing.assert_array_equal(layer["shared"]["up"],
                                           whole["shared"]["up"])
-            part, *_ = latent_moe.routed_experts(
-                layer, x, dataclasses.replace(MODEL, experts_held=share))
+            part, _ = experts.routed_experts(
+                layer, x, dataclasses.replace(MODEL.moe, experts_held=share))
             total = total + part
         block = ref.init_layer(key, s, 2)
         h = x.reshape(1, 40, -1)
@@ -338,26 +338,6 @@ def test_shares_and_the_shared_expert_add_up_to_the_uncut_layer(key):
         mine, _ = ref.layer_forward(block, h, s, "sliding_attention")
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(got, mine, rtol=1e-4, atol=1e-5)
-
-
-@pytest.mark.parametrize("sizes_,rows", [
-    ([512] * 8, 4096), ([0, 700, 0, 100, 1, 511, 0], 2048),
-    ([3, 0, 0], 1024), ([0, 0, 0, 0], 512), ([511, 2, 1023], 2048)])
-def test_product_visits_are_the_grid_of_group_visits(sizes_, rows):
-    sizes_ = jnp.asarray(sizes_, jnp.int32)
-    *_, visits = latent_moe._group_visits(sizes_, rows,
-                                          latent_moe.GROUPED_ROW_TILE)
-    assert int(latent_moe.product_visits(sizes_, rows)) == int(visits)
-
-
-def test_product_visits_at_the_cells_load():
-    """256 groups of 512 rows on a buffer of 131,072 (16,384 tokens × 8):
-    an even split visits each tile once; a shift of one row makes every
-    tile but the first and last straddle a boundary."""
-    even = jnp.full((256,), 512, jnp.int32)
-    assert int(latent_moe.product_visits(even, 131072)) == 256
-    shifted = even.at[0].add(-1).at[-1].add(1)
-    assert int(latent_moe.product_visits(shifted, 131072)) == 511
 
 
 # -- counts --------------------------------------------------------------------
@@ -403,80 +383,6 @@ def test_the_other_stacks_report_neither_gate_nor_shared_expert(key):
     assert counts[telemetry.M_MOE_PRODUCT_VISITS].sum() > 5
 
 
-# -- the other models' programs -----------------------------------------------
-
-# sha256 of the stripped lowered text (no debug information) of each other
-# sequence model's whole program at its CPU test size, lowered for the TPU
-# and for the CPU with float32 weights, as the commit before the visit
-# counts lowered it. The program here, with those two counts taken off its
-# outputs, must lower to the same text: they are all it gained. A change
-# that means to alter one of these programs computes the digests anew.
-BEFORE_THE_VISIT_COUNTS = {
-    "TestShortConvMoE": ("lfm2_moe", "testshortconv-windows"),
-    "TestSpanMoE": ("mellum2_moe", "testspan-windows"),
-    "TestStateSpace": ("jamba_ssm", "teststatespace-windows"),
-    "TestLatentMoE": ("openpangu_moe", "testmoe-windows"),
-}
-DIGESTS = {
-    ("TestLatentMoE", "tpu"): "5a954e8a43953e3e",
-    ("TestLatentMoE", "cpu"): "4f89cf6186888b19",
-    ("TestShortConvMoE", "tpu"): "049ee87f0619f42e",
-    ("TestShortConvMoE", "cpu"): "34ff37fcd8eeee45",
-    ("TestSpanMoE", "tpu"): "40b8c5df13b2043b",
-    ("TestSpanMoE", "cpu"): "4ccc815f9b929f94",
-    ("TestStateSpace", "tpu"): "734e02ab47085d0c",
-    ("TestStateSpace", "cpu"): "734e02ab47085d0c",
-}
-
-
-def _stack_shapes(model):
-    """The shapes of the model's test-size weights, drawn as its benchmark
-    driver draws them (float32), and the experts they hold."""
-    module, name = BEFORE_THE_VISIT_COUNTS[model]
-    other = _load(f"references/{module}.py")
-    config = _config("tests", "rehearsal", "configs", f"{name}.json")
-    s = other.sizes(config)
-    key = jax.random.PRNGKey(0)
-
-    def layer(i):
-        if module == "jamba_ssm":
-            return other.init_layer(key, s, i, other.is_attention(s, i))
-        if module in ("lfm2_moe", "openpangu_moe"):
-            return other.init_layer(key, s, i, i < s.dense_layers)
-        return other.init_layer(key, s, i)
-
-    variables = jax.eval_shape(lambda: {
-        **other.init_embed(key, s), **other.init_head(key, s),
-        "layers": [layer(i) for i in range(s.layers)]})
-    if "head" not in variables:                 # a tied head
-        variables["head"] = variables["embed"]
-    return variables, config.get("experts_held")
-
-
-@pytest.mark.parametrize("platform", ["tpu", "cpu"])
-@pytest.mark.parametrize("model", sorted(BEFORE_THE_VISIT_COUNTS))
-def test_the_other_models_programs_are_theirs_but_for_the_visit_counts(
-        model, platform):
-    window = 32 if model in ("TestStateSpace", "TestLatentMoE") else 40
-    variables, held = _stack_shapes(model)
-    mf = registry.build_sequence_scorer(model, variables, window, held)
-    visits = {telemetry.M_MOE_PRODUCT_VISITS,
-              telemetry.M_MOE_PRODUCT_ROW_TILES}
-
-    def program(vs, tokens):      # its name names the lowered module
-        out = dict(mf.apply_fn(vs, tokens))
-        out[telemetry.PROGRAM_COUNTS] = {
-            k: v for k, v in out[telemetry.PROGRAM_COUNTS].items()
-            if k not in visits}
-        return out
-
-    text = jax.jit(program).trace(
-        variables, jax.ShapeDtypeStruct((2, window), jnp.int32)).lower(
-            lowering_platforms=(platform,)).as_text(debug_info=False)
-    digest = hashlib.sha256(text.encode()).hexdigest()[:16]
-    assert digest == DIGESTS[model, platform]
-
-
 # -- sizes and the FLOP count ---------------------------------------------------
 
 
@@ -507,10 +413,11 @@ def test_the_registry_holds_the_published_widths():
         "layer_types"]
     assert list(published.layer_types[:5]) == PUBLISHED["layer_types"]
     assert (published.hidden, published.kv_heads, published.head_dim,
-            published.dense_width, published.expert_width) == (
+            published.dense_width, published.moe.expert_width) == (
                 2048, 8, 128, 8192, 512)
-    assert (published.experts, published.top_k, published.scaling,
-            published.scoring, published.span, published.vocab) == (
+    assert (published.moe.experts, published.moe.top_k,
+            published.moe.scaling, published.moe.scoring, published.span,
+            published.vocab) == (
                 256, 8, 2.5, "sigmoid", 512, 100352)
     assert PUBLISHED["reduced"] == [
         "num_hidden_layers", "layer_types", "mlp_layer_types",

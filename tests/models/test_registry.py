@@ -106,16 +106,17 @@ def test_sequence_models_are_the_ten_the_docs_name():
 
 @pytest.mark.parametrize("name", sorted(_SEQUENCE_STACKS))
 def test_sequence_model_config_says_what_its_stack_has(name):
-    """The expert fields are at none exactly where the model has no expert
+    """The expert config is none exactly where the model has no expert
     layer, the state-space sizes set exactly where it has such layers, and a
     model without a rotary gives no ``theta``."""
     kind, experts, state_space, rotary = _SEQUENCE_STACKS[name]
     c = registry.SEQUENCE_MODELS[name]
     assert type(c).__name__ == kind
-    assert (c.experts > 0 and c.top_k > 0 and len(c.experts_held)
-            == c.experts and c.expert_width > 0) == experts
-    assert (c.experts == 0 and c.experts_held == () and c.top_k == 0
-            and c.expert_width == 0) != experts
+    assert (c.moe is not None) == experts
+    if c.moe is not None:
+        assert (c.moe.experts > 0 and c.moe.top_k > 0
+                and len(c.moe.experts_held) == c.moe.experts
+                and c.moe.expert_width > 0)
     if kind == "ShortConvMoEConfig":
         assert (c.d_inner > 0 and c.d_state > 0 and c.dt_rank > 0
                 and c.d_conv > 0) == state_space

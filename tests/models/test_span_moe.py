@@ -4,14 +4,13 @@ each kind with its own rotary, a soft-max router) against the benchmark's
 plain reference (benchmarks/references/mellum2_moe.py, which imports nothing
 of the program) at the ``TestSpanMoE`` sizes on the CPU: through the
 transformer and collect(); each kind of attention alone, and that a sliding
-layer reads its span and no further; the fused kernel, interpreted, against
-the blocked oracle with a span on grouped keys, and the key tiles it visits;
-YaRN's ramp, frequencies and amplitude against a hand count; the soft-max
-router and the share test; the head in blocks of positions; the counts in
-telemetry; which path a lowering takes; the benchmark's FLOP count by hand."""
+layer reads its span and no further; YaRN's ramp, frequencies and amplitude
+against a hand count; the soft-max router and the share test; the head in
+blocks of positions; the counts in telemetry; the benchmark's FLOP count by
+hand. The fused kernel with a span on grouped keys, the key tiles it visits
+and which path a lowering takes are test_attention.py's."""
 
 import dataclasses
-import functools
 import importlib.util
 import json
 import math
@@ -26,7 +25,8 @@ import pytest
 from sparkdl_tpu.core import telemetry
 from sparkdl_tpu.engine.dataframe import DataFrame
 from sparkdl_tpu.ml import DeepSequenceScorer
-from sparkdl_tpu.models import latent_moe, registry, shortconv_moe
+from sparkdl_tpu.models import (attention, experts, registry, scoring_head,
+                                shortconv_moe)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), "benchmarks")
@@ -147,7 +147,7 @@ def test_attention_of_each_kind_alone_matches_reference(key, kind):
     changed = np.flatnonzero(np.abs(moved - got).max(1) > 1e-4)
     if kind == "sliding_attention":
         assert changed.tolist() == list(range(t, t + 8))
-        assert scored == latent_moe.scored_keys(WINDOW, 8, 8) == 8 * (
+        assert scored == attention.scored_keys(WINDOW, 8, 8) == 8 * (
             8 + 4 * 15)
     else:
         assert changed.tolist() == list(range(t, WINDOW))
@@ -207,20 +207,20 @@ def test_yarn_ramp_frequencies_and_amplitude_against_a_hand_count():
         PUBLISHED["rope_parameters"]["sliding_attention"], 128)
     np.testing.assert_allclose(table, plain, rtol=2e-6)
     assert amplitude == 1.0
-    assert latent_moe.Rope(theta).frequencies(64).tolist() == plain.astype(
+    assert attention.Rope(theta).frequencies(64).tolist() == plain.astype(
         np.float32).tolist()
 
 
 def test_rotary_takes_frequencies_and_an_amplitude_and_defaults_to_plain():
     x = jax.random.normal(jax.random.PRNGKey(8), (WINDOW, 2 * 8))
-    plain = latent_moe.rotary(x, 100.0, 2)
-    same = latent_moe.rotary(x, 100.0, 2,
-                             frequencies=latent_moe.Rope(100.0).frequencies(4))
+    plain = attention.rotary(x, 100.0, 2)
+    same = attention.rotary(x, 100.0, 2,
+                            frequencies=attention.Rope(100.0).frequencies(4))
     np.testing.assert_allclose(same, plain, rtol=1e-5, atol=1e-6)
     rope = dict(MODEL.rope)["full_attention"]
     assert rope.ramp(4) == (0, 2)
-    turned = latent_moe.rotary(x, 100.0, 2, rope.frequencies(4),
-                               rope.amplitude)
+    turned = attention.rotary(x, 100.0, 2, rope.frequencies(4),
+                              rope.amplitude)
     want = ref.rotary(jnp.swapaxes(x.reshape(WINDOW, 2, 8), 0, 1),
                       *ref.rope_table(CONFIG["rope_parameters"][
                           "full_attention"], 8))
@@ -239,7 +239,7 @@ def test_soft_max_router_matches_reference(key):
     layer = ref.init_layer(key, s, 2)["moe"]
     x = jax.random.normal(jax.random.PRNGKey(9), (40, s.hidden))
     with jax.default_matmul_precision("highest"):
-        chosen, weights = latent_moe.route(layer, x, MODEL)
+        chosen, weights = experts.route(layer, x, MODEL.moe)
         ref_chosen, ref_weights = ref.route(layer, x, s)
         scores = jax.nn.softmax(x @ layer["router"], -1)
     assert np.array_equal(chosen, ref_chosen)
@@ -250,8 +250,8 @@ def test_soft_max_router_matches_reference(key):
     np.testing.assert_allclose(weights, own / own.sum(-1, keepdims=True),
                                rtol=1e-5)
     np.testing.assert_allclose(np.asarray(weights).sum(-1), 1.0, rtol=1e-5)
-    sigmoid, _ = latent_moe.route(
-        layer, x, dataclasses.replace(MODEL, scoring="sigmoid"))
+    sigmoid, _ = experts.route(
+        layer, x, dataclasses.replace(MODEL.moe, scoring="sigmoid"))
     # a monotone score chooses the same experts; the weights differ
     assert np.array_equal(np.sort(sigmoid, -1), np.sort(chosen, -1))
 
@@ -273,20 +273,19 @@ def test_shares_add_up_to_the_uncut_layer(key):
             np.testing.assert_array_equal(
                 layer["experts"]["up"],
                 whole["experts"]["up"][share[0]:share[0] + 4])
-            config = dataclasses.replace(MODEL, experts_held=share)
-            part, _, counts, *_ = latent_moe.routed_experts(layer, x,
-                                                            config)
+            config = dataclasses.replace(MODEL.moe, experts_held=share)
+            part, told = experts.routed_experts(layer, x, config)
             ref_part, _ = ref.routed_part(layer, x, s, identity,
                                           experts_held=share)
             np.testing.assert_allclose(part, ref_part, rtol=1e-4, atol=1e-5)
             total = total + part
-            pairs += int(counts.sum())
-        uncut, _, counts, overflow, *_ = latent_moe.routed_experts(
-            whole, x, MODEL)
+            pairs += int(told["counts"].sum())
+        uncut, told = experts.routed_experts(whole, x, MODEL.moe)
     assert pairs == 40 * 4          # every (token, expert) pair, exactly once
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(uncut, want, rtol=1e-4, atol=1e-5)
-    assert int(counts.sum()) == 40 * 4 and not overflow.any()
+    assert int(told["counts"].sum()) == 40 * 4
+    assert not told["overflow"].any()
 
 
 # -- the head in blocks ---------------------------------------------------------
@@ -297,11 +296,11 @@ def test_head_in_blocks_of_positions_equals_the_head_whole(key, monkeypatch):
     params = {**ref.init_embed(key, s), **ref.init_head(key, s)}
     h = jax.random.normal(jax.random.PRNGKey(11), (2, WINDOW, s.hidden))
     tokens = tokens_of(3, 2)
-    whole, fused = latent_moe.score_head(params, h, tokens, s.eps)
+    whole, fused = scoring_head.score_head(params, h, tokens, s.eps)
     assert fused.tolist() == [0, 0]
     # 40 positions × 32 rows × 4 bytes: a bound of a quarter makes 4 blocks
-    monkeypatch.setattr(latent_moe, "HEAD_LOGITS_BYTES", WINDOW * 32)
-    blocks, _ = latent_moe.score_head(params, h, tokens, s.eps)
+    monkeypatch.setattr(scoring_head, "HEAD_LOGITS_BYTES", WINDOW * 32)
+    blocks, _ = scoring_head.score_head(params, h, tokens, s.eps)
     np.testing.assert_allclose(blocks["logprobs"], whole["logprobs"],
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_array_equal(blocks["pooled"], whole["pooled"])
@@ -309,168 +308,6 @@ def test_head_in_blocks_of_positions_equals_the_head_whole(key, monkeypatch):
     want = ref.head_forward(params, h, tokens, s, block=10)
     np.testing.assert_allclose(blocks["logprobs"], want[1], rtol=1e-4,
                                atol=1e-5)
-
-
-# -- the fused kernel with a span, on grouped keys -----------------------------
-
-HEADS, KEY_HEADS, WIDTH = 4, 2, 128
-
-
-def grouped_operands(window, dtype=jnp.bfloat16, seed=0):
-    """grouped_causal_attention's operands: float32 queries (carrying the
-    scale) and keys as a rotation leaves them, values in ``dtype``."""
-    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(keys[0], (window, HEADS * WIDTH)) * WIDTH ** -0.5 * 3
-    k = jax.random.normal(keys[1], (window, KEY_HEADS * WIDTH))
-    v = jax.random.normal(keys[2], (window, KEY_HEADS * WIDTH)).astype(dtype)
-    return q, k, v
-
-
-def exact_attention(q, k, v, span):
-    """A float32 soft-max at full precision over the operands as the paths
-    round them, keys repeated to the query heads, the band as a mask."""
-    window = q.shape[0]
-    q, k, v = (jnp.swapaxes(a.astype(jnp.bfloat16).astype(
-        jnp.float32).reshape(window, -1, WIDTH), 0, 1) for a in (q, k, v))
-    k, v = (jnp.repeat(a, HEADS // KEY_HEADS, 0) for a in (k, v))
-    at = jnp.arange(window)
-    seen = at[None, :] <= at[:, None]
-    if span is not None:
-        seen &= at[None, :] > at[:, None] - span
-    with jax.default_matmul_precision("highest"):
-        scores = jnp.where(seen, jnp.einsum("hqd,hkd->hqk", q, k), -jnp.inf)
-        out = jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(scores, -1), v)
-    return jnp.swapaxes(out, 0, 1).reshape(window, -1)
-
-
-def distance(got, want):
-    got, want = (np.asarray(a, np.float32) for a in (got, want))
-    return np.linalg.norm(got - want) / np.linalg.norm(want)
-
-
-@pytest.mark.parametrize("span", [
-    None,       # full, on grouped keys
-    40,         # shorter than a key tile
-    200,        # not a multiple of one
-    128,        # exactly a key tile
-    512])       # as long as the window
-def test_fused_kernel_with_a_span_against_the_blocked_oracle(span):
-    window = 512
-    q, k, v = grouped_operands(window, seed=2)
-    fused = latent_moe.fused_causal_attention(
-        q.astype(v.dtype), None, k.astype(v.dtype), None, v, heads=HEADS,
-        span=span, query_tile=256, key_tile=128, interpret=True)
-    blocked, engaged, scored = latent_moe.grouped_causal_attention(
-        q, k, v, HEADS, 64, span)
-    assert engaged == 0 and fused.dtype == blocked.dtype == jnp.bfloat16
-    assert scored == latent_moe.scored_keys(window, span, 64)
-    exact = exact_attention(q, k, v, span)
-    to_exact = distance(fused, exact), distance(blocked, exact)
-    # both are the bfloat16 rounding of the weights and of the output;
-    # neither path may be the looser by more than a quarter
-    assert max(to_exact) < 0.004
-    assert max(to_exact) < 1.25 * min(to_exact)
-    assert distance(fused, blocked) < 0.005
-    if span == 512:     # a span as long as the window masks nothing
-        np.testing.assert_array_equal(
-            np.asarray(fused, np.float32),
-            np.asarray(latent_moe.fused_causal_attention(
-                q.astype(v.dtype), None, k.astype(v.dtype), None, v,
-                heads=HEADS, query_tile=256, key_tile=128, interpret=True),
-                np.float32))
-
-
-@pytest.mark.parametrize("span", [40, 200, 128])
-def test_fused_kernel_reads_a_span_and_no_further(span):
-    """A changed key and value at position t move the queries t … t + span − 1
-    and no other — the tiles before the span are not read, those inside are
-    masked to it."""
-    window, t = 512, 130
-    q, k, v = (a.astype(jnp.bfloat16) for a in grouped_operands(window,
-                                                                seed=3))
-    run = functools.partial(latent_moe.fused_causal_attention, heads=HEADS,
-                            span=span, query_tile=256, key_tile=128,
-                            interpret=True)
-    before = np.asarray(run(q, None, k, None, v), np.float32)
-    after = np.asarray(run(q, None, k.at[t].add(2.0), None,
-                           v.at[t].add(2.0)), np.float32)
-    changed = np.flatnonzero(np.abs(after - before).max(1) > 0)
-    assert changed.min() == t and changed.max() == t + span - 1
-    assert len(changed) > span // 2
-
-
-@pytest.mark.parametrize("window,span,query_tile,key_tile", [
-    (512, 40, 256, 128), (512, 200, 256, 128), (512, 128, 256, 128),
-    (1024, 128, 256, 128), (16384, 1024, 1024, 512), (16384, None, 1024, 512),
-    (4096, None, 1024, 512)])
-def test_the_visited_tiles_are_those_that_hold_a_key_of_a_span(
-        window, span, query_tile, key_tile):
-    """``scored_keys`` — what the counter reports of the kernel — counts, a
-    block of queries at a time, exactly the key tiles that hold a key some
-    query of the block reads (before the diagonal: every row against each;
-    along it: the rows from each tile's first on), and the kernel's loop
-    starts at the first of them."""
-    total = 0
-    for first in range(0, window, query_tile):
-        queries = range(first, first + query_tile)
-        read = {j for t in (queries[0], queries[-1])
-                for j in (max(0, t - span + 1) if span else 0, t)}
-        tiles = [tile for tile in range(0, first + query_tile, key_tile)
-                 if any(tile <= j < tile + key_tile
-                        for j in range(min(read), max(read) + 1))]
-        # every key between the block's earliest and latest is some query's
-        earliest = (max(first - span + 1, 0) if span else 0) // key_tile
-        assert tiles[0] == earliest * key_tile
-        for tile in tiles:
-            total += (query_tile if tile < first
-                      else first + query_tile - tile) * key_tile
-    assert latent_moe.scored_keys(window, span, query_tile, key_tile) == total
-    if window == 16384:
-        # ISSUE 39's figures for the published stack: two full layers and six
-        # sliding ones, whole tiles of 1,024 × 512
-        per_token = total / window
-        assert per_token == (1728 if span else 8448)
-        assert 2 * 8448 + 6 * 1728 == 27264
-
-
-def choice_operands(case):
-    """``(heads, q, k, v)`` of a case: 4 query heads on 2 key heads (16 on 1
-    where the group is to be too large to stack) at the case's head width,
-    window and dtype of the values."""
-    window = {"window-40": 40, "window-over-the-bound": 2
-              * latent_moe.FUSED_MAX_WINDOW}.get(
-        case, latent_moe.FUSED_QUERY_TILE)
-    width = int(case.split("-")[2]) if case.startswith("head-width") else 128
-    heads, key_heads = (16, 1) if case.endswith("group-16") else (4, 2)
-    dtype = jnp.float32 if case.endswith("float32") else jnp.bfloat16
-    q = jax.ShapeDtypeStruct((window, heads * width), jnp.float32)
-    k = jax.ShapeDtypeStruct((window, key_heads * width), jnp.float32)
-    return heads, q, k, jax.ShapeDtypeStruct((window, key_heads * width),
-                                             dtype)
-
-
-@pytest.mark.parametrize("span", [None, 256])
-@pytest.mark.parametrize("case", [
-    "window-40", "window-over-the-bound", "head-width-32", "head-width-96",
-    "float32", "lowered-for-cpu", "lowered-for-tpu", "head-width-64",
-    "head-width-64-float32", "head-width-64-lowered-for-cpu",
-    "head-width-64-group-16"])
-def test_the_choice_follows_what_the_lowering_can_see(case, span):
-    """Grouped keys, with a span or without, beside PR 36's and PR 38's
-    cases: the kernel is taken where the program is lowered for a TPU with
-    bfloat16 values, a window of whole query tiles under the bound and a head
-    width of whole lanes — or of half a lane group, 64, where a key head's
-    query heads are few enough to stack (4 × a block of 512 rows; 16 are
-    not): with a span too, which the stacked rows are masked to as one
-    head's are; the blocked path everywhere else — a CPU, float32 values, a
-    head width of 32 or 96 — with the same span."""
-    platform = "cpu" if case.endswith("lowered-for-cpu") else "tpu"
-    heads, *operands = choice_operands(case)
-    fn = jax.jit(lambda q, k, v: latent_moe.grouped_causal_attention(
-        q, k, v, heads, 512, span))
-    text = fn.trace(*operands).lower(lowering_platforms=(platform,)).as_text()
-    assert text.count("tpu_custom_call") == (
-        case in ("lowered-for-tpu", "head-width-64"))
 
 
 # -- counts, the builder, the FLOP count ---------------------------------------
@@ -559,9 +396,9 @@ def test_builder_takes_the_kinds_by_position(key):
     assert list(published.layer_types) == PUBLISHED["published"][
         "layer_types"]
     assert list(published.layer_types[:8]) == PUBLISHED["layer_types"]
-    assert (published.span, published.experts, published.top_k,
-            published.vocab, published.scoring) == (1024, 64, 8, 98304,
-                                                    "softmax")
+    assert (published.span, published.moe.experts, published.moe.top_k,
+            published.vocab, published.moe.scoring) == (1024, 64, 8, 98304,
+                                                        "softmax")
 
 
 @pytest.mark.parametrize("window", [16, 40])

@@ -25,7 +25,8 @@ import pytest
 from sparkdl_tpu.core import executor, telemetry
 from sparkdl_tpu.engine.dataframe import DataFrame
 from sparkdl_tpu.ml import DeepSequenceScorer
-from sparkdl_tpu.models import latent_moe, registry, shortconv_moe, state_space
+from sparkdl_tpu.models import (attention, registry, sequence_ops,
+                                shortconv_moe, state_space)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), "benchmarks")
@@ -318,19 +319,19 @@ def dense_attention(q, k, v, heads):
 @pytest.mark.parametrize("path", ["blocked", "kernel"])
 def test_twenty_query_heads_on_one_key_head_without_rotary(path):
     heads, width = 20, 128
-    T = latent_moe.FUSED_QUERY_TILE if path == "kernel" else 48
+    T = attention.FUSED_QUERY_TILE if path == "kernel" else 48
     k0, k1, k2 = jax.random.split(jax.random.PRNGKey(4), 3)
     q = jax.random.normal(k0, (T, heads * width)) * width ** -0.5
     k = jax.random.normal(k1, (T, width))
     v = jax.random.normal(k2, (T, width))
     if path == "blocked":
-        out, fused, _ = latent_moe.grouped_causal_attention(q, k, v, heads, 16)
+        out, fused, _ = attention.grouped_causal_attention(q, k, v, heads, 16)
         assert int(fused) == 0
         tolerance = 1e-5
     else:
         q, k, v = (a.astype(jnp.bfloat16) for a in (q, k, v))
-        out = latent_moe.fused_causal_attention(q, None, k, None, v,
-                                                heads=heads, interpret=True)
+        out = attention.fused_causal_attention(q, None, k, None, v,
+                                               heads=heads, interpret=True)
         tolerance = 2e-2        # bfloat16 weights in the second product
     want = dense_attention(*(np.asarray(a, np.float32) for a in (q, k, v)),
                            heads)
@@ -426,14 +427,14 @@ def test_builder_wants_the_state_space_sizes_of_a_state_space_layer(key):
 
 def test_builder_reads_an_all_dense_stack_off_the_weights(key):
     """Any leading layers of the period, the vocabulary by the embedding's
-    rows, no experts to hold: the config's expert fields stay at none."""
+    rows, no experts to hold: the config's expert layer stays at none."""
     variables = make_variables(key)
     variables["layers"] = variables["layers"][:2]
     model = registry.build_sequence_scorer("TestStateSpace", variables, WINDOW)
     out = model.apply_batch(tokens_of(7, 2), batch_size=2)
     assert out[telemetry.PROGRAM_COUNTS][
         telemetry.M_SEQUENCE_SSM_LAYERS].tolist() == [1, 1]
-    assert MODEL.experts == 0 and MODEL.experts_held == () and MODEL.top_k == 0
+    assert MODEL.moe is None
     assert MODEL.theta is None
 
 
@@ -444,7 +445,7 @@ def test_the_shared_taps_against_the_references(key, bias):
     z = jax.random.normal(key, (2, 20, 8))
     taps = jax.random.normal(jax.random.fold_in(key, 1), (8, 4))
     b = jax.random.normal(jax.random.fold_in(key, 2), (8,)) if bias else None
-    got = latent_moe.causal_taps(z, taps, b)
+    got = sequence_ops.causal_taps(z, taps, b)
     if bias:
         want = ref.conv_taps(z, taps, b)
     else:
@@ -452,7 +453,7 @@ def test_the_shared_taps_against_the_references(key, bias):
         want = jnp.stack([lfm2.conv_taps(row, taps) for row in z])
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
     # causal: a later position moves no earlier output
-    moved = latent_moe.causal_taps(z.at[:, 10].add(1.0), taps, b)
+    moved = sequence_ops.causal_taps(z.at[:, 10].add(1.0), taps, b)
     assert np.array_equal(moved[:, :10], got[:, :10])
 
 
