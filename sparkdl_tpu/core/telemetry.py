@@ -293,6 +293,15 @@ M_SEQUENCE_FUSED_SCAN_LAYERS = "sparkdl.sequence.fused_scan_layers"
 # counter (per row: the attention layers whose heads' outputs a sigmoid gate
 # scaled, models/shortconv_moe.py; from a stack whose layers hold one)
 M_SEQUENCE_GATED_ATTENTION_LAYERS = "sparkdl.sequence.gated_attention_layers"
+# counter (per row: the layers whose mixer was linear attention with the
+# gated delta rule, models/linear_attention.py; from a stack that has such
+# layers)
+M_SEQUENCE_LINEAR_ATTENTION_LAYERS = "sparkdl.sequence.linear_attention_layers"
+# counter (per row: the linear-attention layers whose recurrence was lowered
+# to the kernel, models/linear_attention.py fused_delta_rule; beside the
+# former)
+M_SEQUENCE_FUSED_LINEAR_ATTENTION_LAYERS = \
+    "sparkdl.sequence.fused_linear_attention_layers"
 M_MOE_ROUTED_TOKENS = "sparkdl.moe.routed_tokens"      # counter (tokens
                                                        # routed, once for each
                                                        # expert layer)
@@ -323,6 +332,9 @@ M_MOE_PRODUCT_ROW_TILES = "sparkdl.moe.product_row_tiles"
 # counter (per row: the expert layers with a shared expert beside the routed
 # ones, models/shortconv_moe.py; from a stack whose layers hold one)
 M_MOE_SHARED_EXPERT_LAYERS = "sparkdl.moe.shared_expert_layers"
+# counter (per row: the expert layers whose router chose within groups,
+# models/experts.py route; from a stack whose expert layers do)
+M_MOE_GROUP_LIMITED_LAYERS = "sparkdl.moe.group_limited_layers"
 M_MOE_LOAD_MAX_OVER_MEAN = "sparkdl.moe.load_max_over_mean"  # histogram (per
                                                        # row and expert layer:
                                                        # its launch's fullest
@@ -395,6 +407,8 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_SEQUENCE_SSM_LAYERS: "counter",
     M_SEQUENCE_FUSED_SCAN_LAYERS: "counter",
     M_SEQUENCE_GATED_ATTENTION_LAYERS: "counter",
+    M_SEQUENCE_LINEAR_ATTENTION_LAYERS: "counter",
+    M_SEQUENCE_FUSED_LINEAR_ATTENTION_LAYERS: "counter",
     M_MOE_ROUTED_TOKENS: "counter",
     M_MOE_LOCAL_PAIRS: "counter",
     M_MOE_OVERFLOW_PAIRS: "counter",
@@ -404,6 +418,7 @@ CANONICAL_METRIC_KINDS: Dict[str, str] = {
     M_MOE_PRODUCT_VISITS: "counter",
     M_MOE_PRODUCT_ROW_TILES: "counter",
     M_MOE_SHARED_EXPERT_LAYERS: "counter",
+    M_MOE_GROUP_LIMITED_LAYERS: "counter",
     M_MOE_LOAD_MAX_OVER_MEAN: "histogram",
     **{STARTUP_METRIC_PREFIX + key: "gauge" for key in STARTUP_KEYS},
 }

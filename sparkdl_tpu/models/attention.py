@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from sparkdl_tpu.models.sequence_ops import LANES, MASKED
+from sparkdl_tpu.models.sequence_ops import LANES, MASKED, mixed_dot, rms_norm
 
 
 @dataclass(frozen=True)
@@ -40,7 +40,9 @@ class Rope:
     alone, a linear ramp between, and ``amplitude`` on cos and sin (the
     scores carry its square). ``partial`` is the share of a head that turns
     (``partial_rotary_factor``): its first dimensions, over which the
-    frequencies and the ramp are; the rest pass through."""
+    frequencies and the ramp are; the rest pass through. ``interleave``
+    (``rope_interleave``) pairs adjacent dimensions ``(2j, 2j + 1)`` where
+    the plain rotary pairs a head's two halves ``(j, j + r/2)``."""
 
     theta: float
     factor: float = 1.0
@@ -49,6 +51,7 @@ class Rope:
     beta_slow: float = 1.0
     amplitude: float = 1.0
     partial: float = 1.0
+    interleave: bool = False
 
     def turned(self, head_dim):
         """The leading dimensions of a head of ``head_dim`` that turn."""
@@ -78,11 +81,13 @@ class Rope:
             np.float32)
 
 
-def rotary(x, theta, heads=1, frequencies=None, amplitude=1.0):
+def rotary(x, theta, heads=1, frequencies=None, amplitude=1.0,
+           interleave=False):
     """x (T, heads · rope) float32, head by head as a projection leaves it;
-    a head's two halves pair up. The inverse frequencies are ``theta``'s
-    plain ones unless handed in ((rope / 2,), :meth:`Rope.frequencies`);
-    ``amplitude`` multiplies cos and sin."""
+    a head's two halves pair up, or with ``interleave`` its adjacent
+    dimensions, pair j turning at the j-th frequency either way. The inverse
+    frequencies are ``theta``'s plain ones unless handed in ((rope / 2,),
+    :meth:`Rope.frequencies`); ``amplitude`` multiplies cos and sin."""
     T = x.shape[0]
     half = x.shape[1] // heads // 2
     t = jnp.arange(T, dtype=jnp.float32)[:, None]
@@ -92,6 +97,11 @@ def rotary(x, theta, heads=1, frequencies=None, amplitude=1.0):
     cos, sin = jnp.cos(angle)[:, None], jnp.sin(angle)[:, None]
     if amplitude != 1.0:
         cos, sin = cos * amplitude, sin * amplitude
+    if interleave:
+        x = x.reshape(T, heads, half, 2)
+        a, b = x[..., 0], x[..., 1]
+        return jnp.stack([a * cos - b * sin, b * cos + a * sin], 3).reshape(
+            T, -1)
     x = x.reshape(T, heads, 2, half)
     a, b = x[:, :, 0], x[:, :, 1]
     return jnp.stack([a * cos - b * sin, b * cos + a * sin], 2).reshape(
@@ -467,3 +477,58 @@ def grouped_causal_attention(q, k, v, heads, block, span=None):
         return blocked(q, k, v)
     return lax.platform_dependent(q, k, v, tpu=fused, default=blocked)
 
+
+
+def latent_attention(p, x, heads, eps, rotation: Rope, block):
+    """Multi-head latent attention over x (T, hidden) float32, one window →
+    ``((T, hidden) float32, fused)``, ``fused`` as :func:`causal_attention`
+    returns it. Keys and values come through a latent of ``kv_rank`` (the
+    weights' ``kv_norm``): ``[c ; k_r] = W_kv_down x``, ``c`` normed, each
+    head's ``[k_nope ; v] = W_kv_up c``, the ``rope`` dimensions ``k_r`` (the
+    rest of ``W_kv_down``'s columns) one key head for all; queries through a
+    latent of their own where the weights hold ``"q_down"`` (normed, then
+    ``W_q_up``), else straight from ``x`` (``"q"``), ``nope`` + ``rope`` a
+    head, the widths read off the weights. ``rotation`` turns the rope
+    dimensions of both (its ``theta``, by halves or ``interleave``d). Where
+    the weights hold a ``"gate"`` (hidden × heads), each head's output is
+    scaled by ``σ(x W_g)`` of its own, in float32, before ``W_o``. Each part
+    of q, k and v is its own product with its columns of the projection, so
+    it comes out in the layout the attention reads."""
+    T = x.shape[0]
+    act = p["out"].dtype
+    kv_rank = p["kv_norm"].shape[0]
+    rope = p["kv_down"].shape[1] - kv_rank
+    width = p["q" if "q" in p else "q_up"].shape[1] // heads
+    nope = width - rope
+    # the scores' scale is linear through the query's projection and the
+    # rotation: in the float32 input of that projection it costs the queries
+    # no second rounding
+    if "q" in p:
+        query, q_w = x * width ** -0.5, p["q"]
+    else:
+        query = rms_norm(mixed_dot(x, p["q_down"]), p["q_norm"], eps
+                         ) * width ** -0.5
+        q_w = p["q_up"]
+    down = mixed_dot(x, p["kv_down"])
+    ckv = rms_norm(down[:, :kv_rank], p["kv_norm"], eps)
+    k_rope = rotary(down[:, kv_rank:], rotation.theta,
+                    interleave=rotation.interleave).astype(act)
+
+    def part(latent, w, lo, hi):
+        """The latent times columns lo:hi of every head's group of w."""
+        columns = w.reshape(w.shape[0], heads, -1)[:, :, lo:hi]
+        return mixed_dot(latent, columns.reshape(w.shape[0], -1), act)
+
+    q_rope = rotary(part(query, q_w, nope, width).astype(jnp.float32),
+                    rotation.theta, heads,
+                    interleave=rotation.interleave).astype(act)
+    q_rope = jnp.swapaxes(q_rope.reshape(T, heads, rope), 0, 1)
+    v = p["kv_up"].shape[1] // heads - nope
+    out, fused = causal_attention(
+        part(query, q_w, 0, nope), q_rope,
+        part(ckv, p["kv_up"], 0, nope), k_rope,
+        part(ckv, p["kv_up"], nope, nope + v), block)
+    if "gate" in p:
+        gate = jax.nn.sigmoid(mixed_dot(x, p["gate"]))        # (T, heads)
+        out = (out.reshape(T, heads, -1) * gate[..., None]).reshape(T, -1)
+    return mixed_dot(out, p["out"]), fused

@@ -39,6 +39,11 @@ class ExpertConfig:
     norm_topk: bool = True
     topk_eps: float = 0.0     # added to the chosen scores' sum before dividing
     scoring: str = "sigmoid"  # the router's scores: "sigmoid" or "softmax"
+    # group-limited routing: the experts in ``groups`` groups of equal size,
+    # a token's top-k taken within the ``topk_group`` groups whose two best
+    # scores sum highest (1, 1: over all experts)
+    groups: int = 1
+    topk_group: int = 1
     # rows of the grouped products' buffer, as a multiple of the pairs that
     # meet a held expert when routing is even (tokens·top_k·held/experts);
     # pairs beyond it take further rounds
@@ -50,14 +55,25 @@ def route(p, x, c: ExpertConfig):
     scores are the logits' sigmoids, each expert by itself, or their soft-max
     over all published experts (``c.scoring``). Where the layer holds a
     selection bias (``"expert_bias"``), the top-k is taken of ``scores +
-    bias`` and the weights are the unbiased scores of the chosen."""
+    bias`` and the weights are the unbiased scores of the chosen. With
+    ``c.groups`` > 1 the routing is group-limited: a group's score is the
+    sum of its two best of those (biased) scores, and the top-k is taken
+    among the experts of the ``c.topk_group`` best groups only."""
     score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[c.scoring]
     scores = score(jnp.dot(x.astype(jnp.float32),
                            p["router"].astype(jnp.float32),
                            precision=lax.Precision.HIGHEST))
-    if "expert_bias" in p:
-        _, chosen = lax.top_k(scores + p["expert_bias"].astype(jnp.float32),
-                              c.top_k)
+    if "expert_bias" in p or c.groups > 1:
+        choice = scores + p["expert_bias"].astype(jnp.float32) \
+            if "expert_bias" in p else scores
+        if c.groups > 1:
+            grouped = choice.reshape(choice.shape[0], c.groups, -1)
+            _, kept = lax.top_k(jnp.sum(lax.top_k(grouped, 2)[0], -1),
+                                c.topk_group)
+            kept = jnp.any(kept[..., None] == jnp.arange(c.groups), 1)
+            choice = jnp.where(kept[..., None], grouped, -jnp.inf).reshape(
+                choice.shape)
+        _, chosen = lax.top_k(choice, c.top_k)
         top = jnp.take_along_axis(scores, chosen, -1)
     else:
         top, chosen = lax.top_k(scores, c.top_k)
@@ -134,15 +150,24 @@ def product_visits(sizes, rows, row_tile=GROUPED_ROW_TILE):
     return jnp.sum(touched) + tiles - covered
 
 
+def swiglu(gate, up, limit=0.0):
+    """``silu(gate) · up`` of float32 products; with a ``limit`` (a layer's
+    ``swiglu_limit``, 0: none) ``gate`` is first held under it and ``up``
+    within ± it."""
+    if limit:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
 def _grouped_kernel(group_of, tile_of, offsets, rows_ref, *refs, row_tile,
-                    sub_tile):
+                    sub_tile, limit):
     """One visit of :func:`_group_visits`: the tile's rows times the group's
-    one weight block (``out = rows · w``) or two (``out = silu(rows · gate) ·
-    (rows · up)``, on the float32 accumulators), ``sub_tile`` rows at a time
-    in a loop — one product's code whatever the tile: only the sub-tiles that
-    hold a row of the group are multiplied, and written to the rows that are
-    the group's; the tile's other rows are left as the visit before wrote
-    them, or zeroed on the tile's first visit."""
+    one weight block (``out = rows · w``) or two (``out = swiglu(rows ·
+    gate, rows · up, limit)``, on the float32 accumulators), ``sub_tile``
+    rows at a time in a loop — one product's code whatever the tile: only
+    the sub-tiles that hold a row of the group are multiplied, and written to
+    the rows that are the group's; the tile's other rows are left as the
+    visit before wrote them, or zeroed on the tile's first visit."""
     from jax.experimental import pallas as pl
     weights, out_ref = refs[:-1], refs[-1]
     visit = pl.program_id(1)
@@ -161,7 +186,7 @@ def _grouped_kernel(group_of, tile_of, offsets, rows_ref, *refs, row_tile,
             acc = [jnp.dot(rows_ref[rows, :], w[...],
                            preferred_element_type=jnp.float32)
                    for w in weights]
-            acc = jax.nn.silu(acc[0]) * acc[1] if len(acc) == 2 else acc[0]
+            acc = swiglu(*acc, limit) if len(acc) == 2 else acc[0]
             row = first + at + lax.broadcasted_iota(jnp.int32, acc.shape, 0)
             before = jnp.where(
                 revisit, out_ref[rows, :].astype(jnp.float32), 0.0)
@@ -193,14 +218,14 @@ def _column_tile(contraction, columns, operands, row_tile, out_bytes):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "out_dtype", "row_tile", "sub_tile", "interpret"))
+    "out_dtype", "row_tile", "sub_tile", "limit", "interpret"))
 def grouped_product(rows, weights, sizes, out_dtype, *,
                     row_tile=GROUPED_ROW_TILE, sub_tile=GROUPED_SUB_TILE,
-                    interpret=False):
+                    limit=0.0, interpret=False):
     """The grouped product as one Pallas TPU kernel (the algorithm of jax's
     ``megablox.gmm``): ``rows`` (R, K) sorted by group, ``weights`` one array
-    (groups, K, N) or two — ``rows · w[g]``, or ``silu(rows · gate[g]) ·
-    (rows · up[g])`` with both products and the epilogue on float32
+    (groups, K, N) or two — ``rows · w[g]``, or ``swiglu(rows · gate[g],
+    rows · up[g], limit)`` with both products and the epilogue on float32
     accumulators on the chip — and ``sizes`` (groups,) int32 each group's
     rows, which may be traced. Operands in their dtype, float32 accumulation,
     one rounding to ``out_dtype``. The rows are read once a column tile and a
@@ -222,7 +247,7 @@ def grouped_product(rows, weights, sizes, out_dtype, *,
 
     return pl.pallas_call(
         functools.partial(_grouped_kernel, row_tile=row_tile,
-                          sub_tile=min(sub_tile, row_tile)),
+                          sub_tile=min(sub_tile, row_tile), limit=limit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(columns // tile, visits),
@@ -242,12 +267,12 @@ def grouped_product(rows, weights, sizes, out_dtype, *,
     )(group_of, tile_of, offsets, rows, *weights)
 
 
-def expert_products(rows, experts, sizes):
+def expert_products(rows, experts, sizes, limit=0.0):
     """The experts' three grouped products over a buffer sorted by expert:
-    ``silu(rows · gate[g]) · (rows · up[g])``, rounded once to the weights'
-    dtype, times ``down[g]``; ``rows`` (R, hidden) in that dtype, ``sizes``
-    (held,) int32 each expert's rows (it may be traced), float32 accumulation
-    and a float32 result, zero past the last expert's rows.
+    ``swiglu(rows · gate[g], rows · up[g], limit)``, rounded once to the
+    weights' dtype, times ``down[g]``; ``rows`` (R, hidden) in that dtype,
+    ``sizes`` (held,) int32 each expert's rows (it may be traced), float32
+    accumulation and a float32 result, zero past the last expert's rows.
 
     Returns ``(out (R, hidden) float32, fused)``. Lowered for a TPU, with
     bfloat16 operands, a buffer of whole row tiles and widths of whole lanes,
@@ -256,16 +281,23 @@ def expert_products(rows, experts, sizes):
     else three ``lax.ragged_dot`` and 0. Both come out of one
     ``lax.platform_dependent``, so ``fused`` says what was lowered."""
     def ragged(rows, gate, up, down, sizes):
-        hidden = jax.nn.silu(lax.ragged_dot(
-            rows, gate, sizes, preferred_element_type=jnp.float32)
-            ) * lax.ragged_dot(rows, up, sizes,
+        gated = lax.ragged_dot(rows, gate, sizes,
                                preferred_element_type=jnp.float32)
+        if limit:
+            gated = jnp.minimum(gated, limit)
+        gated = jax.nn.silu(gated)
+        up = lax.ragged_dot(rows, up, sizes,
+                            preferred_element_type=jnp.float32)
+        if limit:
+            up = jnp.clip(up, -limit, limit)
+        hidden = gated * up
         return lax.ragged_dot(hidden.astype(rows.dtype), down, sizes,
                               preferred_element_type=jnp.float32
                               ), jnp.int32(0)
 
     def kernels(rows, gate, up, down, sizes):
-        hidden = grouped_product(rows, (gate, up), sizes, rows.dtype)
+        hidden = grouped_product(rows, (gate, up), sizes, rows.dtype,
+                                 limit=limit)
         return grouped_product(hidden, (down,), sizes, jnp.float32
                                ), jnp.int32(1)
 
@@ -549,8 +581,9 @@ def combine(y, out, at, weights, top_k):
     return lax.platform_dependent(*operands, tpu=kernel, default=gathers)
 
 
-def routed_experts(p, x, c: ExpertConfig):
-    """The held experts' part of the layer for tokens x (N, hidden) float32.
+def routed_experts(p, x, c: ExpertConfig, limit=0.0):
+    """The held experts' part of the layer for tokens x (N, hidden) float32;
+    ``limit`` the layer's ``swiglu_limit`` (:func:`swiglu`).
 
     The (token, expert) pairs that meet a held expert are sorted by expert
     into one flat buffer of :func:`buffer_capacity` rows; a round gathers the
@@ -612,7 +645,7 @@ def routed_experts(p, x, c: ExpertConfig):
         rows = xa[pair // c.top_k]                         # (capacity, hidden)
         sizes = (jnp.clip(ends - lo, 0, capacity)
                  - jnp.clip(starts - lo, 0, capacity)).astype(jnp.int32)
-        out, fused = expert_products(rows, experts, sizes)
+        out, fused = expert_products(rows, experts, sizes, limit)
         at = place - lo
         mine = jnp.where((at >= 0) & (at < capacity), held_weights, 0.0)
         return (*combine(y, out, at, mine, c.top_k), fused)

@@ -4,11 +4,11 @@
 
 One block is: multi-head latent attention (queries and keys/values through
 low-rank latents, a rotary part shared by all heads of the key;
-``models/attention.py``), sandwich norms (an RMSNorm before AND after each
-sub-layer), and either a gated MLP or the sparse expert layer
-(``models/experts.py``: a sigmoid router over every published expert, the
-top-k renormalised and scaled) with one shared expert beside the routed
-ones.
+``models/attention.py`` :func:`latent_attention`), sandwich norms (an
+RMSNorm before AND after each sub-layer), and either a gated MLP or the
+sparse expert layer (``models/experts.py``: a sigmoid router over every
+published expert, the top-k renormalised and scaled) with one shared expert
+beside the routed ones.
 
 Precision follows the weights: matrix products run in the weights' dtype
 (bfloat16 as the executor ships them) with float32 accumulation; the router,
@@ -32,11 +32,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from sparkdl_tpu.core import telemetry
-from sparkdl_tpu.models.attention import causal_attention, rotary
+from sparkdl_tpu.models.attention import Rope, latent_attention
 from sparkdl_tpu.models.experts import (ExpertConfig, expert_stats,
                                         routed_experts)
 from sparkdl_tpu.models.scoring_head import program_counts, score_head
-from sparkdl_tpu.models.sequence_ops import gated_mlp, mixed_dot, rms_norm
+from sparkdl_tpu.models.sequence_ops import gated_mlp, rms_norm
 
 
 @dataclass(frozen=True)
@@ -62,37 +62,6 @@ class LatentMoEConfig:
     query_block: int = 512
 
 
-def latent_attention(p, x, c: LatentMoEConfig):
-    """x (T, hidden) float32, one window → ``((T, hidden) float32, fused)``,
-    ``fused`` as :func:`causal_attention` returns it. Each part of q, k and v
-    is its own product of the latents with its columns of the up-projection,
-    so it comes out in the layout the attention reads."""
-    T = x.shape[0]
-    act = p["q_up"].dtype
-    width = c.nope + c.rope
-    # the scores' scale is linear through q_up and the rotation: in the
-    # float32 latent it costs the queries no second rounding
-    cq = rms_norm(mixed_dot(x, p["q_down"]), p["q_norm"], c.eps
-                  ) * width ** -0.5
-    down = mixed_dot(x, p["kv_down"])
-    ckv = rms_norm(down[:, :c.kv_rank], p["kv_norm"], c.eps)
-    k_rope = rotary(down[:, c.kv_rank:], c.theta).astype(act)
-
-    def part(latent, w, lo, hi):
-        """The latent times columns lo:hi of every head's group of w."""
-        columns = w.reshape(w.shape[0], c.heads, -1)[:, :, lo:hi]
-        return mixed_dot(latent, columns.reshape(w.shape[0], -1), act)
-
-    q_rope = rotary(part(cq, p["q_up"], c.nope, width).astype(jnp.float32),
-                    c.theta, c.heads).astype(act)
-    q_rope = jnp.swapaxes(q_rope.reshape(T, c.heads, c.rope), 0, 1)
-    out, fused = causal_attention(
-        part(cq, p["q_up"], 0, c.nope), q_rope,
-        part(ckv, p["kv_up"], 0, c.nope), k_rope,
-        part(ckv, p["kv_up"], c.nope, c.nope + c.v), c.query_block)
-    return mixed_dot(out, p["out"]), fused
-
-
 @functools.partial(jax.jit, static_argnames="c")
 def block(layer, h, c: LatentMoEConfig):
     """One sandwich block over windows h (B, T, hidden) float32. Returns
@@ -104,7 +73,8 @@ def block(layer, h, c: LatentMoEConfig):
     with jax.named_scope("latent_attention"):
         attended, fused = lax.map(
             lambda row: latent_attention(
-                layer["attn"], rms_norm(row, layer["input_norm"], c.eps), c),
+                layer["attn"], rms_norm(row, layer["input_norm"], c.eps),
+                c.heads, c.eps, Rope(c.theta), c.query_block),
             h)
     h = h + rms_norm(attended, layer["post_attn_norm"], c.eps)
     x = rms_norm(h, layer["pre_mlp_norm"], c.eps)

@@ -346,6 +346,44 @@ SEQUENCE_MODELS: Dict[str, SequenceConfig] = {
                 beta_slow=1.0, amplitude=1.1386294361119891, partial=0.5)),
             ("sliding_attention", Rope(theta=100.0))),
         query_block=8),
+    # huggingface.co/inclusionAI/Ling-3.0-flash config.json (model_type
+    # bailing_hybrid): the same pre-norm stack, linear attention with the
+    # gated delta rule (KDA: taps, normed queries and keys, a decay a channel
+    # floored at -5, a gated norm on the output) where (i + 1) % 6 != 0 and
+    # latent attention where (i + 1) % 6 == 0 — no query latent, 64 of a
+    # head's 192 turned by adjacent pairs, a per-head gate —, the mixer
+    # coming with the weights; a dense gated MLP in layers 0-1, after them
+    # 512 experts of 768 in 8 groups, the top 8 taken within the 4 groups
+    # whose two best biased scores sum highest, a sigmoid router
+    # renormalised and scaled by 2.5, a shared expert of 768, the SwiGLUs
+    # clamped in layers 34-41; the head untied
+    "Ling-3.0-flash": ShortConvMoEConfig(
+        hidden=2560, heads=32, kv_heads=32, head_dim=128, dense_width=6144,
+        vocab=157184,
+        moe=ExpertConfig(expert_width=768, experts=512,
+                         experts_held=tuple(range(512)), top_k=8, scaling=2.5,
+                         norm_topk=True, topk_eps=0.0, scoring="sigmoid",
+                         groups=8, topk_group=4),
+        eps=1e-6, theta=6000000.0,
+        rope=(("latent_attention", Rope(theta=6000000.0, interleave=True)),),
+        kda_lower_bound=-5.0,
+        swiglu_limits=tuple(zip((0.0,) * 35 + (4.0,) * 7,
+                                (0.0,) * 34 + (5.0,) * 6 + (7.0,) * 2))),
+    # ... and its stack at sizes a CPU test runs: a dense layer and one
+    # period (linear, linear, linear, linear, latent, linear), 4 heads, 16
+    # experts in 4 groups, the top 4 within the best 2, and the SwiGLUs
+    # clamped in the last layer
+    "TestLing": ShortConvMoEConfig(
+        hidden=64, heads=4, kv_heads=4, head_dim=16, dense_width=128,
+        vocab=64,
+        moe=ExpertConfig(expert_width=32, experts=16,
+                         experts_held=tuple(range(16)), top_k=4, scaling=2.5,
+                         norm_topk=True, topk_eps=0.0, scoring="sigmoid",
+                         groups=4, topk_group=2),
+        eps=1e-6, theta=1000.0,
+        rope=(("latent_attention", Rope(theta=1000.0, interleave=True)),),
+        kda_lower_bound=-5.0,
+        swiglu_limits=((0.0, 0.0),) * 6 + ((1.0, 1.5),), query_block=8),
 }
 
 
@@ -362,13 +400,16 @@ def build_sequence_scorer(name, weights: Dict[str, Any], window: int,
     ``"random"``). The chip's share is read off them: as many layers as
     the list has, dense where a layer has ``"mlp"`` (every layer may be: a
     stack needs no expert layer), for a pre-norm stack the mixer by which
-    one of ``"conv"``, ``"attn"`` and ``"ssm"`` a layer has, the vocabulary
+    one of ``"conv"``, ``"attn"``, ``"ssm"``, ``"kda"`` and ``"mla"`` a
+    layer has, the vocabulary
     slice of ``embed``'s rows; ``experts_held`` names the expert ids the
     expert layers' stacked weights stand for (default: all of them, where
     all are there). Where the config names its layers' kinds the held layers
     are its leading ones, each of the kind at its position. A pre-norm
     layer's query and key heads are its weights' (columns ÷ ``head_dim``),
-    the query heads a multiple of the key heads.
+    the query heads a multiple of the key heads; a linear-attention
+    (``"kda"``) or latent-attention (``"mla"``) layer's widths are its
+    weights' too.
     """
     config = SEQUENCE_MODELS.get(name) if isinstance(name, str) else name
     label = name if isinstance(name, str) else type(config).__name__
@@ -395,10 +436,11 @@ def build_sequence_scorer(name, weights: Dict[str, Any], window: int,
                                                experts_held=experts_held)
         if isinstance(config, LatentMoEConfig):
             share.update(layers=len(layers), dense_layers=dense)
-        elif not all(sum(mixer in layer for mixer in ("conv", "attn", "ssm"))
-                     == 1 for layer in layers):
-            raise ValueError('each layer holds its one mixer: "ssm", '
-                             '"conv" or "attn"')
+        elif not all(sum(mixer in layer for mixer in (
+                "conv", "attn", "ssm", "kda", "mla")) == 1
+                for layer in layers):
+            raise ValueError('each layer holds its one mixer: "ssm", "kda", '
+                             '"mla", "conv" or "attn"')
         elif not config.d_inner and any("ssm" in layer for layer in layers):
             raise ValueError('a layer holds "ssm" and the config gives no '
                              "state-space sizes (d_inner, d_state, dt_rank, "

@@ -28,10 +28,19 @@ def mixed_dot(x, w, out=jnp.float32):
                    ).astype(out)
 
 
-def gated_mlp(p, x):
+def gated_mlp(p, x, limit=0.0):
+    """``W_down (silu(x W_gate) ⊙ x W_up)``; with a ``limit`` (a layer's
+    ``swiglu_limit``, 0: none) ``x W_gate`` is first held under it and ``x
+    W_up`` within ± it."""
     act = p["down"].dtype
-    hidden = jax.nn.silu(mixed_dot(x, p["gate"])) * mixed_dot(x, p["up"])
-    return mixed_dot(hidden.astype(act), p["down"])
+    gate = mixed_dot(x, p["gate"])
+    if limit:
+        gate = jnp.minimum(gate, limit)
+    gate = jax.nn.silu(gate)
+    up = mixed_dot(x, p["up"])
+    if limit:
+        up = jnp.clip(up, -limit, limit)
+    return mixed_dot((gate * up).astype(act), p["down"])
 
 
 def causal_taps(z, taps, bias=None):

@@ -3,14 +3,21 @@
 layers), of the ``mellum`` family (grouped-query attention in every layer,
 sliding-window layers among full ones, each kind with its own rotary), of
 the ``jamba`` family (state-space mixers with an attention layer among them,
-no rotary, no expert layer) and of the ``laguna`` family (full and sliding
+no rotary, no expert layer), of the ``laguna`` family (full and sliding
 layers with their own query heads and rotaries, a sigmoid gate on each
-head's output, a shared expert beside the routed ones), run by
-``DeepSequenceScorer`` exactly as the latent-attention model of
+head's output, a shared expert beside the routed ones) and of the
+``bailing_hybrid`` family (linear attention with the gated delta rule in
+five layers of six, latent attention in the sixth, group-limited routing),
+run by ``DeepSequenceScorer`` exactly as the latent-attention model of
 ``registry.SEQUENCE_MODELS`` is.
 
-``h ← h + mixer(RMSNorm(h))``, ``h ← h + ffn(RMSNorm(h))``. **Three mixers**,
+``h ← h + mixer(RMSNorm(h))``, ``h ← h + ffn(RMSNorm(h))``. **Five mixers**,
 by position, and a layer's weights say which it has. A layer that holds
+``"kda"`` runs **linear attention with the gated delta rule**
+(``models/linear_attention.py``), one that holds ``"mla"`` **latent
+attention** (``attention.latent_attention``: queries straight from the
+input, a per-head gate, the rotary ``rope`` gives ``"latent_attention"``,
+adjacent dimensions paired where it says so). A layer that holds
 ``"conv"`` runs the **gated short convolution**: one projection to three
 parts ``[B ; C ; x̃]``, a depthwise causal convolution of a few taps over
 ``B ⊙ x̃`` (zeros before the window: nothing outlives a launch), the gate
@@ -28,7 +35,8 @@ leading ones: where it names its layers' kinds (``layer_types``), a
 own among them, and a kind's rotary is the one ``rope`` gives it. The ffn is a gated MLP where the layer holds ``"mlp"`` —
 every layer may: **the expert layer is optional** — else the sparse expert
 layer (``models/experts.py``), with a shared expert beside the routed ones
-where the layer holds ``"shared"``.
+where the layer holds ``"shared"``, the SwiGLUs of both clamped where the
+config gives the layer's position a limit (``swiglu_limits``).
 
 Precision follows the weights: bfloat16 products with float32 accumulation;
 the router, the norms' statistics, the soft-max, the rotary tables, the gates
@@ -51,9 +59,10 @@ from jax import lax
 
 from sparkdl_tpu.core import telemetry
 from sparkdl_tpu.models.attention import (Rope, grouped_causal_attention,
-                                          rotary)
+                                          latent_attention, rotary)
 from sparkdl_tpu.models.experts import (ExpertConfig, expert_stats,
                                         routed_experts)
+from sparkdl_tpu.models.linear_attention import kda
 from sparkdl_tpu.models.scoring_head import program_counts, score_head
 from sparkdl_tpu.models.sequence_ops import (causal_taps, gated_mlp,
                                              mixed_dot, rms_norm)
@@ -94,6 +103,12 @@ class ShortConvMoEConfig:
     d_state: int = 0
     dt_rank: int = 0
     d_conv: int = 0
+    # linear attention's floor of a channel's decay a position
+    # (``kda_lower_bound``, models/linear_attention.py)
+    kda_lower_bound: float = -5.0
+    # per held layer, the routed and the shared experts' ``swiglu_limit``
+    # (0: none; layers past the tuple's end have none)
+    swiglu_limits: Tuple[Tuple[float, float], ...] = ()
     query_block: int = 512
 
 
@@ -162,17 +177,19 @@ def grouped_attention(p, u, c: ShortConvMoEConfig, kind=None):
     return mixed_dot(out, p["out"]), fused, scored
 
 
-@functools.partial(jax.jit, static_argnames=("c", "kind"))
-def block(layer, h, c: ShortConvMoEConfig, kind=None):
+@functools.partial(jax.jit, static_argnames=("c", "kind", "limits"))
+def block(layer, h, c: ShortConvMoEConfig, kind=None, limits=(0.0, 0.0)):
     """One pre-norm block over windows h (B, T, hidden) float32; ``kind`` the
     layer's published kind where ``c`` names its layers' (else the weights
-    say which mixer it is). Returns ``(h, stats, told)``: ``stats`` is None
+    say which mixer it is); ``limits`` its routed and shared experts'
+    ``swiglu_limit``. Returns ``(h, stats, told)``: ``stats`` is None
     for a dense layer; ``told`` what the mixer tells of each window, (B,)
     int32 — an attention layer ``"fused"`` and, of a named kind,
-    ``"scored_keys"``, as ``grouped_attention`` returns them; a state-space
-    layer ``"fused_scan"``, as ``state_space`` returns it — and empty for a
-    convolution. Jitted, as ``latent_moe.block`` is: the stack's layers of
-    one kind are traced and lowered once."""
+    ``"scored_keys"``, as ``grouped_attention`` returns them; a latent one
+    ``"fused"``; a state-space layer ``"fused_scan"``, as ``state_space``
+    returns it; a linear-attention layer ``"fused_linear"``, as ``kda``
+    does — and empty for a convolution. Jitted, as ``latent_moe.block`` is:
+    the stack's layers of one kind are traced and lowered once."""
     B, T, _ = h.shape
     u = rms_norm(h, layer["operator_norm"], c.eps)
     told = {}
@@ -184,6 +201,19 @@ def block(layer, h, c: ShortConvMoEConfig, kind=None):
             mixed, _, fused = state_space(layer["ssm"], u, c)
         h = h + mixed
         told = {"fused_scan": fused}
+    elif "kda" in layer:
+        with jax.named_scope("linear_attention"):
+            mixed, fused = kda(layer["kda"], u, c.eps, c.kda_lower_bound)
+        h = h + mixed
+        told = {"fused_linear": jnp.broadcast_to(fused, (B,))}
+    elif "mla" in layer:
+        rotation = dict(c.rope).get("latent_attention", Rope(c.theta))
+        with jax.named_scope("latent_attention"):
+            attended, fused = lax.map(lambda row: latent_attention(
+                layer["mla"], row, c.heads, c.eps, rotation, c.query_block),
+                u)
+        h = h + attended
+        told = {"fused": fused}
     else:
         with jax.named_scope("sliding_attention" if kind == "sliding_attention"
                              else "grouped_attention"):
@@ -199,10 +229,12 @@ def block(layer, h, c: ShortConvMoEConfig, kind=None):
                 None, told)
     flat = x.reshape(B * T, -1)
     with jax.named_scope("routed_experts"):
-        routed, moe_told = routed_experts(layer["moe"], flat, c.moe)
+        routed, moe_told = routed_experts(layer["moe"], flat, c.moe,
+                                          limits[0])
     if "shared" in layer["moe"]:
         with jax.named_scope("shared_expert"):
-            routed = routed + gated_mlp(layer["moe"]["shared"], flat)
+            routed = routed + gated_mlp(layer["moe"]["shared"], flat,
+                                        limits[1])
     return (h + routed.reshape(B, T, -1), expert_stats(moe_told, B, c.moe),
             told)
 
@@ -213,8 +245,10 @@ def forward(params, tokens, c: ShortConvMoEConfig) -> Dict[str, Any]:
     layers = params["layers"]
     kinds = c.layer_types[:len(layers)] or (None,) * len(layers)
     stats, told = [], []
-    for layer, kind in zip(layers, kinds):
-        h, layer_stats, layer_told = block(layer, h, c, kind)
+    for i, (layer, kind) in enumerate(zip(layers, kinds)):
+        limits = c.swiglu_limits[i] if i < len(c.swiglu_limits) \
+            else (0.0, 0.0)
+        h, layer_stats, layer_told = block(layer, h, c, kind, limits)
         if layer_stats is not None:
             stats.append(layer_stats)
         told.append(layer_told)
@@ -248,5 +282,12 @@ def forward(params, tokens, c: ShortConvMoEConfig) -> Dict[str, Any]:
     if any("shared" in layer.get("moe", ()) for layer in layers):
         counts[telemetry.M_MOE_SHARED_EXPERT_LAYERS] = layers_with(
             "shared", "moe")
+    if any("kda" in layer for layer in layers):
+        counts[telemetry.M_SEQUENCE_LINEAR_ATTENTION_LAYERS] = layers_with(
+            "kda")
+        counts[telemetry.M_SEQUENCE_FUSED_LINEAR_ATTENTION_LAYERS] = summed(
+            "fused_linear")
+    if c.moe is not None and c.moe.groups > 1:
+        counts[telemetry.M_MOE_GROUP_LIMITED_LAYERS] = layers_with("moe")
     out.update(program_counts(stats, tokens, counts))
     return out

@@ -50,8 +50,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from sparkdl_tpu.core import ModelFunction, TensorSpec
-from sparkdl_tpu.models import (attention, experts, latent_moe, registry,
-                                scoring_head, shortconv_moe, state_space)
+from sparkdl_tpu.models import (attention, experts, latent_moe,
+                                linear_attention, registry, scoring_head,
+                                shortconv_moe, state_space)
 from sparkdl_tpu.models.layers import ConvBN, SeparableConvBN
 
 
@@ -170,8 +171,9 @@ def test_latent_attention_compiles_to_the_fused_kernel_for_v5e(
                           jnp.bfloat16),
          "out": on_chip((c.heads * c.v, c.hidden), jnp.bfloat16)}
     x = on_chip((window, c.hidden), jnp.float32)
-    text = jax.jit(lambda p, x: latent_moe.latent_attention(p, x, c)
-                   ).lower(p, x).compile().as_text()
+    text = jax.jit(lambda p, x: attention.latent_attention(
+        p, x, c.heads, c.eps, attention.Rope(c.theta), c.query_block)
+    ).lower(p, x).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "fused_causal_attention" in text
     # the blocked path's scores, 128 heads × a block of 512 queries × keys
@@ -558,3 +560,51 @@ def test_the_combine_compiles_to_its_kernel_for_v5e(model, held, one_chip,
     assert (" gather(" in text) != kernel
     if kernel:
         assert "fused_combine" in text
+
+
+@pytest.mark.parametrize("part", ["linear_attention", "latent_attention"])
+def test_ling_model_compiles_for_v5e(part, one_chip, no_persistent_cache):
+    """Ling-3.0-flash's two mixers at the published widths and the cell's
+    launch (one window of 16,384), bfloat16 weights, shapes only: linear
+    attention, whose recurrence is the delta-rule kernel — a grid of 32
+    heads × 64 steps of 4 chunks, the 128 × 128 state on the chip
+    throughout — beside the chunked form's XLA side; and latent attention
+    with its queries straight from the input, 32 heads of 128 + 64, which is
+    the fused attention kernel."""
+    c = registry.SEQUENCE_MODELS["Ling-3.0-flash"]
+    window, wide = 16384, c.heads * c.head_dim
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if part == "linear_attention":
+        p = {**{name: on_chip((c.hidden, wide)) for name in "qkvf"},
+             **{f"{name}_taps": on_chip((wide, 4)) for name in "qkv"},
+             "dt_bias": on_chip((wide,)), "A_log": on_chip((c.heads,)),
+             "beta": on_chip((c.hidden, c.heads)),
+             "gate": on_chip((c.hidden, c.heads)),
+             "o_norm": on_chip((c.head_dim,)),
+             "out": on_chip((wide, c.hidden))}
+
+        def fn(p, x):
+            return linear_attention.kda(p, x, c.eps, c.kda_lower_bound)
+
+        x = on_chip((1, window, c.hidden), jnp.float32)
+    else:
+        p = {"q": on_chip((c.hidden, c.heads * 192)),
+             "kv_down": on_chip((c.hidden, 512 + 64)),
+             "kv_norm": on_chip((512,)),
+             "kv_up": on_chip((512, c.heads * 256)),
+             "gate": on_chip((c.hidden, c.heads)),
+             "out": on_chip((wide, c.hidden))}
+
+        def fn(p, x):
+            return attention.latent_attention(
+                p, x, c.heads, c.eps, dict(c.rope)["latent_attention"],
+                c.query_block)
+
+        x = on_chip((window, c.hidden), jnp.float32)
+    text = jax.jit(fn).lower(p, x).compile().as_text()
+    assert ":T(" in text  # tiled layouts: the TPU's compiler made this
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert ("fused_delta_rule" in text) == (part == "linear_attention")

@@ -24,7 +24,7 @@ import pytest
 from sparkdl_tpu.core import telemetry
 from sparkdl_tpu.engine.dataframe import DataFrame
 from sparkdl_tpu.ml import DeepSequenceScorer
-from sparkdl_tpu.models import (experts, latent_moe, registry,
+from sparkdl_tpu.models import (attention, experts, latent_moe, registry,
                                 scoring_head, sequence_ops)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
@@ -99,14 +99,17 @@ def test_latent_attention_alone_matches_reference(key):
     p = ref.init_layer(key, s, 0, True)["attn"]
     x = jax.random.normal(jax.random.PRNGKey(5), (WINDOW, s.hidden))
     config = dataclasses.replace(MODEL, query_block=8)
+    latent = functools.partial(
+        attention.latent_attention, heads=config.heads, eps=config.eps,
+        rotation=attention.Rope(config.theta), block=config.query_block)
     with jax.default_matmul_precision("highest"):
         want = ref.attention(p, x, s, lambda a: a, block=5)
-        got, fused = latent_moe.latent_attention(p, x, config)
+        got, fused = latent(p, x)
     assert fused == 0
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     # causal: a later token does not move an earlier one
     with jax.default_matmul_precision("highest"):
-        moved, _ = latent_moe.latent_attention(p, x.at[-1].add(1.0), config)
+        moved, _ = latent(p, x.at[-1].add(1.0))
     np.testing.assert_allclose(moved[:-1], got[:-1], rtol=1e-5, atol=1e-6)
 
 

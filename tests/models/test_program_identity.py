@@ -42,13 +42,15 @@ def _config(*parts):
 # that came before the grouped kernel's visit counts are of the program as
 # it lowered then, those two counts taken off its outputs (they are all it
 # gained since); TestLaguna's are of the program as it lowered when the
-# stacks' mechanisms moved to modules of their own, taken the same way.
+# stacks' mechanisms moved to modules of their own, taken the same way;
+# TestLing's of the program as it lowered when it came.
 STACKS = {
     "TestShortConvMoE": ("lfm2_moe", "testshortconv-windows"),
     "TestSpanMoE": ("mellum2_moe", "testspan-windows"),
     "TestStateSpace": ("jamba_ssm", "teststatespace-windows"),
     "TestLatentMoE": ("openpangu_moe", "testmoe-windows"),
     "TestLaguna": ("laguna_moe", "testlaguna-windows"),
+    "TestLing": ("ling_hybrid", "testling-windows"),
 }
 DIGESTS = {
     ("TestLatentMoE", "tpu"): "5a954e8a43953e3e",
@@ -61,6 +63,8 @@ DIGESTS = {
     ("TestStateSpace", "cpu"): "734e02ab47085d0c",
     ("TestLaguna", "tpu"): "4d48621aee1f6cb5",
     ("TestLaguna", "cpu"): "f4a34a768156b036",
+    ("TestLing", "tpu"): "2fb828ab8a941917",
+    ("TestLing", "cpu"): "768ea446b1d35e86",
 }
 
 

@@ -97,6 +97,8 @@ _SEQUENCE_STACKS = {
     "TestStateSpace": ("ShortConvMoEConfig", False, True, False),
     "Laguna-XS.2": ("ShortConvMoEConfig", True, False, True),
     "TestLaguna": ("ShortConvMoEConfig", True, False, True),
+    "Ling-3.0-flash": ("ShortConvMoEConfig", True, False, True),
+    "TestLing": ("ShortConvMoEConfig", True, False, True),
 }
 
 
