@@ -297,9 +297,9 @@ M_SEQUENCE_GATED_ATTENTION_LAYERS = "sparkdl.sequence.gated_attention_layers"
 # gated delta rule, models/linear_attention.py; from a stack that has such
 # layers)
 M_SEQUENCE_LINEAR_ATTENTION_LAYERS = "sparkdl.sequence.linear_attention_layers"
-# counter (per row: the linear-attention layers whose recurrence was lowered
-# to the kernel, models/linear_attention.py fused_delta_rule; beside the
-# former)
+# counter (per row: the linear-attention layers whose chunked form was
+# lowered to the kernel, models/linear_attention.py fused_delta_rule; beside
+# the former)
 M_SEQUENCE_FUSED_LINEAR_ATTENTION_LAYERS = \
     "sparkdl.sequence.fused_linear_attention_layers"
 M_MOE_ROUTED_TOKENS = "sparkdl.moe.routed_tokens"      # counter (tokens

@@ -566,11 +566,13 @@ def test_the_combine_compiles_to_its_kernel_for_v5e(model, held, one_chip,
 def test_ling_model_compiles_for_v5e(part, one_chip, no_persistent_cache):
     """Ling-3.0-flash's two mixers at the published widths and the cell's
     launch (one window of 16,384), bfloat16 weights, shapes only: linear
-    attention, whose recurrence is the delta-rule kernel — a grid of 32
-    heads × 64 steps of 4 chunks, the 128 × 128 state on the chip
-    throughout — beside the chunked form's XLA side; and latent attention
-    with its queries straight from the input, 32 heads of 128 + 64, which is
-    the fused attention kernel."""
+    attention, whose chunked form is the delta-rule kernel alone — a grid of
+    32 heads × 64 steps of 4 chunks that makes each chunk's terms on the
+    chip, the 128 × 128 state there throughout; no triangular solve is left
+    to XLA, and the mixer's bytes accessed are under 25 GB (37.5 GB while
+    XLA made the terms); and latent attention with its queries straight
+    from the input, 32 heads of 128 + 64, which is the fused attention
+    kernel."""
     c = registry.SEQUENCE_MODELS["Ling-3.0-flash"]
     window, wide = 16384, c.heads * c.head_dim
 
@@ -604,7 +606,11 @@ def test_ling_model_compiles_for_v5e(part, one_chip, no_persistent_cache):
                 c.query_block)
 
         x = on_chip((window, c.hidden), jnp.float32)
-    text = jax.jit(fn).lower(p, x).compile().as_text()
+    compiled = jax.jit(fn).lower(p, x).compile()
+    text = compiled.as_text()
     assert ":T(" in text  # tiled layouts: the TPU's compiler made this
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert ("fused_delta_rule" in text) == (part == "linear_attention")
+    if part == "linear_attention":
+        assert "triangular" not in text.lower()
+        assert compiled.cost_analysis()["bytes accessed"] < 25e9

@@ -6,8 +6,10 @@ within groups; the SwiGLU clamps) against the benchmark's plain reference
 (benchmarks/references/ling_hybrid.py, which imports nothing of the
 program) at the ``TestLing`` sizes on the CPU: the whole stack through the
 transformer and collect(); the chunked form against the reference's
-recurrence a position at a time; the kernel, interpreted, against the
-chunked form at a window of no whole chunks; group-limited routing against
+recurrence a position at a time; the kernel, interpreted, from the raw
+inputs against the chunked form at a window of no whole chunks, and the
+terms it makes and its blocked inverse against the chunked form's and the
+triangular solve; group-limited routing against
 a choice made by hand; the interleaved rotary against the halves' on
 permuted weights; the shares of the experts against the uncut layer; the
 clamps; the counts in telemetry; the parameter and FLOP counts."""
@@ -196,26 +198,83 @@ def test_the_chunked_form_matches_the_recurrence_a_position_at_a_time(
     assert np.isfinite(np.asarray(got)).all()
 
 
-def test_the_kernel_matches_the_chunked_form_at_no_whole_chunks():
-    """``fused_delta_rule`` interpreted, at 200 positions (three whole chunks
-    and a padded fourth, two grid steps of two chunks) and 2 heads of 128,
-    against ``scan_chunks`` over the same terms: bfloat16 operands into
-    every product, float32 accumulation and state — each output within 2 %
-    of the outputs' largest."""
-    q, k, v, g, beta = _recurrence_inputs(4, 1, 200, heads=2, d=128)
-    terms = linear_attention.chunk_terms(
-        *[a.reshape(1, 200, -1) for a in (q, k, v, g)], beta)
-    assert terms["v"].shape == (1, 2, 4, 64, 128)
-    want = linear_attention.scan_chunks(terms)
-    got = linear_attention.fused_delta_rule(terms, interpret=True)
-    assert got.dtype == jnp.bfloat16
+def _chunked_form(q, k, v, g, beta):
+    """``scan_chunks(chunk_terms(…))`` over (1, T, H · d) inputs, as (1, T,
+    H · d), and the terms."""
+    terms = linear_attention.chunk_terms(q, k, v, g, beta)
+    o = jnp.transpose(linear_attention.scan_chunks(terms), (0, 2, 3, 1, 4))
+    return o.reshape(1, -1, o.shape[3] * o.shape[4])[:, :q.shape[1]], terms
+
+
+@pytest.mark.parametrize("window,heads,decay", [
+    (200, 2, "drawn"),  # three whole chunks and a padded fourth, one step
+    (200, 2, "floor"),  # every decay at the floor: the ratios at their most
+    (384, 2, "drawn"),  # six whole chunks, three grid steps of two
+    (384, 3, "drawn"),  # three heads: a head a step
+])
+def test_the_kernel_matches_the_chunked_form_at_no_whole_chunks(window,
+                                                                heads,
+                                                                decay):
+    """``fused_delta_rule`` interpreted from the raw ``q``, ``k``, ``v``,
+    ``g``, ``β`` (heads of 128) — its terms made a step's chunks at a time
+    in the kernel, two heads a step where they divide H — against
+    ``scan_chunks`` over ``chunk_terms``: bfloat16 operands into the four
+    state products, float32 terms, accumulation and state — each output
+    within 2 % of the outputs' largest."""
+    q, k, v, g, beta = _recurrence_inputs(4, 1, window, heads=heads, d=128)
+    if decay == "floor":
+        g = jnp.full_like(g, -5.0)
+    flat = [a.reshape(1, window, -1) for a in (q, k, v, g)]
+    want, terms = _chunked_form(*flat, beta)
+    got = linear_attention.fused_delta_rule(*flat, beta, interpret=True)
+    assert got.dtype == jnp.bfloat16 and got.shape == want.shape
     scale = float(jnp.abs(want).max())
     np.testing.assert_allclose(np.asarray(got, np.float32), want,
                                atol=0.02 * scale)
-    # the state crosses every chunk: resetting it moves the last chunks
-    assert float(jnp.abs(want[:, :, 3] - linear_attention.scan_chunks(
-        {name: a[:, :, 3:] for name, a in terms.items()})[:, :, 0]).max()) \
-        > 0.1 * scale
+    if decay == "drawn":        # at the floor e^{−5 · 64} crosses nothing
+        # the state crosses every chunk: resetting it moves the last chunks
+        last = terms["v"].shape[2] - 1
+        assert float(jnp.abs(
+            linear_attention.scan_chunks(terms)[:, :, last]
+            - linear_attention.scan_chunks(
+                {name: a[:, :, last:] for name, a in terms.items()})[
+                :, :, 0]).max()) > 0.1 * scale
+
+
+@pytest.mark.parametrize("scale", [0.1, 1.0])
+def test_the_blocked_inverse_matches_the_triangular_solve(scale):
+    """``(I + A)⁻¹`` by substitution in blocks — the diagonal blocks of 16
+    doubled from 2, packed, then the block rows below them in order —, in
+    float32 for three matrices at once against
+    ``lax.linalg.triangular_solve``: within 1e-5 of the inverse's largest
+    entry."""
+    a = jnp.tril(jax.random.uniform(jax.random.PRNGKey(7), (3, 64, 64),
+                                    minval=-scale, maxval=scale), -1)
+    want = jax.lax.linalg.triangular_solve(
+        a + jnp.eye(64), jnp.broadcast_to(jnp.eye(64), a.shape),
+        left_side=True, lower=True, unit_diagonal=True)
+    got = linear_attention._lower_inverse(a)
+    np.testing.assert_allclose(got, want, atol=1e-5 * float(
+        jnp.abs(want).max()), rtol=0)
+
+
+def test_a_chunks_terms_in_the_kernel_match_the_chunked_forms():
+    """What the kernel makes of a step's chunks from their own rows
+    (``_terms_of_chunks``), float32 at full precision, against
+    ``chunk_terms``' every term for every chunk of a window of three,
+    decays at the floor in the last: 1e-5 of each term's largest."""
+    q, k, v, g, beta = _recurrence_inputs(5, 1, 192, heads=1, d=128)
+    g = g.at[:, 128:].set(-5.0)
+    terms = linear_attention.chunk_terms(
+        *[a.reshape(1, 192, -1) for a in (q, k, v, g)], beta)
+    got = linear_attention._terms_of_chunks(
+        *[a.reshape(3, 64, 128) for a in (q, k, g)], beta.reshape(3, 1, 64))
+    for name, a in got.items():
+        want = terms[name][0, 0]
+        if name == "decay":
+            a = a[..., 0]
+        np.testing.assert_allclose(a, want, rtol=0, atol=1e-5 * float(
+            jnp.abs(want).max()), err_msg=name)
 
 
 def test_kda_reads_heads_and_taps_off_the_weights(key):
